@@ -38,14 +38,18 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 # The compiled quantum loop is a large program (core + protocol + NoC +
-# sync FSMs fused into one while_loop); cold compiles run 1-3 minutes at
+# sync FSMs fused into one while_loop); cold compiles run minutes at
 # large tile counts.  Cache compilations persistently so repeat runs of
-# the same topology start in seconds.  GRAPHITE_TPU_NO_CACHE=1 opts out.
-if (not os.environ.get("GRAPHITE_TPU_NO_CACHE")
+# the same topology start in seconds.  JAX_COMPILATION_CACHE_DIR, when
+# set, places the cache and nothing is set here; otherwise it lives at
+# one fixed path inside the checkout (the path is part of the cache key,
+# so it must never move between runs).
+if (not os.environ.get("JAX_COMPILATION_CACHE_DIR")
         and jax.config.jax_compilation_cache_dir is None):
     jax.config.update(
         "jax_compilation_cache_dir",
-        os.path.join(os.path.expanduser("~"), ".cache", "graphite_tpu_xla"))
+        os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
 
 __version__ = "0.1.0"

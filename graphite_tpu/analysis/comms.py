@@ -14,7 +14,7 @@ artifacts audit/cost/identity consume (one tracing, runs on 1-device
 CPU CI — the mesh programs lower over a device-less AbstractMesh).
 Three layers:
 
-  extraction   `extract_collectives` walks every shard_map/pjit region
+  extraction   `extract_collectives` walks every shard_map/jit region
                and yields one `Collective` per collective equation
                (all_gather, ppermute, psum/pmin/pmax, all_to_all,
                reduce_scatter), each attributed to a protocol phase via
@@ -53,7 +53,7 @@ and `ici_bytes_per_iter` (`collective_metrics` — consumed by
 `cost.CostReport` and ratcheted through BUDGETS.json), the per-phase
 table `tools/audit.py --comms` emits, and the tile-axis uniformity
 dataflow (`shard_map_uniformity`) behind the replication-drift rule:
-every shard_map output whose out_names declare it replicated across
+every shard_map output whose out_specs declare it replicated across
 the tile axis must be PROVABLY uniform — no partial-axis psum leaking
 a shard-dependent value into a replicated carry slot.
 """
@@ -63,6 +63,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+from jax.extend.core import Literal
 
 from graphite_tpu.analysis.walk import (
     as_jaxpr, aval_bytes, aval_sig, call_arg_maps, iter_eqns_with_site,
@@ -465,7 +466,7 @@ def _varying_outputs(jaxpr, in_varying, tile_axes, leaks, memo,
         env[v] = bool(t)
 
     def get(v):
-        return (not isinstance(v, jax.core.Literal)) \
+        return (not isinstance(v, Literal)) \
             and env.get(v, False)
 
     for eqn in j.eqns:
@@ -595,12 +596,14 @@ def _varying_outputs(jaxpr, in_varying, tile_axes, leaks, memo,
     return mask
 
 
-def _names_have_tile(names, tile_axes) -> bool:
-    """Does one shard_map in_names/out_names entry (dim -> axis tuple)
-    mention a tile axis?"""
-    for ax_tuple in (names or {}).values():
-        axs = ax_tuple if isinstance(ax_tuple, (tuple, list)) \
-            else (ax_tuple,)
+def _spec_has_tile(spec, tile_axes) -> bool:
+    """Does one shard_map in_specs/out_specs entry (a PartitionSpec:
+    per dim None, an axis name or a tuple of names) mention a tile
+    axis?"""
+    for entry in spec or ():
+        if entry is None:
+            continue
+        axs = entry if isinstance(entry, (tuple, list)) else (entry,)
         if any(str(a) in tile_axes for a in axs):
             return True
     return False
@@ -608,7 +611,7 @@ def _names_have_tile(names, tile_axes) -> bool:
 
 def shard_map_uniformity(jaxpr, tile_axes=None) -> "list[dict]":
     """Per-shard_map uniformity audit: which outputs are DECLARED
-    replicated across the tile axis (out_names carries no tile entry)
+    replicated across the tile axis (out_specs carries no tile entry)
     but not PROVABLY uniform by the variance dataflow.  Returns one row
     per shard_map region: {"site", "n_outputs", "declared_replicated",
     "non_uniform", "leaks"} — `non_uniform` non-empty means the
@@ -620,12 +623,12 @@ def shard_map_uniformity(jaxpr, tile_axes=None) -> "list[dict]":
     for site, eqn in iter_eqns_with_site(as_jaxpr(jaxpr)):
         if eqn.primitive.name != "shard_map":
             continue
-        in_names = eqn.params.get("in_names") or ()
-        out_names = eqn.params.get("out_names") or ()
+        in_specs = eqn.params["in_specs"]
+        out_specs = eqn.params["out_specs"]
         body = eqn.params.get("jaxpr")
         if body is None:
             continue
-        in_varying = [_names_have_tile(n, tile_axes) for n in in_names]
+        in_varying = [_spec_has_tile(n, tile_axes) for n in in_specs]
         bj = as_jaxpr(body)
         # align with the body's invars (shard_map wires 1:1)
         if len(in_varying) < len(bj.invars):
@@ -634,8 +637,8 @@ def shard_map_uniformity(jaxpr, tile_axes=None) -> "list[dict]":
         out_varying = _varying_outputs(
             body, in_varying[:len(bj.invars)], tile_axes, leaks, {},
             site)
-        declared = [o for o, n in enumerate(out_names)
-                    if not _names_have_tile(n, tile_axes)]
+        declared = [o for o, n in enumerate(out_specs)
+                    if not _spec_has_tile(n, tile_axes)]
         bad = [o for o in declared
                if o < len(out_varying) and out_varying[o]]
         seen = set()
@@ -644,7 +647,7 @@ def shard_map_uniformity(jaxpr, tile_axes=None) -> "list[dict]":
             if lk not in seen:
                 seen.add(lk)
                 uniq_leaks.append({"site": lk[0], "primitive": lk[1]})
-        rows.append({"site": site, "n_outputs": len(out_names),
+        rows.append({"site": site, "n_outputs": len(out_specs),
                      "declared_replicated": declared,
                      "non_uniform": bad, "leaks": uniq_leaks})
     return rows
@@ -673,7 +676,7 @@ def gspmd_insertion_fixture(tiles: int = 8, tile_shards: int = 4):
     from graphite_tpu.parallel.mesh import TILE_AXIS_2D, _shard_map
 
     T, dt = int(tiles), int(tile_shards)
-    mesh = AbstractMesh(((TILE_AXIS_2D, dt),))
+    mesh = AbstractMesh((dt,), (TILE_AXIS_2D,))
 
     def body(mail, types, times, progress):
         # mail: replicated uint8[T, T] mailbox; types/times: the
@@ -726,7 +729,7 @@ def replication_drift_fixture(tiles: int = 8, tile_shards: int = 4,
     from graphite_tpu.parallel.mesh import TILE_AXIS_2D, _shard_map
 
     T, dt = int(tiles), int(tile_shards)
-    mesh = AbstractMesh(((TILE_AXIS_2D, dt),))
+    mesh = AbstractMesh((dt,), (TILE_AXIS_2D,))
     half = list(range(dt // 2)), list(range(dt // 2, dt))
     groups = [list(g) for g in half] if leak else None
 

@@ -46,10 +46,12 @@ import json
 import os
 
 import jax
+from jax.extend.core import Literal
 import numpy as np
 
 from graphite_tpu.analysis.walk import (
-    as_jaxpr, aval_bytes, iter_eqns, iter_eqns_with_site, subjaxprs,
+    _DIRECT_CALLS, as_jaxpr, aval_bytes, iter_eqns, iter_eqns_with_site,
+    subjaxprs,
 )
 
 
@@ -236,17 +238,13 @@ _FREE_PRIMITIVES = frozenset({
 
 # Call-like primitives whose sub-jaxpr cost IS the eqn's cost (counting
 # the call itself would double-count the body).
-_CALL_PRIMITIVES = frozenset({
-    "cond", "while", "scan", "pjit", "closed_call", "core_call",
-    "xla_call", "custom_jvp_call", "custom_vjp_call", "remat",
-    "checkpoint", "remat2",
-})
+_CALL_PRIMITIVES = frozenset({"cond", "while", "scan"}) | _DIRECT_CALLS
 
 
 def _eqn_bytes(eqn) -> "tuple[int, int]":
     """(operand bytes, result bytes) of one equation."""
     in_b = sum(aval_bytes(v.aval) for v in eqn.invars
-               if not isinstance(v, jax.core.Literal))
+               if not isinstance(v, Literal))
     out_b = sum(aval_bytes(v.aval) for v in eqn.outvars)
     return in_b, out_b
 
@@ -337,11 +335,11 @@ def peak_live_bytes(jaxpr, _memo=None) -> int:
         return _memo[id(j)]
 
     outset = {v for v in j.outvars
-              if not isinstance(v, jax.core.Literal)}
+              if not isinstance(v, Literal)}
     last: dict = {}
     for i, eqn in enumerate(j.eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, Literal):
                 last[v] = i
 
     live: dict = {}
@@ -514,7 +512,7 @@ def cost_report(spec) -> CostReport:
     arg_b = sum(aval_bytes(v.aval)
                 for v in list(j.constvars) + list(j.invars))
     out_b = sum(aval_bytes(v.aval) for v in j.outvars
-                if not isinstance(v, jax.core.Literal))
+                if not isinstance(v, Literal))
     n_total = sum(1 for _ in iter_eqns(closed))
     body = main_loop_body(closed)
     if body is not None:

@@ -15,7 +15,7 @@ Two tools, one definition of identity:
       A canonical digest of a ClosedJaxpr.  The traversal assigns
       variables alpha-renaming-invariant numbers (first-appearance
       order per scope), recurses into every sub-jaxpr (cond branches,
-      while cond/body, scan/pjit bodies), normalizes literals and
+      while cond/body, scan/jit bodies), normalizes literals and
       params (arrays hash by shape/dtype/bytes; dicts sort; callables
       reduce to their names; memory addresses are scrubbed), and
       sha256-hashes the token stream.  Two traces of the same config
@@ -42,6 +42,7 @@ import hashlib
 import re
 
 import jax
+from jax.extend.core import Literal
 import numpy as np
 
 from graphite_tpu.analysis.walk import as_jaxpr, aval_bytes, aval_sig
@@ -75,6 +76,11 @@ def _norm_param(v, emit_jaxpr) -> str:
         return repr(float(v))
     if isinstance(v, (tuple, list)):
         return "[" + ",".join(_norm_param(x, emit_jaxpr) for x in v) + "]"
+    if isinstance(v, (set, frozenset)):
+        # iteration order follows the per-process string hash seed
+        # (shard_map's manual_axes is a frozenset of axis names)
+        return "{" + ",".join(sorted(
+            _norm_param(x, emit_jaxpr) for x in v)) + "}"
     if isinstance(v, dict):
         return "{" + ",".join(
             f"{k!r}:{_norm_param(v[k], emit_jaxpr)}"
@@ -110,7 +116,7 @@ class _Canon:
         self.lines: "list[str]" = []
 
     def operand(self, v, env: dict) -> str:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             val = v.val
             if hasattr(val, "shape") or isinstance(val, np.generic):
                 return f"lit({_norm_array(val)})"
@@ -234,7 +240,7 @@ def _human_bytes(n: int) -> str:
 
 
 def _operand_token(v) -> str:
-    if isinstance(v, jax.core.Literal):
+    if isinstance(v, Literal):
         val = v.val
         if hasattr(val, "shape") and np.asarray(val).ndim:
             return f"lit({_norm_array(val)})"

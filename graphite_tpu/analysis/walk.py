@@ -1,7 +1,7 @@
 """Reusable jaxpr traversal: the program auditor's walker.
 
 jax programs arrive as nested jaxprs: `cond` carries one branch jaxpr
-per arm, `while` a cond and a body, `scan`/`pjit`/`remat`/custom-
+per arm, `while` a cond and a body, `scan`/`jit`/`remat`/custom-
 derivative calls one inner jaxpr each — and `vmap` leaves no call at
 all (batching rewrites eqns in place, which is exactly why a gated
 cond can silently become a both-branch select under it).  Every
@@ -24,7 +24,7 @@ Four layers:
    provably collision-free (an iota column survives into every row)"
    and "is this the engines' masked scratch-redirect idiom" — the
    round-11 scatter-determinism rule's analysis.  Resolution follows
-   def chains upward through cond/scan/pjit boundaries via
+   def chains upward through cond/scan/jit boundaries via
    `call_arg_maps` (loop-carried positions stay unresolved: their
    value changes across iterations).
 """
@@ -34,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+from jax.extend.core import Literal
 import numpy as np
 
 
@@ -49,7 +50,7 @@ def subjaxprs(eqn):
     """Yield (tag, Jaxpr) for every sub-jaxpr in eqn.params.
 
     Handles both ClosedJaxpr-valued params (cond branches, while
-    cond/body, scan/pjit jaxprs) and raw-Jaxpr values, singly or in
+    cond/body, scan/jit jaxprs) and raw-Jaxpr values, singly or in
     tuples/lists — the same duck-typing the primitives themselves use.
     """
     for name, val in eqn.params.items():
@@ -188,9 +189,7 @@ def call_arg_maps(eqn) -> "list[SubCall] | None":
             j, list(range(len(j.invars))),
             list(range(n_out)),
             [nc + k if k < ncar else None for k in range(n_out)])]
-    if name in ("pjit", "closed_call", "core_call", "xla_call",
-                "custom_jvp_call", "custom_vjp_call", "remat",
-                "checkpoint", "custom_vjp_call_jaxpr", "remat2"):
+    if name in _DIRECT_CALLS:
         j = p.get("jaxpr") or p.get("call_jaxpr") or p.get("fun_jaxpr")
         if j is not None and hasattr(as_jaxpr(j), "eqns"):
             return [_direct(j, eqn)]
@@ -237,17 +236,17 @@ def used_invar_mask(jaxpr, *, count_outvars=False, _memo=None) -> "list[bool]":
     used = set()
     if count_outvars:
         for v in j.outvars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, Literal):
                 used.add(v)
     for eqn in j.eqns:
         subs = call_arg_maps(eqn)
         if subs is None:
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     used.add(v)
         elif not subs:  # opaque call-like: conservatively all-used
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     used.add(v)
         else:
             for sc in subs:
@@ -257,7 +256,7 @@ def used_invar_mask(jaxpr, *, count_outvars=False, _memo=None) -> "list[bool]":
                     if u and i < len(sc.in_map) \
                             and sc.in_map[i] is not None:
                         v = eqn.invars[sc.in_map[i]]
-                        if not isinstance(v, jax.core.Literal):
+                        if not isinstance(v, Literal):
                             used.add(v)
     mask = [v in used for v in j.invars]
     _memo[key] = mask
@@ -296,7 +295,7 @@ def taint_narrowing(jaxpr, in_taint, on_finding=None, _site="",
 
     Taint propagates through value-preserving/monotone arithmetic (add,
     mul, min/max, selects, data movement, scatters, reductions) and
-    crosses call boundaries (cond/while/scan/pjit) via `call_arg_maps`,
+    crosses call boundaries (cond/while/scan/jit) via `call_arg_maps`,
     iterating loop carries to a fixpoint.  It STOPS at `TAINT_STOP` —
     a difference of two absolute clocks is a delta, which the engine
     legitimately keeps in int32 (time_types.DELTA_DTYPE).  Returns the
@@ -308,7 +307,7 @@ def taint_narrowing(jaxpr, in_taint, on_finding=None, _site="",
         env[v] = bool(t)
 
     def get(v):
-        return (not isinstance(v, jax.core.Literal)) and env.get(v, False)
+        return (not isinstance(v, Literal)) and env.get(v, False)
 
     for eqn in j.eqns:
         site = (f"{_site}.{eqn.primitive.name}" if _site
@@ -468,7 +467,7 @@ def resolve_var(var, scope: Scope):
         if sub.in_map[i] >= cn + bn:
             return None, None, 0
     outer = scope.parent_eqn.invars[sub.in_map[i]]
-    if isinstance(outer, jax.core.Literal):
+    if isinstance(outer, Literal):
         return None, None, 0
     shift = 0
     if scope.parent_eqn.primitive.name == "scan":
@@ -515,16 +514,17 @@ _DISTINCT_PASS_THROUGH = frozenset({
     "device_put",
 })
 
+# Call primitives that wire operands 1:1 into one inner jaxpr, by the
+# installed jax's names (shared with cost._CALL_PRIMITIVES).
 _DIRECT_CALLS = frozenset({
-    "pjit", "closed_call", "core_call", "xla_call", "custom_jvp_call",
-    "custom_vjp_call", "remat", "checkpoint", "remat2",
+    "jit", "closed_call", "custom_jvp_call", "custom_vjp_call", "remat2",
 })
 
 _PROVENANCE_DEPTH = 24
 
 
 def _descend_outvar(eqn, var, scope: Scope):
-    """When `var` is an output of a direct-call eqn (pjit et al — the
+    """When `var` is an output of a direct-call eqn (jit et al — the
     wrappers jnp.where/jnp.mod lowerings hide behind), step INTO the
     sub-jaxpr: returns (inner outvar, inner Scope) or None."""
     if eqn.primitive.name not in _DIRECT_CALLS:
@@ -540,7 +540,7 @@ def _descend_outvar(eqn, var, scope: Scope):
     for io, oo in enumerate(sub.out_map):
         if oo == o:
             inner = as_jaxpr(sub.jaxpr).outvars[io]
-            if isinstance(inner, jax.core.Literal):
+            if isinstance(inner, Literal):
                 return None
             return inner, make_scope(sub.jaxpr, scope, eqn, sub)
     return None
@@ -550,7 +550,7 @@ def _scalar_literal(v, scope: Scope):
     """The Python value of a scalar literal (chasing trivial
     broadcasts/converts), or None."""
     for _ in range(6):
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             val = np.asarray(v.val)
             return val.item() if val.ndim == 0 else None
         if getattr(v.aval, "shape", None) == () and v in scope.consts:
@@ -570,7 +570,7 @@ def _peel_uniform_shift(v, scope: Scope):
     shape of a wrap-fixup select arm (`t` and `t - T` share base `t`
     with shifts 0 and -T).  Returns None when `v` is a literal or the
     chain leaves the provable shape."""
-    if isinstance(v, jax.core.Literal):
+    if isinstance(v, Literal):
         return None
     shift = 0
     for _ in range(12):
@@ -589,14 +589,14 @@ def _peel_uniform_shift(v, scope: Scope):
         if name in ("add", "sub"):
             x, y = eqn.invars[0], eqn.invars[1]
             k = _scalar_literal(y, scope)
-            if k is not None and not isinstance(x, jax.core.Literal):
+            if k is not None and not isinstance(x, Literal):
                 shift += -int(k) if name == "sub" else int(k)
                 v = x
                 continue
             if name == "add":
                 k = _scalar_literal(x, scope)
                 if k is not None \
-                        and not isinstance(y, jax.core.Literal):
+                        and not isinstance(y, Literal):
                     shift += int(k)
                     v = y
                     continue
@@ -636,7 +636,7 @@ def _is_uniform_scalar(v, scope: Scope, _depth: int = 0) -> bool:
     if _depth > 12:
         return False
     while True:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             val = np.asarray(v.val)
             return val.ndim == 0 or len(np.unique(val)) == 1
         if getattr(v.aval, "shape", None) == ():
@@ -688,7 +688,7 @@ def _merge_arm_forms(forms: "list") -> "tuple | None":
 def _axis_forms(var, scope: Scope, _depth: int = 0) -> dict:
     """axis -> provenance form (see above) for `var`.  Conservative:
     a missing axis means "not provable", never "aliasing"."""
-    if _depth > _PROVENANCE_DEPTH or isinstance(var, jax.core.Literal):
+    if _depth > _PROVENANCE_DEPTH or isinstance(var, Literal):
         return {}
     while True:
         eqn = scope.defs.get(var)
@@ -720,7 +720,7 @@ def _axis_forms(var, scope: Scope, _depth: int = 0) -> dict:
         if name == "add":
             candidates.append((y, x, 1))
         for a, b, sign in candidates:
-            if isinstance(a, jax.core.Literal) \
+            if isinstance(a, Literal) \
                     or not _is_uniform_scalar(b, scope):
                 continue
             forms = _axis_forms(a, scope, _depth + 1)
@@ -792,7 +792,7 @@ def _axis_forms(var, scope: Scope, _depth: int = 0) -> dict:
                 return out
         arms = [
             _axis_forms(v, scope, _depth + 1)
-            if not isinstance(v, jax.core.Literal) else {}
+            if not isinstance(v, Literal) else {}
             for v in eqn.invars[1:]
         ]
         out = {}
@@ -857,9 +857,9 @@ def masked_index_select(var, scope: Scope, _depth: int = 0) -> bool:
     uniform scratch slot (`jnp.where(mask, word, SCRATCH)`), the
     round-9 "masked store" shape?  Such a scatter is masked BY
     CONSTRUCTION: disabled lanes all land on the dedicated slot.  The
-    detection sees through jnp's pjit-wrapped where/mod composites and
+    detection sees through jnp's jit-wrapped where/mod composites and
     the index-wrap fixup select the `.at[]` lowering adds on top."""
-    if _depth > _PROVENANCE_DEPTH or isinstance(var, jax.core.Literal):
+    if _depth > _PROVENANCE_DEPTH or isinstance(var, Literal):
         return False
     while True:
         eqn = scope.defs.get(var)
@@ -885,7 +885,7 @@ def masked_index_select(var, scope: Scope, _depth: int = 0) -> bool:
         # part concatenated next to a masked one can alias it
         got_masked = False
         for v in eqn.invars:
-            if isinstance(v, jax.core.Literal) \
+            if isinstance(v, Literal) \
                     or _is_uniform_scalar(v, scope):
                 continue
             if not masked_index_select(v, scope, _depth + 1):
@@ -898,7 +898,7 @@ def masked_index_select(var, scope: Scope, _depth: int = 0) -> bool:
     def is_uniform_arm(v):
         # a literal, a broadcast scalar, or anything else uniform:
         # every masked-off lane lands on ONE slot
-        return isinstance(v, jax.core.Literal) \
+        return isinstance(v, Literal) \
             or _is_uniform_scalar(v, scope)
 
     # select_n(pred, arm0, arm1, ...): one arm a uniform scratch slot
@@ -955,7 +955,7 @@ def scatter_writer_proof(eqn, scope: Scope) -> "str | None":
     if eqn.params.get("unique_indices"):
         return "unique-indices"
     idx = eqn.invars[1]
-    if isinstance(idx, jax.core.Literal):
+    if isinstance(idx, Literal):
         return "constant-index"
     idx_shape = tuple(getattr(idx.aval, "shape", ()) or ())
     rows = tuple(a for a in scatter_row_axes(eqn) if idx_shape[a] > 1)

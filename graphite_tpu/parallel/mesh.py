@@ -23,19 +23,11 @@ TILE_AXIS = "tiles"
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: new API (jax >= 0.5,
-    check_vma) when present, else jax.experimental.shard_map
-    (check_rep).  Both checkers are disabled for the same reason (see
-    make_shard_map_runner): control state is replicated by construction
-    and the checker cannot see it."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """jax.shard_map with the varying-manual-axes checker off: control
+    state is replicated by construction and the checker cannot see it
+    (see make_shard_map_runner)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_tile_mesh(n_devices: int | None = None, devices=None) -> Mesh:
@@ -297,7 +289,7 @@ def make_batch_tile_mesh(batch_shards: int, tile_shards: int,
     if abstract:
         from jax.sharding import AbstractMesh
 
-        return AbstractMesh(((BATCH_AXIS, db), (TILE_AXIS_2D, dt)))
+        return AbstractMesh((db, dt), (BATCH_AXIS, TILE_AXIS_2D))
     if devices is None:
         devices = jax.devices()
     if len(devices) < db * dt:

@@ -35,7 +35,7 @@ import pickle
 
 # bumped whenever the payload tuple layout changes — an old payload
 # under a new reader is an integrity error, not a crash
-PAYLOAD_FORMAT = "graphite-aot-payload-v1"
+PAYLOAD_FORMAT = "graphite-aot-payload-v2"
 
 
 def runtime_env() -> "tuple[str, str, str, str, int]":
@@ -64,21 +64,26 @@ def _fresh_codegen():
     a deserialized XLA:CPU executable silently drops the object code
     its kernels live in, so the store would publish a payload that dies
     at load with "Symbols not found".  Only a cold compile (real
-    codegen) captures every kernel symbol; `jax_compilation_cache_dir
-    = None` is the authoritative off-switch (measured: with the cache
-    dir unset the payload is byte-stable and loads every time; with it
-    set, every warm compile produces a short unloadable payload).  The
-    program store subsumes the role the XLA cache played for these
+    codegen) captures every kernel symbol.  jax initialises its cache
+    object once per process, so un-setting the directory does not stop
+    an initialised cache being consulted; `jax_enable_compilation_cache
+    = False` followed by `reset_cache()` does (the reset also clears
+    jax's memoised "is the cache used" answer).  Both are restored
+    exactly on exit, and the next compile re-opens the same directory.
+    The program store subsumes the role the XLA cache played for these
     programs anyway — one deliberate cold compile per FLEET beats a
     warm compile that cannot be shared."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    old = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
     try:
         yield
     finally:
-        jax.config.update("jax_compilation_cache_dir", old)
+        jax.config.update("jax_enable_compilation_cache", old)
+        compilation_cache.reset_cache()
 
 
 # monotonically unique per-process AOT compile names (see
@@ -125,18 +130,26 @@ def aot_compile_runner(runner, max_quanta: int):
 
 def serialize_compiled(compiled) -> bytes:
     """One self-contained payload blob for a `jax.stages.Compiled`:
-    (format tag, executable bytes, in_tree, out_tree), pickled."""
+    (format tag, executable bytes, in_tree, out_tree, device ids),
+    pickled.  The ids are the devices the executable was compiled over,
+    in assignment order: the loader must name them, or jax loads the
+    executable over EVERY device of the backend and a single-device
+    program then refuses its arguments on a multi-device host."""
     from jax.experimental import serialize_executable as se
 
     payload, in_tree, out_tree = se.serialize(compiled)
-    return pickle.dumps((PAYLOAD_FORMAT, payload, in_tree, out_tree),
-                        protocol=pickle.HIGHEST_PROTOCOL)
+    device_ids = tuple(
+        d.id for d in compiled.runtime_executable().local_devices())
+    return pickle.dumps(
+        (PAYLOAD_FORMAT, payload, in_tree, out_tree, device_ids),
+        protocol=pickle.HIGHEST_PROTOCOL)
 
 
 def deserialize_compiled(blob: bytes):
     """Load a payload blob back into a callable executable.  Raises
     `ValueError` on a foreign or malformed blob — the store maps any
     failure here to a quarantining `StoreIntegrityError`."""
+    import jax
     from jax.experimental import serialize_executable as se
 
     try:
@@ -144,9 +157,12 @@ def deserialize_compiled(blob: bytes):
     except Exception as e:
         raise ValueError(f"payload does not unpickle: "
                          f"{type(e).__name__}: {e}") from e
-    if (not isinstance(obj, tuple) or len(obj) != 4
+    if (not isinstance(obj, tuple) or len(obj) != 5
             or obj[0] != PAYLOAD_FORMAT):
         raise ValueError("payload is not a "
                          f"{PAYLOAD_FORMAT!r} blob")
-    _, payload, in_tree, out_tree = obj
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    _, payload, in_tree, out_tree, device_ids = obj
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])

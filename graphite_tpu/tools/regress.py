@@ -583,7 +583,10 @@ def smoke(tiles: int = 16) -> int:
     #     as 2D, per-device block <= budget) where a 1-device service
     #     rejects it.  Needs >= 4 devices: run in-process when the
     #     platform has them, else re-exec this rung under
-    #     XLA_FLAGS=--xla_force_host_platform_device_count=4.
+    #     XLA_FLAGS=--xla_force_host_platform_device_count=4.  The child
+    #     is forced to JAX_PLATFORMS=cpu: it is a bit-identity check that
+    #     needs no chip, so it is safe to start from this process even
+    #     when the parent holds one.
     import jax as _jax
 
     if len(_jax.devices()) >= 4:

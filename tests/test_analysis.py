@@ -49,7 +49,7 @@ def test_walker_reaches_nested_subjaxprs():
 
     closed = jax.make_jaxpr(f)(0.0, jnp.arange(3.0))
     names = {e.primitive.name for e in iter_eqns(closed)}
-    assert {"pjit", "scan", "cond"} <= names
+    assert {"jit", "scan", "cond"} <= names
 
 
 def test_used_invar_mask_sees_through_while():

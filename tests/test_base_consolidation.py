@@ -13,6 +13,7 @@ import json
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 import numpy as np
 import pytest
 
@@ -126,7 +127,7 @@ def _store_ops(closed, sig):
     for eqn in iter_eqns(closed):
         name = eqn.primitive.name
         in_sigs = [aval_sig(v.aval) for v in eqn.invars
-                   if not isinstance(v, jax.core.Literal)]
+                   if not isinstance(v, Literal)]
         if name == "gather" and in_sigs and in_sigs[0] == sig:
             gathers += 1
         if name.startswith("scatter") and in_sigs and in_sigs[0] == sig:

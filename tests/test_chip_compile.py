@@ -1,0 +1,204 @@
+"""Ask the chip's compiler, without the chip: the fused quantum-loop
+programs of the main path compiled for a DESCRIBED v5e:2x2 topology.
+
+The suite runs on the CPU, and the CPU backend accepts programs the TPU
+compiler may refuse (int64 emulation, scatter staging, HBM footprint).
+These tests lower the programs `Simulator.run()` dispatches from
+`ShapeDtypeStruct`s placed on a described device and compile them with
+the installed TPU compiler.  A compile that passes is NOT a chip run:
+nothing executes, and no time or result comes out of it.
+
+The topology is described inside the module-scoped fixture below and
+nowhere else (only one process may load the TPU library, and xdist
+workers import every test file); shardings, meshes and shapes are built
+in fixtures or tests.  The persistent compile cache is off around these
+compiles: a described-topology entry can be written but never read back
+without a chip.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+from jax.sharding import (
+    Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding,
+)
+
+from graphite_tpu.config import ConfigFile, SimConfig
+from graphite_tpu.engine.simulator import Simulator
+from graphite_tpu.tools._template import (
+    coherence_stress_workload, config_text,
+)
+from graphite_tpu.trace.benchmarks import fft_trace, radix_trace
+
+HBM_BYTES = 16 * 10**9     # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    from graphite_tpu.store.aot import _fresh_codegen
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # jax_enable_compilation_cache off + reset_cache(), restored after
+    with _fresh_codegen():
+        yield desc
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(tree, sharding):
+    """The pytree as ShapeDtypeStructs carrying `sharding` (a sharding,
+    or a same-structure pytree of them)."""
+    shardings = (sharding if not isinstance(sharding, jax.sharding.Sharding)
+                 else jax.tree.map(lambda _: sharding, tree))
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=s),
+        tree, shardings)
+
+
+def _report(name, compiled):
+    m = compiled.memory_analysis()
+    row = {"program": name,
+           "code_bytes": m.generated_code_size_in_bytes,
+           "argument_bytes": m.argument_size_in_bytes,
+           "output_bytes": m.output_size_in_bytes,
+           "alias_bytes": m.alias_size_in_bytes,
+           "temp_bytes": m.temp_size_in_bytes}
+    print(row)
+    return row
+
+
+def _fits(row):
+    live = (row["argument_bytes"] + row["output_bytes"]
+            - row["alias_bytes"] + row["temp_bytes"] + row["code_bytes"])
+    assert live < HBM_BYTES, row
+
+
+def _ref_default(tiles, points):
+    """The reference-default coherent target (iocoom, T1 caches, MSI
+    directory, hop-counter NoC, lax_barrier) on the memory-FFT trace."""
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        tiles, core="iocoom", shared_mem=True,
+        clock_scheme="lax_barrier")))
+    return Simulator(sc, fft_trace(tiles, points_per_tile=points,
+                                   use_memory=True))
+
+
+def _compile_run(sim, sharding, max_quanta=1_000_000):
+    """The single-region program `Simulator.run()` dispatches."""
+    return sim._get_runner(max_quanta).lower(
+        _shapes(sim.state, sharding)).compile()
+
+
+def _compile_host_batch(sim, sharding):
+    """The bounded per-dispatch region `barrier_host` drives."""
+    import jax.numpy as jnp
+
+    return sim._hb_get_runner().lower(
+        _shapes(sim.state, sharding),
+        jax.ShapeDtypeStruct((), jnp.int64, sharding=sharding),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)).compile()
+
+
+def test_entry_step_compiles(one_chip):
+    """`__graft_entry__.entry()`: the memoryless 64-tile quantum step."""
+    import __graft_entry__ as ge
+
+    fn, (state, qend) = ge.entry()
+    compiled = jax.jit(fn).lower(
+        _shapes(state, one_chip), _shapes(qend, one_chip)).compile()
+    _fits(_report("entry-step-64", compiled))
+
+
+def test_ref_default_16_compiles(one_chip):
+    """The reference-default coherent program at 16 tiles — the same
+    engine code as the 64-tile target, cut to tier-1 time."""
+    sim = _ref_default(16, points=16)
+    assert not sim.barrier_host
+    _fits(_report("ref-default-16", _compile_run(sim, one_chip)))
+
+
+@pytest.mark.slow
+def test_ref_default_64_compiles(one_chip):
+    sim = _ref_default(64, points=64)
+    _fits(_report("ref-default-64", _compile_run(sim, one_chip)))
+
+
+@pytest.mark.slow
+def test_hop_by_hop_256_compiles(one_chip):
+    """Graduated config 3: 256-tile emesh_hop_by_hop RADIX."""
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        256, network="emesh_hop_by_hop")))
+    sim = Simulator(sc, radix_trace(256, keys_per_tile=1024))
+    _fits(_report("hbh-256", _compile_run(sim, one_chip)))
+
+
+def _coh1024(barrier_host):
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        1024, shared_mem=True, clock_scheme="lax_barrier")))
+    return Simulator(sc, fft_trace(1024, points_per_tile=16,
+                                   use_memory=True),
+                     barrier_host=barrier_host)
+
+
+@pytest.mark.slow
+def test_coh_1024_host_batch_compiles(one_chip):
+    """1024 tiles, full directory: the bounded region the selection
+    rule picks (engine/step.barrier_host_batch)."""
+    sim = _coh1024(None)
+    assert sim.barrier_host
+    _fits(_report("coh-1024-host-batch", _compile_host_batch(sim, one_chip)))
+
+
+@pytest.mark.slow
+def test_coh_1024_single_region_compiles(one_chip):
+    """1024 tiles, full directory: the single-region lax_barrier
+    program the selection rule avoids."""
+    sim = _coh1024(False)
+    _fits(_report("coh-1024-single-region", _compile_run(sim, one_chip)))
+
+
+@pytest.mark.slow
+def test_shard_1024_compiles_over_four_chips(topo):
+    """The tile-sharded 1024-tile coherence-stress program over a Mesh
+    of the four described chips, with the shardings
+    parallel/mesh.place_shard_map would place."""
+    from graphite_tpu.parallel.mesh import (
+        TILE_AXIS, make_shard_map_runner, shard_map_state_specs,
+        shard_map_trace_specs,
+    )
+
+    mesh = Mesh(np.array(topo.devices), (TILE_AXIS,))
+    sc, batch = coherence_stress_workload(1024, n_accesses=24)
+    sim = Simulator(sc, batch)
+    def named(specs):
+        return jax.tree.map(lambda p: NamedSharding(mesh, p), specs,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    st_shapes = _shapes(sim.state,
+                        named(shard_map_state_specs(sim.state)))
+    tr_shapes = _shapes(sim.device_trace,
+                        named(shard_map_trace_specs(sim.device_trace)))
+    runner = make_shard_map_runner(
+        sim.params, sim.quantum_ps, 1_000_000, mesh, sim.state,
+        sim.device_trace)
+    compiled = runner.lower(st_shapes, tr_shapes).compile()
+    _fits(_report("shard-1024-per-device", compiled))
+    text = compiled.as_text()
+    collectives = {op: text.count(f" {op}(") for op in (
+        "all-gather", "all-reduce", "all-to-all", "collective-permute",
+        "reduce-scatter", "all-gather-start", "all-reduce-start")}
+    print({"program": "shard-1024", "collectives": collectives})
+    assert sum(collectives.values()) > 0

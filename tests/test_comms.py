@@ -32,7 +32,7 @@ TL = TILES // DT
 
 
 def _mesh():
-    return AbstractMesh(((TILE_AXIS_2D, DT),))
+    return AbstractMesh((DT,), (TILE_AXIS_2D,))
 
 
 def _lower(body, in_specs, out_specs, *args):
@@ -190,7 +190,7 @@ class TestSingleDeviceIdentity:
 
             return body
 
-        mesh1 = AbstractMesh(((TILE_AXIS_2D, 1),))
+        mesh1 = AbstractMesh((1,), (TILE_AXIS_2D,))
         ctx1 = ParallelCtx(axis=TILE_AXIS_2D, n_dev=1)
         fn1 = _shard_map(body_for(ctx1), mesh=mesh1,
                          in_specs=(P(),), out_specs=P())
@@ -200,7 +200,7 @@ class TestSingleDeviceIdentity:
             closed1, n_tiles=TILES,
             axis_env=comms.mesh_axis_sizes(closed1)) == []
 
-        mesh2 = AbstractMesh(((TILE_AXIS_2D, 2),))
+        mesh2 = AbstractMesh((2,), (TILE_AXIS_2D,))
         ctx2 = ParallelCtx(axis=TILE_AXIS_2D, n_dev=2)
         fn2 = _shard_map(body_for(ctx2), mesh=mesh2,
                          in_specs=(P(),), out_specs=P())

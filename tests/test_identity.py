@@ -19,6 +19,7 @@ import json
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr
 import pytest
 
 from graphite_tpu.analysis import cost, identity, registry
@@ -133,6 +134,14 @@ class TestFingerprint:
         assert any("v0:" in ln for ln in lines)
         assert all("0x" not in ln for ln in lines)
 
+    def test_set_params_are_hash_seed_free(self):
+        """A set-valued param (shard_map's `manual_axes`) renders in
+        sorted order: set iteration follows the per-process string hash
+        seed, which would make the fingerprint differ between
+        processes — and the store is keyed by it."""
+        tok = identity._norm_param(frozenset({"tile", "batch"}), None)
+        assert tok == "{'batch','tile'}"
+
 
 # ---------------------------------------------------------------------------
 # eqn-count divergences carry the containing phase (round-20 fix)
@@ -216,7 +225,7 @@ class TestEqnCountPhaseAttribution:
             e if i != k else eqn.replace(
                 params={**eqn.params, "branches": br + (br[0],)})
             for i, e in enumerate(j.eqns)])
-        c2 = jax.core.ClosedJaxpr(grown, c.consts)
+        c2 = ClosedJaxpr(grown, c.consts)
         d = identity.structural_diff(c, c2, n_tiles=PC_TILES,
                                      phase_names=PHASE_NAMES)
         assert d is not None and d.kind == "eqn-count"
