@@ -23,6 +23,8 @@ from graphite_tpu.engine.state import DeviceTrace, SimState, init_state
 from graphite_tpu.engine.step import EngineParams
 from graphite_tpu.models.dvfs import module_freq_mhz
 from graphite_tpu.models.network_user import UserNetworkParams
+from graphite_tpu.obs.scopes import tagged
+from graphite_tpu.obs.trace import NO_SPANS, RunSpans
 from graphite_tpu.time_types import cycles_to_ps, ns_to_ps, ps_to_ns
 from graphite_tpu.trace.schema import STATIC_COST_KEYS, Op, TraceBatch
 
@@ -808,6 +810,15 @@ class Simulator:
         # observability: wall / iterations = the engine's per-iteration
         # cost, the number PERF.md's floor analysis tracks)
         self.last_n_iterations = 0
+        # launch counters (plain ints, always on): programs the drive loop
+        # launched — by the last COMPLETED run() (either path; run_chunk
+        # and warmup leave it alone), and by every run / run_chunk /
+        # run_streamed of this instance so far
+        self.last_run_dispatches = 0
+        self.n_dispatches = 0
+        # host span tracing of the drive loop (attach_tracer); None runs
+        # the loop with no span, no annotation and no extra device sync
+        self.tracer = None
         self._runner = None
         self._runner_max_quanta = None
         self._hb_runner = None
@@ -842,6 +853,39 @@ class Simulator:
             self.attach_dvfs(dvfs)
         if hist is not None:
             self.attach_hist(hist)
+
+    def attach_tracer(self, tracer) -> None:
+        """Attach (or, with None, detach) an `obs.Tracer`: every later
+        `run()` / `run_chunk()` / `run_streamed()` records one `run-<n>`
+        trace (or the caller's `trace_id`) of `obs.trace.RUN_SPANS`,
+        each also a `jax.profiler.TraceAnnotation("gt:<name>")`.  Host
+        side only: no program changes and results are bit-equal.  With
+        a tracer the loop adds ONE `block_until_ready` per dispatch (the
+        `wait` span), so that waiting for the device is told apart from
+        copying its results."""
+        self.tracer = tracer
+
+    def _spans(self, trace_id=None):
+        if self.tracer is None:
+            return NO_SPANS
+        return RunSpans(self.tracer, trace_id)
+
+    def compiled_text(self, max_quanta: int = 1_000_000) -> str:
+        """Optimized HLO text of the program `run()` dispatches: each
+        instruction with the `op_name` path (`obs/scopes.py` names) it
+        was compiled with.  After `warmup()` or a run this re-reads the
+        jit's own executable (no compile).  Single-device and GSPMD
+        programs only."""
+        if self.spmd == "shard_map":
+            raise ValueError("compiled_text() does not cover shard_map "
+                             "runners")
+        if self.barrier_host:
+            lowered = self._hb_get_runner().lower(
+                self.state, jnp.asarray(0, jnp.int64),
+                jnp.asarray(1, jnp.int32))
+        else:
+            lowered = self._get_runner(max_quanta).lower(self.state)
+        return lowered.compile().as_text()
 
     def attach_telemetry(self, spec) -> None:
         """Attach (or replace) a telemetry spec on a not-yet-run
@@ -1201,20 +1245,31 @@ class Simulator:
             args = (self.state, self.device_trace)
         return fn, args
 
-    def run_chunk(self, n_quanta: int):
+    def run_chunk(self, n_quanta: int, *, trace_id=None):
         """Run at most `n_quanta` quanta (for sampled/checkpointed runs).
 
         Returns (done, quanta_executed).  Unlike run(), hitting the bound
         is not an error — the caller samples/checkpoints and continues.
         """
+        span = self._spans(trace_id)
+        with span("run", call="run_chunk"):
+            return self._run_chunk(n_quanta, span)
+
+    def _run_chunk(self, n_quanta: int, span):
         if self.barrier_host:
-            nq, all_done = self._host_barrier_loop(n_quanta)
+            nq, all_done = self._host_barrier_loop(n_quanta, span)
             return all_done, nq
-        state, n_quanta_dev, deadlock_dev, n_iters = self._get_runner(
-            n_quanta)(self.state)
-        nq, deadlock, overflow, done, self.last_n_iterations = (
-            jax.device_get((n_quanta_dev, deadlock_dev, state.net.overflow,
-                            state.done, n_iters)))
+        with span("dispatch", parent="run"):
+            state, n_quanta_dev, deadlock_dev, n_iters = self._get_runner(
+                n_quanta)(self.state)
+            self.n_dispatches += 1
+        if span.on:
+            with span("wait", parent="dispatch"):
+                jax.block_until_ready((n_quanta_dev, deadlock_dev, n_iters))
+        with span("fetch", parent="wait"):
+            nq, deadlock, overflow, done, self.last_n_iterations = (
+                jax.device_get((n_quanta_dev, deadlock_dev,
+                                state.net.overflow, state.done, n_iters)))
         if bool(overflow):
             raise MailboxOverflowError(
                 "a (dst,src) mailbox ring overflowed; re-run with a "
@@ -1227,7 +1282,7 @@ class Simulator:
         self.state = state
         return bool(done.all()), int(nq)
 
-    def _run_host_barrier(self, max_quanta: int) -> SimResults:
+    def _run_host_barrier(self, max_quanta: int, span) -> SimResults:
         """lax_barrier quanta driven host-side (see run()): one compiled
         BOUNDED multi-quantum region per dispatch (`barrier_host_batch` —
         a device-side while_loop over up to `barrier_batch` quanta, no
@@ -1240,10 +1295,12 @@ class Simulator:
         loop early-exits to the host on host-visible work (all done,
         mailbox overflow, deadlock), so each host round trip is
         amortized over up to K quanta instead of one."""
-        n, all_done = self._host_barrier_loop(max_quanta)
+        before = self.n_dispatches
+        n, all_done = self._host_barrier_loop(max_quanta, span)
         if not all_done:
             raise RuntimeError(f"exceeded max_quanta={max_quanta}")
-        return self._results_from_state(n)
+        self.last_run_dispatches = self.n_dispatches - before
+        return self._results_from_state(n, span)
 
     def _hb_get_runner(self):
         if self._hb_runner is None:
@@ -1262,30 +1319,41 @@ class Simulator:
                                           profile=prof, dvfs=dv, hist=hs)
 
             self._hb_runner = jax.jit(
-                qrun, donate_argnums=(0,) if self.donate else ())
+                tagged(qrun), donate_argnums=(0,) if self.donate else ())
         return self._hb_runner
 
-    def _host_barrier_loop(self, max_quanta: int):
+    def _host_barrier_loop(self, max_quanta: int, span=NO_SPANS):
         """Run up to max_quanta host-driven barrier quanta in batches of
         `barrier_batch` per dispatch; returns (quanta_executed,
         all_done).  Mutates self.state.  The budget rides as a DYNAMIC
         operand, so run_chunk-style partial budgets never recompile and
-        never overshoot."""
-        import jax.numpy as jnp
+        never overshoot.  Each batch is one `dispatch` / `wait` /
+        `fetch` triple of `span`."""
 
         runner = self._hb_get_runner()
         state = self.state
         prev_qend = jnp.asarray(0, jnp.int64)
         n = 0
         total_iters = 0
+        batch = 0
         done = jax.device_get(state.done)
         while n < max_quanta and not done.all():
             budget = min(self.barrier_batch, max_quanta - n)
-            state, prev_qend, nq_d, deadlock_d, iters_d = runner(
-                state, prev_qend, jnp.asarray(budget, jnp.int32))
-            nq, deadlock, iters, done, overflow = jax.device_get(
-                (nq_d, deadlock_d, iters_d, state.done,
-                 state.net.overflow))
+            with span("dispatch", parent="run", batch=batch):
+                state, prev_qend, nq_d, deadlock_d, iters_d = runner(
+                    state, prev_qend, jnp.asarray(budget, jnp.int32))
+                self.n_dispatches += 1
+            if span.on:
+                with span("wait", parent="dispatch", batch=batch):
+                    jax.block_until_ready((nq_d, deadlock_d, iters_d))
+            with span("fetch", parent="wait", batch=batch) as fetched:
+                nq, deadlock, iters, done, overflow = jax.device_get(
+                    (nq_d, deadlock_d, iters_d, state.done,
+                     state.net.overflow))
+                if fetched is not None:
+                    fetched.attrs.update(quanta=int(nq),
+                                         iterations=int(iters))
+            batch += 1
             n += int(nq)
             total_iters += int(iters)
             if bool(overflow):
@@ -1384,20 +1452,24 @@ class Simulator:
                     edges=self.hist_spec.bucket_edges(),
                     counts=np.asarray(buf), boundaries=int(boundaries))
 
-    def _results_from_state(self, n_quanta: int) -> SimResults:
+    def _results_from_state(self, n_quanta: int,
+                            span=NO_SPANS) -> SimResults:
         """SimResults from the CURRENT state (after run_chunk loops)."""
         state = self.state
         (net_part, mem_part, ioc_part, tel_part, prof_part,
          hist_part) = self._result_parts(state)
-        core_h, net_h, mem_h, ioc_h, tel_h, prof_h, hist_h = \
-            jax.device_get((
-                state.core, net_part, mem_part, ioc_part, tel_part,
-                prof_part, hist_part,
-            ))
-        return self._results_host(core_h, net_h, mem_h, n_quanta, ioc_h,
-                                  telemetry=self._timeline_host(tel_h),
-                                  profile=self._profile_host(prof_h),
-                                  hist=self._hist_host(hist_h))
+        with span("fetch", parent="run"):
+            core_h, net_h, mem_h, ioc_h, tel_h, prof_h, hist_h = \
+                jax.device_get((
+                    state.core, net_part, mem_part, ioc_part, tel_part,
+                    prof_part, hist_part,
+                ))
+        with span("results", parent="fetch"):
+            return self._results_host(
+                core_h, net_h, mem_h, n_quanta, ioc_h,
+                telemetry=self._timeline_host(tel_h),
+                profile=self._profile_host(prof_h),
+                hist=self._hist_host(hist_h))
 
     def write_output(self, results: SimResults,
                      output_dir: str = "results") -> str:
@@ -1417,7 +1489,8 @@ class Simulator:
 
     def run_streamed(self, window_records: int = STREAM_WINDOW_RECORDS,
                      max_quanta: int = 1_000_000,
-                     max_windows: int = 1_000_000) -> SimResults:
+                     max_windows: int = 1_000_000, *,
+                     trace_id=None) -> SimResults:
         """Like run(), but the trace streams host->HBM in [T, W] windows
         (the schema's promised streaming mode — `trace/schema.py`; the
         reference analog is Pin's continuous instruction pipe,
@@ -1433,8 +1506,17 @@ class Simulator:
         (every lane one full window ahead — the lockstep case) is staged
         with an async upload while the device crunches, overlapping
         transfer with compute.
+
+        Traced like run() (`attach_tracer`): per window one `dispatch` /
+        `wait` / `fetch`, and `refill` for each window placement.
         """
-        W = int(window_records)
+        span = self._spans(trace_id)
+        with span("run", call="run_streamed"):
+            return self._run_streamed(int(window_records), max_quanta,
+                                      max_windows, span)
+
+    def _run_streamed(self, W: int, max_quanta: int, max_windows: int,
+                      span) -> SimResults:
         batch = self.trace_batch
 
         # mesh runs shard each [T, W] window on upload (row t of every
@@ -1462,8 +1544,9 @@ class Simulator:
         first_window = None
         if self.spmd == "shard_map":
             bases0 = np.zeros(batch.n_tiles, np.int32)
-            first_window = place(DeviceTrace.window(batch, bases0, W),
-                                 bases0)
+            with span("refill", parent="run", window=0):
+                first_window = place(DeviceTrace.window(batch, bases0, W),
+                                     bases0)
             runner = _streamed_runner(
                 self.params, self.quantum_ps, max_quanta, self.mesh,
                 self.spmd, self.state, first_window[0])
@@ -1473,29 +1556,41 @@ class Simulator:
 
         bases = np.zeros(batch.n_tiles, np.int32)
         state = self.state
-        window, dev_bases = (
-            first_window if first_window is not None
-            else place(DeviceTrace.window(batch, bases, W), bases))
+        if first_window is not None:
+            window, dev_bases = first_window
+        else:
+            with span("refill", parent="run", window=0):
+                window, dev_bases = place(
+                    DeviceTrace.window(batch, bases, W), bases)
         prefetch_bases = None
         prefetch = None
         prefetch_on = True  # lockstep so far; first miss turns it off
         n_quanta = 0
-        for _ in range(max_windows):
-            out = runner(state, window, dev_bases)
+        for w in range(max_windows):
+            with span("dispatch", parent="run", window=w):
+                out = runner(state, window, dev_bases)
+                self.n_dispatches += 1
             # overlap: stage the lockstep-guess window during the run —
             # only while every slide so far matched the guess (a skewed
             # run would rebuild + re-upload a discarded window each slide)
             guess = bases + W
             if prefetch_on and (guess < batch.length).any():
                 prefetch_bases = guess
-                prefetch = place(DeviceTrace.window(batch, guess, W), guess)
+                with span("refill", parent="dispatch", window=w + 1,
+                          prefetch=True):
+                    prefetch = place(DeviceTrace.window(batch, guess, W),
+                                     guess)
             else:
                 prefetch_bases = None
             state, nq_dev, deadlock_dev, n_iters_dev = out
-            done, idx, deadlock, overflow = jax.device_get(
-                (state.done, state.core.idx, deadlock_dev,
-                 state.net.overflow))
-            n_quanta += int(nq_dev)
+            if span.on:
+                with span("wait", parent="dispatch", window=w):
+                    jax.block_until_ready((nq_dev, deadlock_dev))
+            with span("fetch", parent="wait", window=w):
+                done, idx, deadlock, overflow = jax.device_get(
+                    (state.done, state.core.idx, deadlock_dev,
+                     state.net.overflow))
+                n_quanta += int(nq_dev)
             if bool(overflow):
                 raise MailboxOverflowError(
                     "a (dst,src) mailbox ring overflowed; re-run with a "
@@ -1519,13 +1614,16 @@ class Simulator:
                    and np.array_equal(prefetch_bases, bases))
             if not hit:
                 prefetch_on = False
-            window, dev_bases = (
-                prefetch if hit
-                else place(DeviceTrace.window(batch, bases, W), bases))
+            if hit:
+                window, dev_bases = prefetch
+            else:
+                with span("refill", parent="fetch", window=w + 1):
+                    window, dev_bases = place(
+                        DeviceTrace.window(batch, bases, W), bases)
         else:
             raise RuntimeError(f"exceeded max_windows={max_windows}")
         self.state = state
-        return self._results_from_state(n_quanta)
+        return self._results_from_state(n_quanta, span)
 
     def warmup(self, max_quanta: int = 1_000_000) -> None:
         """Compile (and execute once, discarding results) the full runner —
@@ -1590,7 +1688,8 @@ class Simulator:
         self._runner_max_quanta = other._runner_max_quanta
         self._hb_runner = other._hb_runner
 
-    def run(self, max_quanta: int = 1_000_000) -> SimResults:
+    def run(self, max_quanta: int = 1_000_000, *,
+            trace_id=None) -> SimResults:
         """Drive quanta until every tile's trace is exhausted.
 
         The whole quantum loop runs on device as one compiled region
@@ -1609,21 +1708,36 @@ class Simulator:
         combination) the barrier loop runs host-side instead — identical
         quantum semantics, one bounded compiled region per `barrier_batch`
         quanta (early-exiting on host-visible work).
+
+        With a tracer attached (`attach_tracer`) the call records one
+        trace, `run-<n>` or the caller's `trace_id`: `run` > `dispatch`
+        > `wait` > `fetch` > `results` (obs/trace.py: RUN_SPANS).
         """
-        if self.barrier_host:
-            return self._run_host_barrier(max_quanta)
-        state, n_quanta_dev, deadlock_dev, n_iters = self._get_runner(
-            max_quanta)(self.state)
+        span = self._spans(trace_id)
+        with span("run", call="run"):
+            if self.barrier_host:
+                return self._run_host_barrier(max_quanta, span)
+            return self._run_one_region(max_quanta, span)
+
+    def _run_one_region(self, max_quanta: int, span) -> SimResults:
+        with span("dispatch", parent="run"):
+            state, n_quanta_dev, deadlock_dev, n_iters = self._get_runner(
+                max_quanta)(self.state)
+            self.n_dispatches += 1
+        if span.on:
+            with span("wait", parent="dispatch"):
+                jax.block_until_ready((n_quanta_dev, deadlock_dev, n_iters))
         # ONE batched device→host fetch for control flags + all summary
         # counters + the telemetry ring (each separate read is one more
         # host round trip).
         (net_part, mem_part, ioc_part, tel_part, prof_part,
          hist_part) = self._result_parts(state)
-        host = jax.device_get((
-            n_quanta_dev, deadlock_dev, state.net.overflow, state.done,
-            state.core, net_part, mem_part, ioc_part, tel_part,
-            prof_part, hist_part, n_iters,
-        ))
+        with span("fetch", parent="wait"):
+            host = jax.device_get((
+                n_quanta_dev, deadlock_dev, state.net.overflow, state.done,
+                state.core, net_part, mem_part, ioc_part, tel_part,
+                prof_part, hist_part, n_iters,
+            ))
         (n_quanta, deadlock, overflow, done, core_h, net_h, mem_h,
          ioc_h, tel_h, prof_h, hist_h, self.last_n_iterations) = host
         if bool(overflow):
@@ -1640,10 +1754,13 @@ class Simulator:
         if not bool(done.all()):
             raise RuntimeError(f"exceeded max_quanta={max_quanta}")
         self.state = state
-        return self._results_host(core_h, net_h, mem_h, int(n_quanta), ioc_h,
-                                  telemetry=self._timeline_host(tel_h),
-                                  profile=self._profile_host(prof_h),
-                                  hist=self._hist_host(hist_h))
+        self.last_run_dispatches = 1
+        with span("results", parent="fetch"):
+            return self._results_host(
+                core_h, net_h, mem_h, int(n_quanta), ioc_h,
+                telemetry=self._timeline_host(tel_h),
+                profile=self._profile_host(prof_h),
+                hist=self._hist_host(hist_h))
 
     def _results_host(self, core, net_h, mem_h, n_quanta: int,
                       ioc_h=None, telemetry=None,

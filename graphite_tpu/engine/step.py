@@ -49,6 +49,7 @@ from graphite_tpu.intmath import nn_mod
 
 from graphite_tpu.engine.state import SimState, DeviceTrace
 from graphite_tpu.models.network_user import UserNetworkParams, route_latency_ps
+from graphite_tpu.obs.scopes import scope, tagged
 from graphite_tpu.parallel.px import IDENT, ParallelCtx
 from graphite_tpu.trace.schema import (
     FLAG_BRANCH_TAKEN,
@@ -148,6 +149,7 @@ def _elect_min(mask, gid, key, n_groups):
 
 
 
+@scope("gt.core")
 def subquantum_iteration(
     params: EngineParams,
     trace: DeviceTrace,
@@ -208,36 +210,37 @@ def subquantum_iteration(
     # have diverged (blocked on sync/messages).  Under a sharded px the
     # trace and bp_bits rows are block-local: the reads below see only
     # this device's lanes and ONE packed all-gather replicates them.
-    gather_fields = (trace.op, trace.flags, trace.pc, trace.aux0, trace.aux1,
-                     trace.dyn_ps) + (
-        (trace.addr0, trace.addr1) if params.mem is not None else ()) + (
-        (trace.rreg0, trace.rreg1, trace.wreg)
-        if params.iocoom is not None else ())
-    uniform = jnp.all(idx == idx[0])
-    idx_l = px.lo(idx)
+    with scope("gt.fetch"):
+        gather_fields = (trace.op, trace.flags, trace.pc, trace.aux0, trace.aux1,
+                         trace.dyn_ps) + (
+            (trace.addr0, trace.addr1) if params.mem is not None else ()) + (
+            (trace.rreg0, trace.rreg1, trace.wreg)
+            if params.iocoom is not None else ())
+        uniform = jnp.all(idx == idx[0])
+        idx_l = px.lo(idx)
 
-    def _read_uniform(_):
-        return tuple(
-            lax.dynamic_slice_in_dim(f, idx[0], 1, axis=1)[:, 0]
-            for f in gather_fields
-        )
+        def _read_uniform(_):
+            return tuple(
+                lax.dynamic_slice_in_dim(f, idx[0], 1, axis=1)[:, 0]
+                for f in gather_fields
+            )
 
-    def _read_gather(_):
-        return tuple(_gather_field(f, idx_l) for f in gather_fields)
+        def _read_gather(_):
+            return tuple(_gather_field(f, idx_l) for f in gather_fields)
 
-    fetched_l = lax.cond(uniform, _read_uniform, _read_gather, None)
-    # branch prediction reads ride the same exchange (bp_bits block-local)
-    bp_index_l = nn_mod(fetched_l[2], params.bp_size).astype(jnp.int32)
-    bp_pred_l = jnp.take_along_axis(
-        core.bp_bits, bp_index_l[:, None], axis=1)[:, 0]
-    agd = px.ag(fetched_l + (bp_pred_l,))
-    fetched, bp_pred = agd[:-1], agd[-1]
-    op = fetched[0].astype(jnp.int32)
-    flags = fetched[1].astype(jnp.int32)
-    pc = fetched[2]
-    aux0 = fetched[3]
-    aux1 = fetched[4]
-    dyn_ps = fetched[5]
+        fetched_l = lax.cond(uniform, _read_uniform, _read_gather, None)
+        # branch prediction reads ride the same exchange (bp_bits block-local)
+        bp_index_l = nn_mod(fetched_l[2], params.bp_size).astype(jnp.int32)
+        bp_pred_l = jnp.take_along_axis(
+            core.bp_bits, bp_index_l[:, None], axis=1)[:, 0]
+        agd = px.ag(fetched_l + (bp_pred_l,))
+        fetched, bp_pred = agd[:-1], agd[-1]
+        op = fetched[0].astype(jnp.int32)
+        flags = fetched[1].astype(jnp.int32)
+        pc = fetched[2]
+        aux0 = fetched[3]
+        aux1 = fetched[4]
+        dyn_ps = fetched[5]
 
     enabled = state.models_enabled
     stream_end = (op == Op.NOP) | (op == Op.THREAD_EXIT)
@@ -312,23 +315,24 @@ def subquantum_iteration(
         # per-call miss-fill events only materialize when the histograms
         # ask for them — fill_events=False keeps MemStepOut leaf-free and
         # the hist-off trace byte-identical (PROGRAMS.lock fingerprints)
-        fill_ev = hist is not None
-        if params.mem_gate and not px.sharded:
-            need_mem = state.mem.live | jnp.any(
-                active & slots_present(mem_p, rec, enabled).any(axis=1))
-            mem_out = lax.cond(
-                need_mem,
-                lambda _: engine_step(mem_p, state.mem, rec,
-                                      core.clock_ps, core.freq_mhz,
-                                      active, enabled,
-                                      fill_events=fill_ev),
-                lambda _: mem_idle_out(mem_p, state.mem, rec, enabled,
-                                       fill_events=fill_ev),
-                None)
-        else:
-            mem_out = engine_step(
-                mem_p, state.mem, rec, core.clock_ps, core.freq_mhz,
-                active, enabled, px=px, fill_events=fill_ev)
+        with scope("gt.mem.base"):
+            fill_ev = hist is not None
+            if params.mem_gate and not px.sharded:
+                need_mem = state.mem.live | jnp.any(
+                    active & slots_present(mem_p, rec, enabled).any(axis=1))
+                mem_out = lax.cond(
+                    need_mem,
+                    lambda _: engine_step(mem_p, state.mem, rec,
+                                          core.clock_ps, core.freq_mhz,
+                                          active, enabled,
+                                          fill_events=fill_ev),
+                    lambda _: mem_idle_out(mem_p, state.mem, rec, enabled,
+                                           fill_events=fill_ev),
+                    None)
+            else:
+                mem_out = engine_step(
+                    mem_p, state.mem, rec, core.clock_ps, core.freq_mhz,
+                    active, enabled, px=px, fill_events=fill_ev)
         mem_state = mem_out.ms
         mem_ok = mem_out.mem_complete
         mem_acc_ps = mem_out.acc_ps
@@ -411,26 +415,27 @@ def subquantum_iteration(
 
     # --- SEND + RECV: (dst, src) mailbox rings ---------------------------
     def _net_block(_):
-        if params.user_hbh is not None:
-            from graphite_tpu.models.network_hop_by_hop import route_hop_by_hop
-            from graphite_tpu.models.network_user import user_packet_bits
+        with scope("gt.net.route"):
+            if params.user_hbh is not None:
+                from graphite_tpu.models.network_hop_by_hop import route_hop_by_hop
+                from graphite_tpu.models.network_user import user_packet_bits
 
-            noc_user, arrival_ps, _, _ = route_hop_by_hop(
-                params.user_hbh, state.noc_user, tiles, dst,
-                user_packet_bits(aux1), core.clock_ps, send_now, enabled)
-            lat_ps = arrival_ps - core.clock_ps
-        elif params.user_atac is not None:
-            from graphite_tpu.models.network_atac import route_atac
-            from graphite_tpu.models.network_user import user_packet_bits
+                noc_user, arrival_ps, _, _ = route_hop_by_hop(
+                    params.user_hbh, state.noc_user, tiles, dst,
+                    user_packet_bits(aux1), core.clock_ps, send_now, enabled)
+                lat_ps = arrival_ps - core.clock_ps
+            elif params.user_atac is not None:
+                from graphite_tpu.models.network_atac import route_atac
+                from graphite_tpu.models.network_user import user_packet_bits
 
-            noc_user, arrival_ps, _ = route_atac(
-                params.user_atac, state.noc_user, tiles, dst,
-                user_packet_bits(aux1), core.clock_ps, send_now, enabled)
-            lat_ps = arrival_ps - core.clock_ps
-        else:
-            noc_user = state.noc_user
-            lat_ps = route_latency_ps(params.net, tiles, dst, aux1, enabled)
-            arrival_ps = core.clock_ps + lat_ps
+                noc_user, arrival_ps, _ = route_atac(
+                    params.user_atac, state.noc_user, tiles, dst,
+                    user_packet_bits(aux1), core.clock_ps, send_now, enabled)
+                lat_ps = arrival_ps - core.clock_ps
+            else:
+                noc_user = state.noc_user
+                lat_ps = route_latency_ps(params.net, tiles, dst, aux1, enabled)
+                arrival_ps = core.clock_ps + lat_ps
         slot = nn_mod(net.head[dst, tiles], D).astype(jnp.int32)
         # Write under mask: redirect masked-off lanes to their own (t, t)
         # cell at a dummy slot; since each lane writes a distinct src
@@ -494,10 +499,11 @@ def subquantum_iteration(
                 state.noc_user, jnp.zeros((T,), jnp.bool_),
                 jnp.full((T,), FAR_FUTURE_PS, I64), jnp.zeros((T,), jnp.int32))
 
-    (time_ps_new, lat_arr_new, head_new, count_new, overflow, noc_user,
-     recv_now, recv_time, recv_lat) = lax.cond(
-        _gate(jnp.any(send_now | (active & is_recv))), _net_block, _net_skip,
-        None)
+    with scope("gt.net.mailbox"):
+        (time_ps_new, lat_arr_new, head_new, count_new, overflow, noc_user,
+         recv_now, recv_time, recv_lat) = lax.cond(
+            _gate(jnp.any(send_now | (active & is_recv))), _net_block, _net_skip,
+            None)
     recv_wait_ps = jnp.maximum(recv_time - core.clock_ps, 0)
     recv_wait_ps = jnp.where(recv_now, recv_wait_ps, 0)
 
@@ -565,11 +571,12 @@ def subquantum_iteration(
                 jnp.zeros((T,), jnp.bool_), jnp.zeros((T,), jnp.bool_),
                 jnp.zeros((T,), I64))
 
-    (barrier_count, barrier_arrived, barrier_time, barrier_waiting,
-     released, release_time, barrier_gen, barrier_release_ps,
-     barrive_now, bsync_now, bsync_time) = lax.cond(
-        _gate(jnp.any(active & (is_binit | is_bwait | is_barrive | is_bsync))),
-        _barrier_block, _barrier_skip, None)
+    with scope("gt.sync.barrier"):
+        (barrier_count, barrier_arrived, barrier_time, barrier_waiting,
+         released, release_time, barrier_gen, barrier_release_ps,
+         barrive_now, bsync_now, bsync_time) = lax.cond(
+            _gate(jnp.any(active & (is_binit | is_bwait | is_barrive | is_bsync))),
+            _barrier_block, _barrier_skip, None)
     barrier_wait_ps = jnp.maximum(release_time - core.clock_ps, 0)
     barrier_wait_ps = jnp.where(released, barrier_wait_ps, 0)
     bsync_wait_ps = jnp.where(
@@ -766,15 +773,16 @@ def subquantum_iteration(
                 sync.cond_sig_time_ps, sync.cond_bcast_time_ps,
                 jnp.zeros((T,), jnp.bool_))
 
-    (mutex_locked, mutex_owner, mutex_time, mutex_waiting, granted,
-     mutex_wait_ps, cond_waiting, cond_signaled, cond_arrival_ps,
-     cond_wake_ps, cond_sig_time_ps, cond_bcast_time_ps,
-     cond_post_commit) = lax.cond(
-        _gate(jnp.any((active & (is_minit | is_munlock | is_csig
-                               | is_cbcast | is_cinit))
-                      | (is_mlock & ~done & (sync.mutex_waiting | active))
-                      | (is_cwait & ~done))),
-        _mutex_cond_block, _mutex_cond_skip, None)
+    with scope("gt.sync.mutex_cond"):
+        (mutex_locked, mutex_owner, mutex_time, mutex_waiting, granted,
+         mutex_wait_ps, cond_waiting, cond_signaled, cond_arrival_ps,
+         cond_wake_ps, cond_sig_time_ps, cond_bcast_time_ps,
+         cond_post_commit) = lax.cond(
+            _gate(jnp.any((active & (is_minit | is_munlock | is_csig
+                                   | is_cbcast | is_cinit))
+                          | (is_mlock & ~done & (sync.mutex_waiting | active))
+                          | (is_cwait & ~done))),
+            _mutex_cond_block, _mutex_cond_skip, None)
 
     # --- published cond signals + COND_JOIN (co-located split form) ------
     # A publishing signal/broadcast bumps the cond's signal sequence and
@@ -810,12 +818,13 @@ def subquantum_iteration(
         cjoin_t = seq_ps[cid, (aux1 % GEN_RING).astype(jnp.int32)]
         return seq, seq_ps, cjoin_now, cjoin_t
 
-    (cond_sig_seq, cond_sig_seq_ps, cjoin_now, cjoin_time) = lax.cond(
-        _gate(jnp.any(pub_now | (active & is_cjoin))),
-        _pub_block,
-        lambda _: (sync.cond_sig_seq, sync.cond_sig_seq_ps,
-                   jnp.zeros((T,), jnp.bool_), jnp.zeros((T,), I64)),
-        None)
+    with scope("gt.sync.mutex_cond"):
+        (cond_sig_seq, cond_sig_seq_ps, cjoin_now, cjoin_time) = lax.cond(
+            _gate(jnp.any(pub_now | (active & is_cjoin))),
+            _pub_block,
+            lambda _: (sync.cond_sig_seq, sync.cond_sig_seq_ps,
+                       jnp.zeros((T,), jnp.bool_), jnp.zeros((T,), I64)),
+            None)
     cjoin_wait_ps = jnp.where(
         cjoin_now, jnp.maximum(cjoin_time - core.clock_ps, 0), 0)
 
@@ -835,9 +844,10 @@ def subquantum_iteration(
         join_time = jnp.maximum(core.clock_ps, core.clock_ps[join_target])
         return join_now, join_time
 
-    join_now, join_time = lax.cond(
-        _gate(jnp.any(active & is_join)), _join_block,
-        lambda _: (jnp.zeros((T,), jnp.bool_), core.clock_ps), None)
+    with scope("gt.sync.join"):
+        join_now, join_time = lax.cond(
+            _gate(jnp.any(active & is_join)), _join_block,
+            lambda _: (jnp.zeros((T,), jnp.bool_), core.clock_ps), None)
 
     # --- commit: advance mask, clocks, counters --------------------------
     # Instruction records with memory operands commit only once all their
@@ -971,8 +981,9 @@ def subquantum_iteration(
                 out = out + (jnp.zeros((T, ND), jnp.bool_),)
             return out
 
-        dvfs_out = lax.cond(
-            jnp.any(active & is_dvfs_set), _dvfs_block, _dvfs_skip, None)
+        with scope("gt.dvfs"):
+            dvfs_out = lax.cond(
+                jnp.any(active & is_dvfs_set), _dvfs_block, _dvfs_skip, None)
         (dv_freq, dv_volt, dv_errs, dvfs_core_set, dvfs_req) = dvfs_out[:5]
         new_dvfs = state.dvfs.replace(
             freq_mhz=dv_freq, voltage_mv=dv_volt, errors=dv_errs)
@@ -981,12 +992,13 @@ def subquantum_iteration(
                 core_freq_tiles, elect_domains,
             )
 
-            new_rt = elect_domains(dvp, state.dvfs_rt, dvfs_req,
-                                   dvfs_out[5])
-            # chip-global CORE domain: the elected frequency broadcasts
-            # to every tile (the per-tile table above stays the legacy
-            # get/set view)
-            freq_mhz = core_freq_tiles(dvp, new_rt, core.freq_mhz)
+            with scope("gt.dvfs"):
+                new_rt = elect_domains(dvp, state.dvfs_rt, dvfs_req,
+                                       dvfs_out[5])
+                # chip-global CORE domain: the elected frequency broadcasts
+                # to every tile (the per-tile table above stays the legacy
+                # get/set view)
+                freq_mhz = core_freq_tiles(dvp, new_rt, core.freq_mhz)
         else:
             freq_mhz = jnp.where(
                 dvfs_core_set, dvfs_req.astype(core.freq_mhz.dtype),
@@ -1023,13 +1035,14 @@ def subquantum_iteration(
         # dynamic column slice instead of a per-row gather; the gather
         # runs when lanes diverged or the slice would clamp at the edge
         ok_uniform = uniform & (idx[0] + 1 + KX <= trace.length)
-        ops_x_l = lax.cond(
-            ok_uniform,
-            lambda _: lax.dynamic_slice_in_dim(
-                trace.op, idx[0] + 1, KX, axis=1),
-            lambda _: jnp.take_along_axis(trace.op, pos_l, axis=1),
-            None)
-        ops_x = px.ag(ops_x_l).astype(jnp.int32)
+        with scope("gt.fetch"):
+            ops_x_l = lax.cond(
+                ok_uniform,
+                lambda _: lax.dynamic_slice_in_dim(
+                    trace.op, idx[0] + 1, KX, axis=1),
+                lambda _: jnp.take_along_axis(trace.op, pos_l, axis=1),
+                None)
+            ops_x = px.ag(ops_x_l).astype(jnp.int32)
         valid = (idx[:, None] + offs[None, :]) < trace.length
         plain = valid & (ops_x <= int(Op.MFENCE)) & (
             ops_x != int(Op.BRANCH))
@@ -1085,15 +1098,16 @@ def subquantum_iteration(
                 miss_now=mem_out.fill_now & enabled,
                 miss_lat_ps=mem_out.fill_lat_ps,
             )
-        new_hist = hist_commit_update(
-            hist, state.hist,
-            advance=advance, enabled=enabled,
-            recv_now=recv_now, recv_lat_ps=recv_lat,
-            recv_charged=recv_charged, recv_wait_ps=recv_wait_ps,
-            sync_charged=sync_charged,
-            sync_wait_ps=(barrier_wait_ps + mutex_wait_ps
-                          + bsync_wait_ps + cjoin_wait_ps),
-            px=px, **mem_kw)
+        with scope("gt.obs"):
+            new_hist = hist_commit_update(
+                hist, state.hist,
+                advance=advance, enabled=enabled,
+                recv_now=recv_now, recv_lat_ps=recv_lat,
+                recv_charged=recv_charged, recv_wait_ps=recv_wait_ps,
+                sync_charged=sync_charged,
+                sync_wait_ps=(barrier_wait_ps + mutex_wait_ps
+                              + bsync_wait_ps + cjoin_wait_ps),
+                px=px, **mem_kw)
 
     new_core = core.replace(
         clock_ps=clock,
@@ -1244,8 +1258,9 @@ def _quantum_loop(params, trace, state, qend, trace_base=None, px=IDENT,
             # flush per block is already the cheap case.
             from graphite_tpu.memory.engine import dir_stage_flush
 
-            state = state.replace(mem=state.mem.replace(
-                directory=dir_stage_flush(state.mem.directory)))
+            with scope("gt.mem.stage_flush"):
+                state = state.replace(mem=state.mem.replace(
+                    directory=dir_stage_flush(state.mem.directory)))
         return state, progress
 
     def cond(carry):
@@ -1393,31 +1408,35 @@ def run_simulation(
             # reactive governor: step the governed domains' V/f level on
             # the utilization window — masked arithmetic only (the
             # telemetry_tick pattern), evaluated at the quantum boundary
-            rt2 = governor_tick(dvfs.governor, params.dvfs,
-                                st2.dvfs_rt, st2)
-            st2 = st2.replace(
-                dvfs_rt=rt2,
-                core=st2.core.replace(freq_mhz=core_freq_tiles(
-                    params.dvfs, rt2, st2.core.freq_mhz)))
+            with scope("gt.dvfs"):
+                rt2 = governor_tick(dvfs.governor, params.dvfs,
+                                    st2.dvfs_rt, st2)
+                st2 = st2.replace(
+                    dvfs_rt=rt2,
+                    core=st2.core.replace(freq_mhz=core_freq_tiles(
+                        params.dvfs, rt2, st2.core.freq_mhz)))
         if telemetry is not None:
-            st2 = st2.replace(telemetry=telemetry_tick(
-                telemetry, st2, progress=progress, blk_iters=blk_iters,
-                dvfs=dvfs_energy))
+            with scope("gt.obs"):
+                st2 = st2.replace(telemetry=telemetry_tick(
+                    telemetry, st2, progress=progress, blk_iters=blk_iters,
+                    dvfs=dvfs_energy))
         if profile is not None:
             # same boundary arithmetic as the telemetry tick — with
             # equal intervals XLA CSEs the shared scalar reductions, so
             # the two rings cost one boundary test per quantum; under a
             # tile-sharded px the [S, T, m] ring is block-local and the
             # tick appends only this device's lanes (obs/profile.py)
-            st2 = st2.replace(profile=profile_tick(profile, st2, px=px,
-                                                   dvfs=dvfs_energy))
+            with scope("gt.obs"):
+                st2 = st2.replace(profile=profile_tick(profile, st2, px=px,
+                                                       dvfs=dvfs_energy))
         if hist is not None:
             # boundary sources sample EVERY executed quantum (each one
             # is a whole-fleet skew observation — the four-scheme
             # study's instrument); under a tile-sharded px the per-tile
             # ring appends only this device's lanes (obs/hist.py)
-            st2 = st2.replace(hist=hist_boundary_tick(hist, st2, px=px,
-                                                      dvfs=dvfs_energy))
+            with scope("gt.obs"):
+                st2 = st2.replace(hist=hist_boundary_tick(hist, st2, px=px,
+                                                          dvfs=dvfs_energy))
         # Zero progress: if some non-done tile sits beyond qend (it crossed
         # the boundary executing one long record), jump the window up to it
         # — blocked peers may wait on its future sends.  Only when every
@@ -1447,10 +1466,11 @@ def run_simulation(
             stalled = zero & paused
         return st2, qend_next, n + 1, deadlock, stalled, iters + blk_iters
 
-    state, _, n_quanta, deadlock, _, n_iters = lax.while_loop(
-        cond, body,
-        (state, jnp.asarray(0, I64), jnp.asarray(0, jnp.int32),
-         jnp.asarray(False), jnp.asarray(False), jnp.asarray(0, jnp.int64)))
+    with scope("gt.quantum"):
+        state, _, n_quanta, deadlock, _, n_iters = lax.while_loop(
+            cond, body,
+            (state, jnp.asarray(0, I64), jnp.asarray(0, jnp.int32),
+             jnp.asarray(False), jnp.asarray(False), jnp.asarray(0, jnp.int64)))
     return state, n_quanta, deadlock, n_iters
 
 
@@ -1521,22 +1541,26 @@ def barrier_host_batch(
         st2, progress, blk_iters = _quantum_loop(params, trace, st, qend,
                                                  dvfs=dvfs, hist=hist)
         if dvfs is not None and dvfs.governor is not None:
-            rt2 = governor_tick(dvfs.governor, params.dvfs,
-                                st2.dvfs_rt, st2)
-            st2 = st2.replace(
-                dvfs_rt=rt2,
-                core=st2.core.replace(freq_mhz=core_freq_tiles(
-                    params.dvfs, rt2, st2.core.freq_mhz)))
+            with scope("gt.dvfs"):
+                rt2 = governor_tick(dvfs.governor, params.dvfs,
+                                    st2.dvfs_rt, st2)
+                st2 = st2.replace(
+                    dvfs_rt=rt2,
+                    core=st2.core.replace(freq_mhz=core_freq_tiles(
+                        params.dvfs, rt2, st2.core.freq_mhz)))
         if telemetry is not None:
-            st2 = st2.replace(telemetry=telemetry_tick(
-                telemetry, st2, progress=progress, blk_iters=blk_iters,
-                dvfs=dvfs_energy))
+            with scope("gt.obs"):
+                st2 = st2.replace(telemetry=telemetry_tick(
+                    telemetry, st2, progress=progress, blk_iters=blk_iters,
+                    dvfs=dvfs_energy))
         if profile is not None:
-            st2 = st2.replace(profile=profile_tick(profile, st2,
-                                                   dvfs=dvfs_energy))
+            with scope("gt.obs"):
+                st2 = st2.replace(profile=profile_tick(profile, st2,
+                                                       dvfs=dvfs_energy))
         if hist is not None:
-            st2 = st2.replace(hist=hist_boundary_tick(hist, st2,
-                                                      dvfs=dvfs_energy))
+            with scope("gt.obs"):
+                st2 = st2.replace(hist=hist_boundary_tick(hist, st2,
+                                                          dvfs=dvfs_energy))
         zero = (progress == 0) & jnp.any(~st2.done)
         ahead_clock = jnp.min(jnp.where(
             ~st2.done & (st2.core.clock_ps >= qend),
@@ -1549,10 +1573,11 @@ def barrier_host_batch(
         deadlock = zero & ~have_ahead
         return st2, qend_next, n + 1, deadlock, iters + blk_iters
 
-    state, prev_qend, n, deadlock, iters = lax.while_loop(
-        cond, body,
-        (state, jnp.asarray(prev_qend, I64), jnp.asarray(0, jnp.int32),
-         jnp.asarray(False), jnp.asarray(0, jnp.int64)))
+    with scope("gt.quantum"):
+        state, prev_qend, n, deadlock, iters = lax.while_loop(
+            cond, body,
+            (state, jnp.asarray(prev_qend, I64), jnp.asarray(0, jnp.int32),
+             jnp.asarray(False), jnp.asarray(0, jnp.int64)))
     return state, prev_qend, n, deadlock, iters
 
 
@@ -1570,4 +1595,4 @@ def make_simulation_runner(params: EngineParams, trace: DeviceTrace,
                               telemetry=telemetry, profile=profile,
                               dvfs=dvfs, hist=hist)
 
-    return jax.jit(run, donate_argnums=(0,) if donate else ())
+    return jax.jit(tagged(run), donate_argnums=(0,) if donate else ())

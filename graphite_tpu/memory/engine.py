@@ -73,6 +73,7 @@ from graphite_tpu.memory.state import (
     PHASE_IDLE, PHASE_WAIT_REPLY,
     MemState,
 )
+from graphite_tpu.obs.scopes import scope
 from graphite_tpu.parallel.px import IDENT, ParallelCtx
 from graphite_tpu.time_types import cycles_to_ps
 from graphite_tpu.trace.schema import (
@@ -224,6 +225,7 @@ def _req_consume(mail, use_pop, r_col):
                                            mail.req_type))
 
 
+@scope("gt.net.route")
 def mem_net_latency_ps(mp: MemParams, src, dst, bits: int, enabled):
     """MEMORY-network zero-load latency (`network_model_emesh_hop_counter.cc`
     + receive serialization `network_model.cc:119-149`; ATAC zero-load
@@ -248,6 +250,7 @@ def mem_net_latency_ps(mp: MemParams, src, dst, bits: int, enabled):
     return cycles_to_ps(cycles, mp.net_freq_mhz)
 
 
+@scope("gt.net.route")
 def mem_net_send(mp: MemParams, noc, src, dst, bits, t0_ps, mask, enabled):
     """Unicast a coherence message through the MEMORY network.
 
@@ -277,6 +280,7 @@ def mem_net_send(mp: MemParams, noc, src, dst, bits, t0_ps, mask, enabled):
     return noc, arrival_ps
 
 
+@scope("gt.net.route")
 def mem_net_fanout(mp: MemParams, noc, send_hs, bits: int, t0_ps, enabled):
     """A home's INV/FLUSH/WB multicast through the MEMORY network.
 
@@ -1695,10 +1699,11 @@ def memory_engine_step(
     # predicate covers the whole unrolled block
     pred1 = jnp.any(active & (ms.req.phase == PHASE_IDLE)
                     & (next_present(ms.req.slot) < 3))
-    if gate:
-        ms, p = _cond_nodir(pred1, _phase_requester, ms)
-    else:
-        ms, p = _phase_requester(ms)
+    with scope("gt.mem." + PHASE_NAMES[0]):
+        if gate:
+            ms, p = _cond_nodir(pred1, _phase_requester, ms)
+        else:
+            ms, p = _phase_requester(ms)
     progress = progress + p
 
     # ======================================================================
@@ -1744,11 +1749,12 @@ def memory_engine_step(
 
     pred2 = (ms.mail.evict_type != MSG_NONE).any()
     view2 = ws.view(0, eline0, packs) if consolidate else None
-    p = _run_dir_phase(
-        pred2,
-        lambda m, a: _home_evictions(
-            mp, m, dir_access_ps, enabled, jnp.zeros((), jnp.int32),
-            px, acc=a, dsv=view2))
+    with scope("gt.mem." + PHASE_NAMES[1]):
+        p = _run_dir_phase(
+            pred2,
+            lambda m, a: _home_evictions(
+                mp, m, dir_access_ps, enabled, jnp.zeros((), jnp.int32),
+                px, acc=a, dsv=view2))
     progress = progress + p
 
     # ======================================================================
@@ -1757,29 +1763,31 @@ def memory_engine_step(
     pred3 = ((ms.mail.req_type != MSG_NONE).any()
              | (ms.txn.saved_valid & ~ms.txn.active).any())
     view3 = ws.view(1, rline0, list(packs)) if consolidate else None
-    p = _run_dir_phase(
-        pred3,
-        lambda m, a: _home_starts(
-            mp, m, dram_lat_ps, dir_access_ps, sync_dir_l2,
-            sync_dir_net, enabled, jnp.zeros((), jnp.int32), px,
-            acc=a, dsv=view3))
+    with scope("gt.mem." + PHASE_NAMES[2]):
+        p = _run_dir_phase(
+            pred3,
+            lambda m, a: _home_starts(
+                mp, m, dram_lat_ps, dir_access_ps, sync_dir_l2,
+                sync_dir_net, enabled, jnp.zeros((), jnp.int32), px,
+                acc=a, dsv=view3))
     progress = progress + p
 
     # ======================================================================
     # (4) sharers consume one FWD per iteration
     # ======================================================================
     pred4 = (ms.mail.fwd_type != MSG_NONE).any()
-    if gate:
-        ms, p = _cond_nodir(
-            pred4,
-            lambda m: _sharer_step(mp, m, fmhz, enabled,
-                                   jnp.zeros((), jnp.int32),
-                                   sync_l2_net, sync_l1d_l2, px),
-            ms)
-    else:
-        ms, p = _sharer_step(mp, ms, fmhz, enabled,
-                             jnp.zeros((), jnp.int32),
-                             sync_l2_net, sync_l1d_l2, px)
+    with scope("gt.mem." + PHASE_NAMES[3]):
+        if gate:
+            ms, p = _cond_nodir(
+                pred4,
+                lambda m: _sharer_step(mp, m, fmhz, enabled,
+                                       jnp.zeros((), jnp.int32),
+                                       sync_l2_net, sync_l1d_l2, px),
+                ms)
+        else:
+            ms, p = _sharer_step(mp, ms, fmhz, enabled,
+                                 jnp.zeros((), jnp.int32),
+                                 sync_l2_net, sync_l1d_l2, px)
     progress = progress + p
 
     # ======================================================================
@@ -1788,11 +1796,12 @@ def memory_engine_step(
     pred5 = (ms.mail.ack_type != MSG_NONE).any() | ms.txn.active.any()
     view5 = (ws.view_finish(ms.txn.line, list(packs))
              if consolidate else None)
-    p = _run_dir_phase(
-        pred5,
-        lambda m, a: _home_acks_and_finish(
-            mp, m, dram_lat_ps, dir_access_ps, enabled,
-            jnp.zeros((), jnp.int32), px, acc=a, dsv=view5))
+    with scope("gt.mem." + PHASE_NAMES[4]):
+        p = _run_dir_phase(
+            pred5,
+            lambda m, a: _home_acks_and_finish(
+                mp, m, dram_lat_ps, dir_access_ps, enabled,
+                jnp.zeros((), jnp.int32), px, acc=a, dsv=view5))
     progress = progress + p
     if consolidate:
         # the ONE merged scatter per big store for this iteration
@@ -1809,16 +1818,17 @@ def memory_engine_step(
     # even when the whole miss started in phase 1 of this same call
     slot_pre6 = ms.req.slot
     acc_pre6 = ms.req.acc_ps
-    if gate:
-        ms, p = _cond_nodir(
-            pred6,
-            lambda m: _requester_fill(mp, m, rec, clock_ps, fmhz, enabled,
-                                      jnp.zeros((), jnp.int32),
-                                      sync_l2_net, px),
-            ms)
-    else:
-        ms, p = _requester_fill(mp, ms, rec, clock_ps, fmhz, enabled,
-                                jnp.zeros((), jnp.int32), sync_l2_net, px)
+    with scope("gt.mem." + PHASE_NAMES[5]):
+        if gate:
+            ms, p = _cond_nodir(
+                pred6,
+                lambda m: _requester_fill(mp, m, rec, clock_ps, fmhz, enabled,
+                                          jnp.zeros((), jnp.int32),
+                                          sync_l2_net, px),
+                ms)
+        else:
+            ms, p = _requester_fill(mp, ms, rec, clock_ps, fmhz, enabled,
+                                    jnp.zeros((), jnp.int32), sync_l2_net, px)
     progress = progress + p
 
     # ---- completion signal ----------------------------------------------

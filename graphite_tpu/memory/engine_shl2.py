@@ -63,6 +63,7 @@ from graphite_tpu.memory.state import (
     PHASE_IDLE, PHASE_WAIT_REPLY,
     MemCounters, MemMailboxes, RequesterState, init_mem_common,
 )
+from graphite_tpu.obs.scopes import scope
 from graphite_tpu.parallel.px import IDENT, ParallelCtx
 from graphite_tpu.time_types import cycles_to_ps
 from graphite_tpu.trace.schema import (
@@ -525,58 +526,62 @@ def shl2_engine_step(
     # (only the fill phase returns a lane to PHASE_IDLE)
     pred1 = jnp.any(active & (ms.req.phase == PHASE_IDLE)
                     & (next_present(ms.req.slot) < 3))
-    if gate:
-        ms, p = _cond_nodir(pred1, _phase_requester, ms)
-    else:
-        ms, p = _phase_requester(ms)
+    with scope("gt.mem." + SHL2_PHASE_NAMES[0]):
+        if gate:
+            ms, p = _cond_nodir(pred1, _phase_requester, ms)
+        else:
+            ms, p = _phase_requester(ms)
     progress = progress + p
 
     # ======================================================================
     # (2) L1 sharers serve INV/FLUSH/WB from homes
     # ======================================================================
     pred2 = (ms.mail.fwd_type != MSG_NONE).any()
-    if gate:
-        ms, p = _cond_nodir(
-            pred2,
-            lambda m: _sharer_step(mp, m, fmhz, enabled,
-                                   jnp.zeros((), jnp.int32),
-                                   sync_l1_net, px),
-            ms)
-    else:
-        ms, p = _sharer_step(mp, ms, fmhz, enabled,
-                             jnp.zeros((), jnp.int32), sync_l1_net, px)
+    with scope("gt.mem." + SHL2_PHASE_NAMES[1]):
+        if gate:
+            ms, p = _cond_nodir(
+                pred2,
+                lambda m: _sharer_step(mp, m, fmhz, enabled,
+                                       jnp.zeros((), jnp.int32),
+                                       sync_l1_net, px),
+                ms)
+        else:
+            ms, p = _sharer_step(mp, ms, fmhz, enabled,
+                                 jnp.zeros((), jnp.int32), sync_l1_net, px)
     progress = progress + p
 
     # ======================================================================
     # (3) homes consume L1 evictions (directory + L2 dirty fill)
     # ======================================================================
     pred3 = (ms.mail.evict_type != MSG_NONE).any()
-    if gate:
-        ms, p = _cond_dir(
-            pred3,
-            lambda m, a: _home_evictions(mp, m, l2_access, enabled,
-                                         jnp.zeros((), jnp.int32), px,
-                                         acc=a),
-            ms, T, px)
-    else:
-        ms, p = _home_evictions(mp, ms, l2_access, enabled,
-                                jnp.zeros((), jnp.int32), px)
+    with scope("gt.mem." + SHL2_PHASE_NAMES[2]):
+        if gate:
+            ms, p = _cond_dir(
+                pred3,
+                lambda m, a: _home_evictions(mp, m, l2_access, enabled,
+                                             jnp.zeros((), jnp.int32), px,
+                                             acc=a),
+                ms, T, px)
+        else:
+            ms, p = _home_evictions(mp, ms, l2_access, enabled,
+                                    jnp.zeros((), jnp.int32), px)
     progress = progress + p
 
     # ======================================================================
     # (4) homes consume acks / dram arrivals, finish transactions
     # ======================================================================
     pred4 = (ms.mail.ack_type != MSG_NONE).any() | ms.txn.active.any()
-    if gate:
-        ms, p = _cond_dir(
-            pred4,
-            lambda m, a: _home_finish(mp, m, l2_access, sync_l2_net,
-                                      enabled, jnp.zeros((), jnp.int32),
-                                      mesi, px, acc=a),
-            ms, T, px)
-    else:
-        ms, p = _home_finish(mp, ms, l2_access, sync_l2_net, enabled,
-                             jnp.zeros((), jnp.int32), mesi, px)
+    with scope("gt.mem." + SHL2_PHASE_NAMES[3]):
+        if gate:
+            ms, p = _cond_dir(
+                pred4,
+                lambda m, a: _home_finish(mp, m, l2_access, sync_l2_net,
+                                          enabled, jnp.zeros((), jnp.int32),
+                                          mesi, px, acc=a),
+                ms, T, px)
+        else:
+            ms, p = _home_finish(mp, ms, l2_access, sync_l2_net, enabled,
+                                 jnp.zeros((), jnp.int32), mesi, px)
     progress = progress + p
 
     # ======================================================================
@@ -584,16 +589,17 @@ def shl2_engine_step(
     # ======================================================================
     pred5 = ((ms.mail.req_type != MSG_NONE).any()
              | (ms.txn.saved_valid & ~ms.txn.active).any())
-    if gate:
-        ms, p = _cond_dir(
-            pred5,
-            lambda m, a: _home_starts(mp, m, l2_access, sync_l2_net,
-                                      enabled, jnp.zeros((), jnp.int32),
-                                      mesi, px, acc=a),
-            ms, T, px)
-    else:
-        ms, p = _home_starts(mp, ms, l2_access, sync_l2_net, enabled,
-                             jnp.zeros((), jnp.int32), mesi, px)
+    with scope("gt.mem." + SHL2_PHASE_NAMES[4]):
+        if gate:
+            ms, p = _cond_dir(
+                pred5,
+                lambda m, a: _home_starts(mp, m, l2_access, sync_l2_net,
+                                          enabled, jnp.zeros((), jnp.int32),
+                                          mesi, px, acc=a),
+                ms, T, px)
+        else:
+            ms, p = _home_starts(mp, ms, l2_access, sync_l2_net, enabled,
+                                 jnp.zeros((), jnp.int32), mesi, px)
     progress = progress + p
 
     # ======================================================================
@@ -607,16 +613,17 @@ def shl2_engine_step(
     # engine.MemStepOut.fill_now)
     slot_pre6 = ms.req.slot
     acc_pre6 = ms.req.acc_ps
-    if gate:
-        ms, p = _cond_nodir(
-            pred6,
-            lambda m: _requester_fill(mp, m, rec, clock_ps, fmhz, enabled,
-                                      jnp.zeros((), jnp.int32),
-                                      sync_l1_net, px),
-            ms)
-    else:
-        ms, p = _requester_fill(mp, ms, rec, clock_ps, fmhz, enabled,
-                                jnp.zeros((), jnp.int32), sync_l1_net, px)
+    with scope("gt.mem." + SHL2_PHASE_NAMES[5]):
+        if gate:
+            ms, p = _cond_nodir(
+                pred6,
+                lambda m: _requester_fill(mp, m, rec, clock_ps, fmhz, enabled,
+                                          jnp.zeros((), jnp.int32),
+                                          sync_l1_net, px),
+                ms)
+        else:
+            ms, p = _requester_fill(mp, ms, rec, clock_ps, fmhz, enabled,
+                                    jnp.zeros((), jnp.int32), sync_l1_net, px)
     progress = progress + p
 
     final_slot = next_present(ms.req.slot)

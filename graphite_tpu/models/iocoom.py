@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from graphite_tpu.obs.scopes import scope
 from graphite_tpu.time_types import cycles_to_ps
 from graphite_tpu.trace.schema import (
     FLAG_MEM0_VALID, FLAG_MEM0_WRITE, FLAG_MEM1_VALID, FLAG_MEM1_WRITE,
@@ -126,6 +127,7 @@ def _ring_set(arr, idx, val, mask):
     return jnp.where(m, val[:, None], arr)
 
 
+@scope("gt.core.iocoom")
 def iocoom_commit(
     p: IocoomParams,
     ioc: IocoomState,

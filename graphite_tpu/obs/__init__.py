@@ -95,7 +95,8 @@ from graphite_tpu.obs.profile import (  # noqa: F401
     init_profile, profile_from_state, profile_tick,
 )
 from graphite_tpu.obs.trace import (  # noqa: F401
-    JOB_SPANS, Span, TERMINAL_SPANS, Tracer, job_breakdown, load_jsonl,
+    JOB_SPANS, RUN_SPANS, Span, TERMINAL_SPANS, Tracer, job_breakdown,
+    load_jsonl,
 )
 
 __all__ = [
@@ -115,6 +116,7 @@ __all__ = [
     "HistState",
     "Histogram",
     "JOB_SPANS",
+    "RUN_SPANS",
     "LEVEL_SERIES",
     "MEM_SERIES",
     "MetricsError",

@@ -29,6 +29,12 @@ Contracts:
 Tracing is strictly host-side observability: no traced program ever
 sees the tracer, so serve results are bit-equal with tracing on or off
 (regress-pinned).
+
+The same tracer follows `Simulator`'s drive loop (`attach_tracer`): one
+`run-<n>` trace per `run()` / `run_chunk()` / `run_streamed()` call with
+the spans of `RUN_SPANS` (+ `refill` when streaming), each also a
+`jax.profiler.TraceAnnotation("gt:<name>")` so that under a profiler
+trace they lie on the device trace's clock — see `RunSpans`.
 """
 
 from __future__ import annotations
@@ -43,8 +49,16 @@ import time
 JOB_SPANS = ("submit", "validate", "admit", "queue", "execute", "emit")
 # Terminal span names: every submitted job's trace ends in exactly one.
 TERMINAL_SPANS = ("emit", "reject", "failed")
+# Simulator drive-loop spans, in the order one dispatch goes through them:
+# `run` encloses the call; `dispatch` is the call into the compiled runner
+# until it returns its futures; `wait` blocks on the control scalars (made
+# only when a tracer is attached); `fetch` is the device_get; `results`
+# assembles SimResults on the host.
+RUN_SPANS = ("run", "dispatch", "wait", "fetch", "results")
 
 BATCH_TRACE_PREFIX = "batch-"
+RUN_TRACE_PREFIX = "run-"
+ANNOTATION_PREFIX = "gt:"
 
 
 @dataclasses.dataclass
@@ -74,6 +88,13 @@ class Tracer:
         self.spans: "collections.deque[Span]" = collections.deque(
             maxlen=int(max_spans))
         self._epoch: "float | None" = None
+        self._n_run_traces = 0
+
+    def new_run_id(self) -> str:
+        """The next `run-<n>` trace id (n counts this tracer's runs)."""
+        tid = f"{RUN_TRACE_PREFIX}{self._n_run_traces}"
+        self._n_run_traces += 1
+        return tid
 
     def _now(self) -> float:
         t = float(self.clock())
@@ -165,6 +186,43 @@ class Tracer:
         return len(rows)
 
 
+class RunSpans:
+    """The spans of ONE drive-loop call: `spans(name, parent=..., **attrs)`
+    is a context manager that records a `Tracer` span under the call's
+    trace id and enters `jax.profiler.TraceAnnotation("gt:" + name)`.
+    `attrs["parent"]` names the span that caused this one.  `.on` tells
+    the drive loop whether to make the tracer-only `wait`."""
+
+    on = True
+
+    def __init__(self, tracer: Tracer, trace_id: "str | None" = None):
+        self.tracer = tracer
+        self.trace_id = (tracer.new_run_id() if trace_id is None
+                         else str(trace_id))
+
+    @contextlib.contextmanager
+    def __call__(self, name: str, **attrs):
+        import jax
+
+        with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name):
+            with self.tracer.span(self.trace_id, name, **attrs) as s:
+                yield s
+
+
+class _NoSpans:
+    """`RunSpans` with no tracer attached: every span is the one shared
+    null context (yields None), nothing is created or recorded."""
+
+    on = False
+    _null = contextlib.nullcontext()
+
+    def __call__(self, name: str, **attrs):
+        return self._null
+
+
+NO_SPANS = _NoSpans()
+
+
 def load_jsonl(path_or_file) -> "list[dict]":
     """Read spans back from a `export_jsonl` file (report input)."""
     if hasattr(path_or_file, "read"):
@@ -200,6 +258,10 @@ def job_breakdown(rows: "list[dict]") -> "list[dict]":
                 if k not in ("trace", "span", "start_us", "dur_us"):
                     row.setdefault(k, v)
     for row in by_job.values():
+        if row["job"].startswith(RUN_TRACE_PREFIX):
+            # a drive-loop trace: `run` encloses its other spans
+            row["total_us"] = row.get("run_us", 0)
+            continue
         row["total_us"] = sum(v for k, v in row.items()
                               if isinstance(v, int) and k.endswith("_us"))
     return list(by_job.values())

@@ -30,6 +30,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from graphite_tpu.obs.scopes import scope
+
 I64 = jnp.int64
 
 
@@ -86,34 +88,35 @@ class ParallelCtx:
         leaves, tdef = jax.tree.flatten(tree)
         if not leaves:
             return tree
-        cols = []
-        meta = []
-        for leaf in leaves:
-            k = 1
-            for d in leaf.shape[1:]:
-                k *= d
-            meta.append((leaf.shape, leaf.dtype, k))
-            flat = leaf.reshape(leaf.shape[0], k)
-            if leaf.dtype == jnp.uint32:
-                # widen via uint64 so values >= 2^31 survive the round trip
-                flat = flat.astype(jnp.uint64).astype(I64)
-            else:
-                flat = flat.astype(I64)
-            cols.append(flat)
-        buf = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
-        full = jax.lax.all_gather(buf, self.axis, axis=0, tiled=True)
-        out = []
-        off = 0
-        for shape, dtype, k in meta:
-            piece = full[:, off:off + k]
-            off += k
-            if dtype == jnp.uint32:
-                piece = piece.astype(jnp.uint64).astype(dtype)
-            elif dtype == jnp.bool_:
-                piece = piece != 0
-            else:
-                piece = piece.astype(dtype)
-            out.append(piece.reshape((full.shape[0],) + tuple(shape[1:])))
+        with scope("gt.px"):
+            cols = []
+            meta = []
+            for leaf in leaves:
+                k = 1
+                for d in leaf.shape[1:]:
+                    k *= d
+                meta.append((leaf.shape, leaf.dtype, k))
+                flat = leaf.reshape(leaf.shape[0], k)
+                if leaf.dtype == jnp.uint32:
+                    # widen via uint64 so values >= 2^31 survive the round trip
+                    flat = flat.astype(jnp.uint64).astype(I64)
+                else:
+                    flat = flat.astype(I64)
+                cols.append(flat)
+            buf = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+            full = jax.lax.all_gather(buf, self.axis, axis=0, tiled=True)
+            out = []
+            off = 0
+            for shape, dtype, k in meta:
+                piece = full[:, off:off + k]
+                off += k
+                if dtype == jnp.uint32:
+                    piece = piece.astype(jnp.uint64).astype(dtype)
+                elif dtype == jnp.bool_:
+                    piece = piece != 0
+                else:
+                    piece = piece.astype(dtype)
+                out.append(piece.reshape((full.shape[0],) + tuple(shape[1:])))
         return jax.tree.unflatten(tdef, out)
 
     def lo_const(self, x):

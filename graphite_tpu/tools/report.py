@@ -94,7 +94,8 @@ def _text_table(tl) -> "list[str]":
 def render_spans(path: str, fmt: str) -> "list[str]":
     """Span JSON-lines -> per-job latency breakdown + batch table."""
     from graphite_tpu.obs.trace import (
-        BATCH_TRACE_PREFIX, JOB_SPANS, job_breakdown, load_jsonl,
+        BATCH_TRACE_PREFIX, JOB_SPANS, RUN_SPANS, job_breakdown,
+        load_jsonl,
     )
 
     rows = load_jsonl(path)
@@ -108,7 +109,7 @@ def render_spans(path: str, fmt: str) -> "list[str]":
         return out
     # aligned per-job table: lifecycle spans in canonical order, then
     # any extra recorded spans (split/retry/...), then status/total
-    span_cols = [s + "_us" for s in JOB_SPANS]
+    span_cols = [s + "_us" for s in JOB_SPANS + RUN_SPANS]
     extra = sorted({k for r in jobs for k in r
                     if k.endswith("_us") and k != "total_us"
                     and k not in span_cols})

@@ -1,0 +1,175 @@
+"""Device scope names (graphite_tpu/obs/scopes.py): every registered name
+lands in the `op_name` paths of the programs that must contain it, an
+unregistered name is refused, and the scopes change no equation (the
+lowered jaxpr is `identity.same_program` with and without them, so
+PROGRAMS.lock and BUDGETS.json cannot move).
+"""
+
+import contextlib
+import re
+
+import jax
+import pytest
+
+from graphite_tpu.analysis import identity
+from graphite_tpu.config import ConfigFile, SimConfig
+from graphite_tpu.engine import step
+from graphite_tpu.engine.simulator import Simulator
+from graphite_tpu.memory import engine, engine_shl2
+from graphite_tpu.memory.engine import PHASE_NAMES
+from graphite_tpu.memory.engine_shl2 import SHL2_PHASE_NAMES
+from graphite_tpu.models import iocoom
+from graphite_tpu.obs import TelemetrySpec, scopes
+from graphite_tpu.parallel import px
+from graphite_tpu.tools._template import config_text
+from graphite_tpu.trace.benchmarks import fft_trace
+
+TILES = 16
+MSI = "pr_l1_pr_l2_dram_directory_msi"
+SHL2 = "pr_l1_sh_l2_msi"
+SCOPED_MODULES = (step, engine, engine_shl2, iocoom, px)
+
+# what each program must contain: everything but the scopes whose code it
+# does not run
+ONLY_SHARDED = {"gt.px"}
+MSI_SCOPES = [s for s in scopes.SCOPES if s not in ONLY_SHARDED]
+SHL2_SCOPES = [s for s in scopes.SCOPES if s not in ONLY_SHARDED
+               | {"gt.core.iocoom", "gt.mem.stage_flush", "gt.obs"}]
+
+
+def build(program: str) -> Simulator:
+    batch = fft_trace(n_tiles=TILES, points_per_tile=64, use_memory=True)
+    if program == "shl2":
+        text = config_text(TILES, shared_mem=True, protocol=SHL2)
+        return Simulator(SimConfig(ConfigFile.from_string(text)), batch)
+    text = config_text(TILES, core="iocoom", shared_mem=True, protocol=MSI)
+    kw = {}
+    if program == "msi":
+        # staging and a telemetry ring, so that their scopes have code
+        kw = dict(dir_stage=True,
+                  telemetry=TelemetrySpec(sample_interval_ps=1_000_000))
+    elif program == "msi-sharded":
+        from graphite_tpu.parallel.mesh import make_tile_mesh
+
+        kw = dict(mesh=make_tile_mesh(2), spmd="shard_map")
+    return Simulator(SimConfig(ConfigFile.from_string(text)), batch, **kw)
+
+
+def op_names(sim: Simulator) -> set:
+    """The location names of the program `run()` dispatches, lowered and
+    not compiled: its `op_name` paths (`jit(fn)/...`; relative to the
+    body under shard_map) among file, function and argument names."""
+    if sim.mesh is not None:
+        from graphite_tpu.parallel.mesh import make_shard_map_runner
+
+        lowered = make_shard_map_runner(
+            sim.params, sim.quantum_ps, 4096, sim.mesh, sim.state,
+            sim.device_trace).lower(sim.state, sim.device_trace)
+    else:
+        fn, args = sim._auditable_fn(4096)
+        lowered = jax.jit(fn).lower(*args)
+    return set(re.findall(r'loc\("([^"]*)"',
+                          lowered.as_text(debug_info=True)))
+
+
+@pytest.fixture(scope="module")
+def found():
+    """{program: the set of deepest scopes over its op_names}, each
+    program lowered once."""
+    cache = {}
+
+    def get(program):
+        if program not in cache:
+            cache[program] = {scopes.deepest(n)
+                              for n in op_names(build(program))
+                              if n.startswith(("jit(", "gt."))
+                              and "/" in n}
+        return cache[program]
+    return get
+
+
+@pytest.mark.parametrize("name", MSI_SCOPES)
+def test_msi_iocoom_program_names_scope(found, name):
+    assert name in found("msi")
+
+
+@pytest.mark.parametrize("name", SHL2_SCOPES)
+def test_shared_l2_program_names_scope(found, name):
+    assert name in found("shl2")
+
+
+def test_sharded_program_names_the_exchange(found):
+    assert "gt.px" in found("msi-sharded")
+
+
+def test_every_operation_with_a_path_is_scoped(found):
+    """`gt.quantum` encloses the loop nest: what is left unscoped is what
+    XLA adds on its own, never an equation of the program."""
+    assert None not in found("msi") and None not in found("shl2")
+
+
+def test_registry():
+    assert len(set(scopes.SCOPES)) == len(scopes.SCOPES)
+    assert all(s.startswith("gt.") and "/" not in s for s in scopes.SCOPES)
+    assert set(PHASE_NAMES) == set(SHL2_PHASE_NAMES)
+    for phase in PHASE_NAMES:
+        assert "gt.mem." + phase in scopes.SCOPES
+    with pytest.raises(ValueError, match="not a registered scope"):
+        scopes.scope("gt.typo")
+
+
+def test_cache_tag_follows_the_registry():
+    """Scopes live in the executable and JAX's cache key ignores them, so
+    the drive loop's jitted functions carry a tag derived from the
+    registry: a stale executable cannot be served for a newer one."""
+    import hashlib
+
+    assert scopes.CACHE_TAG == "s" + hashlib.sha1(
+        ",".join(scopes.SCOPES).encode()).hexdigest()[:6]
+    sim = build("shl2")
+    assert sim._get_runner(4096).__name__ == "run_" + scopes.CACHE_TAG
+    assert sim._hb_get_runner().__name__ == "qrun_" + scopes.CACHE_TAG
+    assert f"jit_run_{scopes.CACHE_TAG}" in sim._get_runner(4096).lower(
+        sim.state).as_text()[:400]
+
+
+@pytest.mark.parametrize("path,want", [
+    ("jit(run)/gt.quantum/while/body/gt.core/gt.net.mailbox/cond/"
+     "branch_1_fun/scatter-add", "gt.net.mailbox"),
+    ("jit(run)/vmap(gt.quantum)/while/body/vmap(gt.core)/add", "gt.core"),
+    ("jit(run)/gt.quantum/while/body/gt.core/gt.mem.base/"
+     "gt.mem.requester_fill/cond/branch_1_fun/gt.net.route/mul",
+     "gt.net.route"),
+    ("jit(run)/gt.quantum/while/body/gt.core/gt.mem.requester_fill/add",
+     "gt.mem.requester_fill"),
+    ("jit(run)/gt.quantum/while/body/gt.core/gt.typo/add", "gt.core"),
+    ("jit(run)/while/body/add", None),
+    ("", None),
+])
+def test_deepest(path, want):
+    assert scopes.deepest(path) == want
+
+
+@contextlib.contextmanager
+def scopes_off(monkeypatch):
+    """`scope` patched to a null context, decorated functions unwrapped."""
+    with monkeypatch.context() as m:
+        for mod in SCOPED_MODULES:
+            m.setattr(mod, "scope", lambda name: contextlib.nullcontext())
+            for attr, fn in list(vars(mod).items()):
+                if callable(fn) and hasattr(fn, "__wrapped__") \
+                        and getattr(fn, "__module__", "").startswith(
+                            "graphite_tpu."):
+                    m.setattr(mod, attr, fn.__wrapped__)
+        yield
+
+
+@pytest.mark.parametrize("program", ["msi", "shl2"])
+def test_scopes_change_no_equation(monkeypatch, program):
+    scoped = build(program).lower()[0]
+    with scopes_off(monkeypatch):
+        sim = build(program)
+        assert not any("gt." in n for n in op_names(sim)), \
+            "the null patch left a scope in place"
+        plain = sim.lower()[0]
+    assert identity.same_program(scoped, plain)
