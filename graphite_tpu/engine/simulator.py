@@ -1152,6 +1152,23 @@ class Simulator:
         names = mem_phase_names(self.params)
         return {n: int(v) for n, v in zip(names, skips.tolist())}
 
+    @property
+    def last_base_skips(self):
+        """What the memory engine's home-activity gate skipped across
+        everything run so far: {"base": iterations whose directory
+        working-set gather and merged scatter did not run (a
+        whole-engine mem_gate skip counts), "flush": inner blocks whose
+        staging flush did not run}.  Denominators: `last_n_iterations`
+        and that over `inner_block`.  None when the run has no memory
+        subsystem or its engine has no such gate (shared-L2)."""
+        skips = getattr(self.state.mem, "base_skips", None)
+        if skips is None:
+            return None
+        from graphite_tpu.memory.engine import BASE_SKIP_NAMES
+
+        return dict(zip(BASE_SKIP_NAMES,
+                        np.asarray(jax.device_get(skips)).tolist()))
+
     def _get_runner(self, max_quanta: int):
         if self._runner is None or self._runner_max_quanta != max_quanta:
             if self.spmd == "shard_map":

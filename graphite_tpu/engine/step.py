@@ -39,6 +39,7 @@ Timing semantics per record mirror the reference exactly:
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -288,11 +289,21 @@ def subquantum_iteration(
             RecView, mem_idle_out, memory_engine_step, slots_present,
         )
 
+        # below its size ceiling the whole engine sits under ONE cond
+        # (mem_gate, further down), which skips the base with it
+        whole_gate = params.mem_gate and not px.sharded
         if params.mem.protocol.startswith("pr_l1_sh_l2"):
             from graphite_tpu.memory.engine_shl2 import shl2_engine_step
             engine_step = shl2_engine_step
         else:
-            engine_step = memory_engine_step
+            # The engine's own home-activity gate is for the regime
+            # where the per-phase conds are the only gating.  Inside the
+            # whole-engine cond it was measured to cost what it saves:
+            # at 64 tiles the base is live in most iterations that run
+            # the engine at all, and its passes over 8 MB stores are
+            # cheaper than two control-flow headers (PERF.md §6, PR 29).
+            engine_step = functools.partial(memory_engine_step,
+                                            home_gate=not whole_gate)
         # knob lifting: swap the timing-scalar fields for the (traced)
         # sweep knobs; geometry and every other static field untouched
         mem_p = params.mem if knobs is None else knobs.apply_mem(params.mem)
@@ -317,7 +328,7 @@ def subquantum_iteration(
         # the hist-off trace byte-identical (PROGRAMS.lock fingerprints)
         with scope("gt.mem.base"):
             fill_ev = hist is not None
-            if params.mem_gate and not px.sharded:
+            if whole_gate:
                 need_mem = state.mem.live | jnp.any(
                     active & slots_present(mem_p, rec, enabled).any(axis=1))
                 mem_out = lax.cond(
@@ -1240,27 +1251,46 @@ def _quantum_loop(params, trace, state, qend, trace_base=None, px=IDENT,
                                            dvfs=dvfs, hist=hist)
             return st, prog + adv, i + 1
 
+        staged = (params.mem is not None
+                  and getattr(params.mem, "dir_stage_cap", 0))
+        # the flush's gate goes through the same forcing as the others
+        flush_gate = (staged and params.mem.phase_gate
+                      and params.block_gates)
+        if flush_gate:
+            base_skips0 = state.mem.base_skips[0]
         state, progress, _ = lax.while_loop(
             lambda c: c[2] < params.inner_block, body,
             (state, progress, jnp.asarray(0, jnp.int32)),
         )
-        if (params.mem is not None
-                and getattr(params.mem, "dir_stage_cap", 0)):
+        if staged:
             # One amortized dense pass applies the block's staged
             # directory writes (memory/engine.dir_stage_flush); capacity
             # covers a full block, so flushing here is always in time.
-            # Deliberately UNCONDITIONAL (no lax.cond on sn > 0): a cond
-            # would double-buffer the multi-GB sharers store in HBM —
-            # the same pathology that disables mem_gate at this scale —
-            # and in the big configs where staging auto-enables, the
-            # direct path paid its three full-array dense passes every
-            # iteration even with all-false write masks, so an empty
-            # flush per block is already the cheap case.
-            from graphite_tpu.memory.engine import dir_stage_flush
+            # Never under a lax.cond: a cond that returns the multi-GB
+            # sharers store double-buffers it in HBM (the pathology that
+            # disables mem_gate at this scale).  Gated IN PLACE instead
+            # (engine._run_if), by the home-activity gate: a slot is
+            # staged only by a home phase, a home phase runs only in an
+            # iteration whose base ran, and the table is empty at block
+            # entry (every block ends here) — so a block whose
+            # inner_block iterations ALL skipped the base staged
+            # nothing, and its flush would drop every slot.  The counter
+            # is replicated control state (unlike the block-local `sn`),
+            # so every device of a mesh takes the same arm.
+            from graphite_tpu.memory.engine import (
+                FLUSH_SKIPPED, dir_stage_flush,
+            )
 
+            mem = state.mem
+            flush_live = None
+            if flush_gate:
+                flush_live = (mem.base_skips[0] - base_skips0
+                              < params.inner_block)
+                mem = mem.replace(base_skips=mem.base_skips + jnp.where(
+                    flush_live, 0, FLUSH_SKIPPED))
             with scope("gt.mem.stage_flush"):
-                state = state.replace(mem=state.mem.replace(
-                    directory=dir_stage_flush(state.mem.directory)))
+                state = state.replace(mem=mem.replace(
+                    directory=dir_stage_flush(mem.directory, flush_live)))
         return state, progress
 
     def cond(carry):

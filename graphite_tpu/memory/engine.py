@@ -652,6 +652,35 @@ PHASE_NAMES = ("requester", "home_evict", "home_start", "sharer",
                "home_finish", "requester_fill")
 
 
+# MemState.base_skips order: iterations whose consolidated base (the
+# working-set gather and the merged scatter) was skipped, inner blocks
+# whose staging flush was skipped
+BASE_SKIP_NAMES = ("base", "flush")
+BASE_SKIPPED = np.array([1, 0], np.int64)
+FLUSH_SKIPPED = np.array([0, 1], np.int64)
+
+
+def _run_if(live, fn, stores):
+    """`fn(stores)` where `live`, else `stores` untouched: the in-place
+    gate for a WRITER of the big directory stores.
+
+    A `lax.cond` that returned the stores would double-buffer them (its
+    branch outputs are fresh buffers — the round-2 pathology,
+    `dir_store_avals`).  A `lax.while_loop` carry is aliased in place, so
+    the gate is a zero-or-one-trip loop over `(flag, stores)`: the body
+    clears the flag and applies `fn`; a closed gate runs no body, and
+    the scatter and the relayouts XLA puts around it are instructions of
+    the body.  `live=None` is the forced-live form (gates off): `fn`
+    inline, today's program."""
+    if live is None:
+        return fn(stores)
+    _, out = jax.lax.while_loop(
+        lambda c: c[0],
+        lambda c: (jnp.zeros((), jnp.bool_), fn(c[1])),
+        (live, stores))
+    return out
+
+
 def dir_store_avals(ms) -> tuple:
     """(shape, dtype) signatures of the big directory stores — the
     [T, DS, DW] packed entry words and [T, DS, DW*SW] sharers bitvector
@@ -680,6 +709,8 @@ def mem_idle_out(mp: MemParams, ms, rec: "RecView", enabled,
     mem_complete = (ms.req.phase == PHASE_IDLE) & (final_slot >= 3)
     if ms.phase_skips is not None:
         ms = ms.replace(phase_skips=ms.phase_skips + 1)
+    if getattr(ms, "base_skips", None) is not None:
+        ms = ms.replace(base_skips=ms.base_skips + BASE_SKIPPED)
     T = ms.req.phase.shape[0]
     return MemStepOut(
         ms=ms, mem_complete=mem_complete, acc_ps=ms.req.acc_ps,
@@ -797,8 +828,13 @@ def _stage_overlay_rows(d, sets, rows):
     return out.reshape(T, K, DW * SW)
 
 
-def dir_stage_flush(d):
+def dir_stage_flush(d, live=None):
     """Apply the staging rows to the big sharers store and reset them.
+
+    `live` (a scalar bool, or None = forced live) gates the whole flush
+    in place (`_run_if`): the caller passes a predicate that is false
+    only where no slot was staged since the last flush, and then every
+    key is -1, every slot is dropped and the reset writes what is there.
 
     ROW-form add-a-delta: gather each staged slot's whole [DW*SW] set
     row (structured [t, s] row indexing — the fast TPU gather path; the
@@ -816,28 +852,35 @@ def dir_stage_flush(d):
     SW = d.sval.shape[2]
     C = d.skey.shape[1]
     tiles = np.arange(T, dtype=np.int32)[:, None]
-    valid = d.skey >= 0                                       # [T, c]
-    key = jnp.where(valid, d.skey, 0)
-    w = nn_mod(key, DW)
-    s = nn_div(key, DW)
-    # a slot applies iff no LATER slot in its lane row stages the same key
-    later = (valid[:, :, None] & valid[:, None, :]
-             & (key[:, :, None] == key[:, None, :])
-             & (np.arange(C)[None, None, :] > np.arange(C)[None, :, None]))
-    is_last = valid & ~later.any(axis=2)
-    row = d.sharers[tiles, s]                                 # [T, c, DW*SW]
-    row3 = row.reshape(T, C, DW, SW)
-    cur = jnp.take_along_axis(row3, w[:, :, None, None], axis=2)[:, :, 0]
-    delta = jnp.where(is_last[..., None], d.sval - cur, jnp.uint32(0))
-    onehot = (np.arange(DW, dtype=np.int32)[None, None, :, None]
-              == w[:, :, None, None])
-    row_delta = jnp.where(onehot, delta[:, :, None, :],
-                          jnp.uint32(0)).reshape(T, C, DW * SW)
-    s_oob = jnp.where(is_last, s, DS)              # dropped when superseded
-    return d.replace(
-        sharers=d.sharers.at[tiles, s_oob].add(row_delta, mode="drop"),
-        skey=jnp.full_like(d.skey, -1),
-        sn=jnp.zeros_like(d.sn))
+
+    def flush(stores):
+        sharers, skey, sn = stores
+        valid = skey >= 0                                     # [T, c]
+        key = jnp.where(valid, skey, 0)
+        w = nn_mod(key, DW)
+        s = nn_div(key, DW)
+        # a slot applies iff no LATER slot in its lane row stages the
+        # same key
+        later = (valid[:, :, None] & valid[:, None, :]
+                 & (key[:, :, None] == key[:, None, :])
+                 & (np.arange(C)[None, None, :]
+                    > np.arange(C)[None, :, None]))
+        is_last = valid & ~later.any(axis=2)
+        row = sharers[tiles, s]                               # [T, c, DW*SW]
+        row3 = row.reshape(T, C, DW, SW)
+        cur = jnp.take_along_axis(
+            row3, w[:, :, None, None], axis=2)[:, :, 0]
+        delta = jnp.where(is_last[..., None], d.sval - cur, jnp.uint32(0))
+        onehot = (np.arange(DW, dtype=np.int32)[None, None, :, None]
+                  == w[:, :, None, None])
+        row_delta = jnp.where(onehot, delta[:, :, None, :],
+                              jnp.uint32(0)).reshape(T, C, DW * SW)
+        s_oob = jnp.where(is_last, s, DS)          # dropped when superseded
+        return (sharers.at[tiles, s_oob].add(row_delta, mode="drop"),
+                jnp.full_like(skey, -1), jnp.zeros_like(sn))
+
+    sharers, skey, sn = _run_if(live, flush, (d.sharers, d.skey, d.sn))
+    return d.replace(sharers=sharers, skey=skey, sn=sn)
 
 
 class _DirAcc:
@@ -1027,29 +1070,46 @@ class _DirWorkingSet:
     operate on rows-in-registers, and the big stores see exactly one
     gather and one scatter per iteration."""
 
-    def __init__(self, px: ParallelCtx, d: "DirectoryArrays", mp, lines):
+    def __init__(self, px: ParallelCtx, d: "DirectoryArrays", mp, select,
+                 live=None):
+        """`select()` picks the three lines (earliest EVICT cell's,
+        earliest REQUEST's or the saved original, the transaction's);
+        `live` is the home-activity gate (None = forced live).  With the
+        gate closed no home phase runs, so no view is read: selection,
+        gather and staging overlay are all skipped and the rows are
+        zeros."""
         self._dw = d.entry.shape[2]
         self._dir_sets = mp.dir_sets
-        self.sets3 = jnp.stack(
-            [nn_mod(ln, mp.dir_sets).astype(jnp.int32) for ln in lines],
-            axis=1)                                           # [T, 3]
+
+        def rows(lo=lambda x: x):
+            lines = tuple(select())
+            sets3 = jnp.stack(
+                [nn_mod(ln, mp.dir_sets).astype(jnp.int32)
+                 for ln in lines], axis=1)                    # [T, 3]
+            sets = lo(sets3)
+            lt = np.arange(d.entry.shape[0], dtype=np.int32)[:, None]
+            ew = d.entry[lt, sets]                            # [Tl, 3, DW]
+            sh = d.sharers[lt, sets]                          # [Tl, 3, DW*SW]
+            if d.skey is not None:
+                sh = _stage_overlay_rows(d, sets, sh)
+            return lines, sets3, (ew, sh)
+
         if px.sharded:
-            sets_l = px.lo(self.sets3)
-            Tl = d.entry.shape[0]
-            lt = np.arange(Tl, dtype=np.int32)[:, None]
-            ew = d.entry[lt, sets_l]                          # [Tl, 3, DW]
-            sh = d.sharers[lt, sets_l]                        # [Tl, 3, DW*SW]
-            if d.skey is not None:
-                sh = _stage_overlay_rows(d, sets_l, sh)
-            self.entry_rows, self.sharer_rows = px.ag((ew, sh))
+            # the rows ride one collective, which must not sit inside a
+            # lax.cond (engine/step.py, the whole-engine gate): ungated
+            self.lines, self.sets3, local = rows(px.lo)
+            self.entry_rows, self.sharer_rows = px.ag(local)
+            return
+        if live is None:
+            out = rows()
         else:
-            T = d.entry.shape[0]
-            tl = np.arange(T, dtype=np.int32)[:, None]
-            self.entry_rows = d.entry[tl, self.sets3]
-            sh = d.sharers[tl, self.sets3]
-            if d.skey is not None:
-                sh = _stage_overlay_rows(d, self.sets3, sh)
-            self.sharer_rows = sh
+            # the stores are cond INPUTS only and the outputs are the
+            # gathered rows — nothing big is double-buffered (the
+            # argument `_cond_dir` relies on)
+            out = jax.lax.cond(
+                live, rows,
+                lambda: jax.tree.map(jnp.zeros_like, jax.eval_shape(rows)))
+        self.lines, self.sets3, (self.entry_rows, self.sharer_rows) = out
 
     def _forward(self, sets, ew, sh, packs):
         """Add earlier phases' pending deltas where their target set is
@@ -1082,7 +1142,7 @@ class _DirWorkingSet:
         return _DirRowView(line, sets, ew, sh, self._dw)
 
 
-def _dir_apply_merged(d, px: ParallelCtx, packs):
+def _dir_apply_merged(d, px: ParallelCtx, packs, live=None):
     """ONE merged scatter per big directory store per iteration: the
     home phases' consolidated delta plans land together at the end of
     the engine step.  Duplicate targets (two phases updating the same
@@ -1091,39 +1151,53 @@ def _dir_apply_merged(d, px: ParallelCtx, packs):
     (in-place friendly) and the summed deltas stay exact — each phase's
     delta was computed against the forwarded view, so the fold telescopes
     to final-minus-initial.  Sharers deltas apply only in unstaged mode
-    (staged writes ride the per-lane table and flush per block)."""
+    (staged writes ride the per-lane table and flush per block).
+
+    `live` (the home-activity gate, or None = forced live) gates the
+    fold and the scatters in place (`_run_if`): where it is false every
+    plan is a skipped phase's zero pack, and adding zeros writes what is
+    there."""
     packs = [tuple(px.lo(p)) for p in packs]
     Tl = d.entry.shape[0]
     t = np.arange(Tl, dtype=np.int32)
-    sets = [p[0] for p in packs]
-    way = [p[1] for p in packs]
-    ed = [p[2] for p in packs]
-    shd = [p[3] for p in packs]
-    n = len(packs)
-    drop_e = [jnp.zeros(Tl, jnp.bool_) for _ in range(n)]
-    drop_s = [jnp.zeros(Tl, jnp.bool_) for _ in range(n)]
-    for j in range(1, n):
-        for i in range(j):
-            eq_e = ((sets[i] == sets[j]) & (way[i] == way[j])
-                    & ~drop_e[i] & ~drop_e[j])
-            ed[i] = ed[i] + jnp.where(eq_e, ed[j], 0)
-            drop_e[j] = drop_e[j] | eq_e
-            eq_s = (sets[i] == sets[j]) & ~drop_s[i] & ~drop_s[j]
-            shd[i] = shd[i] + jnp.where(eq_s[:, None], shd[j],
-                                        jnp.zeros_like(shd[j]))
-            drop_s[j] = drop_s[j] | eq_s
-    t_e = jnp.concatenate([jnp.where(dr, Tl, t) for dr in drop_e])
-    s_all = jnp.concatenate(sets)
-    w_all = jnp.concatenate(way)
-    ed_all = jnp.concatenate(ed)
-    out = d.replace(entry=d.entry.at[t_e, s_all, w_all].add(
-        ed_all, mode="drop", unique_indices=True))
-    if d.skey is None:
+    staged = d.skey is not None
+
+    def land(stores):
+        sets = [p[0] for p in packs]
+        way = [p[1] for p in packs]
+        ed = [p[2] for p in packs]
+        shd = [p[3] for p in packs]
+        n = len(packs)
+        drop_e = [jnp.zeros(Tl, jnp.bool_) for _ in range(n)]
+        drop_s = [jnp.zeros(Tl, jnp.bool_) for _ in range(n)]
+        for j in range(1, n):
+            for i in range(j):
+                eq_e = ((sets[i] == sets[j]) & (way[i] == way[j])
+                        & ~drop_e[i] & ~drop_e[j])
+                ed[i] = ed[i] + jnp.where(eq_e, ed[j], 0)
+                drop_e[j] = drop_e[j] | eq_e
+                eq_s = (sets[i] == sets[j]) & ~drop_s[i] & ~drop_s[j]
+                shd[i] = shd[i] + jnp.where(eq_s[:, None], shd[j],
+                                            jnp.zeros_like(shd[j]))
+                drop_s[j] = drop_s[j] | eq_s
+        t_e = jnp.concatenate([jnp.where(dr, Tl, t) for dr in drop_e])
+        s_all = jnp.concatenate(sets)
+        w_all = jnp.concatenate(way)
+        ed_all = jnp.concatenate(ed)
+        entry = stores[0].at[t_e, s_all, w_all].add(
+            ed_all, mode="drop", unique_indices=True)
+        if staged:
+            return (entry,)
         t_s = jnp.concatenate([jnp.where(dr, Tl, t) for dr in drop_s])
         shd_all = jnp.concatenate(shd)
-        out = out.replace(sharers=out.sharers.at[t_s, s_all].add(
+        return (entry, stores[1].at[t_s, s_all].add(
             shd_all, mode="drop", unique_indices=True))
-    return out
+
+    out = _run_if(live, land,
+                  (d.entry,) if staged else (d.entry, d.sharers))
+    if staged:
+        return d.replace(entry=out[0])
+    return d.replace(entry=out[0], sharers=out[1])
 
 
 def _cond_dir_c(pred, fn, ms, n_tiles: int):
@@ -1323,6 +1397,7 @@ def memory_engine_step(
     enabled,                  # bool[] models enabled
     px: ParallelCtx = IDENT,  # shard_map exchange context (parallel/px.py)
     fill_events: bool = False,  # emit per-call MemStepOut.fill_now/_lat_ps
+    home_gate: bool = True,   # False: the caller gates the whole engine
 ) -> MemStepOut:
     T = mp.n_tiles
     tiles = np.arange(T, dtype=np.int32)
@@ -1714,18 +1789,46 @@ def memory_engine_step(
     # gather (entry + sharers rows, staging overlaid) serves phases
     # 2/3/5, each phase's cond returns its delta plan for forwarding,
     # and the plans land in ONE merged scatter per store after phase 5.
+    #
+    # The home-activity gate (phase_gate regime): one scalar, evaluated
+    # HERE, under which that base runs — the gather under a lax.cond
+    # (its outputs are the small rows), the merged scatter under an
+    # in-place zero-or-one-trip loop (`_run_if`).  `home_live` is a
+    # superset of pred2 | pred3 | pred5 at their own evaluation points.
+    # With it false there is no EVICT cell, so pred2 is false and phase
+    # 2 is a no-op; then no REQ cell and no saved transaction, so pred3
+    # is false, phase 3 is a no-op and emits no FWD; there was no FWD
+    # cell, so phase 4 is a no-op and emits no ACK; there was no ACK
+    # cell and no active transaction, so pred5 is false.  Every view
+    # then goes unread and every plan is a zero pack: the gather's rows
+    # are dead and the scatter adds zeros.  Replicated control state
+    # only, so every device of a mesh takes the same arm.  None =
+    # forced live (gates off, or `home_gate` false because the caller
+    # already has the whole engine under one cond: today's program).
     ws = None
     packs = []
+    home_live = None
     if consolidate:
-        mail0 = ms.mail
-        src_e0, _ = _row_earliest(mail0.evict_type, mail0.evict_time)
-        eline0 = mail0.evict_line[tiles, src_e0]
-        use_saved0 = ~ms.txn.active & ms.txn.saved_valid
-        r_col0, _ = _req_earliest(mail0)
-        rline0 = jnp.where(use_saved0, ms.txn.saved_line,
-                           mail0.req_line[r_col0])
-        ws = _DirWorkingSet(px, ms.directory, mp,
-                            (eline0, rline0, ms.txn.line))
+        mail0, txn0 = ms.mail, ms.txn
+        if gate and home_gate:
+            home_live = ((mail0.evict_type != MSG_NONE).any()
+                         | (mail0.req_type != MSG_NONE).any()
+                         | (mail0.fwd_type != MSG_NONE).any()
+                         | (mail0.ack_type != MSG_NONE).any()
+                         | txn0.active.any()
+                         | txn0.saved_valid.any())
+
+        def _ws_lines():
+            src_e0, _ = _row_earliest(mail0.evict_type, mail0.evict_time)
+            eline0 = mail0.evict_line[tiles, src_e0]
+            use_saved0 = ~txn0.active & txn0.saved_valid
+            r_col0, _ = _req_earliest(mail0)
+            rline0 = jnp.where(use_saved0, txn0.saved_line,
+                               mail0.req_line[r_col0])
+            return eline0, rline0, txn0.line
+
+        ws = _DirWorkingSet(px, ms.directory, mp, _ws_lines, live=home_live)
+        eline0, rline0, _ = ws.lines
 
     def _run_dir_phase(pred, fn):
         """One home phase in the selected regime; consolidated runs
@@ -1806,7 +1909,7 @@ def memory_engine_step(
     if consolidate:
         # the ONE merged scatter per big store for this iteration
         ms = ms.replace(directory=_dir_apply_merged(
-            ms.directory, px, packs))
+            ms.directory, px, packs, live=home_live))
 
     # ======================================================================
     # (6) requesters consume replies (fill L2+L1, complete slot)
@@ -1841,6 +1944,9 @@ def memory_engine_step(
         skipped = 1 - jnp.stack(
             [pred1, pred2, pred3, pred4, pred5, pred6]).astype(I64)
         ms = ms.replace(phase_skips=ms.phase_skips + skipped)
+    if home_live is not None:
+        ms = ms.replace(base_skips=ms.base_skips
+                        + jnp.where(home_live, 0, BASE_SKIPPED))
     return MemStepOut(
         ms=ms, mem_complete=mem_complete, acc_ps=ms.req.acc_ps,
         slot_lat_ps=ms.req.slot_lat_ps,

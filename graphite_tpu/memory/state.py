@@ -245,6 +245,15 @@ class MemState:
     # mem_gate skip counts every phase.  Replicated control state under
     # shard_map (deterministic from replicated predicates).
     phase_skips: jax.Array = None
+    # int64[2] — what the home-activity gate skipped (engine.
+    # BASE_SKIP_NAMES order): iterations whose consolidated base (the
+    # directory working-set gather and the merged scatter) did not run,
+    # inner blocks whose staging flush did not run.  A whole-engine
+    # mem_gate skip counts as a skipped base.  Stays 0 with the gates
+    # off.  Replicated control state, like phase_skips — and not only
+    # observed: the flush's gate reads how far `base` moved over a block
+    # (engine/step.py).
+    base_skips: jax.Array = None
     # per-port queue state of the MEMORY NoC when `[network] memory =
     # emesh_hop_by_hop` (models/network_hop_by_hop.NocState), else None
     noc: "object" = None
@@ -377,5 +386,6 @@ def init_mem_state(mp: MemParams) -> MemState:
         txn=txn,
         live=jnp.zeros((), jnp.bool_),
         mt=mt,
+        base_skips=jnp.zeros(2, I64),
         **init_mem_common(mp),
     )

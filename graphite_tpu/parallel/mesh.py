@@ -63,7 +63,7 @@ _REPLICATED_STATE_FIELDS = {
     # gate observability: the [6] per-phase skip-count vector is global
     # control state (and at 6-tile counts would otherwise be mistaken
     # for a tile-major array by the shape heuristic below)
-    "phase_skips",
+    "phase_skips", "base_skips",
 }
 
 

@@ -9,6 +9,7 @@ exists for; the equivalence claims are randomized-trace bit-identity
 exactness for both memory engines.
 """
 
+import dataclasses
 import json
 
 import jax
@@ -118,21 +119,29 @@ def _iteration_jaxpr(sim):
                                         st, qend))(sim.state)
 
 
+def _store_sites(closed, sig):
+    """(gather sites, scatter sites) of the store with signature
+    `sig`, at any depth."""
+    from graphite_tpu.analysis.walk import aval_sig, iter_eqns_with_site
+
+    gathers, scatters = [], []
+    for site, eqn in iter_eqns_with_site(closed):
+        name = eqn.primitive.name
+        if (not eqn.invars or isinstance(eqn.invars[0], Literal)
+                or aval_sig(eqn.invars[0].aval) != sig):
+            continue
+        if name == "gather":
+            gathers.append(site)
+        elif name.startswith("scatter"):
+            scatters.append(site)
+    return gathers, scatters
+
+
 def _store_ops(closed, sig):
     """(gathers, scatters) on the store with aval signature `sig` at any
     depth of the iteration program."""
-    from graphite_tpu.analysis.walk import aval_sig, iter_eqns
-
-    gathers, scatters = 0, 0
-    for eqn in iter_eqns(closed):
-        name = eqn.primitive.name
-        in_sigs = [aval_sig(v.aval) for v in eqn.invars
-                   if not isinstance(v, Literal)]
-        if name == "gather" and in_sigs and in_sigs[0] == sig:
-            gathers += 1
-        if name.startswith("scatter") and in_sigs and in_sigs[0] == sig:
-            scatters += 1
-    return gathers, scatters
+    gathers, scatters = _store_sites(closed, sig)
+    return len(gathers), len(scatters)
 
 
 def test_one_gather_one_merged_scatter_1024_shape():
@@ -180,6 +189,96 @@ def test_phase_conds_survive_consolidation_1024_shape():
     sim = _big_shape_sim()
     closed = _iteration_jaxpr(sim)
     assert len(phase_conds(closed, 1024)) == 6
+
+
+# ---- the home-activity gate over the base (PR 29) --------------------------
+
+STAGED = dict(dir_stage=True, inner_block=4)
+
+
+def _program_jaxpr(sim, state=None):
+    """The whole program the drive loop dispatches (quantum loop, inner
+    blocks, the per-block flush), not one iteration of it."""
+    state = sim.state if state is None else state
+    if sim.barrier_host:
+        return jax.make_jaxpr(sim._hb_get_runner())(
+            state, jnp.asarray(0, jnp.int64), jnp.asarray(1, jnp.int32))
+    return jax.make_jaxpr(sim._get_runner(8))(state)
+
+
+def _depth(site):
+    """Control-flow constructs enclosing a site."""
+    return site.count("cond/branches") + site.count("while/body")
+
+
+@pytest.mark.parametrize("staged", [False, True])
+def test_gated_base_under_control_flow_1024_shape(staged):
+    """The gather and both writers of the big directory stores sit under
+    control flow, not at the iteration's (block's) top level: the
+    working-set gather under the home-activity gate's `cond` (small row
+    outputs), the merged scatter and the block flush under its
+    zero-or-one-trip `while` (in-place carry) — each exactly ONE
+    construct deeper than in the gates-off program, still one gather and
+    one scatter per store, six phase conds, and NO cond anywhere in the
+    program returns a store."""
+    from graphite_tpu.analysis.rules import cond_payload, phase_conds
+    from graphite_tpu.memory.engine import dir_store_avals
+
+    kw = STAGED if staged else {}
+    sim = _big_shape_sim(**kw)
+    entry_sig, sharers_sig = dir_store_avals(sim.state.mem)
+
+    it = _iteration_jaxpr(sim)
+    assert len(phase_conds(it, 1024)) == 6
+    for sig in (entry_sig, sharers_sig):
+        (g,), sc = _store_sites(it, sig)
+        assert "cond/branches" in g and "while/body" not in g, g
+        assert len(sc) == (0 if staged and sig == sharers_sig else 1)
+        for site in sc:
+            assert site.startswith("while/body"), site
+
+    prog = _program_jaxpr(sim)
+    assert not cond_payload(prog, forbidden=(entry_sig, sharers_sig))
+    off = _big_shape_sim(**kw)
+    off.params = dataclasses.replace(
+        off.params, mem=dataclasses.replace(off.params.mem,
+                                            phase_gate=False))
+    prog_off = _program_jaxpr(off)
+    for sig in (entry_sig, sharers_sig):
+        g_on, s_on = _store_sites(prog, sig)
+        g_off, s_off = _store_sites(prog_off, sig)
+        assert len(g_on) == len(g_off) and len(s_on) == len(s_off) == 1
+        # (the flush gathers the sharers rows it adds to: inside its gate)
+        for on, ref in zip(g_on + s_on, g_off + s_off):
+            assert _depth(on) == _depth(ref) + 1, (on, ref)
+
+
+@pytest.mark.parametrize("block_gates", [True, False])
+def test_gates_off_program_is_the_counter_alone_1024_shape(block_gates):
+    """phase_gate=False (what SweepRunner campaigns compile), with and
+    without block_gates: the home-activity gate adds a carried counter
+    and NOTHING else — the program with the counter in its state is
+    equation for equation the program without it (same gathers,
+    scatters, conds and whiles at the same sites), one carried value
+    apart.  PROGRAMS.lock pins the same program against the parent's."""
+    from graphite_tpu.analysis.walk import iter_eqns_with_site
+
+    sim = _big_shape_sim(**STAGED)
+    sim.params = dataclasses.replace(
+        sim.params, block_gates=block_gates,
+        mem=dataclasses.replace(sim.params.mem, phase_gate=False))
+    bare = sim.state.replace(mem=sim.state.mem.replace(base_skips=None))
+    with_counter = _program_jaxpr(sim)
+    without = _program_jaxpr(sim, bare)
+
+    def eqns(closed):
+        return [site for site, _ in iter_eqns_with_site(closed)]
+
+    assert eqns(with_counter) == eqns(without)
+    assert (len(with_counter.jaxpr.invars)
+            == len(without.jaxpr.invars) + 1)
+    assert (len(with_counter.jaxpr.outvars)
+            == len(without.jaxpr.outvars) + 1)
 
 
 # ---- bit-identity: consolidated vs round-11 layout ------------------------

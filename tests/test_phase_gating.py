@@ -66,26 +66,46 @@ def mutex_rmw(n, rounds, base=0x900000, lines=2):
     return TraceBatch.from_builders(bs)
 
 
-def assert_exact_gated(sc, batch, **kw):
-    """Gated run (phase conds the ONLY gating: whole-engine mem_gate
-    forced off) must be bit-exact vs the golden oracle."""
-    res = Simulator(sc, batch, phase_gate=True, mem_gate_bytes=0,
-                    **kw).run()
-    gold = run_golden(sc, batch)
-    np.testing.assert_array_equal(res.clock_ps, gold.clock_ps,
-                                  err_msg="clock")
-    for k, g in gold.mem_counters.items():
-        np.testing.assert_array_equal(np.asarray(res.mem_counters[k]), g,
-                                      err_msg=k)
+# what a gated run is held to: the golden oracle, or the UNGATED program
+# (phase_gate=False: no phase cond, and the consolidated base — working-
+# set gather, merged scatter, block flush — run every iteration)
+AGAINST = ("golden", "ungated")
+
+
+def assert_exact_gated(sc, batch, against="golden", **kw):
+    """Gated run (phase conds and the home-activity gate the ONLY
+    gating: whole-engine mem_gate forced off) must be bit-exact vs the
+    golden oracle / the ungated program.  On the private-L2 engine the
+    home-activity gate must have been both open and closed, or the case
+    pins nothing about it."""
+    sim = Simulator(sc, batch, phase_gate=True, mem_gate_bytes=0, **kw)
+    res = sim.run()
+    if against == "golden":
+        ref = run_golden(sc, batch)
+    else:
+        ref = Simulator(sc, batch, phase_gate=False, mem_gate_bytes=0,
+                        **kw).run()
+        np.testing.assert_array_equal(
+            np.asarray(res.instruction_count),
+            np.asarray(ref.instruction_count), err_msg="instructions")
+    np.testing.assert_array_equal(np.asarray(res.clock_ps),
+                                  np.asarray(ref.clock_ps), err_msg="clock")
+    for k, g in ref.mem_counters.items():
+        np.testing.assert_array_equal(np.asarray(res.mem_counters[k]),
+                                      np.asarray(g), err_msg=k)
+    base = sim.last_base_skips
+    if base is not None:
+        assert 0 < base["base"] < int(sim.last_n_iterations), base
     return res
 
 
-# ---- bit-exactness vs the golden oracles ----------------------------------
+# ---- bit-exactness vs the golden oracles and the ungated program ----------
 
 
+@pytest.mark.parametrize("against", AGAINST)
 @pytest.mark.parametrize("proto", [MSI, MOSI])
-def test_gated_serialized_exact(proto):
-    assert_exact_gated(make_config(4, proto), mutex_rmw(4, 5))
+def test_gated_serialized_exact(proto, against):
+    assert_exact_gated(make_config(4, proto), mutex_rmw(4, 5), against)
 
 
 @pytest.mark.parametrize("proto", [SHL2_MSI, SHL2_MESI])
@@ -93,38 +113,37 @@ def test_gated_shl2_serialized_exact(proto):
     assert_exact_gated(make_config(4, proto), mutex_rmw(4, 5))
 
 
-def test_gated_staged_exact():
+@pytest.mark.parametrize("against", AGAINST)
+def test_gated_staged_exact(against):
     """Gating composes with directory write-staging: staged sharers ride
-    the small table INSIDE the home-phase conds, flushes stay per-block
-    outside; inner_block=4 crosses many flush boundaries."""
+    the small table INSIDE the home-phase conds, the per-block flush is
+    gated in place outside them; inner_block=4 crosses many flush
+    boundaries, flushed and skipped."""
     assert_exact_gated(make_config(4, MSI), mutex_rmw(4, 4, lines=3),
-                       dir_stage=True, inner_block=4)
+                       against, dir_stage=True, inner_block=4)
 
 
-def test_gated_limited_scheme_exact():
+@pytest.mark.parametrize("against", AGAINST)
+def test_gated_limited_scheme_exact(against):
     """limited_no_broadcast issues THREE deferred _dir_update calls per
     home-start — the delta plan must sum them exactly."""
     extra = ("[dram_directory]\ndirectory_type = limited_no_broadcast\n"
              "max_hw_sharers = 2\n")
-    assert_exact_gated(make_config(4, MSI, extra=extra), mutex_rmw(4, 4))
+    assert_exact_gated(make_config(4, MSI, extra=extra), mutex_rmw(4, 4),
+                       against)
 
 
-def test_gated_matches_ungated_racy():
+@pytest.mark.parametrize("staged", [False, True])
+def test_gated_matches_ungated_racy(staged):
     """On free-running racy traffic the engine may diverge from the
     oracle (documented envelope) but gated and ungated programs must be
-    BIT-IDENTICAL to each other: gating is mechanism, not policy."""
+    BIT-IDENTICAL to each other: gating is mechanism, not policy.  The
+    compute gaps between accesses close the home-activity gate."""
     batch = synthetic.memory_stress_trace(
         8, n_accesses=80, working_set_bytes=1 << 12,
         write_fraction=0.4, shared_fraction=0.6, seed=11)
-    sc = make_config(8)
-    r0 = Simulator(sc, batch, phase_gate=False, mem_gate_bytes=0).run()
-    r1 = Simulator(sc, batch, phase_gate=True, mem_gate_bytes=0).run()
-    np.testing.assert_array_equal(np.asarray(r0.clock_ps),
-                                  np.asarray(r1.clock_ps))
-    for k in r0.mem_counters:
-        np.testing.assert_array_equal(np.asarray(r0.mem_counters[k]),
-                                      np.asarray(r1.mem_counters[k]),
-                                      err_msg=k)
+    kw = dict(dir_stage=True, inner_block=4) if staged else {}
+    assert_exact_gated(make_config(8), batch, "ungated", **kw)
 
 
 def test_phase_gate_default_on():
@@ -154,7 +173,89 @@ def test_phase_skip_counts():
     assert sum(skips.values()) > 0
 
 
-def test_phase_skips_none_without_memory():
+def test_base_skip_counts_equal_host_count():
+    """`last_base_skips` against a count made on the host.  The home-
+    activity predicate is true exactly where one of phases 2-5 fires
+    (it is a superset of pred2 | pred3 | pred5 by the argument beside
+    it, pred4 needs a FWD cell that was there or that phase 3 emitted,
+    and each of its terms survives to the predicate that reads it), so
+    stepping the program ONE iteration at a time and reading the phase
+    skip vector after each gives the count independently of the
+    counter: an iteration skipped its base iff it skipped phases 2-5,
+    and a block skipped its flush iff all its iterations did."""
+    from graphite_tpu.engine.step import subquantum_iteration
+
+    K = 4
+    bs = [TraceBuilder() for _ in range(4)]
+    for t, b in enumerate(bs):
+        b.load(0x100000 + t * 64, 8)          # a cold miss each
+        for _ in range(3 * K + t):            # idle stretches
+            b.instr(Op.IALU)
+        b.store(0x100000 + ((t + 1) % 4) * 64, 8)   # a neighbour's line
+        for _ in range(2 * K):
+            b.instr(Op.IALU)
+    batch = TraceBatch.from_builders(bs)
+    # lax: one unbounded quantum, so the run is blocks of K iterations
+    # until a block makes no progress — the loop below
+    sc = make_config(4, extra="[clock_skew_management]\nscheme = lax\n")
+    sim = Simulator(sc, batch, phase_gate=True, mem_gate_bytes=0,
+                    dir_stage=True, inner_block=K)
+    assert sim.quantum_ps is None
+    state0 = sim.state
+    sim.run()
+    iters = int(sim.last_n_iterations)
+    got = sim.last_base_skips
+
+    qend = jnp.asarray(2**61, jnp.int64)
+    step = jax.jit(lambda st: subquantum_iteration(
+        sim.params, sim.device_trace, st, qend))
+    st, idle = state0, []
+    while True:                       # run_simulation's block structure
+        blk = 0
+        for _ in range(K):
+            before = np.asarray(st.mem.phase_skips)
+            st, adv = step(st)
+            fired = 1 - (np.asarray(st.mem.phase_skips) - before)
+            idle.append(not fired[1:5].any())
+            blk += int(adv)
+        if blk == 0:
+            break
+    assert len(idle) == iters
+    blocks = np.asarray(idle).reshape(-1, K)
+    want = {"base": int(np.sum(idle)), "flush": int(blocks.all(axis=1).sum())}
+    assert got == want, (got, want)
+    assert 0 < want["base"] < iters and 0 < want["flush"] < len(blocks)
+
+
+def test_whole_engine_gate_leaves_home_gate_out():
+    """Below the mem_gate ceiling the whole engine sits under ONE cond,
+    which skips the base with it: the engine's own home-activity gate
+    stays out (measured to cost what it saves there), the iteration
+    holds no in-place loop, and `base` counts the whole-engine skips —
+    each of which also counts once for every phase."""
+    from graphite_tpu.analysis import iter_eqns
+    from graphite_tpu.engine.step import subquantum_iteration
+
+    bs = [TraceBuilder() for _ in range(4)]
+    for t, b in enumerate(bs):
+        b.load(0x100000 + t * 64, 8)
+        for _ in range(12):
+            b.instr(Op.IALU)
+        b.store(0x100000 + ((t + 1) % 4) * 64, 8)
+    sim = Simulator(make_config(4), TraceBatch.from_builders(bs))
+    assert sim.params.mem_gate and sim.params.mem.phase_gate
+    closed = jax.make_jaxpr(lambda st: subquantum_iteration(
+        sim.params, sim.device_trace, st,
+        jnp.asarray(2**61, jnp.int64)))(sim.state)
+    assert not [e for e in iter_eqns(closed) if e.primitive.name == "while"]
+    sim.run()
+    base = sim.last_base_skips
+    assert 0 < base["base"] <= min(sim.last_phase_skips.values())
+    assert base["flush"] == 0          # no staging at this size
+
+
+@pytest.mark.parametrize("counter", ["last_phase_skips", "last_base_skips"])
+def test_phase_skips_none_without_memory(counter):
     cfg = """
 [general]
 total_cores = 2
@@ -168,7 +269,7 @@ ialu = 1
     sim = Simulator(SimConfig(ConfigFile.from_string(cfg)),
                     TraceBatch.from_builders(bs))
     sim.run()
-    assert sim.last_phase_skips is None
+    assert getattr(sim, counter) is None
 
 
 # ---- program structure at the 1024-tile shape -----------------------------
