@@ -46,7 +46,13 @@ import json
 import time
 
 # Span names in job-lifecycle order (report tables render this order).
-JOB_SPANS = ("submit", "validate", "admit", "queue", "execute", "emit")
+JOB_SPANS = ("submit", "validate", "admit", "queue", "execute", "job",
+             "emit")
+# What a batch pays around its run, in order (`serve/service.py`): traces
+# packed to one layout; a fresh runner + Simulator built and its inputs
+# placed; the compiled-program cache resolved; the program run (enclosing
+# the runner's RUN_SPANS); results demuxed into envelopes.
+BATCH_SPANS = ("pack", "build", "cache", "execute", "demux")
 # Terminal span names: every submitted job's trace ends in exactly one.
 TERMINAL_SPANS = ("emit", "reject", "failed")
 # Simulator drive-loop spans, in the order one dispatch goes through them:
@@ -261,6 +267,10 @@ def job_breakdown(rows: "list[dict]") -> "list[dict]":
         if row["job"].startswith(RUN_TRACE_PREFIX):
             # a drive-loop trace: `run` encloses its other spans
             row["total_us"] = row.get("run_us", 0)
+            continue
+        if "job_us" in row:
+            # `job` is submit -> envelope: it encloses every other span
+            row["total_us"] = row["job_us"]
             continue
         row["total_us"] = sum(v for k, v in row.items()
                               if isinstance(v, int) and k.endswith("_us"))

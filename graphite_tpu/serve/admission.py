@@ -80,6 +80,9 @@ class Pending:
     # batch-form measured from it
     enqueue_ts: "float | None" = None
     dwell_s: float = 0.0
+    # service-clock timestamp of the FIRST enqueue (the `job` span's
+    # start: submit -> envelope, across splits and retries)
+    submit_ts: "float | None" = None
 
 
 @dataclasses.dataclass

@@ -65,6 +65,54 @@ class CacheEntry:
     # paid, read from the entry manifest)
     source: str = "compile"
     deserialize_s: float = 0.0
+    # what `ResidentProgram` reads: the program's inputs as shapes, the
+    # last batch's loop trip count and launches, and the tracer that
+    # follows the program's dispatches
+    inputs: "tuple | None" = None
+    last_n_iterations: int = 0
+    last_run_dispatches: int = 0
+    tracer: object = None
+
+
+class ResidentProgram:
+    """A handle to the resident program of one class: the cached
+    executable as the service keeps dispatching it, with the hooks a
+    `Simulator` has for its own program - `attach_tracer`,
+    `compiled_text`, `last_n_iterations`, `last_run_dispatches` - so
+    that whatever attributes a `Simulator`'s program by scope can
+    attribute a served one (`CampaignService.resident_program`)."""
+
+    def __init__(self, entry: CacheEntry):
+        self.entry = entry
+
+    @property
+    def name(self) -> str:
+        return self.entry.name
+
+    def attach_tracer(self, tracer) -> None:
+        """Every later batch of this class records its `SweepRunner.run`
+        spans (`run` > `dispatch` > `wait` > `fetch` > `results`) in
+        `tracer` under a `run-<n>` trace; None detaches (the spans then
+        go to the service's own tracer, under the batch's trace)."""
+        self.entry.tracer = tracer
+
+    def compiled_text(self) -> str:
+        """Optimized HLO text of the executable the class's batches
+        dispatch, each instruction with its `op_name` path; no compile."""
+        from graphite_tpu.sweep.runner import executable_text
+
+        if self.entry.inputs is None:
+            raise ValueError(f"program {self.name!r} has served no batch")
+        return executable_text(self.entry.jitted, self.entry.inputs)
+
+    @property
+    def last_n_iterations(self) -> int:
+        """Loop trip count of the last batch served (max over its sims)."""
+        return self.entry.last_n_iterations
+
+    @property
+    def last_run_dispatches(self) -> int:
+        return self.entry.last_run_dispatches
 
 
 class ProgramCache:

@@ -39,8 +39,15 @@ and batch occupancy are fixed-bucket histograms; the accounting
 identities are counters), `counters` is a compatibility view over that
 registry, and — when constructed with `tracing=` — every job gets a
 lifecycle span trace (submit → validate → admit/reject → queue dwell →
-execute → emit/failed) and every batch an execution span carrying the
-class, capacity, occupancy, cache hit, compile time and residency.
+execute → emit/failed, and `job`: submit → envelope) and every batch an
+execution span carrying the class, capacity, occupancy, cache hit,
+compile time and residency, over the spans of what a batch pays around
+its run: `pack` (traces to one [B, T, L] layout), `build` (a fresh
+`SweepRunner` + `Simulator`, the inputs placed on the device), `cache`,
+`execute` (enclosing the runner's own `run` > `dispatch` > `wait` >
+`fetch` > `results`) and `demux`, each also a `gt:<name>`
+TraceAnnotation so that under a profiler they lie on the device's
+clock.
 Both ride an injectable monotonic clock (`clock=`) so tests pin exact
 latencies; neither ever touches a traced program, so serve results are
 bit-equal with tracing on or off (regress rung 9).
@@ -55,11 +62,11 @@ import time
 from graphite_tpu.obs.metrics import (
     DEFAULT_COUNT_BUCKETS, MetricsRegistry, RATIO_BUCKETS,
 )
-from graphite_tpu.obs.trace import Tracer
+from graphite_tpu.obs.trace import NO_SPANS, RunSpans, Tracer
 from graphite_tpu.serve.admission import AdmissionController, JobClass, \
     Pending, QueueFullError
 from graphite_tpu.serve.cache import CacheEntry, ProgramCache, \
-    ProgramCacheError
+    ProgramCacheError, ResidentProgram
 from graphite_tpu.serve.job import (
     Job, JobResult, STATUS_FAILED, STATUS_OK,
 )
@@ -208,6 +215,8 @@ class CampaignService:
         self._last_cache_hit = False
         self._last_compile_s = 0.0
         self._last_layout = "solo"
+        # the cache entry the last batch dispatched (`resident_program`)
+        self._last_entry: "CacheEntry | None" = None
         # persistent AOT program store (round 17): the in-memory
         # cache's miss/fill backend — a fleet of service processes
         # sharing one store dir compiles each class once per FLEET
@@ -248,6 +257,9 @@ class CampaignService:
             "backpressure": m.counter(
                 "backpressure_total", "submits refused by a full queue"),
             "batches": m.counter("batches_total", "batches executed"),
+            "padded_slots": m.counter(
+                "padded_slots_total", "batch slots filled with a replica "
+                "of the batch's first job (capacity - real jobs)"),
             "splits": m.counter(
                 "splits_total", "failed batches split in half"),
             "retries": m.counter(
@@ -315,6 +327,20 @@ class CampaignService:
             return contextlib.nullcontext(None)
         return self.tracer.span(trace_id, name, **attrs)
 
+    def _batch_spans(self, batch_id: int):
+        """The span maker of one batch (`RunSpans` under `batch-<n>`:
+        tracer row + `gt:<name>` annotation), or the null one."""
+        if self.tracer is None:
+            return NO_SPANS
+        return RunSpans(self.tracer, f"batch-{batch_id}")
+
+    def resident_program(self) -> "ResidentProgram | None":
+        """A handle to the cached program the last batch dispatched, or
+        None before any batch has run."""
+        if self._last_entry is None:
+            return None
+        return ResidentProgram(self._last_entry)
+
     def export_spans(self, path_or_file) -> int:
         """Write the retained spans as JSON-lines (the `--trace-out`
         artifact); returns the span count, 0 when tracing is off."""
@@ -358,6 +384,7 @@ class CampaignService:
         now = self._clock()
         self._h["admission"].observe(now - t0)
         pending.enqueue_ts = now
+        pending.submit_ts = t0
         self._m["submitted"].inc()
         self._g["queue_depth"].set(self.admission.queue_depth)
         return pending.seq
@@ -531,6 +558,7 @@ class CampaignService:
                 if res.hist is not None:
                     attrs["hist_events"] = int(sum(
                         res.hist.total(s) for s in res.hist.sources))
+                self._job_span(p, batch_id)
                 self.tracer.event(p.job.job_id, "emit", **attrs)
         for p, res in zip(pendings, results):
             self._h["split_depth"].observe(res.attempts)
@@ -540,6 +568,14 @@ class CampaignService:
         self._completed.extend(results)
         self._m["completed"].inc(len(results))
         return results
+
+    def _job_span(self, p: Pending, batch_id: int) -> None:
+        """`job`: first submit -> terminal envelope, over every queue
+        dwell, split and retry between them (reconstructed, like
+        `queue`: a row, no annotation)."""
+        if p.submit_ts is not None:
+            self.tracer.record(p.job.job_id, "job", p.submit_ts,
+                               self._clock(), batch=batch_id)
 
     def _finish_batch_metrics(self, wall: float) -> None:
         self._m["execute_wall"].inc(wall)
@@ -625,6 +661,7 @@ class CampaignService:
             self._m["failed"].inc()
             self._h["split_depth"].observe(p.attempts)
             if self.tracer is not None:
+                self._job_span(p, batch_id)
                 self.tracer.event(p.job.job_id, "failed",
                                   batch=batch_id, attempts=p.attempts,
                                   error=msg)
@@ -669,6 +706,7 @@ class CampaignService:
         jobs = [p.job for p in pendings]
         n, B = len(jobs), cls.batch_cap
         btid = f"batch-{batch_id}"
+        self._m["padded_slots"].inc(B - n)
         # per-batch stats reset FIRST: a failure before they are
         # recomputed must not report the previous batch's numbers
         self._last_residency = 0
@@ -694,8 +732,10 @@ class CampaignService:
                                 for f in cls.params.dvfs.domain_freq_mhz)
                 for p in points:
                     p.setdefault(DVFS_KNOB_FIELD, default)
-        pack = pack_traces(traces, validate=False,
-                           pad_length=cls.pad_length)
+        span = self._batch_spans(batch_id)
+        with span("pack", batch=batch_id):
+            pack = pack_traces(traces, validate=False,
+                               pad_length=cls.pad_length)
         # the budget is passed as an INT always: 0 explicitly disables
         # the runner's fail-fast (None would fall back to the config's
         # own `[general] hbm_budget_bytes`, refusing batches the
@@ -707,13 +747,18 @@ class CampaignService:
             layout_kw = {"layout": (cls.batch_shards, cls.tile_shards)}
         else:
             layout_kw = {"shard_batch": self.shard_batch}
-        runner = SweepRunner(
-            cls.config, pack, points,
-            mailbox_depth=cls.mailbox_depth,
-            hbm_budget_bytes=self.hbm_budget_bytes,
-            telemetry=cls.telemetry,
-            profile=cls.profile, dvfs=cls.dvfs,
-            hist=getattr(cls, "hist", None), **layout_kw)
+        with span("build", batch=batch_id):
+            # what every batch pays before its program can run: a fresh
+            # runner (and the Simulator inside it), and the [B, ...]
+            # initial states and [B, T, L] traces placed on the device
+            runner = SweepRunner(
+                cls.config, pack, points,
+                mailbox_depth=cls.mailbox_depth,
+                hbm_budget_bytes=self.hbm_budget_bytes,
+                telemetry=cls.telemetry,
+                profile=cls.profile, dvfs=cls.dvfs,
+                hist=getattr(cls, "hist", None), **layout_kw)
+            runner._batched_inputs()
         self._last_layout = runner.layout_name
         self._last_residency = int(
             runner.residency_breakdown()["total"])
@@ -730,7 +775,7 @@ class CampaignService:
             raise AssertionError(
                 f"admitted batch per-device residency {admitted} "
                 f"exceeds hbm_budget_bytes={self.hbm_budget_bytes}")
-        with self._span(btid, "cache") as cspan:
+        with span("cache", batch=batch_id) as cspan:
             entry = self._resolve_program(cls, runner, B)
             if cspan is not None:
                 cspan.attrs.update(hit=self._last_cache_hit,
@@ -739,18 +784,28 @@ class CampaignService:
                                    store_hit=self._last_store_hit,
                                    deserialize_s=round(
                                        self._last_deserialize_s, 6))
+        # the program's dispatches are followed by the tracer attached
+        # to its handle (`resident_program().attach_tracer`, a `run-<n>`
+        # trace of its own), else by the service's, inside the batch's
+        own = entry.tracer is None
+        runner.attach_tracer(self.tracer if own else entry.tracer)
         t_exec = self._clock()
-        out = runner.run(max_quanta=self.max_quanta)
+        with span("execute", batch=batch_id,
+                  cache_hit=self._last_cache_hit):
+            out = runner.run(max_quanta=self.max_quanta,
+                             trace_id=btid if own else None)
+        entry.inputs = runner.abstract_inputs()
+        entry.last_n_iterations = runner.last_n_iterations
+        entry.last_run_dispatches = runner.last_run_dispatches
+        self._last_entry = entry
         t_done = self._clock()
         if self.tracer is not None:
-            # one batch-trace execute span + one per member, so a job
-            # trace alone carries its full host timeline
-            self.tracer.record(btid, "execute", t_exec, t_done,
-                               cache_hit=self._last_cache_hit)
+            # one execute span per member too, so a job trace alone
+            # carries its full host timeline
             for p in pendings:
                 self.tracer.record(p.job.job_id, "execute",
                                    t_exec, t_done, batch=batch_id)
-        with self._span(btid, "demux"):
+        with span("demux", batch=batch_id):
             results = []
             for b in range(n):  # the padded tail [n:B] never leaves here
                 p = pendings[b]
@@ -1046,6 +1101,7 @@ class CampaignService:
             "rejected": int(m["rejected"].value),
             "backpressure": int(m["backpressure"].value),
             "batches": int(m["batches"].value),
+            "padded_slots": int(m["padded_slots"].value),
             "splits": int(m["splits"].value),
             "retries": int(m["retries"].value),
             "cache_hits": hits,

@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from graphite_tpu.obs.scopes import tagged
+from graphite_tpu.obs.trace import NO_SPANS, RunSpans
 from graphite_tpu.sweep.knobs import Knobs
 from graphite_tpu.sweep.pack import PackedTraces, pack_traces
 
@@ -89,6 +91,15 @@ class SweepOutcome:
                 "func_errors": r.func_errors,
             })
         return rows
+
+
+def executable_text(program, inputs: tuple) -> str:
+    """Optimized HLO text of a campaign program: a `jax.stages.Compiled`
+    (AOT, store) gives its own; a jit that has run re-reads its own
+    executable for `inputs` (abstract ones will do: no compile)."""
+    if hasattr(program, "as_text"):
+        return program.as_text()
+    return program.lower(*inputs).compile().as_text()
 
 
 def _divisors(n: int) -> "list[int]":
@@ -277,7 +288,15 @@ class SweepRunner:
                     f"quantum_ps knob points must be positive "
                     f"(sims {np.flatnonzero(q <= 0).tolist()}): the "
                     "boundary math divides by the quantum")
-        self.last_n_iterations = None
+        # the last completed run()'s loop trip count (the max over the
+        # batch: a finished sim's carry is frozen while the others go on;
+        # per sim: `SweepOutcome.n_iterations`) and launch count, as
+        # `Simulator` keeps them: one batched dispatch per run()
+        self.last_n_iterations = 0
+        self.last_run_dispatches = 0
+        # host span tracing of run() (attach_tracer); None runs with no
+        # span, no annotation and no extra device sync
+        self.tracer = None
         self._runner = None
         self._runner_max_quanta = None
         self._dtr = None      # device-resident [B, T, L] traces (cached)
@@ -694,9 +713,54 @@ class SweepRunner:
     def _get_runner(self, max_quanta: int):
         self._sync_with_sim()
         if self._runner is None or self._runner_max_quanta != max_quanta:
-            self._runner = jax.jit(self._runner_fn(max_quanta))
+            fn = self._runner_fn(max_quanta)
+
+            def campaign(states, traces, knobs):
+                return fn(states, traces, knobs)
+
+            # the scope registry's tag in the module's name: a cached
+            # executable cannot be served without its scopes (obs/scopes)
+            self._runner = jax.jit(tagged(campaign))
             self._runner_max_quanta = max_quanta
         return self._runner
+
+    def attach_tracer(self, tracer) -> None:
+        """Attach (or, with None, detach) an `obs.Tracer`: every later
+        `run()` records one `run-<n>` trace (or the caller's `trace_id`)
+        of `obs.trace.RUN_SPANS`, each also a `gt:<name>`
+        TraceAnnotation, exactly as `Simulator.attach_tracer`.  Host side
+        only; with a tracer run() adds ONE `block_until_ready` (the
+        `wait` span)."""
+        self.tracer = tracer
+
+    def _spans(self, trace_id=None):
+        if self.tracer is None:
+            return NO_SPANS
+        return RunSpans(self.tracer, trace_id)
+
+    def compiled_text(self, max_quanta: int = 1_000_000) -> str:
+        """Optimized HLO text of the batched program `run()` dispatches,
+        each instruction with its `op_name` path (`obs/scopes.py`)."""
+        return executable_text(self._get_runner(max_quanta),
+                               self.abstract_inputs())
+
+    def abstract_inputs(self) -> tuple:
+        """(states, traces, knobs) as `run()` passes them, the two big
+        ones as shapes only: enough to trace, lower or look up the
+        program, with nothing placed on the device."""
+        from graphite_tpu.engine.state import DeviceTrace
+        from graphite_tpu.sweep.pack import PackedTraces
+
+        B = self.pack.n_sims
+        states_abs = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((B,) + jnp.shape(x),
+                                           jnp.result_type(x)),
+            self.sim.state)
+        dtr_abs = DeviceTrace(**{
+            f: jax.ShapeDtypeStruct(getattr(self.pack, f).shape,
+                                    getattr(self.pack, f).dtype)
+            for f in PackedTraces._TRACE_FIELDS})
+        return states_abs, dtr_abs, self.knobs
 
     def _batched_inputs(self):
         """The [B, ...] initial states and [B, T, L] device traces,
@@ -721,50 +785,66 @@ class SweepRunner:
         so audit-only callers never pay the [B, ...] state broadcast or
         the [B, T, L] trace upload run() caches for execution.
         Lower-once: cached per max_quanta, so audit + cost +
-        fingerprint share one tracing (`lower_count` is the probe)."""
+        fingerprint share one tracing (`lower_count` is the probe), and
+        on one device the jit shares it too."""
         from graphite_tpu.analysis.walk import invar_path_strings
-        from graphite_tpu.engine.state import DeviceTrace
-        from graphite_tpu.sweep.pack import PackedTraces
 
         self._sync_with_sim()
         hit = self._lowered.get(max_quanta)
         if hit is not None:
             return hit
-        B = self.pack.n_sims
-        states_abs = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct((B,) + jnp.shape(x),
-                                           jnp.result_type(x)),
-            self.sim.state)
-        dtr_abs = DeviceTrace(**{
-            f: jax.ShapeDtypeStruct(getattr(self.pack, f).shape,
-                                    getattr(self.pack, f).dtype)
-            for f in PackedTraces._TRACE_FIELDS})
-        closed = jax.make_jaxpr(self._runner_fn(max_quanta,
-                                                abstract=True))(
-            states_abs, dtr_abs, self.knobs)
+        inputs = self.abstract_inputs()
+        jitted = None if isinstance(self.layout_spec, tuple) \
+            else self._get_runner(max_quanta)
+        if hasattr(jitted, "trace"):
+            # the jit's OWN trace (what `make_jaxpr` would return): a
+            # run() after this dispatches without tracing the program a
+            # second time (19.6 s at 64 tiles on the v5e's host, PR 31)
+            closed = jitted.trace(*inputs).jaxpr
+        else:
+            # a mesh layout lowers over a device-less AbstractMesh; an
+            # injected executable (AOT / cache hit) has no trace to share
+            closed = jax.make_jaxpr(self._runner_fn(
+                max_quanta, abstract=True))(*inputs)
         self.lower_count += 1
-        hit = (closed, invar_path_strings((states_abs, dtr_abs,
-                                           self.knobs)))
+        hit = (closed, invar_path_strings(inputs))
         self._lowered[max_quanta] = hit
         return hit
 
-    def run(self, max_quanta: int = 1_000_000) -> SweepOutcome:
+    def run(self, max_quanta: int = 1_000_000,
+            trace_id: "str | None" = None) -> SweepOutcome:
+        """Run the batch: ONE dispatch, one batched fetch, B results.
+        With a tracer attached (`attach_tracer`) the call records `run`
+        > `dispatch` > `wait` > `fetch` > `results` (obs/trace.py:
+        RUN_SPANS) under `run-<n>` or the caller's `trace_id`."""
+        span = self._spans(trace_id)
+        with span("run", call="sweep"):
+            return self._run(max_quanta, span)
+
+    def _run(self, max_quanta: int, span) -> SweepOutcome:
         from graphite_tpu.engine.simulator import (
             DeadlockError, MailboxOverflowError, Simulator,
         )
 
-        B = self.pack.n_sims
         # B identical initial states (same config/geometry -> same init)
         states0, dtr = self._batched_inputs()
-        state, nq_d, deadlock_d, iters_d = self._get_runner(max_quanta)(
-            states0, dtr, self.knobs)
+        with span("dispatch", parent="run"):
+            state, nq_d, deadlock_d, iters_d = self._get_runner(
+                max_quanta)(states0, dtr, self.knobs)
+        if span.on:
+            with span("wait", parent="dispatch"):
+                jax.block_until_ready((nq_d, deadlock_d, iters_d))
         net_part, mem_part, ioc_part, tel_part, prof_part, hist_part = \
             Simulator._result_parts(state)
-        (nq, deadlock, overflow, done, core_h, net_h, mem_h, ioc_h,
-         tel_h, prof_h, hist_h, iters) = jax.device_get((
-            nq_d, deadlock_d, state.net.overflow, state.done, state.core,
-            net_part, mem_part, ioc_part, tel_part, prof_part, hist_part,
-            iters_d))
+        skips_d = None if state.mem is None else state.mem.phase_skips
+        # ONE batched device->host fetch: control flags, every summary
+        # counter, the rings and the gates' skip counts
+        with span("fetch", parent="wait"):
+            (nq, deadlock, overflow, done, core_h, net_h, mem_h, ioc_h,
+             tel_h, prof_h, hist_h, iters, skips_h) = jax.device_get((
+                nq_d, deadlock_d, state.net.overflow, state.done,
+                state.core, net_part, mem_part, ioc_part, tel_part,
+                prof_part, hist_part, iters_d, skips_d))
         if overflow.any():
             raise MailboxOverflowError(
                 f"mailbox ring overflow in sim(s) "
@@ -781,7 +861,16 @@ class SweepRunner:
                 f"max_quanta={max_quanta}")
         # self.sim.state keeps the PRISTINE initial state: repeat run()
         # calls (timed benchmark loops) restart the campaign from zero
-        self.last_n_iterations = np.asarray(iters)
+        self.last_n_iterations = int(np.max(iters))
+        self.last_run_dispatches = 1
+        with span("results", parent="fetch"):
+            return self._outcome(nq, iters, core_h, net_h, mem_h, ioc_h,
+                                 tel_h, prof_h, hist_h, skips_h)
+
+    def _outcome(self, nq, iters, core_h, net_h, mem_h, ioc_h, tel_h,
+                 prof_h, hist_h, skips_h) -> SweepOutcome:
+        """Demux the fetched host arrays into B SimResults."""
+        B = self.pack.n_sims
 
         def row(tree, b):
             return jax.tree_util.tree_map(lambda x: x[b], tree)
@@ -826,10 +915,10 @@ class SweepRunner:
             for b in range(B)
         ]
         phase_skips = None
-        if state.mem is not None:
+        if skips_h is not None:
             from graphite_tpu.engine.simulator import mem_phase_names
 
-            skips = np.asarray(jax.device_get(state.mem.phase_skips))
+            skips = np.asarray(skips_h)
             names = mem_phase_names(self.sim.params)
             phase_skips = [
                 {n: int(v) for n, v in zip(names, skips[b].tolist())}

@@ -94,8 +94,8 @@ def _text_table(tl) -> "list[str]":
 def render_spans(path: str, fmt: str) -> "list[str]":
     """Span JSON-lines -> per-job latency breakdown + batch table."""
     from graphite_tpu.obs.trace import (
-        BATCH_TRACE_PREFIX, JOB_SPANS, RUN_SPANS, job_breakdown,
-        load_jsonl,
+        BATCH_SPANS, BATCH_TRACE_PREFIX, JOB_SPANS, RUN_SPANS,
+        job_breakdown, load_jsonl,
     )
 
     rows = load_jsonl(path)
@@ -122,14 +122,28 @@ def render_spans(path: str, fmt: str) -> "list[str]":
                if r["trace"].startswith(BATCH_TRACE_PREFIX)
                and r["span"] == "batch"]
     if batches:
+        # what each batch paid around its run (BATCH_SPANS) and inside
+        # it (the runner's RUN_SPANS, recorded under the batch's trace)
+        inside = {}
+        for r in rows:
+            if r["trace"].startswith(BATCH_TRACE_PREFIX) \
+                    and r["span"] in BATCH_SPANS + RUN_SPANS:
+                per = inside.setdefault(r["trace"], {})
+                per[r["span"]] = per.get(r["span"], 0) + r["dur_us"]
+        parts = [n for n in BATCH_SPANS + RUN_SPANS
+                 if any(n in per for per in inside.values())]
         bcols = ["batch", "class", "n_jobs", "capacity", "occupancy",
-                 "cache_hit", "compile_s", "dur_us", "ok"]
+                 "cache_hit", "compile_s"] \
+            + [n + "_us" for n in parts] + ["dur_us", "ok"]
         brows = [[str(r["trace"]), str(r.get("class", "-")),
                   str(r.get("n_jobs", "-")), str(r.get("capacity", "-")),
                   str(r.get("occupancy", "-")),
                   str(r.get("cache_hit", "-")),
-                  str(r.get("compile_s", "-")), str(r["dur_us"]),
-                  str(r.get("ok", "-"))] for r in batches]
+                  str(r.get("compile_s", "-"))]
+                 + [str(inside.get(r["trace"], {}).get(n, "-"))
+                    for n in parts]
+                 + [str(r["dur_us"]), str(r.get("ok", "-"))]
+                 for r in batches]
         lines.append("")
         lines.extend(_align(bcols, brows))
     return lines

@@ -278,7 +278,9 @@ class TestServiceObservability:
         # the stub bypasses _execute, so no per-job execute span here
         # (the end-to-end test asserts the full chain)
         names = [s.name for s in svc.tracer.trace("j0")]
-        assert names == ["validate", "admit", "submit", "queue", "emit"]
+        # `job` (submit -> envelope) closes just before the terminal span
+        assert names == ["validate", "admit", "submit", "queue", "job",
+                         "emit"]
         # batch spans carry the execution bookkeeping
         batches = [s for s in svc.tracer.spans if s.name == "batch"]
         assert len(batches) == 2
@@ -527,7 +529,8 @@ class TestEndToEnd:
             [j for j, _ in jobs]) == []
         # the full lifecycle chain, in order, on the real execute path
         assert [s.name for s in svc_on.tracer.trace("j0")] == \
-            ["validate", "admit", "submit", "queue", "execute", "emit"]
+            ["validate", "admit", "submit", "queue", "execute", "job",
+             "emit"]
 
         # span export -> report --spans (text + json)
         spath = str(tmp_path / "spans.jsonl")
