@@ -142,6 +142,35 @@ def gather_row(cache: CacheArrays, line: jax.Array,
                     meta0=meta)
 
 
+def gather_row_pair(cache: CacheArrays, line: jax.Array, line2: jax.Array,
+                    sets_mod=None) -> "tuple[CacheRow, CacheRow]":
+    """`gather_row(cache, line, nonneg=True)` plus the same lanes' rows
+    at a second line (`line2` may be -1: the floor-mod path) — ONE
+    gather, [T, 2, W].
+
+    For a phase that consults a second set of the store it scatters
+    into.  A second `gather_row` there is a reader of the carried store
+    that is no data-dependence predecessor of the phase's `scatter_row`:
+    XLA cannot order it before the in-place write and copies the whole
+    store every iteration instead (see `scatter_row`).  Both rows of one
+    gather precede the scatter through the first row's `meta0`; the
+    second row is read-only (never scatter it: the two rows of a lane
+    may be the same set).
+
+    Keep the [T, 2, W] result.  Laid [2T, W] (lane-major halves) the TPU
+    compiler drops three small relayout copies a phase, and on the v5e
+    the 1024-tile program is 7.6% slower a run and the 64-tile one 0.7%
+    (PERF.md, PR 32): measured, not understood."""
+    T = cache.meta.shape[0]
+    tiles = np.arange(T, dtype=np.int32)[:, None]
+    mod = cache.num_sets if sets_mod is None else jnp.asarray(sets_mod)
+    sets = jnp.stack([nn_mod(line, mod), line2 % mod],
+                     axis=1).astype(jnp.int32)
+    meta = cache.meta[tiles, sets]                 # [T, 2, W] — ONE gather
+    return (row_from_meta(meta[:, 0], sets[:, 0]),
+            row_from_meta(meta[:, 1], sets[:, 1]))
+
+
 def row_from_meta(meta: jax.Array, sets: jax.Array) -> CacheRow:
     """Rebuild a CacheRow from its packed (meta, sets) pair — the compact
     form a row travels in through the shard_map phase exchange (pack ∘

@@ -492,6 +492,22 @@ def _rows_exchange(px: ParallelCtx, local_rows, extra=()):
     return rows, out[1]
 
 
+def _l1_fill_ways(mp: MemParams, px: ParallelCtx, l1i_row, l1d_row, comp_l):
+    """(l1i_way, l1d_way, victim_valid, victim_line): the way of each
+    block-local L1 row that a fill would take and, for the L1 the access
+    goes to (`comp_l`: the L1I), whether that way holds a live line and
+    which.  The L1 rows alone decide it, so a requester phase knows the
+    victim's line BEFORE it reads the L2 store and can fetch the victim's
+    L2 set in the same gather as the request's
+    (`cache_array.gather_row_pair`)."""
+    l1i_way, l1i_vv, l1i_vline, _ = ca.row_pick_victim(
+        l1i_row, mp.l1i.replacement, px.lo_const(mp.l1i.ways_limit))
+    l1d_way, l1d_vv, l1d_vline, _ = ca.row_pick_victim(
+        l1d_row, mp.l1d.replacement, px.lo_const(mp.l1d.ways_limit))
+    return (l1i_way, l1d_way, jnp.where(comp_l, l1i_vv, l1d_vv),
+            jnp.where(comp_l, l1i_vline, l1d_vline))
+
+
 class _DirSetView:
     """Each home lane's directory SET at `line`, behind one interface for
     both programs:
@@ -1483,18 +1499,50 @@ def memory_engine_step(
         # L1 lookups (both caches, masked by component) — each lane's set rows
         # are gathered ONCE per cache level here and scattered back once below
         # (the engine is op-count-bound; see cache_array.py).  Under a
-        # sharded px the gathers read this device's block and ONE packed
-        # all-gather replicates the rows (plus the pre-update miss-type
-        # test bits, which must be read before this phase's own writes).
-        s_line_l = px.lo(s_line)
-        rows_l = (
-            ca.gather_row(ms.l1i, s_line_l, px.lo_const(mp.l1i.sets_mod),
-                          nonneg=True),
-            ca.gather_row(ms.l1d, s_line_l, px.lo_const(mp.l1d.sets_mod),
-                          nonneg=True),
-            ca.gather_row(ms.l2, s_line_l, px.lo_const(mp.l2.sets_mod),
-                          nonneg=True),
-        )
+        # sharded px the gathers read this device's block.
+        do_l1 = starting & ~ibuf_hit
+
+        # The L1 path reads no L2 row, so it runs first and block-local,
+        # on this device's lanes: look-ups, the hit's recency refresh, the
+        # miss's invalidate, and the way (with the line in it) that an L2
+        # hit would then fill.  Whether that fill HAPPENS is the L2's to
+        # say — a mask, not an index — so the victim's line is known
+        # before the L2 store is read and its L2 set rides the phase's one
+        # gather of that store.  The L1 rows never travel: the replicated
+        # control needs only `l1_hit_now` / `l1_miss` of them.
+        s_line_l, comp_l, write_l, do_l1_l = px.lo(
+            (s_line, s_comp_l1i, s_write, do_l1))
+        l1i_row = ca.gather_row(ms.l1i, s_line_l,
+                                px.lo_const(mp.l1i.sets_mod), nonneg=True)
+        l1d_row = ca.gather_row(ms.l1d, s_line_l,
+                                px.lo_const(mp.l1d.sets_mod), nonneg=True)
+        _, l1i_way, l1i_state = ca.row_lookup(l1i_row, s_line_l)
+        _, l1d_way, l1d_state = ca.row_lookup(l1d_row, s_line_l)
+        l1_state = jnp.where(comp_l, l1i_state, l1d_state)
+        l1_permit = jnp.where(write_l, state_writable(l1_state),
+                              state_readable(l1_state))
+        hit_l = do_l1_l & l1_permit
+        miss_l = do_l1_l & ~l1_permit
+        # hits refresh recency under LRU; round_robin's update is a no-op
+        if mp.l1i.replacement != "round_robin":
+            l1i_row = ca.row_touch(l1i_row, l1i_way, hit_l & comp_l)
+        if mp.l1d.replacement != "round_robin":
+            l1d_row = ca.row_touch(l1d_row, l1d_way, hit_l & ~comp_l)
+        # L1 line invalidated on miss before L2 is consulted
+        # (`l1_cache_cntlr.cc:137`) — must precede the L2-hit fill below, so
+        # the fill lands in the just-freed way and survives
+        l1i_row = ca.row_invalidate(l1i_row, s_line_l, miss_l & comp_l)
+        l1d_row = ca.row_invalidate(l1d_row, s_line_l, miss_l & ~comp_l)
+        l1i_fway, l1d_fway, ev_valid_l, ev_line_l = _l1_fill_ways(
+            mp, px, l1i_row, l1d_row, comp_l)
+        # the L2 store's ONE reader in this phase: the request's set row
+        # and the candidate victim's (cache_array.gather_row_pair).  Only
+        # the request's row travels; the victim's look-up feeds the local
+        # cloc scatter and nothing else.
+        l2_mod_l = px.lo_const(mp.l2.sets_mod)
+        l2_row_l, ev_row_l = ca.gather_row_pair(
+            ms.l2, s_line_l, ev_line_l, l2_mod_l)
+        ev_hit_l, ev_way_l, _ = ca.row_lookup(ev_row_l, ev_line_l)
         if mp.l2.track_miss_types:
             mt_bits_l = (_mt_test(ms.mt, MT_EVICTED, s_line_l),
                          _mt_test(ms.mt, MT_INVALIDATED, s_line_l),
@@ -1503,17 +1551,15 @@ def memory_engine_step(
             mt_bits_l = ()
         if mp.l2.track_line_utilization:
             mt_bits_l = mt_bits_l + (_util_row_local(
-                ms.l2_util, s_line_l, px.lo_const(mp.l2.sets_mod)),)
-        (l1i_row, l1d_row, l2_row), mt_bits = _rows_exchange(
-            px, rows_l, mt_bits_l)
+                ms.l2_util, s_line_l, l2_mod_l),)
+        # ONE packed all-gather under a sharded px: the L2 row, the L1
+        # path's two verdicts, and the pre-update miss-type test bits
+        # (read before this phase's own writes)
+        (l2_row,), mt_bits = _rows_exchange(
+            px, (l2_row_l,), (hit_l, miss_l) + mt_bits_l)
+        l1_hit_now, l1_miss, mt_bits = mt_bits[0], mt_bits[1], mt_bits[2:]
         if mp.l2.track_line_utilization:
             lu_row, mt_bits = mt_bits[-1], mt_bits[:-1]
-        l1i_hit, l1i_way, l1i_state = ca.row_lookup(l1i_row, s_line)
-        l1d_hit, l1d_way, l1d_state = ca.row_lookup(l1d_row, s_line)
-        l1_state = jnp.where(s_comp_l1i, l1i_state, l1d_state)
-        l1_permit = jnp.where(s_write, state_writable(l1_state),
-                              state_readable(l1_state))
-        do_l1 = starting & ~ibuf_hit
 
         sync_core = jnp.where(s_comp_l1i, sync_core_l1i, sync_core_l1d)
         l1_dat = jnp.where(
@@ -1522,9 +1568,6 @@ def memory_engine_step(
         l1_tag = jnp.where(
             s_comp_l1i, ccycles(mp.l1i.tags_cycles), ccycles(mp.l1d.tags_cycles))
         sync_l1_l2 = jnp.where(s_comp_l1i, sync_l1i_l2, sync_l1d_l2)
-
-        l1_hit_now = do_l1 & l1_permit
-        l1_miss = do_l1 & ~l1_permit
 
         # L2 lookup for L1 misses
         l2_hit, l2_way, l2_state = ca.row_lookup(l2_row, s_line)
@@ -1547,52 +1590,26 @@ def memory_engine_step(
         stall_start = upgrade & evict_cell_busy
         l2_miss_go = l2_miss & ~stall_start
 
-        # --- apply the L1-hit path -------------------------------------------
+        # --- the L1-hit path (its rows were refreshed above) ------------------
         sclock = clock_ps + sync_core           # processMemOpFromCore entry
         l1_hit_done_ps = sclock + l1_dat
-
-        # hits refresh recency under LRU; round_robin's update is a no-op
-        if mp.l1i.replacement != "round_robin":
-            l1i_row = ca.row_touch(l1i_row, l1i_way, l1_hit_now & s_comp_l1i)
-        if mp.l1d.replacement != "round_robin":
-            l1d_row = ca.row_touch(l1d_row, l1d_way, l1_hit_now & ~s_comp_l1i)
-
-        # L1 line invalidated on miss before L2 is consulted
-        # (`l1_cache_cntlr.cc:137`) — must precede the L2-hit fill below, so
-        # the fill lands in the just-freed way and survives
-        l1i_row = ca.row_invalidate(l1i_row, s_line, l1_miss & s_comp_l1i)
-        l1d_row = ca.row_invalidate(l1d_row, s_line, l1_miss & ~s_comp_l1i)
 
         # --- apply the L2-hit path (fill L1 from L2) -------------------------
         # timing: L1 tags (miss) + L2 sync + L2 data+tags + L1 data+tags
         l2_hit_done_ps = sclock + l1_tag + sync_l1_l2 + ccycles(
             mp.l2.data_and_tags_cycles) + l1_dat
-        # L1 fill state = L2 state (`insertCacheLineInL1`)
-        fill_l1i = l2_hit_now & s_comp_l1i
-        fill_l1d = l2_hit_now & ~s_comp_l1i
-
-        def l1_fill(row, mask, st, policy, ways):
-            way, v_valid, v_line, _ = ca.row_pick_victim(row, policy, ways)
-            out = ca.row_insert(row, s_line, way, st, mask)
-            return out, way, v_valid & mask, v_line
-
-        l1i_row, _, l1i_ev, l1i_ev_line = l1_fill(
-            l1i_row, fill_l1i, l2_state, mp.l1i.replacement,
-            mp.l1i.ways_limit)
-        l1d_row, _, l1d_ev, l1d_ev_line = l1_fill(
-            l1d_row, fill_l1d, l2_state, mp.l1d.replacement,
-            mp.l1d.ways_limit)
+        # L1 fill state = L2 state (`insertCacheLineInL1`), into the way
+        # picked above; block-local like the rest of the L1 path
+        fill_l, l2_state_l = px.lo((l2_hit_now, l2_state))
+        l1i_row = ca.row_insert(l1i_row, s_line_l, l1i_fway, l2_state_l,
+                                fill_l & comp_l)
+        l1d_row = ca.row_insert(l1d_row, s_line_l, l1d_fway, l2_state_l,
+                                fill_l & ~comp_l)
         # L1 victims: clear their cached-loc in L2 (line stays valid in L2).
         # The whole read-modify-write chain is block-local: its only
         # consumer is the local cloc scatter, so nothing travels.
-        l1_ev = l1i_ev | l1d_ev
-        l1_ev_line = jnp.where(l1i_ev, l1i_ev_line, l1d_ev_line)
-        ev_line_l = px.lo(l1_ev_line)
-        l2_mod_l = px.lo_const(mp.l2.sets_mod)
-        ev_hit_l, ev_way_l, _ = ca.lookup(ms.l2, ev_line_l, l2_mod_l)
-        ev_sets_l = (ev_line_l % jnp.asarray(l2_mod_l)).astype(jnp.int32)
-        l2_cloc = px.entry_set(ms.l2_cloc, ev_sets_l, ev_way_l,
-                               px.lo(l1_ev) & ev_hit_l, 0)
+        l2_cloc = px.entry_set(ms.l2_cloc, ev_row_l.sets, ev_way_l,
+                               fill_l & ev_valid_l & ev_hit_l, 0)
         # record new cached-loc for the filled line
         f_sets = nn_mod(s_line, jnp.asarray(mp.l2.sets_mod)).astype(jnp.int32)
         new_cloc = jnp.where(s_comp_l1i, MOD_L1I, MOD_L1D).astype(jnp.uint8)
@@ -1624,8 +1641,8 @@ def memory_engine_step(
                 ms.counters, lu_cur, up_go, enabled))
         # scatter the three set rows back — ONE scatter per cache level,
         # each device taking its own lanes' rows
-        l1i_upd = ca.scatter_row(ms.l1i, px.lo(l1i_row))
-        l1d_upd = ca.scatter_row(ms.l1d, px.lo(l1d_row))
+        l1i_upd = ca.scatter_row(ms.l1i, l1i_row)
+        l1d_upd = ca.scatter_row(ms.l1d, l1d_row)
         l2_upd = ca.scatter_row(ms.l2, px.lo(l2_row))
         mail = ms.mail
         noc = ms.noc
@@ -2077,8 +2094,9 @@ def _sharer_step(mp, ms: MemState, fmhz, enabled, progress,
     if mp.l2.track_miss_types:
         ms = ms.replace(mt=_mt_update(ms.mt, MT_INVALIDATED, fline_l,
                                       px.lo(inv_l1), True))
+    # `cloc` is this very element, read above: the store's one reader
     l2_cloc = px.entry_set(ms.l2_cloc, sets_l, px.lo(l2_way),
-                           px.lo(inv_l1), 0)
+                           px.lo(inv_l1), 0, cur=px.lo(cloc))
 
     # ack message back to the home
     ack = jnp.where(
@@ -2678,12 +2696,21 @@ def _requester_fill(mp, ms: MemState, rec: RecView, clock_ps, fmhz, enabled,
     # miss-type test bits — the victim's own bitmap write is folded back
     # in below via the bucket-collision correction)
     line_l = px.lo(line)
-    rows_l = (ca.gather_row(ms.l2, line_l, px.lo_const(mp.l2.sets_mod),
-                            nonneg=True),
-              ca.gather_row(ms.l1i, line_l, px.lo_const(mp.l1i.sets_mod),
-                            nonneg=True),
-              ca.gather_row(ms.l1d, line_l, px.lo_const(mp.l1d.sets_mod),
-                            nonneg=True))
+    l2_mod_l = px.lo_const(mp.l2.sets_mod)
+    # The L1 rows stay block-local: the fill's L1 way and its victim are
+    # the L1 rows' to say and nothing replicated reads them.  So the
+    # victim's line is known before the L2 store is read, and the store's
+    # ONE reader fetches the filled line's set row and the candidate
+    # victim's (cache_array.gather_row_pair; only the first travels).
+    comp_l = px.lo(comp_l1i)
+    l1i_r = ca.gather_row(ms.l1i, line_l, px.lo_const(mp.l1i.sets_mod),
+                          nonneg=True)
+    l1d_r = ca.gather_row(ms.l1d, line_l, px.lo_const(mp.l1d.sets_mod),
+                          nonneg=True)
+    l1i_way, l1d_way, ev_valid_l, ev_line_l = _l1_fill_ways(
+        mp, px, l1i_r, l1d_r, comp_l)
+    l2_row_l, ev_row_l = ca.gather_row_pair(ms.l2, line_l, ev_line_l,
+                                            l2_mod_l)
     if mp.l2.track_miss_types:
         mt_bits_l = (_mt_test(ms.mt, MT_EVICTED, line_l),
                      _mt_test(ms.mt, MT_INVALIDATED, line_l))
@@ -2692,7 +2719,7 @@ def _requester_fill(mp, ms: MemState, rec: RecView, clock_ps, fmhz, enabled,
     if mp.l2.track_line_utilization:
         mt_bits_l = mt_bits_l + (_util_row_local(
             ms.l2_util, line_l, px.lo_const(mp.l2.sets_mod)),)
-    (l2_r, l1i_r, l1d_r), mt_bits = _rows_exchange(px, rows_l, mt_bits_l)
+    (l2_r,), mt_bits = _rows_exchange(px, (l2_row_l,), mt_bits_l)
     if mp.l2.track_line_utilization:
         lu_row, mt_bits = mt_bits[-1], mt_bits[:-1]
 
@@ -2708,8 +2735,8 @@ def _requester_fill(mp, ms: MemState, rec: RecView, clock_ps, fmhz, enabled,
     evict_go = need_evict & fill
 
     new_state = jnp.where(mail.rep_type == MSG_EX_REP, MODIFIED, SHARED)
-    l2 = ca.scatter_row(ms.l2, px.lo(ca.row_insert(l2_r, line, way,
-                                                   new_state, fill)))
+    l2_new_l = px.lo(ca.row_insert(l2_r, line, way, new_state, fill))
+    l2 = ca.scatter_row(ms.l2, l2_new_l)
     if mp.l2.track_line_utilization:
         # the victim leaves the L2 (classify); the filled line's counter
         # restarts with the miss access itself as its first use
@@ -2760,27 +2787,25 @@ def _requester_fill(mp, ms: MemState, rec: RecView, clock_ps, fmhz, enabled,
         rep_time=jnp.where(fill, 0, mail.rep_time),
     )
 
-    # L1 fill (the rows were gathered in the phase exchange above)
+    # L1 fill, block-local (rows and ways picked above)
     l1_state = new_state  # L1 gets the L2 state (`insertCacheLineInL1`)
-    l1i_way, l1i_vv, l1i_vline, _ = ca.row_pick_victim(
-        l1i_r, mp.l1i.replacement, mp.l1i.ways_limit)
-    l1d_way, l1d_vv, l1d_vline, _ = ca.row_pick_victim(
-        l1d_r, mp.l1d.replacement, mp.l1d.ways_limit)
+    fill_l, l1_state_l = px.lo((fill, l1_state))
     l1i = ca.scatter_row(
-        ms.l1i, px.lo(ca.row_insert(l1i_r, line, l1i_way, l1_state,
-                                    fill & comp_l1i)))
+        ms.l1i, ca.row_insert(l1i_r, line_l, l1i_way, l1_state_l,
+                              fill_l & comp_l))
     l1d = ca.scatter_row(
-        ms.l1d, px.lo(ca.row_insert(l1d_r, line, l1d_way, l1_state,
-                                    fill & ~comp_l1i)))
-    # clear cached-loc of L1 victims in L2 (block-local RMW chain)
-    l1_ev = (fill & comp_l1i & l1i_vv) | (fill & ~comp_l1i & l1d_vv)
-    l1_ev_line = jnp.where(comp_l1i, l1i_vline, l1d_vline)
-    ev_line_l = px.lo(l1_ev_line)
-    l2_mod_l = px.lo_const(mp.l2.sets_mod)
-    ev_hit_l, ev_way_l, _ = ca.lookup(l2, ev_line_l, l2_mod_l)
-    ev_sets_l = (ev_line_l % jnp.asarray(l2_mod_l)).astype(jnp.int32)
-    l2_cloc = px.entry_set(l2_cloc, ev_sets_l, ev_way_l,
-                           px.lo(l1_ev) & ev_hit_l, 0)
+        ms.l1d, ca.row_insert(l1d_r, line_l, l1d_way, l1_state_l,
+                              fill_l & ~comp_l))
+    # clear cached-loc of L1 victims in L2 (block-local RMW chain).  The
+    # victim is looked up in the L2 as this phase's fill leaves it: its
+    # row as gathered, or the filled row where both lines share a set
+    same_set = (ev_row_l.sets == l2_new_l.sets)[:, None]
+    ev_hit_l, ev_way_l, _ = ca.row_lookup(
+        ev_row_l.replace(tag=jnp.where(same_set, l2_new_l.tag, ev_row_l.tag),
+                         st=jnp.where(same_set, l2_new_l.st, ev_row_l.st)),
+        ev_line_l)
+    l2_cloc = px.entry_set(l2_cloc, ev_row_l.sets, ev_way_l,
+                           fill_l & ev_valid_l & ev_hit_l, 0)
 
     if mp.l2.track_miss_types:
         mt = ms.mt

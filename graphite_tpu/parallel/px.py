@@ -138,14 +138,22 @@ class ParallelCtx:
         lt = jnp.arange(arr.shape[0], dtype=jnp.int32)
         return arr.at[lt, col].add(delta.astype(arr.dtype))
 
-    def entry_set(self, arr, sets, way, mask, value):
+    def entry_set(self, arr, sets, way, mask, value, cur=None):
         """``arr[t, sets[t], way[t]] = value[t] where mask[t]`` on this
         device's rows; arr is block-local [Tl, S, W] and every operand is
         block-local [Tl] (callers px.lo replicated operands first; value
         may be a scalar).  Written add-a-delta so the scatter aliases in
-        place (per-lane rows are unique)."""
+        place (per-lane rows are unique).
+
+        `cur`: the elements as the caller has ALREADY read them from
+        `arr` (block-local [Tl]).  A phase that has looked at the element
+        passes it here, so that the store keeps one reader and that
+        reader feeds the scatter: a second read that the scatter does not
+        depend on makes XLA copy the whole carried store
+        (`cache_array.scatter_row`)."""
         lt = jnp.arange(arr.shape[0], dtype=jnp.int32)
-        cur = arr[lt, sets, way]
+        if cur is None:
+            cur = arr[lt, sets, way]
         value = jnp.broadcast_to(jnp.asarray(value, arr.dtype), cur.shape)
         return arr.at[lt, sets, way].add(
             jnp.where(mask, value - cur, jnp.zeros_like(cur)),

@@ -3,11 +3,14 @@
 Graphite's whole reason to exist is simulation *throughput* — the
 reference parallelizes ONE simulation across host machines because
 architects run campaigns: design-space sweeps over timing parameters,
-traces, and seeds.  The TPU port has the inverse opportunity: the
-per-iteration op tail (ROADMAP: config 5's ~0.2 ms dense floor) is a
-per-*program* cost, so `vmap`ping B independent simulations through one
-program amortizes it B-ways — the batching shape that makes inference
-stacks fast.
+traces, and seeds.  The TPU port has the inverse opportunity: `vmap`
+B independent simulations through ONE program, so that a campaign pays
+one compile, one dispatch and one fetch a batch.  What it does NOT buy,
+measured on the v5e (PERF.md, PR 31 / PR 32; `campaign64-dram`, B = 4):
+a B-fold amortisation of the iteration.  Under `vmap` every gate is off
+(a batched predicate turns a `lax.cond` into a select), so the batched
+iteration runs every phase, mailbox and sync block for every sim, and
+its device time is several times the gated solo program's per lane.
 
 Mechanics:
  - traces pack to a common [B, T, L] layout (sweep/pack.py); `vmap` maps

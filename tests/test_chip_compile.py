@@ -130,6 +130,42 @@ def test_ref_default_16_compiles(one_chip):
     _fits(_report("ref-default-16", _compile_run(sim, one_chip)))
 
 
+def test_ungated_16_updates_the_l2_meta_store_in_place(one_chip):
+    """What this guards against: a reader of a carried store that is not
+    a data-dependence predecessor of the store's scatter.  XLA cannot
+    order such a read before the in-place write and copies the whole
+    store every iteration instead; `cache_array.scatter_row`'s contract
+    ("the scatter is then the meta array's only remaining use and XLA
+    updates the loop-carried buffer in place instead of copying it")
+    holds only while a phase has ONE reader of the store it scatters
+    into.  tests/test_inplace_stores.py asks the CPU backend, a proxy;
+    this asks the compiler whose answer the served campaign cell pays
+    for (PR 32: four `copy u32[4,64,1024,8]` an iteration were a third
+    of `campaign64-dram`'s device time), of the gates-off program a
+    campaign runs, at 16 tiles: no `while` body of it copies a
+    [T, sets, ways] half of the int64 L2 meta store (the TPU splits
+    it into two u32 halves; `l2_cloc` has the same dimensions at u8)."""
+    from graphite_tpu.analysis.loop_copies import copies_of, loop_copies
+    from graphite_tpu.trace.synthetic import memory_stress_trace
+
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        16, core="iocoom", shared_mem=True, clock_scheme="lax_barrier")))
+    sim = Simulator(sc, memory_stress_trace(16, n_accesses=8),
+                    phase_gate=False, mem_gate_bytes=0)
+    compiled = _compile_run(sim, one_chip)
+    _fits(_report("ref-default-16-ungated", compiled))
+    copies = loop_copies(compiled.as_text())
+    meta = sim.state.mem.l2.meta
+    assert str(meta.dtype) == "int64"
+    halves = copies_of(copies, meta.shape, ("u32", "s64"))
+    assert not halves, [c.line[:200] for c in halves]
+    # what the iteration body still copies whole (PERF.md section 5): the
+    # directory-entry store's relayout in front of the working-set gather
+    print({"program": "ref-default-16-ungated", "in_loop_copies": sorted(
+        (c.loop.depth, c.dtype, c.shape) for c in copies
+        if c.size >= meta.size)})
+
+
 @pytest.mark.slow
 def test_ref_default_64_compiles(one_chip):
     sim = _ref_default(64, points=64)
