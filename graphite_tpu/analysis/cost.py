@@ -2,7 +2,7 @@
 
 PR 3's auditor checks *structural* invariants of the lowered jaxpr;
 nothing measured what a program *costs* until it ran on hardware we
-rarely have.  This module is the static counterpart to bench.py: walking
+rarely have.  This module is the static counterpart to a run: walking
 the same `jax.make_jaxpr` artifacts `Simulator.lower()` /
 `SweepRunner.lower()` expose (via the analysis/walk.py traversal), it
 computes

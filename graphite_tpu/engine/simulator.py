@@ -189,8 +189,8 @@ def _mem_state_bytes(mp) -> int:
 
 def auto_mailbox_depth(batch: "TraceBatch") -> int:
     """Upper-bound the per-(dst, src) mailbox ring occupancy from the
-    recorded trace, so no caller has to guess `mailbox_depth` (VERDICT
-    round-3 ask: overflow unreachable for recorded traces).
+    recorded trace, so no caller has to guess `mailbox_depth`: overflow
+    is unreachable for recorded traces.
 
     The bound is barrier-phase aware: records are bucketed by the count
     of completed blocking barrier waits before them on their lane (the
@@ -363,7 +363,6 @@ class Simulator:
         barrier_batch: int | None = None,
         telemetry=None,
         profile=None,
-        base_consolidate: bool | None = None,
         dvfs=None,
         hist=None,
     ):
@@ -491,10 +490,8 @@ class Simulator:
             # (PERF.md round-5).  Private-L2 protocols only.  Auto-on
             # stays conservative: single-device programs whose sharers
             # store alone is >= 64 MB.  Meshed runs stage on EXPLICIT
-            # dir_stage=True (round 12: the per-lane rows shard with the
-            # directory, but only under the consolidated base — the
-            # check below enforces that; auto-enabling under a mesh
-            # would surprise base_consolidate=False configurations).
+            # dir_stage=True (the per-lane rows shard with the
+            # directory).
             private_l2 = mem_params.protocol.startswith("pr_l1_pr_l2")
             sharers_bytes = (4 * n_tiles * mem_params.dir_sets
                              * mem_params.dir_ways
@@ -502,14 +499,6 @@ class Simulator:
             if dir_stage is None:
                 dir_stage = (private_l2 and mesh is None
                              and sharers_bytes >= 64 << 20)
-            # Round-12 base consolidation (one packed directory gather +
-            # one merged scatter per iteration; MemParams.base_consolidate).
-            # None = config `[general] base_consolidate` (default on);
-            # False restores the round-11 per-phase layout — the regress
-            # equivalence oracle.
-            if base_consolidate is not None:
-                mem_params = dataclasses.replace(
-                    mem_params, base_consolidate=bool(base_consolidate))
             if dir_stage:
                 if not private_l2:
                     # Not "pending work": the shared-L2 engines don't
@@ -526,27 +515,10 @@ class Simulator:
                         "per phase (no per-entry dense-pass storm to "
                         "stage away), so staging would add table scans "
                         "for nothing")
-                if mesh is not None and not mem_params.base_consolidate:
-                    # the per-lane staging rows shard with the directory
-                    # (round 12), but only the consolidated working-set
-                    # gather overlays them block-locally before the
-                    # exchange — the legacy per-phase view never did
-                    raise ValueError(
-                        "dir_stage under a mesh needs the round-12 "
-                        "consolidated base (base_consolidate=True): the "
-                        "legacy per-phase directory view does not "
-                        "overlay the staging rows before the shard_map "
-                        "exchange.  Drop base_consolidate=False (the "
-                        "consolidated default shards the per-home-lane "
-                        "staging rows with the directory), or run the "
-                        "sim as a campaign under SweepRunner's 2D "
-                        "batch x tile layout (layout='tile'/'2d'), "
-                        "which composes the consolidated exchange with "
-                        "batching")
                 wpi = (5 if mem_params.dir_type == "limited_no_broadcast"
                        else 3)
-                # per-LANE capacity (round-12 layout): each home stages
-                # at most writes_per_iter entries per iteration
+                # per-LANE capacity: each home stages at most
+                # writes_per_iter entries per iteration
                 mem_params = dataclasses.replace(
                     mem_params,
                     dir_stage_cap=wpi * inner_block)

@@ -115,13 +115,6 @@ class EngineParams:
     # stays quantum-bounded like the per-iteration active check).
     # Simple-core memoryless runs only; 1 = off.
     plain_unroll: int = 1
-    # Run the net/barrier/mutex/pub/join machinery unconditionally
-    # instead of behind their any-lane-active lax.conds.  The conds are a
-    # pure wall-clock optimization (skip scatter kernels on quiet
-    # iterations); disabling them works around an XLA TPU kernel fault
-    # observed at 1024 tiles x full directory on send-heavy traces
-    # (PERF.md "Known limitation").
-    block_gates: bool = True
     # lax_p2p clock-skew scheme (`lax_p2p_sync_client.h:13-83`): when set,
     # each iteration every tile draws a pseudorandom partner and advances
     # only if its clock is within `slack` of the partner's — the
@@ -274,11 +267,6 @@ def subquantum_iteration(
         p2p_round = state.p2p_round + 1
     else:
         p2p_round = state.p2p_round
-
-    def _gate(pred):
-        # block_gates=False forces every machinery cond down its live
-        # branch (constant predicate folds the cond away entirely)
-        return pred if params.block_gates else jnp.asarray(True)
 
     # --- memory subsystem (caches + coherence protocol) ------------------
     # Runs every iteration: requester lanes start/advance their record's
@@ -513,7 +501,7 @@ def subquantum_iteration(
     with scope("gt.net.mailbox"):
         (time_ps_new, lat_arr_new, head_new, count_new, overflow, noc_user,
          recv_now, recv_time, recv_lat) = lax.cond(
-            _gate(jnp.any(send_now | (active & is_recv))), _net_block, _net_skip,
+            jnp.any(send_now | (active & is_recv)), _net_block, _net_skip,
             None)
     recv_wait_ps = jnp.maximum(recv_time - core.clock_ps, 0)
     recv_wait_ps = jnp.where(recv_now, recv_wait_ps, 0)
@@ -586,7 +574,7 @@ def subquantum_iteration(
         (barrier_count, barrier_arrived, barrier_time, barrier_waiting,
          released, release_time, barrier_gen, barrier_release_ps,
          barrive_now, bsync_now, bsync_time) = lax.cond(
-            _gate(jnp.any(active & (is_binit | is_bwait | is_barrive | is_bsync))),
+            jnp.any(active & (is_binit | is_bwait | is_barrive | is_bsync)),
             _barrier_block, _barrier_skip, None)
     barrier_wait_ps = jnp.maximum(release_time - core.clock_ps, 0)
     barrier_wait_ps = jnp.where(released, barrier_wait_ps, 0)
@@ -789,10 +777,10 @@ def subquantum_iteration(
          mutex_wait_ps, cond_waiting, cond_signaled, cond_arrival_ps,
          cond_wake_ps, cond_sig_time_ps, cond_bcast_time_ps,
          cond_post_commit) = lax.cond(
-            _gate(jnp.any((active & (is_minit | is_munlock | is_csig
-                                   | is_cbcast | is_cinit))
-                          | (is_mlock & ~done & (sync.mutex_waiting | active))
-                          | (is_cwait & ~done))),
+            jnp.any((active & (is_minit | is_munlock | is_csig
+                               | is_cbcast | is_cinit))
+                    | (is_mlock & ~done & (sync.mutex_waiting | active))
+                    | (is_cwait & ~done)),
             _mutex_cond_block, _mutex_cond_skip, None)
 
     # --- published cond signals + COND_JOIN (co-located split form) ------
@@ -831,7 +819,7 @@ def subquantum_iteration(
 
     with scope("gt.sync.mutex_cond"):
         (cond_sig_seq, cond_sig_seq_ps, cjoin_now, cjoin_time) = lax.cond(
-            _gate(jnp.any(pub_now | (active & is_cjoin))),
+            jnp.any(pub_now | (active & is_cjoin)),
             _pub_block,
             lambda _: (sync.cond_sig_seq, sync.cond_sig_seq_ps,
                        jnp.zeros((T,), jnp.bool_), jnp.zeros((T,), I64)),
@@ -857,7 +845,7 @@ def subquantum_iteration(
 
     with scope("gt.sync.join"):
         join_now, join_time = lax.cond(
-            _gate(jnp.any(active & is_join)), _join_block,
+            jnp.any(active & is_join), _join_block,
             lambda _: (jnp.zeros((T,), jnp.bool_), core.clock_ps), None)
 
     # --- commit: advance mask, clocks, counters --------------------------
@@ -1253,9 +1241,7 @@ def _quantum_loop(params, trace, state, qend, trace_base=None, px=IDENT,
 
         staged = (params.mem is not None
                   and getattr(params.mem, "dir_stage_cap", 0))
-        # the flush's gate goes through the same forcing as the others
-        flush_gate = (staged and params.mem.phase_gate
-                      and params.block_gates)
+        flush_gate = staged and params.mem.phase_gate
         if flush_gate:
             base_skips0 = state.mem.base_skips[0]
         state, progress, _ = lax.while_loop(

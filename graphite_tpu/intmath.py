@@ -9,8 +9,8 @@ values that are non-negative by construction (line numbers, tile ids,
 cycle counts, picosecond durations) — where truncating and flooring
 division agree exactly.  These helpers emit the single `lax.div` /
 `lax.rem` equation instead; results are bit-identical to the floor
-forms for non-negative operands (the golden interpreters and the
-regress base-consolidation rung pin this on randomized traces).
+forms for non-negative operands (the golden interpreters pin this on
+randomized traces).
 
 CONTRACT: both operands must be provably >= 0 (divisor > 0).  Sites
 where a value can be negative — e.g. victim lines read off an invalid

@@ -197,10 +197,9 @@ def _req_earliest(mail):
     """Earliest pending request per HOME over the per-requester lanes:
     (requester int32[T], found bool[T]).
 
-    The compact form of the old [T, T] row scan: key = time * T +
-    requester, segment-min'd into home buckets — the SAME deterministic
-    total order `_row_earliest` used on the matrix, so the pop order is
-    bit-identical to the round-11 layout."""
+    key = time * T + requester, segment-min'd into home buckets — the
+    same deterministic total order `_row_earliest` applies to a [T, T]
+    matrix, without the matrix."""
     T = mail.req_type.shape[0]
     r = np.arange(T, dtype=np.int64)
     live = mail.req_type != MSG_NONE
@@ -508,79 +507,6 @@ def _l1_fill_ways(mp: MemParams, px: ParallelCtx, l1i_row, l1d_row, comp_l):
             jnp.where(comp_l, l1i_vline, l1d_vline))
 
 
-class _DirSetView:
-    """Each home lane's directory SET at `line`, behind one interface for
-    both programs:
-
-     - single-device (IDENT px): ONE lazy [T, DW] packed-word row gather
-       serves the lookup, the allocation rows, and every entry() field
-       (unpacked with free ALU bit math inside the consuming fusions),
-       plus the lazy sharers-row gather;
-     - sharded px: the whole set's rows are gathered block-locally and
-       exchanged in ONE collective up front; lookup/entry() are then
-       replicated take_along_axis selections (a second exchange for the
-       way-dependent entry read would double the phase's collectives).
-    """
-
-    def __init__(self, px: ParallelCtx, d: "DirectoryArrays", line, mp):
-        self.sets = nn_mod(line, mp.dir_sets).astype(jnp.int32)
-        self._line = line
-        self._sharded = px.sharded
-        self._dw = d.entry.shape[2]
-        if px.sharded:
-            line_l = px.lo(line)
-            Tl = d.entry.shape[0]
-            lt = np.arange(Tl, dtype=np.int32)
-            sets_l = nn_mod(line_l, mp.dir_sets).astype(jnp.int32)
-            self._word_r, self._sharers_r = px.ag((
-                d.entry[lt, sets_l], d.sharers[lt, sets_l]))
-        else:
-            self._d = d
-            T = d.entry.shape[0]
-            self._tiles = np.arange(T, dtype=np.int32)
-            self._word_r = None
-            self._sharers_r = None
-
-    def _word_row(self):
-        """The set's packed entry words, [T, DW]."""
-        if self._word_r is None:
-            self._word_r = self._d.entry[self._tiles, self.sets]
-        return self._word_r
-
-    def rows(self):
-        """(tag_row, nsharers_row) — the [T, DW] set rows the allocation
-        decisions (free way / min-sharer victim) need."""
-        row = self._word_row()
-        return dir_tag(row), dir_nsh(row)
-
-    def lookup(self):
-        """(found, way) of `line` within the set."""
-        tag_row = dir_tag(self._word_row())
-        way_hits = tag_row == self._line[:, None]
-        found = way_hits.any(axis=1)
-        way = jnp.argmax(way_hits, axis=1).astype(jnp.int32)
-        return found, way
-
-    def _sharers_row(self):
-        """The set's sharer words, [T, DW*SW] (stored set-row-major)."""
-        if self._sharers_r is None:
-            self._sharers_r = self._d.sharers[self._tiles, self.sets]
-        return self._sharers_r
-
-    def entry(self, way):
-        """(tags, dstate, owner, sharers, nsh) at `way`."""
-        row = self._sharers_row()
-        row3 = row.reshape(row.shape[0], self._dw, -1)
-        sharers = jnp.take_along_axis(row3, way[:, None, None], axis=1)[:, 0]
-        word = jnp.take_along_axis(self._word_row(), way[:, None],
-                                   axis=1)[:, 0]
-        if not self._sharded and self._d.skey is not None:
-            # staged writes since the last flush supersede the big store
-            sharers = _stage_overlay(self._d, self.sets, way, sharers)
-        return (dir_tag(word), dir_state(word), dir_owner(word),
-                sharers, dir_nsh(word))
-
-
 @dataclasses.dataclass(frozen=True)
 class RecView:
     """Current trace record fields needed by the memory engine (all [T])."""
@@ -668,7 +594,7 @@ PHASE_NAMES = ("requester", "home_evict", "home_start", "sharer",
                "home_finish", "requester_fill")
 
 
-# MemState.base_skips order: iterations whose consolidated base (the
+# MemState.base_skips order: iterations whose base (the directory
 # working-set gather and the merged scatter) was skipped, inner blocks
 # whose staging flush was skipped
 BASE_SKIP_NAMES = ("base", "flush")
@@ -752,32 +678,23 @@ def mem_idle_out(mp: MemParams, ms, rec: "RecView", enabled,
 # store once per inner_block iterations (engine/step._quantum_loop), one
 # amortized dense pass instead of 3*inner_block.
 #
-# Round-12 layout: the table is [T, c] per home lane (c = writes_per_
-# iter * inner_block), not one global [C = wpi * T * inner_block] list.
-# Every directory write is home-lane-local, so a put is a single
-# append-at-cursor scatter — the old layout's [T, C] unique-key dedup
-# scan (trip product T * wpi * T * inner_block at 1024 tiles) is gone,
-# and every staging operation's cost now scales with the per-lane
-# staged-entry count.  Keys may repeat within a lane row; reads take
-# the LATEST slot and the flush applies only each key's last slot, so
-# the big-store values are bit-identical to the unique-key layout.
-# Lane-locality also makes the table block-local under shard_map (each
-# device stages its own home rows), which is what lets big sharded
-# directories stage at all — the standing "dir_stage is single-device"
-# restriction fell with it.  Reference hot path this lifts:
-# `dram_directory_cntlr.cc:44-559` per-message directory updates.
+# The table is [T, c] per home lane (c = writes_per_iter *
+# inner_block).  Every directory write is home-lane-local, so a put is
+# a single append-at-cursor scatter with no dedup scan, and every
+# staging operation's cost scales with the per-lane staged-entry count.
+# Keys may repeat within a lane row; reads take the LATEST slot and the
+# flush applies only each key's last slot, so the big-store values are
+# those of a unique-key table.  Lane-locality also makes the table
+# block-local under shard_map (each device stages its own home rows),
+# which is what lets big sharded directories stage at all.  Reference
+# hot path this lifts: `dram_directory_cntlr.cc:44-559` per-message
+# directory updates.
 
 
-def _stage_key(d, sets, way, dw=None):
-    """Within-lane staging key of a (set, way) entry.  `dw` overrides
-    the way count when the entry store is detached from the caller's
-    cond (the consolidated home phases)."""
-    DW = d.entry.shape[2] if dw is None else dw
-    return sets * DW + way
-
-
-def _stage_put(d, sets, way, mask, new_sh, dw=None):
-    """Append a masked per-lane sharers write at each lane's cursor.
+def _stage_put(d, sets, way, mask, new_sh, dw: int):
+    """Append a masked per-lane sharers write at each lane's cursor,
+    under the within-lane key `sets * dw + way` (`dw` = the directory's
+    way count: the entry store is detached from a gated phase's cond).
 
     ONE out-of-bounds-dropping scatter per table array — no dedup scan,
     no cond.  Masked-off lanes target slot c (dropped); capacity
@@ -786,7 +703,7 @@ def _stage_put(d, sets, way, mask, new_sh, dw=None):
     C = d.skey.shape[1]
     T = d.skey.shape[0]
     tiles = np.arange(T, dtype=np.int32)
-    key = _stage_key(d, sets, way, dw)
+    key = sets * dw + way
     pos = jnp.where(mask, d.sn, C)
     return d.replace(
         skey=d.skey.at[tiles, pos].set(key, mode="drop",
@@ -796,30 +713,14 @@ def _stage_put(d, sets, way, mask, new_sh, dw=None):
         sn=d.sn + mask.astype(jnp.int32))
 
 
-def _stage_overlay(d, sets, way, sharers):
-    """The latest staged value of each lane's (set, way) entry, if any,
-    else the given big-store value ([*, SW]).  Scans only the lane's own
-    [c] staging row."""
-    C = d.skey.shape[1]
-    key = _stage_key(d, sets, way)
-    m = (d.skey >= 0) & (d.skey == key[:, None])   # [T, c]
-    rank = np.arange(1, C + 1, dtype=np.int32)
-    best = jnp.max(jnp.where(m, rank, 0), axis=1)  # latest slot + 1
-    found = best > 0
-    c = jnp.where(found, best - 1, 0)
-    T = d.skey.shape[0]
-    return jnp.where(found[:, None],
-                     d.sval[np.arange(T, dtype=np.int32), c], sharers)
-
-
 def _stage_overlay_rows(d, sets, rows):
     """Overlay each lane's staged writes onto gathered sharers SET rows.
 
     `sets` int32[T, K] (the gathered rows' set indices), `rows`
     uint32[T, K, DW*SW].  For every way of every gathered row the
     LATEST staged slot matching (lane, set, way) wins — append order is
-    program order, so this reproduces the old unique-key overwrite
-    semantics exactly.  Cost scales with the per-lane capacity c."""
+    program order, so a later write overwrites an earlier one.  Cost
+    scales with the per-lane capacity c."""
     if d.skey is None:
         return rows
     T, C = d.skey.shape
@@ -900,92 +801,60 @@ def dir_stage_flush(d, live=None):
 
 
 class _DirAcc:
-    """Deferred directory writes of one gated home phase.
+    """Deferred directory writes of one home phase: its delta plan.
 
-    Under per-phase gating (MemParams.phase_gate) the home phases run
-    inside a lax.cond that must not carry the big [T, DS, DW] entry /
-    [T, DS, DW*SW] sharers stores (a cond's branch outputs are
-    double-buffered — the round-2 pathology that disabled the
-    whole-engine gate above 1 GB).  `_dir_update` therefore accumulates
-    its writes here as compact block-local per-lane deltas — one int64
-    entry-word delta and (unstaged mode only) one [Tl, DW*SW] sharers
-    set-row delta — which the cond returns and `_dir_apply` scatters
-    outside it.  Staged sharers writes keep going through the small
-    (skey, sval) table inside the cond.
+    A gated home phase (MemParams.phase_gate) runs inside a lax.cond
+    that must not carry the big [T, DS, DW] entry / [T, DS, DW*SW]
+    sharers stores (a cond's branch outputs are double-buffered — the
+    round-2 pathology that disabled the whole-engine gate above 1 GB).
+    `_dir_update` therefore accumulates its writes here as compact
+    per-lane deltas, replicated full-width — one int64 entry-word delta
+    and one [T, DW*SW] sharers set-row delta, recorded in staged mode
+    too because later phases' views forward it — which the phase
+    returns as its plan (`pack`); `_dir_apply_merged` lands all three
+    phases' plans in one scatter per store at the end of the iteration.
+    Staged sharers writes also go through the small (skey, sval) table
+    inside the cond.
 
     Invariants (hold by construction in the three home phases):
      - every `_dir_update` call of one phase targets the SAME per-lane
-       (sets, way) pair (checked by object identity on the pre-px.lo
-       operands at trace time);
+       (sets, way) pair (checked by object identity on the operands at
+       trace time);
      - the calls' masks are pairwise disjoint per lane, so summing
-       new-minus-cur deltas read against the unmodified pre-phase store
-       is exact.
+       new-minus-cur deltas read against the phase's forwarded view is
+       exact.
     """
 
-    def __init__(self, consolidated: bool = False):
-        # consolidated (round 12): deltas stay replicated full-width and
-        # the sharers row delta is recorded in EVERY mode (staged too —
-        # later phases' views forward it); `pack_c` is the plan shape
-        # and `_dir_apply_merged` lands all three phases' plans in one
-        # scatter per store at the end of the iteration.
-        self.consolidated = consolidated
-        self._ref = None
+    def __init__(self):
         self.sets = None
         self.way = None
         self.entry_delta = None
         self.sharers_delta = None
 
-    def _bind(self, ref, sets_l, way_l):
-        # ref is the (sets, way) operand pair itself — holding the
-        # objects pins their identity for the check's lifetime (a bare
-        # id() tuple could be recycled after gc)
-        if self._ref is None:
-            self._ref, self.sets, self.way = ref, sets_l, way_l
-        elif not (self._ref[0] is ref[0] and self._ref[1] is ref[1]):
+    def _bind(self, sets, way):
+        # holding the (sets, way) operands themselves pins their
+        # identity for the check's lifetime (a bare id() tuple could be
+        # recycled after gc)
+        if self.sets is None:
+            self.sets, self.way = sets, way
+        elif not (self.sets is sets and self.way is way):
             raise AssertionError(
                 "_DirAcc: a gated home phase issued _dir_update calls "
                 "with different (sets, way) operands — the deferred "
                 "delta plan assumes one target entry per lane per phase")
 
-    def add_entry(self, ref, sets_l, way_l, delta):
-        self._bind(ref, sets_l, way_l)
+    def add_entry(self, sets, way, delta):
+        self._bind(sets, way)
         self.entry_delta = (delta if self.entry_delta is None
                             else self.entry_delta + delta)
 
-    def add_sharers(self, ref, sets_l, way_l, row_delta):
-        self._bind(ref, sets_l, way_l)
+    def add_sharers(self, sets, way, row_delta):
+        self._bind(sets, way)
         self.sharers_delta = (row_delta if self.sharers_delta is None
                               else self.sharers_delta + row_delta)
 
-    def pack(self, d):
-        """The cond-carried plan: (sets, way, entry_delta[, sharers_row
-        _delta]) — all block-local [Tl(, DW*SW)] arrays, zeros when the
-        phase made no writes of that kind."""
-        Tl = d.entry.shape[0]
-        sets = (self.sets if self.sets is not None
-                else jnp.zeros(Tl, jnp.int32))
-        way = (self.way if self.way is not None
-               else jnp.zeros(Tl, jnp.int32))
-        ed = (self.entry_delta if self.entry_delta is not None
-              else jnp.zeros(Tl, I64))
-        if d.skey is not None:
-            return (sets, way, ed)
-        row_shape = (d.sharers.shape[0], d.sharers.shape[2])
-        shd = (self.sharers_delta if self.sharers_delta is not None
-               else jnp.zeros(row_shape, U32))
-        return (sets, way, ed, shd)
-
-    @staticmethod
-    def zero_pack(d):
-        Tl = d.entry.shape[0]
-        base = (jnp.zeros(Tl, jnp.int32), jnp.zeros(Tl, jnp.int32),
-                jnp.zeros(Tl, I64))
-        if d.skey is not None:
-            return base
-        return base + (jnp.zeros((Tl, d.sharers.shape[2]), U32),)
-
-    def pack_c(self, d, n_tiles: int):
-        """The consolidated plan: (sets, way, entry_delta, sharers_row
+    def pack(self, d, n_tiles: int):
+        """The phase's plan: (sets, way, entry_delta, sharers_row
         _delta) — replicated full-width [T(, DW*SW)], zeros when the
         phase made no writes of that kind."""
         sets = (self.sets if self.sets is not None
@@ -999,38 +868,20 @@ class _DirAcc:
         return (sets, way, ed, shd)
 
     @staticmethod
-    def zero_pack_c(d, n_tiles: int):
+    def zero_pack(d, n_tiles: int):
         return (jnp.zeros(n_tiles, jnp.int32),
                 jnp.zeros(n_tiles, jnp.int32),
                 jnp.zeros(n_tiles, I64),
                 jnp.zeros((n_tiles, d.sharers.shape[2]), U32))
 
 
-def _dir_apply(d, pack):
-    """Scatter a gated home phase's deferred delta plan into the big
-    directory stores — OUTSIDE the phase's lax.cond, so the stores are
-    never cond outputs.  Zero deltas (masked-off lanes, skipped phases)
-    add nothing; indices are per-lane rows, so the adds alias in
-    place."""
-    sets, way, entry_delta = pack[:3]
-    T = d.entry.shape[0]
-    tiles = np.arange(T, dtype=np.int32)
-    d = d.replace(entry=d.entry.at[tiles, sets, way].add(
-        entry_delta, unique_indices=True, indices_are_sorted=True))
-    if len(pack) > 3:
-        d = d.replace(sharers=d.sharers.at[tiles, sets].add(
-            pack[3], unique_indices=True, indices_are_sorted=True))
-    return d
-
-
 class _DirRowView:
-    """A `_DirSetView`-compatible view over ONE pre-gathered (and
-    delta-forwarded) directory set row per home lane — what the round-12
-    consolidated home phases read instead of re-gathering the big
-    stores.  Staged writes were already overlaid at gather time
-    (`_stage_overlay_rows`), and earlier phases' pending deltas were
-    forwarded in (`_DirWorkingSet.view`), so `entry()` is pure register
-    math."""
+    """ONE pre-gathered (and delta-forwarded) directory set row per home
+    lane — all a home phase reads of the directory; the big stores are
+    gathered once an iteration (`_DirWorkingSet`).  Staged writes were
+    already overlaid at gather time (`_stage_overlay_rows`), and earlier
+    phases' pending deltas were forwarded in (`_DirWorkingSet.view`), so
+    `lookup()`, `rows()` and `entry()` are pure register math."""
 
     def __init__(self, line, sets, entry_row, sharers_row, dw):
         self.sets = sets
@@ -1040,9 +891,12 @@ class _DirRowView:
         self._dw = dw
 
     def rows(self):
+        """(tag_row, nsharers_row) — the [T, DW] set rows the allocation
+        decisions (free way / min-sharer victim) need."""
         return dir_tag(self._word), dir_nsh(self._word)
 
     def lookup(self):
+        """(found, way) of `line` within the set."""
         tag_row = dir_tag(self._word)
         way_hits = tag_row == self._line[:, None]
         found = way_hits.any(axis=1)
@@ -1056,6 +910,7 @@ class _DirRowView:
         return self._sh.reshape(self._sh.shape[0], self._dw, -1)
 
     def entry(self, way):
+        """(tags, dstate, owner, sharers, nsh) at `way`."""
         sharers = jnp.take_along_axis(
             self.sharers_row3(), way[:, None, None], axis=1)[:, 0]
         word = self.word_at(way)
@@ -1064,7 +919,7 @@ class _DirRowView:
 
 
 class _DirWorkingSet:
-    """The iteration's packed directory working set (round 12).
+    """The iteration's packed directory working set.
 
     After the requester phase, every set the three home phases can
     touch is known: the earliest EVICT cell's line, the earliest
@@ -1120,8 +975,7 @@ class _DirWorkingSet:
             out = rows()
         else:
             # the stores are cond INPUTS only and the outputs are the
-            # gathered rows — nothing big is double-buffered (the
-            # argument `_cond_dir` relies on)
+            # gathered rows — nothing big is double-buffered
             out = jax.lax.cond(
                 live, rows,
                 lambda: jax.tree.map(jnp.zeros_like, jax.eval_shape(rows)))
@@ -1160,7 +1014,7 @@ class _DirWorkingSet:
 
 def _dir_apply_merged(d, px: ParallelCtx, packs, live=None):
     """ONE merged scatter per big directory store per iteration: the
-    home phases' consolidated delta plans land together at the end of
+    home phases' delta plans land together at the end of
     the engine step.  Duplicate targets (two phases updating the same
     per-lane entry) are folded into the earliest plan and the duplicate
     slot redirected out of bounds, so the scatters keep unique indices
@@ -1216,13 +1070,16 @@ def _dir_apply_merged(d, px: ParallelCtx, packs, live=None):
     return d.replace(entry=out[0], sharers=out[1])
 
 
-def _cond_dir_c(pred, fn, ms, n_tiles: int):
-    """Round-12 form of `_cond_dir`: the phase reads the directory only
+def _cond_dir(pred, fn, ms, n_tiles: int):
+    """Run a home-side phase (evictions / starts / acks+finish) under a
+    scalar-predicate lax.cond.  The phase reads the directory only
     through its pre-gathered `_DirRowView` (closed over by `fn` — cond
     inputs), so BOTH big stores detach from the cond entirely; the cond
-    returns the phase's consolidated delta plan for forwarding and the
+    returns the phase's delta plan for forwarding and the
     end-of-iteration merged scatter.  The per-lane staging rows (small,
-    lane-local) stay carried — staged puts happen inside."""
+    lane-local) stay carried — staged puts happen inside.
+    `fn(ms, acc) -> (ms, progress)` defers every directory write via
+    acc."""
     d0 = ms.directory
 
     def detach(m):
@@ -1232,13 +1089,12 @@ def _cond_dir_c(pred, fn, ms, n_tiles: int):
     def run(m):
         # the phase runs with BOTH big stores detached — its only
         # directory reads are the view rows, its only writes the plan
-        acc = _DirAcc(consolidated=True)
+        acc = _DirAcc()
         m2, prog = fn(m, acc)
-        return m2, prog, acc.pack_c(d0, n_tiles)
+        return m2, prog, acc.pack(d0, n_tiles)
 
     def skip(m):
-        return m, jnp.zeros((), jnp.int32), _DirAcc.zero_pack_c(
-            d0, n_tiles)
+        return m, jnp.zeros((), jnp.int32), _DirAcc.zero_pack(d0, n_tiles)
 
     ms2, prog, pack = jax.lax.cond(pred, run, skip, detach(ms))
     d = ms2.directory.replace(entry=d0.entry, sharers=d0.sharers)
@@ -1263,140 +1119,46 @@ def _cond_nodir(pred, fn, ms):
     return ms2.replace(directory=d0), prog
 
 
-def _cond_dir(pred, fn, ms):
-    """Run a home-side phase (evictions / starts / acks+finish) under a
-    scalar-predicate lax.cond.  The phase reads the big directory stores
-    (cond inputs — no double-buffering) but writes them only through a
-    `_DirAcc` delta plan the cond returns; `_dir_apply` lands the plan
-    outside.  Staged sharers writes ride the small (skey, sval) table,
-    which IS carried.  `fn(ms, acc) -> (ms, progress)` must leave
-    ms.directory.entry/.sharers untouched (it defers via acc)."""
-    d0 = ms.directory
+def _dir_update(d, sets, way, mask, *, view: _DirRowView, acc: _DirAcc,
+                px: ParallelCtx = IDENT, tags=None, dstate=None,
+                owner=None, sharers=None, nsharers=None):
+    """Masked per-lane write of one directory entry, deferred.
 
-    def detach(m):
-        return m.replace(directory=m.directory.replace(
-            entry=None, sharers=None))
-
-    def run(m):
-        acc = _DirAcc()
-        m2, prog = fn(m.replace(directory=d0), acc)
-        return detach(m2), prog, acc.pack(d0)
-
-    def skip(m):
-        return m, jnp.zeros((), jnp.int32), _DirAcc.zero_pack(d0)
-
-    ms2, prog, pack = jax.lax.cond(pred, run, skip, detach(ms))
-    d = ms2.directory.replace(entry=d0.entry, sharers=d0.sharers)
-    return ms2.replace(directory=_dir_apply(d, pack)), prog
-
-
-def _dir_update(d, sets, way, mask, *, px: ParallelCtx = IDENT, tags=None,
-                dstate=None, owner=None, sharers=None, nsharers=None,
-                acc: "_DirAcc | None" = None,
-                view: "_DirRowView | None" = None):
-    """Masked per-lane write of one directory entry.
-
-    Add-a-delta scatters (new = cur + (new - cur) under mask): per-lane
-    indices are unique (row = lane), so the add is exact and the scatter
-    can update the loop-carried buffers in place.  The operands arrive
-    replicated full-width; a sharded px applies only this device's home
-    rows.  With `acc` set (per-phase gating) the entry-word and unstaged
-    sharers deltas are accumulated instead of scattered — the caller's
-    lax.cond returns them and `_dir_apply` lands them outside it.
-
-    With `view` set (round-12 consolidation) the current values are
-    read from the phase's forwarded working-set row instead of the big
-    stores (which may be detached from the cond entirely), deltas stay
-    replicated full-width in the acc — `_dir_apply_merged` lands every
-    phase's plan in one scatter per store at the end of the iteration —
-    and the sharers row delta is recorded in staged mode too so later
-    phases' views can forward it."""
-    if view is not None:
-        ref = (sets, way)
-        out = d
-        cur = view.word_at(way)
-        new = cur
-        if tags is not None:
-            new = _dir_set_field(new, tags.astype(I64) + 1, 0, _TAG_MASK)
-        if dstate is not None:
-            new = _dir_set_field(new, jnp.asarray(dstate, jnp.uint8),
-                                 DIR_STATE_SHIFT, 7)
-        if owner is not None:
-            new = _dir_set_field(new, owner.astype(I64) + 1,
-                                 DIR_OWNER_SHIFT, _ID_MASK)
-        if nsharers is not None:
-            new = _dir_set_field(new, nsharers, DIR_NSH_SHIFT, _ID_MASK)
-        if new is not cur:
-            delta = jnp.where(mask, new - cur, jnp.zeros_like(cur))
-            acc.add_entry(ref, sets, way, delta)
-        if sharers is not None:
-            DW = view._dw
-            row3 = view.sharers_row3()
-            onehot = (np.arange(DW, dtype=np.int32)[None, :, None]
-                      == way[:, None, None]) & mask[:, None, None]
-            new3 = jnp.where(onehot, sharers[:, None, :], row3)
-            row_delta = (new3 - row3).reshape(row3.shape[0], -1)
-            acc.add_sharers(ref, sets, way, row_delta)
-            if out.skey is not None:
-                out = _stage_put(out, *px.lo((sets, way, mask, sharers)),
-                                 dw=DW)
-        return out
-
-    ref = (sets, way)
-    sets, way, mask = px.lo((sets, way, mask))
-    T = d.entry.shape[0]
-    tiles = np.arange(T, dtype=np.int32)
-    out = d
-
-    # ONE packed RMW scatter updates every written word field together
-    # (four separate arrays cost four dense-lowered scatters plus their
-    # layout-conversion copies each phase)
-    cur = out.entry[tiles, sets, way]
+    Add-a-delta (new = cur + (new - cur) under mask): the current values
+    are read from the phase's forwarded working-set row `view` (the big
+    stores are detached from a gated phase's cond entirely), and the
+    entry-word and sharers-row deltas are accumulated in `acc`,
+    replicated full-width — `_dir_apply_merged` lands every phase's plan
+    in one scatter per store at the end of the iteration.  The sharers
+    row delta is recorded in staged mode too, so that later phases'
+    views can forward it; there the write itself goes to this device's
+    home rows of the staging table (`px` serves nothing else here)."""
+    cur = view.word_at(way)
     new = cur
     if tags is not None:
-        new = _dir_set_field(new, px.lo(tags).astype(I64) + 1, 0, _TAG_MASK)
+        new = _dir_set_field(new, tags.astype(I64) + 1, 0, _TAG_MASK)
     if dstate is not None:
-        new = _dir_set_field(new, px.lo(jnp.asarray(dstate, jnp.uint8)),
+        new = _dir_set_field(new, jnp.asarray(dstate, jnp.uint8),
                              DIR_STATE_SHIFT, 7)
     if owner is not None:
-        new = _dir_set_field(new, px.lo(owner).astype(I64) + 1,
+        new = _dir_set_field(new, owner.astype(I64) + 1,
                              DIR_OWNER_SHIFT, _ID_MASK)
     if nsharers is not None:
-        new = _dir_set_field(new, px.lo(nsharers), DIR_NSH_SHIFT, _ID_MASK)
+        new = _dir_set_field(new, nsharers, DIR_NSH_SHIFT, _ID_MASK)
     if new is not cur:
         delta = jnp.where(mask, new - cur, jnp.zeros_like(cur))
-        if acc is not None:
-            acc.add_entry(ref, sets, way, delta)
-        else:
-            out = out.replace(entry=out.entry.at[tiles, sets, way].add(
-                delta, unique_indices=True, indices_are_sorted=True))
+        acc.add_entry(sets, way, delta)
     if sharers is not None:
-        new_sh = px.lo(sharers)                       # [Tl, SW]
-        if out.skey is not None:
-            # staged mode (legacy view: single-device programs only —
-            # the Simulator forbids staging under a mesh without the
-            # consolidated base)
-            assert not px.sharded
-            out = _stage_put(out, sets, way, mask, new_sh)
-        else:
-            # sharers store set-row-major [T, DS, DW*SW]: RMW the lane's
-            # set row, placing the entry's [SW] words at its way's slot
-            # (per-lane rows unique, so the 2D-indexed add aliases in
-            # place)
-            DW = out.entry.shape[2]
-            row = out.sharers[tiles, sets]            # [Tl, DW*SW]
-            row3 = row.reshape(row.shape[0], DW, -1)
-            onehot = (np.arange(DW, dtype=np.int32)[None, :, None]
-                      == way[:, None, None]) & mask[:, None, None]
-            new3 = jnp.where(onehot, new_sh[:, None, :], row3)
-            row_delta = (new3 - row3).reshape(row.shape)
-            if acc is not None:
-                acc.add_sharers(ref, sets, way, row_delta)
-            else:
-                out = out.replace(sharers=out.sharers.at[tiles, sets].add(
-                    row_delta,
-                    unique_indices=True, indices_are_sorted=True))
-    return out
+        DW = view._dw
+        row3 = view.sharers_row3()
+        onehot = (np.arange(DW, dtype=np.int32)[None, :, None]
+                  == way[:, None, None]) & mask[:, None, None]
+        new3 = jnp.where(onehot, sharers[:, None, :], row3)
+        row_delta = (new3 - row3).reshape(row3.shape[0], -1)
+        acc.add_sharers(sets, way, row_delta)
+        if d.skey is not None:
+            d = _stage_put(d, *px.lo((sets, way, mask, sharers)), DW)
+    return d
 
 
 # --------------------------------------------------------------------------
@@ -1775,7 +1537,6 @@ def memory_engine_step(
     # only small per-phase state — see _cond_nodir/_cond_dir.
 
     gate = bool(getattr(mp, "phase_gate", False))
-    consolidate = bool(getattr(mp, "base_consolidate", True))
 
     def _phase_requester(ms):
         prog = jnp.zeros((), jnp.int32)
@@ -1801,11 +1562,11 @@ def memory_engine_step(
     # ======================================================================
     # (2) homes consume one EVICT per iteration
     # ======================================================================
-    # Round-12 consolidated base: after the requester phase every set
-    # the home phases can touch is known, so ONE packed working-set
-    # gather (entry + sharers rows, staging overlaid) serves phases
-    # 2/3/5, each phase's cond returns its delta plan for forwarding,
-    # and the plans land in ONE merged scatter per store after phase 5.
+    # The base: after the requester phase every set the home phases can
+    # touch is known, so ONE packed working-set gather (entry + sharers
+    # rows, staging overlaid) serves phases 2/3/5, each phase returns
+    # its delta plan for forwarding, and the plans land in ONE merged
+    # scatter per store after phase 5.
     #
     # The home-activity gate (phase_gate regime): one scalar, evaluated
     # HERE, under which that base runs — the gather under a lax.cond
@@ -1822,59 +1583,51 @@ def memory_engine_step(
     # only, so every device of a mesh takes the same arm.  None =
     # forced live (gates off, or `home_gate` false because the caller
     # already has the whole engine under one cond: today's program).
-    ws = None
     packs = []
     home_live = None
-    if consolidate:
-        mail0, txn0 = ms.mail, ms.txn
-        if gate and home_gate:
-            home_live = ((mail0.evict_type != MSG_NONE).any()
-                         | (mail0.req_type != MSG_NONE).any()
-                         | (mail0.fwd_type != MSG_NONE).any()
-                         | (mail0.ack_type != MSG_NONE).any()
-                         | txn0.active.any()
-                         | txn0.saved_valid.any())
+    mail0, txn0 = ms.mail, ms.txn
+    if gate and home_gate:
+        home_live = ((mail0.evict_type != MSG_NONE).any()
+                     | (mail0.req_type != MSG_NONE).any()
+                     | (mail0.fwd_type != MSG_NONE).any()
+                     | (mail0.ack_type != MSG_NONE).any()
+                     | txn0.active.any()
+                     | txn0.saved_valid.any())
 
-        def _ws_lines():
-            src_e0, _ = _row_earliest(mail0.evict_type, mail0.evict_time)
-            eline0 = mail0.evict_line[tiles, src_e0]
-            use_saved0 = ~txn0.active & txn0.saved_valid
-            r_col0, _ = _req_earliest(mail0)
-            rline0 = jnp.where(use_saved0, txn0.saved_line,
-                               mail0.req_line[r_col0])
-            return eline0, rline0, txn0.line
+    def _ws_lines():
+        src_e0, _ = _row_earliest(mail0.evict_type, mail0.evict_time)
+        eline0 = mail0.evict_line[tiles, src_e0]
+        use_saved0 = ~txn0.active & txn0.saved_valid
+        r_col0, _ = _req_earliest(mail0)
+        rline0 = jnp.where(use_saved0, txn0.saved_line,
+                           mail0.req_line[r_col0])
+        return eline0, rline0, txn0.line
 
-        ws = _DirWorkingSet(px, ms.directory, mp, _ws_lines, live=home_live)
-        eline0, rline0, _ = ws.lines
+    ws = _DirWorkingSet(px, ms.directory, mp, _ws_lines, live=home_live)
+    eline0, rline0, _ = ws.lines
 
     def _run_dir_phase(pred, fn):
-        """One home phase in the selected regime; consolidated runs
-        collect the phase's delta plan into `packs`."""
-        nonlocal ms, packs
-        if consolidate:
-            if gate:
-                ms, p, pk = _cond_dir_c(pred, fn, ms, T)
-            else:
-                a = _DirAcc(consolidated=True)
-                d0 = ms.directory
-                ms, p = fn(ms, a)
-                pk = a.pack_c(d0, T)
-            packs.append(pk)
-            return p
+        """One home phase, gated (the cond returns its delta plan) or
+        not (the plan straight); the plan joins `packs`."""
+        nonlocal ms
         if gate:
-            ms, p = _cond_dir(pred, fn, ms)
-            return p
-        ms, p = fn(ms, None)
+            ms, p, pk = _cond_dir(pred, fn, ms, T)
+        else:
+            a = _DirAcc()
+            d0 = ms.directory
+            ms, p = fn(ms, a)
+            pk = a.pack(d0, T)
+        packs.append(pk)
         return p
 
     pred2 = (ms.mail.evict_type != MSG_NONE).any()
-    view2 = ws.view(0, eline0, packs) if consolidate else None
+    view2 = ws.view(0, eline0, packs)
     with scope("gt.mem." + PHASE_NAMES[1]):
         p = _run_dir_phase(
             pred2,
             lambda m, a: _home_evictions(
                 mp, m, dir_access_ps, enabled, jnp.zeros((), jnp.int32),
-                px, acc=a, dsv=view2))
+                view2, a, px))
     progress = progress + p
 
     # ======================================================================
@@ -1882,14 +1635,14 @@ def memory_engine_step(
     # ======================================================================
     pred3 = ((ms.mail.req_type != MSG_NONE).any()
              | (ms.txn.saved_valid & ~ms.txn.active).any())
-    view3 = ws.view(1, rline0, list(packs)) if consolidate else None
+    view3 = ws.view(1, rline0, list(packs))
     with scope("gt.mem." + PHASE_NAMES[2]):
         p = _run_dir_phase(
             pred3,
             lambda m, a: _home_starts(
                 mp, m, dram_lat_ps, dir_access_ps, sync_dir_l2,
-                sync_dir_net, enabled, jnp.zeros((), jnp.int32), px,
-                acc=a, dsv=view3))
+                sync_dir_net, enabled, jnp.zeros((), jnp.int32), view3, a,
+                px))
     progress = progress + p
 
     # ======================================================================
@@ -1914,19 +1667,17 @@ def memory_engine_step(
     # (5) homes consume ACKs, finish transactions
     # ======================================================================
     pred5 = (ms.mail.ack_type != MSG_NONE).any() | ms.txn.active.any()
-    view5 = (ws.view_finish(ms.txn.line, list(packs))
-             if consolidate else None)
+    view5 = ws.view_finish(ms.txn.line, list(packs))
     with scope("gt.mem." + PHASE_NAMES[4]):
         p = _run_dir_phase(
             pred5,
             lambda m, a: _home_acks_and_finish(
                 mp, m, dram_lat_ps, dir_access_ps, enabled,
-                jnp.zeros((), jnp.int32), px, acc=a, dsv=view5))
+                jnp.zeros((), jnp.int32), view5, a, px))
     progress = progress + p
-    if consolidate:
-        # the ONE merged scatter per big store for this iteration
-        ms = ms.replace(directory=_dir_apply_merged(
-            ms.directory, px, packs, live=home_live))
+    # the ONE merged scatter per big store for this iteration
+    ms = ms.replace(directory=_dir_apply_merged(
+        ms.directory, px, packs, live=home_live))
 
     # ======================================================================
     # (6) requesters consume replies (fill L2+L1, complete slot)
@@ -2138,8 +1889,8 @@ def _sharer_step(mp, ms: MemState, fmhz, enabled, progress,
 
 
 def _home_evictions(mp, ms: MemState, dir_access_ps, enabled, progress,
-                    px: ParallelCtx = IDENT, acc: "_DirAcc | None" = None,
-                    dsv=None):
+                    dsv: _DirRowView, acc: _DirAcc,
+                    px: ParallelCtx = IDENT):
     T = mp.n_tiles
     tiles = np.arange(T, dtype=np.int32)
     mail = ms.mail
@@ -2150,9 +1901,6 @@ def _home_evictions(mp, ms: MemState, dir_access_ps, enabled, progress,
     etime = mail.evict_time[tiles, src]
 
     d = ms.directory
-    if dsv is None:
-        dsv = _DirSetView(px, d, eline, mp)
-    vw = dsv if isinstance(dsv, _DirRowView) else None
     sets = dsv.sets
     dfound, way = dsv.lookup()
     apply = found & dfound
@@ -2174,7 +1922,7 @@ def _home_evictions(mp, ms: MemState, dir_access_ps, enabled, progress,
     ).astype(jnp.uint8)
     d = _dir_update(d, sets, way, apply, px=px, dstate=new_dstate,
                     owner=new_owner, sharers=new_sharers, nsharers=new_nsh,
-                    acc=acc, view=vw)
+                    acc=acc, view=dsv)
 
     # active same-line transaction: treat the eviction as the ack
     txn = ms.txn
@@ -2211,8 +1959,8 @@ def _home_evictions(mp, ms: MemState, dir_access_ps, enabled, progress,
 
 
 def _home_acks_and_finish(mp, ms: MemState, dram_lat_ps, dir_access_ps,
-                          enabled, progress, px: ParallelCtx = IDENT,
-                          acc: "_DirAcc | None" = None, dsv=None):
+                          enabled, progress, dsv: _DirRowView,
+                          acc: _DirAcc, px: ParallelCtx = IDENT):
     T = mp.n_tiles
     tiles = np.arange(T, dtype=np.int32)
     mail = ms.mail
@@ -2255,9 +2003,6 @@ def _home_acks_and_finish(mp, ms: MemState, dram_lat_ps, dir_access_ps,
     is_nullify = txn.mtype == MSG_NULLIFY
 
     d = ms.directory
-    if dsv is None:
-        dsv = _DirSetView(px, d, txn.line, mp)
-    vw = dsv if isinstance(dsv, _DirRowView) else None
     sets = dsv.sets
     dfound, way = dsv.lookup()
     r = txn.requester
@@ -2293,7 +2038,7 @@ def _home_acks_and_finish(mp, ms: MemState, dram_lat_ps, dir_access_ps,
         sharers=jnp.where(exf[:, None], rbit_words,
                           set_bit(cur_sharers, r, shf)),
         nsharers=jnp.where(exf, 1, cur_nsh + (~had).astype(jnp.int32)),
-        acc=acc, view=vw)
+        acc=acc, view=dsv)
     # NULLIFY finish: the entry was already replaced at allocation; nothing
     # directory-side remains (`processNullifyReq` UNCACHED branch)
 
@@ -2352,8 +2097,7 @@ def _home_acks_and_finish(mp, ms: MemState, dram_lat_ps, dir_access_ps,
 
 def _home_starts(mp, ms: MemState, dram_lat_ps, dir_access_ps,
                  sync_dir_l2, sync_dir_net, enabled, progress,
-                 px: ParallelCtx = IDENT, acc: "_DirAcc | None" = None,
-                 dsv=None):
+                 dsv: _DirRowView, acc: _DirAcc, px: ParallelCtx = IDENT):
     T = mp.n_tiles
     tiles = np.arange(T, dtype=np.int32)
     mail = ms.mail
@@ -2390,9 +2134,6 @@ def _home_starts(mp, ms: MemState, dram_lat_ps, dir_access_ps,
 
     # ---- directory entry lookup / allocation -----------------------------
     d = ms.directory
-    if dsv is None:
-        dsv = _DirSetView(px, d, rline, mp)
-    vw = dsv if isinstance(dsv, _DirRowView) else None
     sets = dsv.sets
     dfound, way = dsv.lookup()
     tag_row, nsh_row = dsv.rows()
@@ -2498,7 +2239,7 @@ def _home_starts(mp, ms: MemState, dram_lat_ps, dir_access_ps,
     # when dfound).
     upd = is_new | imm
     d = _dir_update(
-        d, sets, alloc_way, upd, px=px, acc=acc, view=vw,
+        d, sets, alloc_way, upd, px=px, acc=acc, view=dsv,
         tags=jnp.where(is_new, rline, v_line),
         dstate=jnp.where(
             imm, jnp.where(imm_ex, DIR_MODIFIED, DIR_SHARED),
@@ -2576,7 +2317,7 @@ def _home_starts(mp, ms: MemState, dram_lat_ps, dir_access_ps,
         # drop the victim from the entry now — its INV/FLUSH ack is consumed
         # by this transaction, not the eviction path (one txn per home)
         d = _dir_update(
-            d, sets, alloc_way, sh_over, px=px, acc=acc, view=vw,
+            d, sets, alloc_way, sh_over, px=px, acc=acc, view=dsv,
             sharers=v_sharers & ~victim_bits,
             nsharers=v_nsh - 1,
             owner=jnp.where(victim_is_owner, -1, v_owner),
@@ -2594,7 +2335,7 @@ def _home_starts(mp, ms: MemState, dram_lat_ps, dir_access_ps,
         fwd_msg = jnp.where(sh_over_m, MSG_FLUSH_REQ, fwd_msg).astype(
             jnp.uint8)
         d = _dir_update(
-            d, sets, alloc_way, sh_over_m, px=px, acc=acc, view=vw,
+            d, sets, alloc_way, sh_over_m, px=px, acc=acc, view=dsv,
             sharers=jnp.zeros((T, mp.sharer_words), U32),
             nsharers=jnp.zeros(T, jnp.int32),
             owner=jnp.full(T, -1, jnp.int32),

@@ -261,23 +261,13 @@ class MemParams:
     # Simulator sizes cap = writes_per_iter * inner_block (overflow-
     # impossible) and auto-enables on big directories.  Lane-local by
     # construction, so the rows shard with the directory under
-    # shard_map (round 12; the old global-table form was single-device
-    # only).
+    # shard_map.
     dir_stage_cap: int = 0
-    # Round-12 base consolidation: the three home phases read the
-    # directory through ONE packed per-iteration set-row gather (entry +
-    # sharers, one collective under shard_map) with pending-delta
-    # forwarding between phases, and their delta plans land in ONE
-    # merged scatter per store at the end of the iteration.  False
-    # restores the round-11 per-phase gather/apply layout (bit-identical
-    # by construction — `tools/regress.py --smoke` pins it), kept as the
-    # equivalence oracle.
-    base_consolidate: bool = True
     # Per-phase activity gating (round 6): each protocol phase runs under
     # its OWN scalar-predicate lax.cond whose carried operands are only
     # the small per-phase state — the big directory/sharers stores are
-    # read through the existing views and written outside the conds
-    # (home phases return compact per-lane delta plans; see
+    # read through the iteration's working-set rows and written outside
+    # the conds (home phases return compact per-lane delta plans; see
     # engine._cond_dir), so the conds never double-buffer them and
     # gating survives at the >= 1 GB scale where the whole-engine
     # mem_gate must stay off.  Predicates are pure functions of
@@ -480,8 +470,6 @@ class MemParams:
             icache_modeling=cfg.get_bool("general/enable_icache_modeling", False),
             func_mem_words=cfg.get_int("general/functional_memory_kb", 256) * 256,
             requester_unroll=requester_unroll,
-            base_consolidate=cfg.get_bool("general/base_consolidate",
-                                          True),
         )
 
     def sync_cycles(self, module_a: int, module_b: int) -> int:

@@ -246,7 +246,7 @@ class MemState:
     # shard_map (deterministic from replicated predicates).
     phase_skips: jax.Array = None
     # int64[2] — what the home-activity gate skipped (engine.
-    # BASE_SKIP_NAMES order): iterations whose consolidated base (the
+    # BASE_SKIP_NAMES order): iterations whose base (the
     # directory working-set gather and the merged scatter) did not run,
     # inner blocks whose staging flush did not run.  A whole-engine
     # mem_gate skip counts as a skipped base.  Stays 0 with the gates

@@ -1,7 +1,7 @@
 """Capture REAL program executions as traces; calibrate the skeletons.
 
-The reusable harness behind `capture_fft.py` (VERDICT round-3/4 ask:
-generalize the one-off FFT capture), plus real SPLASH-2-shaped
+The reusable harness behind `capture_fft.py` (the one-off FFT capture,
+generalized), plus real SPLASH-2-shaped
 implementations of RADIX and LU recorded the same way.  These are not
 synthetic generators: each app EXECUTES its algorithm — real data, true
 addresses — under the live-recording Carbon API (the reference analog is

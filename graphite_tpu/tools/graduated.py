@@ -142,8 +142,8 @@ def main() -> int:
               f"{res.total_instructions} instrs, {dt:.2f}s wall, "
               f"{res.total_instructions / dt / 1e6:.2f}M instr/s "
               f"{'PASS' if ok else 'FAIL'}")
-        # one machine-readable line per config so BENCH_r{N}-style
-        # captures track gate skip rates alongside throughput
+        # one machine-readable line per config: gate skip rates
+        # alongside throughput
         import json
 
         print(json.dumps({

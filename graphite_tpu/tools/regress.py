@@ -137,19 +137,6 @@ def smoke(tiles: int = 16) -> int:
     failures += _compare("phase-gated vs ungated (MSI, 16t)", r_gate,
                          r_flat)
 
-    # 1b) base consolidation is layout, not policy (round 12): the
-    #     packed one-gather/one-merged-scatter directory working set
-    #     must be bit-identical to the round-11 per-phase layout
-    #     (base_consolidate=False) on gated AND ungated MSI, and on the
-    #     B=4 campaign — the same pattern as the round-6 gating rung
-    for gate, label in ((True, "gated"), (False, "ungated")):
-        r_new = Simulator(sc, batch, phase_gate=gate,
-                          mem_gate_bytes=0).run()
-        r_old = Simulator(sc, batch, phase_gate=gate, mem_gate_bytes=0,
-                          base_consolidate=False).run()
-        failures += _compare(
-            f"base-consolidated vs round-11 ({label})", r_new, r_old)
-
     # 2) batched host-barrier dispatch == per-quantum dispatch
     sc_b = SimConfig(ConfigFile.from_string(config_text(
         tiles, shared_mem=True, clock_scheme="lax_barrier")))
@@ -177,14 +164,6 @@ def smoke(tiles: int = 16) -> int:
                           mailbox_depth=sweep.mailbox_depth).run()
         failures += _compare(f"sweep B=4 sim {b} (seed {s}) vs sequential",
                              out.results[b], r_seq)
-    # 3b) the B=4 campaign under the round-11 layout must demux the
-    #     same per-sim results as the consolidated default (round 12)
-    out_old = SweepRunner(sc, sweep_traces, base_consolidate=False).run()
-    for b, s in enumerate(seeds):
-        failures += _compare(
-            f"sweep B=4 sim {b} consolidated vs round-11",
-            out.results[b], out_old.results[b])
-
     # 4) telemetry is pure observability (round 9): recording a dense
     #    device timeline must leave every SimResults field bit-identical
     #    (gated + ungated), and the B=4 campaign's demuxed [B, S, n]

@@ -47,7 +47,7 @@ Input kinds, one renderer:
 Output (stdout):
 
   --format json   machine rows (one JSON line per sample / job / metric)
-                  — the shape bench.py and the CI artifacts consume;
+                  — the shape the CI artifacts consume;
   --format text   aligned-text tables;
   --summary       summaries only (timeline/heatmap modes).  Timeline
                   summaries carry per-series `peaks` (max + argmax

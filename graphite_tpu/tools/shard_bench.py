@@ -1,7 +1,7 @@
 """Measure multi-device step wall-clock vs single-device (virtual mesh).
 
-VERDICT/PERF follow-up: `parallel/mesh.py` replicates the sync tables and
-`func_mem` and relies on whole-program GSPMD — the concern is that mailbox
+`parallel/mesh.py` replicates the sync tables and `func_mem` and relies
+on whole-program GSPMD — the concern is that mailbox
 scatters and replicated-buffer updates lower to cross-device collectives
 that make the 8-device step *slower* than one device.  Real ICI speedups
 cannot be measured on one chip; what a virtual CPU mesh CAN measure is
@@ -12,8 +12,8 @@ is broken.  Run:
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python -m graphite_tpu.tools.shard_bench
 
-Output is JSON lines in bench.py's field convention — one row per
-workload with {"metric", "value", "unit", "vs_baseline"} plus
+Output is JSON lines — one row per workload with
+{"metric", "value", "unit", "vs_baseline"} plus
 companions: the single-device and GSPMD wall-clocks, and the STATIC
 collective counts of the packed-exchange lowering (analysis/comms.py
 over a SweepRunner tile-axis lowering of the same config —

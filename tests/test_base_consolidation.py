@@ -1,11 +1,11 @@
-"""Round-12 base consolidation: one packed directory working-set gather
-and one merged row scatter per engine iteration, bit-identical to the
-round-11 per-phase layout, plus the budget ratchet that locks the win in.
+"""The memory engine's base: one packed directory working-set gather and
+one merged row scatter per engine iteration, plus the budget ratchet
+that locks the win in.
 
 The structural claims are jaxpr-level (via the shared analysis/walk
-traversal) at a 1024-tile shape — the config-5 regime the consolidation
-exists for; the equivalence claims are randomized-trace bit-identity
-(consolidated vs round-11 layout) and serialized-trace golden-oracle
+traversal) at a 1024-tile shape — the config-5 regime the layout exists
+for; the equivalence claims are randomized-trace bit-identity (gated vs
+un-gated, staged vs un-staged) and serialized-trace golden-oracle
 exactness for both memory engines.
 """
 
@@ -145,7 +145,7 @@ def _store_ops(closed, sig):
 
 
 def test_one_gather_one_merged_scatter_1024_shape():
-    """The consolidated iteration touches each big directory store
+    """The iteration touches each big directory store
     exactly once in each direction: ONE packed working-set row gather up
     front, ONE merged row scatter at the end — for the sharers store AND
     the packed entry-word store."""
@@ -181,9 +181,8 @@ def test_staged_iteration_has_no_sharers_scatter_1024_shape():
 
 
 def test_phase_conds_survive_consolidation_1024_shape():
-    """The six per-phase gating conds are unchanged in count — the
-    consolidation moves the big-store traffic out of the phases, not
-    the phases themselves."""
+    """Six per-phase gating conds — the working set moves the big-store
+    traffic out of the phases, not the phases themselves."""
     from graphite_tpu.analysis.rules import phase_conds
 
     sim = _big_shape_sim()
@@ -253,19 +252,18 @@ def test_gated_base_under_control_flow_1024_shape(staged):
             assert _depth(on) == _depth(ref) + 1, (on, ref)
 
 
-@pytest.mark.parametrize("block_gates", [True, False])
-def test_gates_off_program_is_the_counter_alone_1024_shape(block_gates):
-    """phase_gate=False (what SweepRunner campaigns compile), with and
-    without block_gates: the home-activity gate adds a carried counter
-    and NOTHING else — the program with the counter in its state is
-    equation for equation the program without it (same gathers,
-    scatters, conds and whiles at the same sites), one carried value
-    apart.  PROGRAMS.lock pins the same program against the parent's."""
+def test_gates_off_program_is_the_counter_alone_1024_shape():
+    """phase_gate=False (what SweepRunner campaigns compile): the
+    home-activity gate adds a carried counter and NOTHING else — the
+    program with the counter in its state is equation for equation the
+    program without it (same gathers, scatters, conds and whiles at the
+    same sites), one carried value apart.  PROGRAMS.lock pins the same
+    program against the parent's."""
     from graphite_tpu.analysis.walk import iter_eqns_with_site
 
     sim = _big_shape_sim(**STAGED)
     sim.params = dataclasses.replace(
-        sim.params, block_gates=block_gates,
+        sim.params,
         mem=dataclasses.replace(sim.params.mem, phase_gate=False))
     bare = sim.state.replace(mem=sim.state.mem.replace(base_skips=None))
     with_counter = _program_jaxpr(sim)
@@ -281,42 +279,38 @@ def test_gates_off_program_is_the_counter_alone_1024_shape(block_gates):
             == len(without.jaxpr.outvars) + 1)
 
 
-# ---- bit-identity: consolidated vs round-11 layout ------------------------
+# ---- bit-identity: gated vs un-gated, staged vs un-staged ------------------
 
 
 @pytest.mark.parametrize("proto", [MSI, MOSI])
-@pytest.mark.parametrize("gate", [True, False])
-def test_consolidated_matches_round11_randomized(proto, gate):
-    """Randomized coherence traffic: the consolidated base must be
-    bit-identical to the round-11 per-phase layout, gated and ungated."""
+@pytest.mark.parametrize("seed", [3, 11])
+def test_gated_matches_ungated_randomized(proto, seed):
+    """Randomized coherence traffic: the gated engine (six phase conds,
+    the home-activity gate over the base) must be bit-identical to the
+    un-gated one (every phase, the gather and the merged scatter run
+    every iteration — what a campaign compiles)."""
     sc = make_config(8, proto)
-    for seed in (3, 11):
-        batch = synthetic.memory_stress_trace(
-            8, n_accesses=40, working_set_bytes=1 << 12,
-            write_fraction=0.4, shared_fraction=0.6, seed=seed)
-        r_new = Simulator(sc, batch, phase_gate=gate,
-                          mem_gate_bytes=0).run()
-        r_old = Simulator(sc, batch, phase_gate=gate, mem_gate_bytes=0,
-                          base_consolidate=False).run()
-        _assert_results_equal(r_new, r_old)
+    batch = synthetic.memory_stress_trace(
+        8, n_accesses=40, working_set_bytes=1 << 12,
+        write_fraction=0.4, shared_fraction=0.6, seed=seed)
+    r_gated = Simulator(sc, batch, phase_gate=True, mem_gate_bytes=0).run()
+    r_flat = Simulator(sc, batch, phase_gate=False, mem_gate_bytes=0).run()
+    _assert_results_equal(r_gated, r_flat)
 
 
-def test_consolidated_staged_matches_round11():
-    """Consolidation composes with directory write-staging (per-lane
-    rows, round 12): staged consolidated == staged round-11 layout ==
-    unstaged, on shared-line traffic crossing many flush boundaries."""
+def test_staged_matches_unstaged_randomized():
+    """The working set composes with directory write-staging (per-lane
+    rows): staged == unstaged on shared-line traffic crossing many flush
+    boundaries."""
     sc = make_config(8, MSI)
     batch = synthetic.memory_stress_trace(
         8, n_accesses=40, working_set_bytes=1 << 12,
         write_fraction=0.5, shared_fraction=0.7, seed=5)
-    r_new = Simulator(sc, batch, mem_gate_bytes=0, dir_stage=True,
-                      inner_block=4).run()
-    r_old = Simulator(sc, batch, mem_gate_bytes=0, dir_stage=True,
-                      inner_block=4, base_consolidate=False).run()
+    r_staged = Simulator(sc, batch, mem_gate_bytes=0, dir_stage=True,
+                         inner_block=4).run()
     r_uns = Simulator(sc, batch, mem_gate_bytes=0, dir_stage=False,
                       inner_block=4).run()
-    _assert_results_equal(r_new, r_old)
-    _assert_results_equal(r_new, r_uns)
+    _assert_results_equal(r_staged, r_uns)
 
 
 # ---- sharded staging: the standing dir_stage gap, closed ------------------
@@ -325,9 +319,8 @@ def test_consolidated_staged_matches_round11():
 @pytest.mark.skipif(len(jax.devices()) < 8,
                     reason="needs 8 virtual devices")
 def test_sharded_dir_stage_matches_single_device():
-    """Round 12 closes the "dir_stage is single-device" gap: the
-    per-lane staging rows shard with the directory and ride the
-    consolidated working-set gather block-locally, so a meshed staged
+    """The per-lane staging rows shard with the directory and ride the
+    working-set gather block-locally, so a meshed staged
     run must be bit-identical to the single-device staged (and
     unstaged) runs."""
     from graphite_tpu.parallel.mesh import make_tile_mesh
@@ -343,25 +336,13 @@ def test_sharded_dir_stage_matches_single_device():
     assert int(np.asarray(r_solo.mem_counters["l2_misses"]).sum()) > 0
 
 
-def test_legacy_layout_refuses_sharded_staging():
-    from graphite_tpu.parallel.mesh import make_tile_mesh
-    from graphite_tpu.tools._template import coherence_stress_workload
-
-    if len(jax.devices()) < 8:
-        pytest.skip("needs 8 virtual devices")
-    sc, batch = coherence_stress_workload(64, protocol=MSI)
-    with pytest.raises(ValueError, match="base_consolidate"):
-        Simulator(sc, batch, dir_stage=True, mesh=make_tile_mesh(8),
-                  base_consolidate=False)
-
-
 # ---- golden-oracle exactness (serialized traffic) -------------------------
 
 
 @pytest.mark.parametrize("proto", [MSI, MOSI, SHL2_MESI])
 def test_consolidated_golden_exact(proto):
-    """Serialized RMW traffic: the consolidated engines (private-L2 MSI/
-    MOSI and shared-L2 MESI) stay bit-exact vs the golden interpreters."""
+    """Serialized RMW traffic: the engines (private-L2 MSI/MOSI and
+    shared-L2 MESI) stay bit-exact vs the golden interpreters."""
     sc = make_config(4, proto)
     batch = mutex_rmw(4, 4, lines=3)
     res = Simulator(sc, batch, phase_gate=True, mem_gate_bytes=0).run()
@@ -372,10 +353,15 @@ def test_consolidated_golden_exact(proto):
                                       err_msg=k)
 
 
-def test_consolidated_staged_golden_exact():
-    sc = make_config(4, MSI)
+@pytest.mark.parametrize("proto", [MSI, MOSI])
+@pytest.mark.parametrize("gate", [True, False])
+def test_consolidated_staged_golden_exact(proto, gate):
+    """The staged engine against the golden interpreter, with the
+    home-activity gate and the in-place flush gate (`gate`) and with
+    every gate off (what a campaign of a staged-size target compiles)."""
+    sc = make_config(4, proto)
     batch = mutex_rmw(4, 4, lines=3)
-    res = Simulator(sc, batch, phase_gate=True, mem_gate_bytes=0,
+    res = Simulator(sc, batch, phase_gate=gate, mem_gate_bytes=0,
                     dir_stage=True, inner_block=4).run()
     gold = run_golden(sc, batch)
     np.testing.assert_array_equal(res.clock_ps, gold.clock_ps)

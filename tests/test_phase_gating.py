@@ -67,7 +67,7 @@ def mutex_rmw(n, rounds, base=0x900000, lines=2):
 
 
 # what a gated run is held to: the golden oracle, or the UNGATED program
-# (phase_gate=False: no phase cond, and the consolidated base — working-
+# (phase_gate=False: no phase cond, and the base — working-
 # set gather, merged scatter, block flush — run every iteration)
 AGAINST = ("golden", "ungated")
 
