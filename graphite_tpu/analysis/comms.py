@@ -138,6 +138,17 @@ def _collective_axes(eqn) -> "tuple[str, ...]":
     return (str(axes),)
 
 
+def _over_positional_axes_only(eqn) -> bool:
+    """A psum/pmin/pmax whose `axes` are all POSITIONAL (ints): what
+    `vmap` leaves of a reduction over its own named axis (the sim axis
+    of a campaign, `ParallelCtx.any_sim`) — a `reduce_max` over an array
+    axis on one device.  No mesh axis, no fabric bytes: not a
+    collective."""
+    axes = eqn.params.get("axes")
+    return (eqn.primitive.name in _PSUM_LIKE and bool(axes)
+            and all(isinstance(a, int) for a in axes))
+
+
 def _group_size(eqn) -> "int | None":
     groups = eqn.params.get("axis_index_groups")
     if not groups:
@@ -294,7 +305,8 @@ def extract_collectives(jaxpr, *, n_tiles: int, phase_names=(),
         for eqn in as_jaxpr(jx).eqns:
             name = eqn.primitive.name
             here = f"{site}.{name}" if site else name
-            if name in COLLECTIVE_PRIMS:
+            if name in COLLECTIVE_PRIMS \
+                    and not _over_positional_axes_only(eqn):
                 if phase is not None:
                     ph = pname(phase)
                 elif passed["n"] < len(pcs):

@@ -409,6 +409,13 @@ def subquantum_iteration(
     # lax.cond keyed on "any lane has such an op right now" — compute-heavy
     # stretches then skip the scatter-heavy machinery entirely (a TPU
     # scatter costs ~0.2-0.9 ms regardless of how many lanes are masked on).
+    # Each predicate goes through px.any_sim: in a campaign's batched
+    # program it is OR-ed over the sims, so it stays a scalar and the cond
+    # stays a cond under vmap; a sim whose own predicate is false runs the
+    # block with every lane masked off, which returns its inputs (the
+    # mutex/cond block has to be told to: see there).  (The two
+    # `uniform` fetch conds are an ALL over a per-sim index, not an ANY
+    # over lanes, and stay per-sim: a select under vmap.)
     dst = jnp.clip(aux0, 0, T - 1)
     send_now = active & is_send
 
@@ -473,7 +480,7 @@ def subquantum_iteration(
             return jnp.argmin(masked_times, axis=1).astype(jnp.int32)
 
         any_src = lax.cond(
-            jnp.any(active & is_any_recv),
+            px.any_sim(jnp.any(active & is_any_recv)),
             _any_src, lambda _: jnp.zeros((T,), jnp.int32), None)
         want_src = jnp.where(is_any_recv, any_src, jnp.clip(aux0, 0, T - 1))
         sel_count = count_sent[tiles, want_src]
@@ -501,8 +508,8 @@ def subquantum_iteration(
     with scope("gt.net.mailbox"):
         (time_ps_new, lat_arr_new, head_new, count_new, overflow, noc_user,
          recv_now, recv_time, recv_lat) = lax.cond(
-            jnp.any(send_now | (active & is_recv)), _net_block, _net_skip,
-            None)
+            px.any_sim(jnp.any(send_now | (active & is_recv))),
+            _net_block, _net_skip, None)
     recv_wait_ps = jnp.maximum(recv_time - core.clock_ps, 0)
     recv_wait_ps = jnp.where(recv_now, recv_wait_ps, 0)
 
@@ -574,7 +581,8 @@ def subquantum_iteration(
         (barrier_count, barrier_arrived, barrier_time, barrier_waiting,
          released, release_time, barrier_gen, barrier_release_ps,
          barrive_now, bsync_now, bsync_time) = lax.cond(
-            jnp.any(active & (is_binit | is_bwait | is_barrive | is_bsync)),
+            px.any_sim(jnp.any(
+                active & (is_binit | is_bwait | is_barrive | is_bsync))),
             _barrier_block, _barrier_skip, None)
     barrier_wait_ps = jnp.maximum(release_time - core.clock_ps, 0)
     barrier_wait_ps = jnp.where(released, barrier_wait_ps, 0)
@@ -759,6 +767,15 @@ def subquantum_iteration(
         mutex_time = sync.mutex_time_ps.at[un_mux].add(
             jnp.where(un_do, core.clock_ps - sync.mutex_time_ps[un_mux], 0)
         )
+        if px.sim_axis is not None:
+            # The one thing this block does with every lane masked off:
+            # it drops a pending signal or broadcast as LOST once every
+            # running tile has reached its time.  A sim whose own
+            # predicate is false keeps it pending, as its solo program
+            # does (a waiter arriving AT the signal's time still takes
+            # it), so that a neighbour's sync record cannot move it.
+            psig = jnp.where(mc_own, psig, sync.cond_sig_time_ps)
+            pbc = jnp.where(mc_own, pbc, sync.cond_bcast_time_ps)
         return (mutex_locked, mutex_owner, mutex_time, mutex_waiting,
                 granted, mutex_wait_ps, cond_waiting, cond_signaled,
                 cond_arrival, cond_wake, psig, pbc,
@@ -773,15 +790,16 @@ def subquantum_iteration(
                 jnp.zeros((T,), jnp.bool_))
 
     with scope("gt.sync.mutex_cond"):
+        mc_own = jnp.any(
+            (active & (is_minit | is_munlock | is_csig
+                       | is_cbcast | is_cinit))
+            | (is_mlock & ~done & (sync.mutex_waiting | active))
+            | (is_cwait & ~done))
         (mutex_locked, mutex_owner, mutex_time, mutex_waiting, granted,
          mutex_wait_ps, cond_waiting, cond_signaled, cond_arrival_ps,
          cond_wake_ps, cond_sig_time_ps, cond_bcast_time_ps,
          cond_post_commit) = lax.cond(
-            jnp.any((active & (is_minit | is_munlock | is_csig
-                               | is_cbcast | is_cinit))
-                    | (is_mlock & ~done & (sync.mutex_waiting | active))
-                    | (is_cwait & ~done)),
-            _mutex_cond_block, _mutex_cond_skip, None)
+            px.any_sim(mc_own), _mutex_cond_block, _mutex_cond_skip, None)
 
     # --- published cond signals + COND_JOIN (co-located split form) ------
     # A publishing signal/broadcast bumps the cond's signal sequence and
@@ -819,7 +837,7 @@ def subquantum_iteration(
 
     with scope("gt.sync.mutex_cond"):
         (cond_sig_seq, cond_sig_seq_ps, cjoin_now, cjoin_time) = lax.cond(
-            jnp.any(pub_now | (active & is_cjoin)),
+            px.any_sim(jnp.any(pub_now | (active & is_cjoin))),
             _pub_block,
             lambda _: (sync.cond_sig_seq, sync.cond_sig_seq_ps,
                        jnp.zeros((T,), jnp.bool_), jnp.zeros((T,), I64)),
@@ -845,7 +863,7 @@ def subquantum_iteration(
 
     with scope("gt.sync.join"):
         join_now, join_time = lax.cond(
-            jnp.any(active & is_join), _join_block,
+            px.any_sim(jnp.any(active & is_join)), _join_block,
             lambda _: (jnp.zeros((T,), jnp.bool_), core.clock_ps), None)
 
     # --- commit: advance mask, clocks, counters --------------------------
@@ -982,7 +1000,8 @@ def subquantum_iteration(
 
         with scope("gt.dvfs"):
             dvfs_out = lax.cond(
-                jnp.any(active & is_dvfs_set), _dvfs_block, _dvfs_skip, None)
+                px.any_sim(jnp.any(active & is_dvfs_set)),
+                _dvfs_block, _dvfs_skip, None)
         (dv_freq, dv_volt, dv_errs, dvfs_core_set, dvfs_req) = dvfs_out[:5]
         new_dvfs = state.dvfs.replace(
             freq_mhz=dv_freq, voltage_mv=dv_volt, errors=dv_errs)

@@ -21,6 +21,13 @@ this exchange is the process-striped directory traffic over
 `memory/engine.py`; the default ``IDENT`` context makes every operation an
 identity, so the single-device path compiles to exactly the program it
 always was.
+
+The context also names the SIM axis of a campaign (``sim_axis``: the
+``axis_name`` under which `sweep/runner.py` maps `run_simulation` over B
+sims).  It carries no data motion: ``any_sim`` ORs an activity predicate
+over the sims of one program so that the predicate stays a scalar and the
+``lax.cond`` it keys stays a cond under ``vmap`` (a batched predicate
+would turn it into both branches and a select).
 """
 
 from __future__ import annotations
@@ -34,6 +41,9 @@ from graphite_tpu.obs.scopes import scope
 
 I64 = jnp.int64
 
+# the `vmap` axis name a campaign maps its sims under (sweep/runner.py)
+SIM_AXIS = "sim"
+
 
 @dataclasses.dataclass(frozen=True)
 class ParallelCtx:
@@ -41,10 +51,13 @@ class ParallelCtx:
 
     axis: mesh axis name the tile dimension is sharded over, or None.
     n_dev: number of devices on that axis.
+    sim_axis: the `vmap` axis name the program is mapped over B sims
+        under (sweep/runner.py), or None for a program of one sim.
     """
 
     axis: str | None = None
     n_dev: int = 1
+    sim_axis: str | None = None
 
     @property
     def sharded(self) -> bool:
@@ -54,6 +67,19 @@ class ParallelCtx:
         # pins this; a size-1 all_gather would still round-trip every
         # field through the int64 descriptor packing)
         return self.axis is not None and self.n_dev > 1
+
+    # -- activity gates under a sim axis ----------------------------------
+
+    def any_sim(self, pred):
+        """`pred` (bool[]) OR-ed over the sims of this program: the
+        predicate itself when there is no sim axis (nothing is traced),
+        else a reduction over it, which `vmap` leaves UNBATCHED — a
+        `reduce_max` over [B], no collective.  A gate keyed on it runs its
+        block when ANY sim needs it; a sim that does not runs the block
+        with every lane masked off, which must return its inputs."""
+        if self.sim_axis is None:
+            return pred
+        return jax.lax.pmax(pred.astype(jnp.int32), self.sim_axis) > 0
 
     # -- local block addressing ------------------------------------------
 

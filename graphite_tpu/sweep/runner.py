@@ -6,11 +6,18 @@ architects run campaigns: design-space sweeps over timing parameters,
 traces, and seeds.  The TPU port has the inverse opportunity: `vmap`
 B independent simulations through ONE program, so that a campaign pays
 one compile, one dispatch and one fetch a batch.  What it does NOT buy,
-measured on the v5e (PERF.md, PR 31 / PR 32; `campaign64-dram`, B = 4):
-a B-fold amortisation of the iteration.  Under `vmap` every gate is off
-(a batched predicate turns a `lax.cond` into a select), so the batched
-iteration runs every phase, mailbox and sync block for every sim, and
-its device time is several times the gated solo program's per lane.
+measured on the v5e (PERF.md, PR 31 / PR 32 / PR 34; `campaign64-dram`,
+B = 4): a B-fold amortisation of the iteration.  A predicate batched by
+`vmap` turns a `lax.cond` into both branches and a select, so a gate
+holds under the batch only where its predicate is reduced over the sims
+to a scalar.  The ENGINE's activity gates are (since PR 34: the sims
+are mapped under a named axis and `engine/step.py` ORs each predicate
+over it, `ParallelCtx.any_sim`): a batch runs the mailbox, NoC, barrier,
+mutex/cond, join and DVFS blocks only in iterations where some sim of
+it needs them.  The MEMORY engine's gates are not yet (ROADMAP M3b):
+its six phases and its directory base run in every iteration for every
+sim, so the batched iteration's device time is still a multiple of the
+gated solo program's per lane.
 
 Mechanics:
  - traces pack to a common [B, T, L] layout (sweep/pack.py); `vmap` maps
@@ -132,11 +139,12 @@ class SweepRunner:
     Four batching programs, chosen by `layout` (or the legacy
     `shard_batch` kwarg):
      - "solo": `vmap` over the sim axis (the default on one device):
-       one program, B-wide arrays.  vmap converts the engine's
-       activity-gating lax.conds into both-branch selects, so this
-       program runs UNGATED by default (gating is mechanism, not policy
-       — results are bit-identical either way; pass phase_gate=True to
-       override).
+       one program, B-wide arrays.  The engine's activity gates hold
+       (their predicates are OR-ed over the sims); the memory engine's
+       conds would become both-branch selects, so its phase and
+       whole-engine gates are OFF by default (gating is mechanism, not
+       policy — results are bit-identical either way; pass
+       phase_gate=True to override).
      - "batch" (legacy `shard_batch=True`): batch-axis `shard_map` when
        several devices are visible and B divides evenly: each device
        runs B/ndev sims; with one sim per device the per-device program
@@ -506,9 +514,12 @@ class SweepRunner:
         config, trace0, mbd, kwargs = self._sim_ctor
         kwargs = dict(kwargs)
         if self._sims_per_cell(layout) > 1 and self._has_mem:
-            # the per-cell program is vmapped: its gating conds become
-            # both-branch selects, so default them OFF (bit-identical
-            # results, measured faster; explicit kwargs win)
+            # the per-cell program is vmapped: the memory engine's
+            # gating conds (predicates per sim) would become both-branch
+            # selects, so default them OFF (bit-identical results,
+            # measured faster; explicit kwargs win).  The engine's own
+            # activity gates need no switch: they reduce their
+            # predicates over the sim axis (`_runner_fn`: over_sims)
             kwargs.setdefault("phase_gate", False)
             kwargs.setdefault("mem_gate_bytes", 0)
         return Simulator(config, trace0, mailbox_depth=mbd,
@@ -598,6 +609,7 @@ class SweepRunner:
         is auditable/fingerprintable on hosts without the forced
         device platform."""
         from graphite_tpu.engine.step import run_simulation
+        from graphite_tpu.parallel.px import IDENT, SIM_AXIS, ParallelCtx
 
         params = self.sim.params
         unbounded = self.sim.quantum_ps is None
@@ -606,9 +618,8 @@ class SweepRunner:
         hs = self.sim.hist_spec
         dv = self.sim.dvfs_spec
 
-        def one(state, trace, kn, px=None):
+        def one(state, trace, kn, px=IDENT):
             q = None if unbounded else kn.quantum_ps
-            kw = {} if px is None else {"px": px}
             if dv is not None and kn.dvfs_domain_mhz is not None:
                 # per-point operating seed: rebuild the DVFS carry from
                 # this row's [n_domains] frequencies (AUTO voltage) and
@@ -633,7 +644,15 @@ class SweepRunner:
                             state.dvfs.voltage_mv.shape)))
             return run_simulation(params, trace, state, q, max_quanta,
                                   knobs=kn, telemetry=tel, profile=prof,
-                                  dvfs=dv, hist=hs, **kw)
+                                  dvfs=dv, hist=hs, px=px)
+
+        def over_sims(px=IDENT):
+            # `one` over the sims of a cell, under a NAMED axis: the
+            # engine's activity gates OR their predicates over it
+            # (px.any_sim), so they stay scalar and the conds stay conds
+            pxs = dataclasses.replace(px, sim_axis=SIM_AXIS)
+            return jax.vmap(lambda s, t, k: one(s, t, k, pxs),
+                            axis_name=SIM_AXIS)
 
         if isinstance(self.layout_spec, tuple):
             # the 2D batch x tile mesh: each device holds a tile block
@@ -645,8 +664,6 @@ class SweepRunner:
                 TILE_AXIS_2D, _shard_map, campaign_state_specs,
                 campaign_trace_specs, make_batch_tile_mesh,
             )
-            from graphite_tpu.parallel.px import ParallelCtx
-
             db, dt = self.layout_spec
             px = ParallelCtx(axis=TILE_AXIS_2D, n_dev=dt)
             mesh = make_batch_tile_mesh(db, dt, abstract=abstract)
@@ -664,8 +681,7 @@ class SweepRunner:
                     out = one(*(sq(lambda x: x[0], t)
                                 for t in (state, trace, kn)), px)
                     return sq(lambda x: x[None], out)
-                return jax.vmap(lambda s, t, k: one(s, t, k, px))(
-                    state, trace, kn)
+                return over_sims(px)(state, trace, kn)
 
             return _shard_map(
                 per_cell, mesh=mesh,
@@ -674,7 +690,7 @@ class SweepRunner:
                            P("batch")))
 
         if not self.shard_batch:
-            return jax.vmap(one)
+            return over_sims()
 
         from jax.sharding import Mesh, PartitionSpec as P
 
@@ -685,7 +701,7 @@ class SweepRunner:
 
         def per_device(state, trace, kn):
             if K > 1:
-                return jax.vmap(one)(state, trace, kn)
+                return over_sims()(state, trace, kn)
             # one sim per device: strip the [1] batch dim and run
             # the plain UNBATCHED program — real lax.cond gating,
             # bit-identical to a sequential Simulator run

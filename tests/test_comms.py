@@ -24,7 +24,7 @@ from graphite_tpu.analysis.audit import (
 )
 from graphite_tpu.analysis.cost import COMMS_METRICS, cost_report
 from graphite_tpu.parallel.mesh import TILE_AXIS_2D, _shard_map
-from graphite_tpu.parallel.px import ParallelCtx
+from graphite_tpu.parallel.px import SIM_AXIS, ParallelCtx
 
 TILES = 8
 DT = 4  # devices on the tile axis in the hand-built programs
@@ -93,6 +93,28 @@ class TestExtraction:
         assert c.shard_bytes == TL * 8 == 16
         assert c.ici_bytes == (2 * (DT - 1) * 16) // DT == 24
         assert c.hops == DT - 1
+        assert c.kind == comms.KIND_REDUCTION
+
+    def test_pmax_over_a_vmap_axis_is_no_collective(self):
+        """What `vmap` leaves of a reduction over its OWN named axis (a
+        campaign's sim axis, `ParallelCtx.any_sim`) is a `pmax` whose
+        `axes` are positional: a reduce over an array axis on one
+        device.  It is not listed, priced or counted — the psum over
+        the mesh axis beside it in the same program is."""
+        px = ParallelCtx(sim_axis=SIM_AXIS)
+
+        def one(row):
+            live = px.any_sim(jnp.any(row > 0))
+            return jnp.where(live, jax.lax.psum(row, TILE_AXIS_2D), row)
+
+        closed = _lower(jax.vmap(one, axis_name=SIM_AXIS),
+                        (P(None, TILE_AXIS_2D),), P(),
+                        jax.ShapeDtypeStruct((4, TILES), jnp.int64))
+        pmaxes = [e for e in comms.iter_eqns_with_site(closed)
+                  if e[1].primitive.name == "pmax"]
+        assert [e.params["axes"] for _, e in pmaxes] == [(0,)]
+        (c,) = _extract(closed)
+        assert c.primitive == "psum" and c.axis_name == TILE_AXIS_2D
         assert c.kind == comms.KIND_REDUCTION
 
     def test_ppermute_ring_distance(self):

@@ -13,6 +13,8 @@ The two contract pins:
 """
 
 import dataclasses
+import os
+import sys
 
 import jax
 import numpy as np
@@ -25,10 +27,11 @@ from graphite_tpu.sweep import (
 )
 from graphite_tpu.tools._template import config_text
 from graphite_tpu.trace import synthetic
-from graphite_tpu.trace.schema import NO_REG, Op
+from graphite_tpu.trace.schema import NO_REG, Op, TraceBatch, TraceBuilder
 
 
 TILES = 8
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _config(clock="lax"):
@@ -168,6 +171,218 @@ class TestSweepEqualsSequential:
             op=np.where(b.op < 20, np.uint8(Op.IALU), b.op))
         with pytest.raises(ValueError, match="agree on touching memory"):
             SweepRunner(sc, [_trace(1), memoryless])
+
+
+def _hetero_traces():
+    """Four sims of one geometry, each needing other blocks of the
+    iteration: messages (one ANY_SENDER receive) and barriers; a mutex
+    chain, a cond broadcast, a published signal with its COND_JOIN and a
+    THREAD_JOIN; memory only; memory only and done within a few
+    iterations.  Every sim touches memory (one engine program)."""
+    n = TILES
+    msg = [TraceBuilder() for _ in range(n)]
+    msg[0].barrier_init(0, n)
+    for t, b in enumerate(msg):
+        b.load(0x10000 * (t + 1), 8).barrier_wait(0)
+        for i in range(1, n):
+            b.send((t + i) % n, 8)
+        for i in range(1, n):
+            b.recv((t - i) % n, 8)
+        b.store(0x10000 * (t + 1), 8).barrier_wait(0)
+    msg[1].send(0, 8)
+    msg[0].recv(-1, 8)                      # ANY_SENDER
+    mtx = [TraceBuilder() for _ in range(n)]
+    mtx[0].mutex_init(0).cond_init(0).barrier_init(1, n)
+    for b in mtx:
+        b.barrier_wait(1)
+    for r in range(2 * n):
+        addr = 0x900000 + (r % 2) * 64
+        mtx[r % n].mutex_lock(0).load(addr, 8).store(addr, 8) \
+            .mutex_unlock(0)
+    for t in (1, 2, 3):
+        mtx[t].mutex_lock(0).cond_wait(0, 0).instr(Op.IALU) \
+            .mutex_unlock(0)
+    mtx[0].bblock(1000, 200_000)            # the waiters wait first
+    mtx[0].mutex_lock(0).cond_broadcast(0).mutex_unlock(0)
+    mtx[4].cond_signal(1, publish=True)
+    mtx[5].cond_join(1, 1)
+    mtx[6].thread_join(7)
+    return [TraceBatch.from_builders(msg), TraceBatch.from_builders(mtx),
+            _trace(3), _trace(4, n=2)]
+
+
+@pytest.fixture(scope="module")
+def hetero_refs():
+    """The heterogeneous batch and each sim's own sequential run — of
+    its PADDED trace, at the batch's ring depth and the batch's memory
+    gating, so that every leaf of the final state is comparable."""
+    from graphite_tpu.engine.simulator import auto_mailbox_depth
+
+    sc = _config("lax_barrier")
+    pack = pack_traces(_hetero_traces())
+    depth = max(auto_mailbox_depth(pack.sim(b)) for b in range(4))
+    refs = []
+    for b in range(4):
+        sim = Simulator(sc, pack.sim(b), mailbox_depth=depth,
+                        phase_gate=False, mem_gate_bytes=0)
+        refs.append((sim.run(), jax.device_get(sim.state)))
+    return sc, pack, depth, refs
+
+
+def _assert_whole_results_equal(ra, rb, msg):
+    for f in dataclasses.fields(ra):
+        a, b = getattr(ra, f.name), getattr(rb, f.name)
+        for k in (a if isinstance(a, dict) else (None,)):
+            np.testing.assert_array_equal(
+                a if k is None else a[k], b if k is None else b[k],
+                err_msg=f"{msg}: {f.name} {k or ''}")
+
+
+class TestActivityGatesUnderTheSimAxis:
+    """ISSUE 34: the engine's activity gates take their predicates OR-ed
+    over the sim axis (`px.any_sim`), so they stay conds under `vmap`; a
+    sim that does not need a block its neighbour needs runs it with every
+    lane masked off.  Nothing a sim computes may change."""
+
+    @pytest.mark.parametrize("layout", ["solo", (2, 2)],
+                             ids=["solo_vmap", "2d_b2_t2"])
+    def test_heterogeneous_batch_bit_identical_to_sequential_runs(
+            self, hetero_refs, layout):
+        sc, pack, depth, refs = hetero_refs
+        if layout != "solo" and len(jax.devices()) < 4:
+            pytest.skip("the 2D layout needs 4 devices")
+        sweep = SweepRunner(sc, pack, mailbox_depth=depth, layout=layout)
+        assert sweep._sims_per_dev == (4 if layout == "solo" else 2)
+        out = sweep.run()
+        # the batch ran until its slowest sim was done; one finished early
+        iters = out.n_iterations.tolist()
+        assert min(iters) < max(iters) and iters[3] == min(iters), iters
+        for b in range(4):
+            _assert_whole_results_equal(out.results[b], refs[b][0],
+                                        f"sim {b}")
+        # vacuity guards: the sims did exercise the blocks they are for
+        assert out.results[0].packets_received.sum() >= 8 * 7 + 1
+        assert out.results[1].sync_instructions.sum() > 0
+        assert out.results[2].packets_sent.sum() == 0
+        # ... and every leaf of every sim's final state
+        states0, dtr = sweep._batched_inputs()
+        state = jax.device_get(sweep._get_runner(1_000_000)(
+            states0, dtr, sweep.knobs)[0])
+        for b in range(4):
+            got = jax.tree_util.tree_leaves_with_path(
+                jax.tree_util.tree_map(lambda x: x[b], state))
+            want = jax.tree_util.tree_leaves(refs[b][1])
+            assert len(got) == len(want)
+            for (path, g), w in zip(got, want):
+                np.testing.assert_array_equal(
+                    g, w, err_msg=f"sim {b}: state leaf "
+                    f"{jax.tree_util.keystr(path)}")
+
+    def test_pending_signal_is_not_dropped_by_a_neighbours_sync(self):
+        """The mutex/cond block is the one block that changes state with
+        every lane masked off: it drops a pending signal as LOST once
+        every running tile has reached its time.  Solo, a sim with no
+        sync record in flight skips the block and keeps the signal, and
+        a waiter that arrives AT the signal's time takes it.  A
+        neighbour whose mutex traffic keeps the shared gate open must
+        not change that (without the keep in `_mutex_cond_block` sim 0
+        loses the signal and deadlocks)."""
+        n = TILES
+        tie = [TraceBuilder() for _ in range(n)]
+        # tile 0 signals at S = one IALU; tile 1 is behind S then (so the
+        # signal pends), reaches exactly S, idles there on records of no
+        # cost and no sync, and waits at W = S
+        tie[0].cond_init(0).instr(Op.IALU).cond_signal(0)
+        tie[1].mutex_init(0).mutex_lock(0).thread_spawn(2).instr(Op.IALU)
+        for _ in range(3):
+            tie[1].thread_spawn(2)
+        tie[1].cond_wait(0, 0).instr(Op.IALU).mutex_unlock(0)
+        busy = [TraceBuilder() for _ in range(n)]
+        busy[0].mutex_init(0)
+        for r in range(4 * n):
+            busy[r % n].instr(Op.IALU).mutex_lock(0).mutex_unlock(0)
+        traces = [TraceBatch.from_builders(tie),
+                  TraceBatch.from_builders(busy)]
+        sc = SimConfig(ConfigFile.from_string(config_text(
+            TILES, shared_mem=False, clock_scheme="lax")))
+        refs = [Simulator(sc, t, mailbox_depth=4).run() for t in traces]
+        assert refs[0].clock_ps[1] > 0      # the waiter was woken
+        out = SweepRunner(sc, traces, mailbox_depth=4,
+                          layout="solo").run()
+        for b in range(2):
+            _assert_whole_results_equal(out.results[b], refs[b],
+                                        f"sim {b}")
+
+
+    # the activity gates of `subquantum_iteration`, by the scope their
+    # cond is traced under (obs/scopes.py); the ANY_SENDER cond lies
+    # inside the net block's branch, whose name stack starts anew
+    GATES = ["gt.net.mailbox", None, "gt.sync.barrier",
+             "gt.sync.mutex_cond", "gt.sync.mutex_cond", "gt.sync.join",
+             "gt.dvfs"]
+
+    @pytest.mark.parametrize("layout", ["solo", (2, 2)],
+                             ids=["solo_vmap", "2d_b2_t2"])
+    def test_every_gate_of_a_b4_program_is_a_cond_on_a_scalar(
+            self, layout):
+        """Structure, from `SweepRunner.lower()`: each activity gate is a
+        `cond` equation whose predicate is rank 0 (a bare `vmap` leaves
+        none: a batched predicate turns a cond into both branches and a
+        `select_n`); what `vmap` leaves of the reduction is a `pmax` over
+        a POSITIONAL axis — no named-axis collective, so nothing for a
+        fabric to carry; and the un-gated memory engine adds no cond."""
+        from graphite_tpu.analysis.comms import extract_collectives
+        from graphite_tpu.analysis.walk import find_eqns, iter_eqns
+        from graphite_tpu.obs.scopes import deepest
+
+        sweep = SweepRunner(_config("lax_barrier"),
+                            [_trace(s) for s in range(1, 5)],
+                            layout=layout)
+        closed, _ = sweep.lower()
+        conds = [e for _, e in find_eqns(closed, "cond")]
+        assert [deepest(str(e.source_info.name_stack))
+                for e in conds] == self.GATES
+        assert [e.invars[0].aval.shape for e in conds] == [()] * 7
+        over_sims = [e for e in iter_eqns(closed)
+                     if e.primitive.name == "pmax"]
+        assert [e.params["axes"] for e in over_sims] == [(0,)] * 7
+        assert all(e.outvars[0].aval.shape == () for e in over_sims)
+        # the analyzer prices none of them, and under the 2D mesh finds
+        # the tile axis's packed exchanges and nothing else
+        cols = extract_collectives(closed, n_tiles=TILES)
+        assert {c.axis_name for c in cols} == (
+            set() if layout == "solo" else {"tile"})
+        assert {c.primitive for c in cols} <= {"all_gather"}
+
+    @pytest.mark.parametrize("config", ["ref-default-64", "coh-1024"])
+    def test_no_sim_axis_no_reduction(self, config):
+        """With no sim axis the helper is the identity at trace time: the
+        benchmark's solo program of `ref-default-64` and `coh-1024`'s
+        host-driven runner hold no reduction over sims and every gate as
+        a cond (their fingerprints, parent = change: CHANGES.md, PR 34)."""
+        from graphite_tpu.analysis.comms import COLLECTIVE_PRIMS
+        from graphite_tpu.analysis.walk import find_eqns, iter_eqns
+        from graphite_tpu.parallel.px import IDENT
+
+        pred = jax.numpy.asarray(True)
+        assert IDENT.any_sim(pred) is pred
+        sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+        try:
+            from lib import target
+        finally:
+            sys.path.pop(0)
+        cfg = target.load_config(config)
+        sim = Simulator(target.build_sim_config(cfg),
+                        target.build_trace(cfg), **cfg["simulator"])
+        assert sim.barrier_host == (config == "coh-1024")
+        closed, _ = sim.lower(1_000_000)
+        assert not [e for e in iter_eqns(closed)
+                    if e.primitive.name in COLLECTIVE_PRIMS]
+        gates = [e for _, e in find_eqns(closed, "cond")
+                 if str(e.source_info.name_stack).split("/")[-1]
+                 in self.GATES]
+        assert len(gates) == 6
+        assert all(e.invars[0].aval.shape == () for e in gates)
 
 
 class TestKnobTracing:
