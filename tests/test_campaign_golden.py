@@ -19,7 +19,10 @@ the engine and gets its latency through the config text:
 - BIT-EXACT, clocks and all 21 memory counters, on the sharing the
   golden's ordering contract covers (tests/test_memory_golden.py):
   the cell's own generator with its private half only (line-disjoint,
-  40% stores, evictions), an INV multicast to 63 sharers, and a
+  40% stores, evictions; at 64 tiles no directory set holds more lines
+  than its 16 ways - past that, line-disjoint traffic races for the
+  set's victim and is not exact either: BASELINE.md, ROADMAP M6), an INV
+  multicast to 63 sharers, and a
   read-modify-write chain that walks modified lines from tile to tile
   (write-back, downgrade, invalidation of the old owner);
 - within an ENVELOPE on the cell's own traffic.  Free-running tiles that

@@ -4,7 +4,13 @@ engine vs the sequential golden model (`golden/memory_model.py`).
 Contract (see the golden model's ordering-discipline docstring):
  - bit-exact on serialized or line-disjoint workloads — clocks AND all
    memory counters (the message-carried-timestamp algebra makes disjoint
-   transactions commutative, so iteration order cannot matter);
+   transactions commutative, so iteration order cannot matter) — as long
+   as no directory set holds more live lines of SEVERAL tiles than it has
+   ways: past that, the NULLIFY victim depends on the order in which
+   unrelated tiles' requests reached the home, and the two sides part by
+   a miss or two (ROADMAP M6, BASELINE.md; every line-disjoint case here
+   stays under the ways, and one tile overflowing a set alone is ordered
+   by its own program: `test_nullify_tiny_directory`);
  - a quantified envelope on free-running racy workloads, where the
    engine's iteration interleaving and the oracle's clock ordering may
    resolve same-line races differently (BASELINE's <=2% divergence
