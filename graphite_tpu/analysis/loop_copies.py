@@ -9,6 +9,11 @@ reads that program's text (`Simulator.compiled_text()`,
 `SweepRunner.compiled_text()`, or `compiled.as_text()` of a program
 compiled for a described topology) and lists the copies by loop.
 
+`conditionals` lists the program's `conditional` instructions with the
+arrays each returns: a store among them is a fresh buffer of the branch,
+not the loop's carried one (a conditional's outputs are not aliased to
+its operands), which is how a gate double-buffers a store.
+
 Text only: nothing here compiles, times or runs anything.
 """
 
@@ -25,6 +30,9 @@ _CALLED = re.compile(
 _COPY = re.compile(
     r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\](?:\{[^}]*\})? copy\(")
 _BODY = re.compile(r"\bbody=%?([\w.\-]+)")
+_COND = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) conditional\(")
+_ARRAY = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +63,48 @@ class LoopCopy:
         for d in self.shape:
             n *= d
         return n
+
+
+@dataclasses.dataclass(frozen=True)
+class Conditional:
+    """One `conditional` instruction (a `lax.cond` whose predicate
+    reached the compiler as a scalar: a batched one is lowered to both
+    branches and selects, and leaves no such instruction)."""
+
+    name: str
+    op_name: str     # the metadata's op_name ("" if none)
+    outputs: tuple   # ((dtype, dims), ...) of the arrays it returns
+
+    def returns(self, shape, dtypes=None) -> list:
+        """Its outputs of `shape` (as `copies_of`: a leading batch axis
+        allowed for) and one of `dtypes` (None: any)."""
+        return [(d, dims) for d, dims in self.outputs
+                if _is_store(dims, d, shape, dtypes)]
+
+
+def _is_store(dims, dtype, shape, dtypes) -> bool:
+    """`dims` END with `shape` under at most one leading (batch) axis,
+    and `dtype` is one of `dtypes` (None: any)."""
+    shape = tuple(shape)
+    return (dims[-len(shape):] == shape and len(dims) <= len(shape) + 1
+            and (dtypes is None or dtype in dtypes))
+
+
+def conditionals(hlo_text: str) -> list:
+    """Every `conditional` of the program, in the order printed."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _COND.match(line)
+        if not m:
+            continue
+        name = _OP_NAME.search(line)
+        out.append(Conditional(
+            name=m.group(1), op_name=name.group(1) if name else "",
+            outputs=tuple(
+                (a.group(1), tuple(int(x) for x in a.group(2).split(",")
+                                   if x))
+                for a in _ARRAY.finditer(m.group(2)))))
+    return out
 
 
 def computations(hlo_text: str) -> dict:
@@ -161,8 +211,4 @@ def copies_of(copies, shape, dtypes=None) -> list:
     axis of a campaign program is allowed for) and whose element type is
     one of `dtypes` (None: any).  A 64-bit store is two 32-bit halves on
     the TPU: ask for ("s64", "u32") there."""
-    shape = tuple(shape)
-    return [c for c in copies
-            if c.shape[-len(shape):] == shape
-            and len(c.shape) <= len(shape) + 1
-            and (dtypes is None or c.dtype in dtypes)]
+    return [c for c in copies if _is_store(c.shape, c.dtype, shape, dtypes)]

@@ -18,7 +18,8 @@ a Python-level abstraction:
   vmap-gate     a program built with phase_gate=True whose gating conds
                 lowered to both-branch selects (vmap batching) is paying
                 gating's bookkeeping and buying nothing (round-7 PERF
-                finding — SweepRunner defaults gates off under vmap)
+                finding — SweepRunner maps its sims under a named axis
+                the predicates are reduced over, so its conds survive)
   host-sync     no callback/infeed/outfeed/debug_print primitive inside
                 the compiled step (a host round trip per iteration —
                 the whole reason the quantum loop is device-driven;
@@ -306,9 +307,11 @@ def vmap_gate(jaxpr, n_tiles: int, expect_gated: bool,
     `vmap` batches a cond's predicate, which rewrites the cond into
     both-branch execution + `select_n` — every phase then runs every
     iteration AND pays the select (PERF.md round 7 measured gated-vmap
-    ~2.8x slower than ungated-vmap; SweepRunner therefore defaults
-    gates OFF in vmapped programs).  Warning severity: the program is
-    correct, just paying for a mechanism that buys nothing.
+    ~2.8x slower than ungated-vmap).  The engines therefore reduce each
+    predicate over the campaign's named sim axis (`ParallelCtx.any_sim`)
+    and SweepRunner maps the sims under that name; a bare `vmap` of a
+    gated program still fires this rule.  Warning severity: the program
+    is correct, just paying for a mechanism that buys nothing.
     """
     if not expect_gated:
         return []
@@ -324,8 +327,9 @@ def vmap_gate(jaxpr, n_tiles: int, expect_gated: bool,
             f"program was built with phase_gate=True but NO per-phase "
             f"gating cond survived lowering ({n_sel} mailbox-shaped "
             f"select_n eqns present) — batching turned the gates into "
-            f"both-branch selects; run the batched program ungated "
-            f"(SweepRunner's default) or shard the batch axis",
+            f"both-branch selects; map the sims under the named sim "
+            f"axis (SweepRunner does), run the batched program ungated, "
+            f"or shard the batch axis",
             data={"phase_conds": 0, "mailbox_selects": n_sel})]
     return [Finding(
         "vmap-gate", SEV_WARNING, "jaxpr",

@@ -1281,7 +1281,10 @@ def _quantum_loop(params, trace, state, qend, trace_base=None, px=IDENT,
             # inner_block iterations ALL skipped the base staged
             # nothing, and its flush would drop every slot.  The counter
             # is replicated control state (unlike the block-local `sn`),
-            # so every device of a mesh takes the same arm.
+            # so every device of a mesh takes the same arm.  Under a sim
+            # axis it counts the program's skips (the OR-ed `home_live`),
+            # but it rides the batched state: reduced over the sims so
+            # the in-place loop's predicate stays a scalar.
             from graphite_tpu.memory.engine import (
                 FLUSH_SKIPPED, dir_stage_flush,
             )
@@ -1289,8 +1292,8 @@ def _quantum_loop(params, trace, state, qend, trace_base=None, px=IDENT,
             mem = state.mem
             flush_live = None
             if flush_gate:
-                flush_live = (mem.base_skips[0] - base_skips0
-                              < params.inner_block)
+                flush_live = px.any_sim(mem.base_skips[0] - base_skips0
+                                        < params.inner_block)
                 mem = mem.replace(base_skips=mem.base_skips + jnp.where(
                     flush_live, 0, FLUSH_SKIPPED))
             with scope("gt.mem.stage_flush"):

@@ -1534,7 +1534,12 @@ def memory_engine_step(
     # branch with no new collectives.  A phase with its predicate false is
     # a provable no-op (every write is masked by the very condition the
     # predicate disjoins over), so gating is bit-exact; the conds carry
-    # only small per-phase state — see _cond_nodir/_cond_dir.
+    # only small per-phase state — see _cond_nodir/_cond_dir.  Under a
+    # campaign's sim axis every predicate, `home_live` too, is OR-ed
+    # over the sims of the program (px.any_sim), so it stays a scalar
+    # under `vmap` and the cond stays a cond: a sim with nothing for a
+    # phase that a sibling needs runs it with every lane masked off,
+    # and the skip counters count what the PROGRAM skipped.
 
     gate = bool(getattr(mp, "phase_gate", False))
 
@@ -1550,8 +1555,8 @@ def memory_engine_step(
     # a lane that cannot start at block entry cannot start mid-unroll
     # either (only phase 6 returns a lane to PHASE_IDLE), so one
     # predicate covers the whole unrolled block
-    pred1 = jnp.any(active & (ms.req.phase == PHASE_IDLE)
-                    & (next_present(ms.req.slot) < 3))
+    pred1 = px.any_sim(jnp.any(active & (ms.req.phase == PHASE_IDLE)
+                               & (next_present(ms.req.slot) < 3)))
     with scope("gt.mem." + PHASE_NAMES[0]):
         if gate:
             ms, p = _cond_nodir(pred1, _phase_requester, ms)
@@ -1587,12 +1592,12 @@ def memory_engine_step(
     home_live = None
     mail0, txn0 = ms.mail, ms.txn
     if gate and home_gate:
-        home_live = ((mail0.evict_type != MSG_NONE).any()
-                     | (mail0.req_type != MSG_NONE).any()
-                     | (mail0.fwd_type != MSG_NONE).any()
-                     | (mail0.ack_type != MSG_NONE).any()
-                     | txn0.active.any()
-                     | txn0.saved_valid.any())
+        home_live = px.any_sim((mail0.evict_type != MSG_NONE).any()
+                               | (mail0.req_type != MSG_NONE).any()
+                               | (mail0.fwd_type != MSG_NONE).any()
+                               | (mail0.ack_type != MSG_NONE).any()
+                               | txn0.active.any()
+                               | txn0.saved_valid.any())
 
     def _ws_lines():
         src_e0, _ = _row_earliest(mail0.evict_type, mail0.evict_time)
@@ -1620,7 +1625,7 @@ def memory_engine_step(
         packs.append(pk)
         return p
 
-    pred2 = (ms.mail.evict_type != MSG_NONE).any()
+    pred2 = px.any_sim((ms.mail.evict_type != MSG_NONE).any())
     view2 = ws.view(0, eline0, packs)
     with scope("gt.mem." + PHASE_NAMES[1]):
         p = _run_dir_phase(
@@ -1633,8 +1638,8 @@ def memory_engine_step(
     # ======================================================================
     # (3) homes start transactions (pop request / resume saved)
     # ======================================================================
-    pred3 = ((ms.mail.req_type != MSG_NONE).any()
-             | (ms.txn.saved_valid & ~ms.txn.active).any())
+    pred3 = px.any_sim((ms.mail.req_type != MSG_NONE).any()
+                       | (ms.txn.saved_valid & ~ms.txn.active).any())
     view3 = ws.view(1, rline0, list(packs))
     with scope("gt.mem." + PHASE_NAMES[2]):
         p = _run_dir_phase(
@@ -1648,7 +1653,7 @@ def memory_engine_step(
     # ======================================================================
     # (4) sharers consume one FWD per iteration
     # ======================================================================
-    pred4 = (ms.mail.fwd_type != MSG_NONE).any()
+    pred4 = px.any_sim((ms.mail.fwd_type != MSG_NONE).any())
     with scope("gt.mem." + PHASE_NAMES[3]):
         if gate:
             ms, p = _cond_nodir(
@@ -1666,7 +1671,8 @@ def memory_engine_step(
     # ======================================================================
     # (5) homes consume ACKs, finish transactions
     # ======================================================================
-    pred5 = (ms.mail.ack_type != MSG_NONE).any() | ms.txn.active.any()
+    pred5 = px.any_sim((ms.mail.ack_type != MSG_NONE).any()
+                       | ms.txn.active.any())
     view5 = ws.view_finish(ms.txn.line, list(packs))
     with scope("gt.mem." + PHASE_NAMES[4]):
         p = _run_dir_phase(
@@ -1682,8 +1688,8 @@ def memory_engine_step(
     # ======================================================================
     # (6) requesters consume replies (fill L2+L1, complete slot)
     # ======================================================================
-    pred6 = ((ms.req.phase == PHASE_WAIT_REPLY)
-             & (ms.mail.rep_type != MSG_NONE)).any()
+    pred6 = px.any_sim(((ms.req.phase == PHASE_WAIT_REPLY)
+                        & (ms.mail.rep_type != MSG_NONE)).any())
     # fill observability: only phase 6's fill advances req.slot / adds to
     # req.acc_ps, so the pre/post delta IS the per-call fill event — exact
     # even when the whole miss started in phase 1 of this same call

@@ -521,11 +521,14 @@ def shl2_engine_step(
         ms = _apply_functional(mp, ms, rec, slot, s_addr, s_write, slot_done_now)
         return ms, prog
 
+    # per-phase gating as in the private-L2 engine: each predicate is
+    # OR-ed over a campaign's sim axis (px.any_sim; the identity without
+    # one), so a phase cond stays a cond under `vmap`
     gate = bool(getattr(mp, "phase_gate", False))
     # a lane that cannot start now cannot start later this iteration
     # (only the fill phase returns a lane to PHASE_IDLE)
-    pred1 = jnp.any(active & (ms.req.phase == PHASE_IDLE)
-                    & (next_present(ms.req.slot) < 3))
+    pred1 = px.any_sim(jnp.any(active & (ms.req.phase == PHASE_IDLE)
+                               & (next_present(ms.req.slot) < 3)))
     with scope("gt.mem." + SHL2_PHASE_NAMES[0]):
         if gate:
             ms, p = _cond_nodir(pred1, _phase_requester, ms)
@@ -536,7 +539,7 @@ def shl2_engine_step(
     # ======================================================================
     # (2) L1 sharers serve INV/FLUSH/WB from homes
     # ======================================================================
-    pred2 = (ms.mail.fwd_type != MSG_NONE).any()
+    pred2 = px.any_sim((ms.mail.fwd_type != MSG_NONE).any())
     with scope("gt.mem." + SHL2_PHASE_NAMES[1]):
         if gate:
             ms, p = _cond_nodir(
@@ -553,7 +556,7 @@ def shl2_engine_step(
     # ======================================================================
     # (3) homes consume L1 evictions (directory + L2 dirty fill)
     # ======================================================================
-    pred3 = (ms.mail.evict_type != MSG_NONE).any()
+    pred3 = px.any_sim((ms.mail.evict_type != MSG_NONE).any())
     with scope("gt.mem." + SHL2_PHASE_NAMES[2]):
         if gate:
             ms, p = _cond_dir(
@@ -570,7 +573,8 @@ def shl2_engine_step(
     # ======================================================================
     # (4) homes consume acks / dram arrivals, finish transactions
     # ======================================================================
-    pred4 = (ms.mail.ack_type != MSG_NONE).any() | ms.txn.active.any()
+    pred4 = px.any_sim((ms.mail.ack_type != MSG_NONE).any()
+                       | ms.txn.active.any())
     with scope("gt.mem." + SHL2_PHASE_NAMES[3]):
         if gate:
             ms, p = _cond_dir(
@@ -587,8 +591,8 @@ def shl2_engine_step(
     # ======================================================================
     # (5) homes start transactions
     # ======================================================================
-    pred5 = ((ms.mail.req_type != MSG_NONE).any()
-             | (ms.txn.saved_valid & ~ms.txn.active).any())
+    pred5 = px.any_sim((ms.mail.req_type != MSG_NONE).any()
+                       | (ms.txn.saved_valid & ~ms.txn.active).any())
     with scope("gt.mem." + SHL2_PHASE_NAMES[4]):
         if gate:
             ms, p = _cond_dir(
@@ -605,8 +609,8 @@ def shl2_engine_step(
     # ======================================================================
     # (6) requesters consume replies (fill L1)
     # ======================================================================
-    pred6 = ((ms.req.phase == PHASE_WAIT_REPLY)
-             & (ms.mail.rep_type != MSG_NONE)).any()
+    pred6 = px.any_sim(((ms.req.phase == PHASE_WAIT_REPLY)
+                        & (ms.mail.rep_type != MSG_NONE)).any())
     # fill observability for the round-21 latency histograms: phase 6's
     # fill is the only writer of req.slot / req.acc_ps in this block, so
     # the pre/post delta is the exact per-call miss completion (see

@@ -238,6 +238,12 @@ class JobResult:
     knob_point: "dict | None" = None
     n_quanta: "int | None" = None
     n_iterations: "int | None" = None
+    # what the memory engine's gates skipped in this job's lanes of the
+    # batch's program (`SweepOutcome.phase_skips` / `.base_skips`: of
+    # `n_iterations`; device counters, not simulated statistics, in no
+    # digest), or None where the program counts none
+    phase_skips: "dict | None" = None
+    base_skips: "dict | None" = None
     # host latency breakdown (round 14) — populated when the service
     # runs with tracing on: {"queue_dwell_s": ..., "batch_execute_s": ...}
     timings: "dict | None" = None

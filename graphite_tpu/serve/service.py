@@ -813,6 +813,8 @@ class CampaignService:
                 pf = None if out.profiles is None else out.profiles[b]
                 hf = (None if getattr(out, "hists", None) is None
                       else out.hists[b])
+                ps = None if out.phase_skips is None else out.phase_skips[b]
+                bs = None if out.base_skips is None else out.base_skips[b]
                 results.append(JobResult(
                     job_id=p.job.job_id, status=STATUS_OK,
                     results=out.results[b], telemetry=tl, profile=pf,
@@ -820,7 +822,8 @@ class CampaignService:
                     batch_id=batch_id, attempts=p.attempts + 1,
                     seed=p.job.seed, knob_point=dict(p.job.knobs),
                     n_quanta=int(out.n_quanta[b]),
-                    n_iterations=int(out.n_iterations[b])))
+                    n_iterations=int(out.n_iterations[b]),
+                    phase_skips=ps, base_skips=bs))
         return results
 
     # -- program cache ---------------------------------------------------

@@ -14,10 +14,10 @@ to a scalar.  The ENGINE's activity gates are (since PR 34: the sims
 are mapped under a named axis and `engine/step.py` ORs each predicate
 over it, `ParallelCtx.any_sim`): a batch runs the mailbox, NoC, barrier,
 mutex/cond, join and DVFS blocks only in iterations where some sim of
-it needs them.  The MEMORY engine's gates are not yet (ROADMAP M3b):
-its six phases and its directory base run in every iteration for every
-sim, so the batched iteration's device time is still a multiple of the
-gated solo program's per lane.
+it needs them.  The MEMORY engines' six phase conds, the home-activity
+gate over the directory base and the staging flush follow the same rule
+since PR 36; the whole-engine `mem_gate` cond stays off under a batch
+(`_build_sim`; ROADMAP M3b, second half).
 
 Mechanics:
  - traces pack to a common [B, T, L] layout (sweep/pack.py); `vmap` maps
@@ -58,7 +58,11 @@ class SweepOutcome:
     knobs: "Knobs"                # the [B] knob batch that ran
     n_iterations: np.ndarray      # int64[B] subquantum iterations per sim
     n_quanta: np.ndarray          # int32[B]
-    phase_skips: "list[dict] | None"  # per-sim gate skip counts (or None)
+    # per-sim counts of what the PROGRAM skipped (or None): under a sim
+    # axis a gate's predicate is OR-ed over the batch, so a sim counts a
+    # skip only where every sim of its batch had nothing to do — device
+    # counters, not simulated statistics
+    phase_skips: "list[dict] | None"
     seeds: "np.ndarray | None" = None  # per-sim trace seeds (pack metadata)
     # per-sim device-recorded timelines (obs.Timeline) when the campaign
     # ran with a TelemetrySpec: the batched [B, S, n_series] ring demuxed
@@ -80,6 +84,9 @@ class SweepOutcome:
     # "solo", "1d-batch(d=N)", "1d-tile(t=N)", or "2d(b=DB,t=DT)" —
     # reported per row so a result line names the program that made it
     layout: str = "solo"
+    # per-sim {"base", "flush"} skip counts of the private-L2 engine's
+    # home-activity gate (memory/engine.BASE_SKIP_NAMES), or None
+    base_skips: "list[dict] | None" = None
 
     def json_rows(self) -> "list[dict]":
         """One JSON-able dict per sim (the CLI's output lines)."""
@@ -347,7 +354,8 @@ class SweepRunner:
                     if (self._sims_per_cell(layout) > 1) != old_vmapped \
                             and self._has_mem and not self._user_gating:
                         # the gating defaults follow the per-cell
-                        # program shape (vmapped cells run ungated);
+                        # program shape (vmapped cells keep the
+                        # whole-engine mem_gate off);
                         # rebuild the wrapped sim so the executed and
                         # certified program agree
                         self.sim = self._build_sim(layout)
@@ -514,13 +522,14 @@ class SweepRunner:
         config, trace0, mbd, kwargs = self._sim_ctor
         kwargs = dict(kwargs)
         if self._sims_per_cell(layout) > 1 and self._has_mem:
-            # the per-cell program is vmapped: the memory engine's
-            # gating conds (predicates per sim) would become both-branch
-            # selects, so default them OFF (bit-identical results,
-            # measured faster; explicit kwargs win).  The engine's own
-            # activity gates need no switch: they reduce their
-            # predicates over the sim axis (`_runner_fn`: over_sims)
-            kwargs.setdefault("phase_gate", False)
+            # the per-cell program is vmapped.  The memory engines'
+            # phase conds and the home-activity gate reduce their
+            # predicates over the sim axis, like the engine's own
+            # activity gates (`_runner_fn`: over_sims), so `phase_gate`
+            # keeps the Simulator's default.  The whole-engine
+            # `mem_gate` cond does not: its predicate is per sim and its
+            # outputs are the batch's stores, which a both-branch select
+            # would double-buffer — default it OFF (explicit kwargs win)
             kwargs.setdefault("mem_gate_bytes", 0)
         return Simulator(config, trace0, mailbox_depth=mbd,
                          barrier_host=False, **kwargs)
@@ -855,7 +864,8 @@ class SweepRunner:
                 jax.block_until_ready((nq_d, deadlock_d, iters_d))
         net_part, mem_part, ioc_part, tel_part, prof_part, hist_part = \
             Simulator._result_parts(state)
-        skips_d = None if state.mem is None else state.mem.phase_skips
+        skips_d = None if state.mem is None else (
+            state.mem.phase_skips, getattr(state.mem, "base_skips", None))
         # ONE batched device->host fetch: control flags, every summary
         # counter, the rings and the gates' skip counts
         with span("fetch", parent="wait"):
@@ -933,20 +943,24 @@ class SweepRunner:
                 hist=None if hists is None else hists[b])
             for b in range(B)
         ]
-        phase_skips = None
+        phase_skips = base_skips = None
         if skips_h is not None:
             from graphite_tpu.engine.simulator import mem_phase_names
+            from graphite_tpu.memory.engine import BASE_SKIP_NAMES
 
-            skips = np.asarray(skips_h)
-            names = mem_phase_names(self.sim.params)
-            phase_skips = [
-                {n: int(v) for n, v in zip(names, skips[b].tolist())}
-                for b in range(B)
-            ]
+            def named(names, rows):
+                return None if rows is None else [
+                    {n: int(v) for n, v in zip(names, rows[b].tolist())}
+                    for b in range(B)]
+
+            phase_skips = named(mem_phase_names(self.sim.params),
+                                skips_h[0])
+            base_skips = named(BASE_SKIP_NAMES, skips_h[1])
         return SweepOutcome(results=results, knobs=self.knobs,
                             n_iterations=np.asarray(iters),
                             n_quanta=np.asarray(nq),
                             phase_skips=phase_skips,
+                            base_skips=base_skips,
                             seeds=self.pack.seeds,
                             quantum_valid=self.sim.quantum_ps is not None,
                             timelines=timelines,

@@ -111,6 +111,22 @@ def _compare(name, ra, rb):
     return 0 if ok else 1
 
 
+def _timeline_matches(tl, solo) -> bool:
+    """A campaign sim's device timeline against its own sequential
+    run's: every series but the skip_* ones.  A batch's gates are keyed
+    on the OR of their predicate over its sims (`ParallelCtx.any_sim`),
+    so those count what the BATCH's program skipped and no solo run is
+    their oracle."""
+    import numpy as np
+
+    from graphite_tpu.obs.telemetry import SKIP_PREFIX
+
+    keep = [i for i, n in enumerate(tl.series)
+            if not n.startswith(SKIP_PREFIX)]
+    return (tl.n_total == solo.n_total
+            and np.array_equal(tl.data[:, keep], solo.data[:, keep]))
+
+
 def smoke(tiles: int = 16) -> int:
     """The tier-1 companion fast path: gated/ungated bit-exactness and
     batched-barrier equivalence at 16 tiles on CPU."""
@@ -187,9 +203,7 @@ def smoke(tiles: int = 16) -> int:
                          mailbox_depth=sweep_tel.mailbox_depth,
                          phase_gate=False, mem_gate_bytes=0,
                          telemetry=tel).run().telemetry
-        tl = out_tel.timelines[b]
-        ok = (tl.n_total == solo.n_total
-              and np.array_equal(tl.data, solo.data))
+        ok = _timeline_matches(out_tel.timelines[b], solo)
         print(f"{f'sweep B=4 sim {b} timeline vs sequential':44} "
               f"{'PASS' if ok else 'FAIL'}")
         failures += 0 if ok else 1
@@ -302,20 +316,12 @@ def smoke(tiles: int = 16) -> int:
     served = {r.job_id: r for r in svc.drain()}
     for job in serve_jobs:
         sc_j = sc4 if job.n_tiles == 4 else sc8
-        if job.telemetry is not None:
-            # the vmapped campaign runs gates-off (SweepRunner default),
-            # so the telemetry oracle's skip_* series must too
-            seq = Simulator(sc_j, job.trace, phase_gate=False,
-                            mem_gate_bytes=0, telemetry=tel_sv).run()
-        else:
-            seq = Simulator(sc_j, job.trace).run()
+        seq = Simulator(sc_j, job.trace, telemetry=job.telemetry).run()
         got = served[job.job_id]
         failures += _compare(f"serve {job.job_id} vs sequential",
                              got.results, seq)
         if job.telemetry is not None:
-            ok = (got.telemetry.n_total == seq.telemetry.n_total
-                  and np.array_equal(got.telemetry.data,
-                                     seq.telemetry.data))
+            ok = _timeline_matches(got.telemetry, seq.telemetry)
             print(f"{f'serve {job.job_id} timeline vs sequential':44} "
                   f"{'PASS' if ok else 'FAIL'}")
             failures += 0 if ok else 1

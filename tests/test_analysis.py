@@ -298,30 +298,58 @@ def test_vmap_gate_clean_on_real_cond_or_ungated():
                                n_phases=1)
 
 
-def test_vmap_gate_fires_on_gated_sweep_runner():
-    """End-to-end: forcing phase_gate=True through a vmapped
-    SweepRunner produces a program the rule flags (the PERF round-7
-    finding the runner's default avoids)."""
+def test_vmap_gate_clean_on_gated_sweep_runner():
+    """End-to-end: a vmapped SweepRunner keeps the memory engine's phase
+    gates on by default (ISSUE 36: their predicates are OR-ed over the
+    sim axis), and the rule finds every phase cond alive - no batched
+    cond in the served program; and no cond carries a directory store."""
     from graphite_tpu.analysis.audit import spec_from_sweep
     from graphite_tpu.sweep import SweepRunner
 
-    tiles = 4
-    sc = SimConfig(ConfigFile.from_string(config_text(
-        tiles, shared_mem=True, clock_scheme="lax_barrier")))
-    traces = [synthetic.memory_stress_trace(
-        tiles, n_accesses=8, working_set_bytes=1 << 10,
-        write_fraction=0.4, shared_fraction=0.5, seed=s)
-        for s in (1, 2)]
-    runner = SweepRunner(sc, traces, shard_batch=False,
-                         phase_gate=True, mem_gate_bytes=0)
+    runner = SweepRunner(*_gated_sweep_args(), shard_batch=False)
+    assert runner.sim.params.mem.phase_gate
     spec = spec_from_sweep("gated-vmap", runner, max_quanta=256)
     assert spec.expect_gated
-    fs = rules.vmap_gate(spec.closed, spec.n_tiles, spec.expect_gated,
-                         n_phases=spec.n_phases)
-    assert fs and fs[0].rule == "vmap-gate"
+    assert not rules.vmap_gate(spec.closed, spec.n_tiles,
+                               spec.expect_gated, n_phases=spec.n_phases)
+    assert len(rules.phase_conds(spec.closed, spec.n_tiles)) \
+        == spec.n_phases == 6
+    assert spec.forbidden_cond_avals
+    assert not rules.cond_payload(spec.closed,
+                                  forbidden=spec.forbidden_cond_avals)
     # lowering is abstract: auditing must not materialize the [B, ...]
     # campaign state run() caches for execution
     assert runner._states0 is None
+
+
+def test_vmap_gate_fires_on_bare_vmap_of_a_gated_engine():
+    """... while a bare `vmap` of the same gated program - no named sim
+    axis, so nothing to reduce the predicates over - still turns every
+    phase cond into both branches and a select, and the rule says so."""
+    from graphite_tpu.engine.step import run_simulation
+    from graphite_tpu.sweep import SweepRunner
+
+    runner = SweepRunner(*_gated_sweep_args(), shard_batch=False)
+    params = runner.sim.params
+    assert params.mem.phase_gate
+    states, traces, knobs = runner.abstract_inputs()
+    closed = jax.make_jaxpr(jax.vmap(
+        lambda s, t, k: run_simulation(
+            params, t, s, k.quantum_ps, 256, knobs=k)))(
+                states, traces, knobs)
+    fs = rules.vmap_gate(closed, params.n_tiles, True, n_phases=6)
+    assert fs and fs[0].rule == "vmap-gate"
+    assert fs[0].data["phase_conds"] == 0
+
+
+def _gated_sweep_args():
+    tiles = 4
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        tiles, shared_mem=True, clock_scheme="lax_barrier")))
+    return sc, [synthetic.memory_stress_trace(
+        tiles, n_accesses=8, working_set_bytes=1 << 10,
+        write_fraction=0.4, shared_fraction=0.5, seed=s)
+        for s in (1, 2)]
 
 
 # ---- rule 5: host-sync ----------------------------------------------------

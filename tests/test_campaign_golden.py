@@ -6,10 +6,11 @@ The cell's `correct` compares served envelopes with digests the engine
 itself made on the CPU backend (`benchmark/make_reference_campaign.py`):
 independent of `serve/`, `sweep/`, `vmap` and the knob operands, not of
 `engine/step.py` or `memory/engine.py`.  What is new in that cell is the
-program: the engine under `vmap` (B = 4) with `phase_gate`, `mem_gate`
-and the home gate OFF, its MSI phases 2-5 doing work (INV fan-out,
-write-backs of modified lines, evictions), the DRAM latency a traced
-knob.  Here that program - the cell's own 64-tile target
+program: the engine under `vmap` (B = 4), the whole-engine `mem_gate`
+OFF and, since ISSUE 36, the six phase gates and the home gate ON with
+their predicates OR-ed over the batch, its MSI phases 2-5 doing work
+(INV fan-out, write-backs of modified lines, evictions), the DRAM
+latency a traced knob.  Here that program - the cell's own 64-tile target
 (`benchmark/configs/ref-default-64-campaign.json`) with the in-order core
 the golden interpreter models (the configuration's `control`), through
 `CampaignService(batch_size=4)` at the cell's four latencies - is
@@ -45,6 +46,7 @@ import pytest
 
 from graphite_tpu.config import ConfigFile, SimConfig
 from graphite_tpu.golden import run_golden
+from graphite_tpu.memory.engine import PHASE_NAMES
 from graphite_tpu.serve.job import Job
 from graphite_tpu.serve.service import CampaignService
 from graphite_tpu.sweep.runner import SweepRunner
@@ -134,7 +136,8 @@ def total(x) -> int:
 def test_the_program_is_the_cells(served):
     """4-wide batches of one trace at the four latencies, nothing padded,
     on the cell's geometry; and a 4-wide runner, as the service builds
-    one per batch, has the phase gates and the whole-engine gate off."""
+    one per batch, has the phase gates on (ISSUE 36) and the whole-engine
+    gate off; the envelopes carry what the gates skipped."""
     svc = served["svc"]
     log = list(svc.batch_log)
     assert [(b.n_jobs, b.batch_cap) for b in log] == [(4, 4)] * 4
@@ -148,13 +151,17 @@ def test_the_program_is_the_cells(served):
         SimConfig(ConfigFile.from_string(text())),
         [served["traces"]["cell"]] * 4,
         [{"dram_latency_ns": lat} for lat in LATENCIES], shard_batch=False)
-    assert not runner.sim.params.mem.phase_gate
+    assert runner.sim.params.mem.phase_gate
     assert not runner.sim.params.mem_gate
+    for env in served["envelopes"].values():
+        assert set(env.phase_skips) == set(PHASE_NAMES)
+        assert 0 < sum(env.phase_skips.values()) < 6 * env.n_iterations
+        assert 0 < env.base_skips["base"] < env.n_iterations
 
 
 @pytest.mark.parametrize("lat", LATENCIES)
 @pytest.mark.parametrize("name", sorted(EXACT))
-def test_served_ungated_equals_golden(served, name, lat):
+def test_served_program_equals_golden(served, name, lat):
     env = served["envelopes"][f"{name}-L{lat}"]
     assert env.status == "ok" and env.knob_point == {"dram_latency_ns": lat}
     gold = golden(served, name, lat)

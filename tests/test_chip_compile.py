@@ -166,6 +166,61 @@ def test_ungated_16_updates_the_l2_meta_store_in_place(one_chip):
         if c.size >= meta.size)})
 
 
+def test_served_b4_16_gates_return_no_directory_store(one_chip):
+    """The program a campaign's batch runs since ISSUE 36: B = 4 sims of
+    the 16-tile reference-default target under `vmap`, the memory
+    engine's phase gates on, their predicates OR-ed over the sim axis.
+    Asked of the TPU compiler, because the served cell's `peak_hbm_gb`
+    pays for the answer: all fourteen gates (seven of the memory engine,
+    seven of the iteration's other blocks) reach it as `conditional`s -
+    a batched predicate leaves none, only both branches and selects; no
+    conditional returns a directory store (a branch output is a fresh
+    buffer: the round-2 double-buffering, `engine.dir_store_avals`); and
+    exactly the three directory-free phases return the two u32 halves of
+    the int64 L2 meta store, which XLA must alias to the loop's carry
+    for the batch's peak to hold - a fourth such region is a regression
+    to measure (`_hand/memstat34.py`: PERF.md section 6, PR 36)."""
+    from graphite_tpu.analysis.loop_copies import (
+        conditionals, copies_of, loop_copies,
+    )
+    from graphite_tpu.memory.engine import PHASE_NAMES, dir_store_avals
+    from graphite_tpu.sweep import SweepRunner
+    from graphite_tpu.trace.synthetic import memory_stress_trace
+
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        16, shared_mem=True, clock_scheme="lax_barrier")))
+    runner = SweepRunner(
+        sc, [memory_stress_trace(16, n_accesses=8)],
+        [{"dram_latency_ns": lat} for lat in (60, 100, 140, 180)],
+        shard_batch=False)
+    assert runner.sim.params.mem.phase_gate
+    assert not runner.sim.params.mem_gate
+    compiled = runner._get_runner(1_000_000).lower(
+        *(_shapes(t, one_chip) for t in runner.abstract_inputs())).compile()
+    _fits(_report("served-b4-16", compiled))
+    text = compiled.as_text()
+    conds = conditionals(text)
+    mem = [c for c in conds if "gt.mem." in c.op_name]
+    assert len(conds) == 14 and len(mem) == 7, [c.op_name for c in conds]
+    mem_state = runner.sim.state.mem
+    for shape, _ in dir_store_avals(mem_state):
+        hit = [c.op_name for c in conds if c.returns(shape)]
+        assert not hit, (shape, hit)
+    assert str(mem_state.l2.meta.dtype) == "int64"
+    carriers = [c for c in conds
+                if c.returns(mem_state.l2.meta.shape, ("u32", "s64"))]
+    assert sorted(c.op_name.split("/")[-2] for c in carriers) == sorted(
+        "gt.mem." + PHASE_NAMES[i] for i in (0, 3, 5)), [
+            c.op_name for c in carriers]
+    assert all(len(c.returns(mem_state.l2.meta.shape, ("u32", "s64"))) == 2
+               for c in carriers)
+    # ... and the engine's iteration body still copies neither half
+    # (PR 32's guard, asked of the program a campaign runs now)
+    body = loop_copies(text, under="gt.mem.requester/")
+    halves = copies_of(body, mem_state.l2.meta.shape, ("u32", "s64"))
+    assert not halves, [c.line[:200] for c in halves]
+
+
 @pytest.mark.slow
 def test_ref_default_64_compiles(one_chip):
     sim = _ref_default(64, points=64)

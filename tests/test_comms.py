@@ -281,13 +281,17 @@ class TestPhaseAttribution:
         assert rep.ici_bytes_per_iter == sum(
             c.ici_bytes for c in rep.collectives) > 0
 
-    def test_vmapped_2d_attributes_base(self, mesh_specs):
-        """sweep-b4-2d's vmapped layout traded its phase conds for
-        masked always-run phases, so every collective lands on the
-        'base' phase — and all five are whitelisted px exchanges."""
+    def test_vmapped_2d_per_phase_counts(self, mesh_specs):
+        """sweep-b4-2d's vmapped layout keeps its phase conds since
+        ISSUE 36 (their predicates OR-ed over the cell's sims, the same
+        on every device of the tile axis), so its five whitelisted px
+        exchanges land on the phases they serve, as in the gated
+        program of one sim a cell."""
         spec = next(s for s in mesh_specs if s.name == "sweep-b4-2d")
         rep = comms.comms_report(spec)
-        assert [r.phase for r in rep.phase_rows()] == [comms.BASE_PHASE]
+        counts = {r.phase: r.collectives for r in rep.phase_rows()}
+        assert counts == {"requester": 2, "home_evict": 1,
+                          "sharer": 1, "requester_fill": 1}
         assert rep.collectives_per_iter == 5
         assert all(c.kind == comms.KIND_PX for c in rep.collectives)
 

@@ -26,6 +26,7 @@ from graphite_tpu.analysis.registry import ProgramRecord
 from graphite_tpu.config import ConfigFile, SimConfig
 from graphite_tpu.engine.simulator import DeadlockError, Simulator
 from graphite_tpu.obs import TelemetrySpec
+from graphite_tpu.obs.telemetry import SKIP_PREFIX
 from graphite_tpu.serve import (
     AdmissionController, CacheEntry, CampaignService, Job, JobResult,
     ProgramCache, ProgramCacheError, QueueFullError, STATUS_OK,
@@ -426,14 +427,21 @@ class TestServeTelemetryAndSchemes:
             svc.submit(Job(f"t{i}", _config(), _trace(s), telemetry=tel))
         out = {r.job_id: r for r in svc.drain()}
         for i, s in enumerate((1, 2)):
-            # the vmapped campaign program runs gates-off (SweepRunner
-            # default), so the skip_* series oracle must too
-            solo = Simulator(_config(), _trace(s), phase_gate=False,
-                             mem_gate_bytes=0, telemetry=tel).run()
+            # the skip_* series count what the BATCH's program skipped
+            # (the gates' predicates are OR-ed over its sims): no solo
+            # run is their oracle, every other series has one
+            solo = Simulator(_config(), _trace(s), mem_gate_bytes=0,
+                             telemetry=tel).run()
             tl = out[f"t{i}"].telemetry
             assert tl is not None
             assert tl.n_total == solo.telemetry.n_total
-            np.testing.assert_array_equal(tl.data, solo.telemetry.data)
+            skips = np.array([n.startswith(SKIP_PREFIX)
+                              for n in tl.series])
+            assert skips.any() and not skips.all()
+            np.testing.assert_array_equal(tl.data[:, ~skips],
+                                          solo.telemetry.data[:, ~skips])
+            assert (tl.data[:, skips] <= solo.telemetry.data[:, skips]
+                    ).all() and tl.data[:, skips].any()
             _assert_results_equal(out[f"t{i}"].results, solo, msg=f"t{i}")
 
     def test_clock_scheme_axis_batches_separately(self):

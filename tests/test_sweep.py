@@ -229,6 +229,13 @@ def hetero_refs():
     return sc, pack, depth, refs
 
 
+def _loop_flag(eqn):
+    """The first carried value of a `while` equation: the flag of the
+    in-place gate `memory/engine._run_if`."""
+    p = eqn.params
+    return eqn.invars[p["cond_nconsts"] + p["body_nconsts"]].aval
+
+
 def _assert_whole_results_equal(ra, rb, msg):
     for f in dataclasses.fields(ra):
         a, b = getattr(ra, f.name), getattr(rb, f.name)
@@ -264,7 +271,15 @@ class TestActivityGatesUnderTheSimAxis:
         assert out.results[0].packets_received.sum() >= 8 * 7 + 1
         assert out.results[1].sync_instructions.sum() > 0
         assert out.results[2].packets_sent.sum() == 0
-        # ... and every leaf of every sim's final state
+        # the memory engine's gates engaged (ISSUE 36), less often than
+        # in a batch of one stream: the sims' idle iterations differ
+        for b in range(4):
+            assert 0 < sum(out.phase_skips[b].values()) \
+                < 6 * iters[b], out.phase_skips[b]
+            assert 0 < out.base_skips[b]["base"] < iters[b]
+        # ... and every leaf of every sim's final state, but for the
+        # counters of what the PROGRAM skipped (the references ran
+        # un-gated and count none)
         states0, dtr = sweep._batched_inputs()
         state = jax.device_get(sweep._get_runner(1_000_000)(
             states0, dtr, sweep.knobs)[0])
@@ -274,9 +289,12 @@ class TestActivityGatesUnderTheSimAxis:
             want = jax.tree_util.tree_leaves(refs[b][1])
             assert len(got) == len(want)
             for (path, g), w in zip(got, want):
+                leaf = jax.tree_util.keystr(path)
+                if leaf in (".mem.phase_skips", ".mem.base_skips"):
+                    assert not np.asarray(w).any() and np.asarray(g).any()
+                    continue
                 np.testing.assert_array_equal(
-                    g, w, err_msg=f"sim {b}: state leaf "
-                    f"{jax.tree_util.keystr(path)}")
+                    g, w, err_msg=f"sim {b}: state leaf {leaf}")
 
     def test_pending_signal_is_not_dropped_by_a_neighbours_sync(self):
         """The mutex/cond block is the one block that changes state with
@@ -320,6 +338,13 @@ class TestActivityGatesUnderTheSimAxis:
     GATES = ["gt.net.mailbox", None, "gt.sync.barrier",
              "gt.sync.mutex_cond", "gt.sync.mutex_cond", "gt.sync.join",
              "gt.dvfs"]
+    # ... and the private-L2 memory engine's (ISSUE 36): the six phase
+    # conds, and after the requester's the working-set gather's under
+    # the home-activity gate (not under a tile mesh: its rows ride a
+    # collective, which must not sit in a cond)
+    MEM_GATES = ["gt.mem.requester", "gt.mem.base", "gt.mem.home_evict",
+                 "gt.mem.home_start", "gt.mem.sharer",
+                 "gt.mem.home_finish", "gt.mem.requester_fill"]
 
     @pytest.mark.parametrize("layout", ["solo", (2, 2)],
                              ids=["solo_vmap", "2d_b2_t2"])
@@ -330,7 +355,9 @@ class TestActivityGatesUnderTheSimAxis:
         none: a batched predicate turns a cond into both branches and a
         `select_n`); what `vmap` leaves of the reduction is a `pmax` over
         a POSITIONAL axis — no named-axis collective, so nothing for a
-        fabric to carry; and the un-gated memory engine adds no cond."""
+        fabric to carry.  The memory engine's seven predicates go the
+        same way, and the merged scatter's in-place gate (`_run_if`, a
+        zero-or-one-trip `while`) is keyed on a scalar flag."""
         from graphite_tpu.analysis.comms import extract_collectives
         from graphite_tpu.analysis.walk import find_eqns, iter_eqns
         from graphite_tpu.obs.scopes import deepest
@@ -340,13 +367,18 @@ class TestActivityGatesUnderTheSimAxis:
                             layout=layout)
         closed, _ = sweep.lower()
         conds = [e for _, e in find_eqns(closed, "cond")]
+        mem_gates = [g for g in self.MEM_GATES
+                     if layout == "solo" or g != "gt.mem.base"]
         assert [deepest(str(e.source_info.name_stack))
-                for e in conds] == self.GATES
-        assert [e.invars[0].aval.shape for e in conds] == [()] * 7
+                for e in conds] == mem_gates + self.GATES
+        assert [e.invars[0].aval.shape for e in conds] == [()] * len(conds)
         over_sims = [e for e in iter_eqns(closed)
                      if e.primitive.name == "pmax"]
-        assert [e.params["axes"] for e in over_sims] == [(0,)] * 7
+        assert [e.params["axes"] for e in over_sims] == [(0,)] * 14
         assert all(e.outvars[0].aval.shape == () for e in over_sims)
+        in_place = [e for _, e in find_eqns(closed, "while")
+                    if "gt.mem" in str(e.source_info.name_stack)]
+        assert [_loop_flag(e).shape for e in in_place] == [()]
         # the analyzer prices none of them, and under the 2D mesh finds
         # the tile axis's packed exchanges and nothing else
         cols = extract_collectives(closed, n_tiles=TILES)
@@ -383,6 +415,129 @@ class TestActivityGatesUnderTheSimAxis:
                  in self.GATES]
         assert len(gates) == 6
         assert all(e.invars[0].aval.shape == () for e in gates)
+
+    # ---- the memory engines' gates (ISSUE 36) --------------------------
+    # `campaign64-dram`'s batch at 16 tiles: one stream of the cell's
+    # generator at its four DRAM latencies, the cell's core
+
+    LATENCIES = (60, 100, 140, 180)
+    MEM_TILES = 16
+
+    @classmethod
+    def _cell_config(cls, latency_ns=None, **kw):
+        text = config_text(cls.MEM_TILES, shared_mem=True,
+                           clock_scheme="lax_barrier", **kw)
+        if latency_ns is not None:
+            text += f"\n[dram]\nlatency = {latency_ns}\n"
+        return SimConfig(ConfigFile.from_string(text))
+
+    @classmethod
+    def _cell_stream(cls, seed=0, n=40):
+        return synthetic.memory_stress_trace(
+            cls.MEM_TILES, n_accesses=n, working_set_bytes=8192,
+            write_fraction=0.4, shared_fraction=0.5, seed=seed)
+
+    @classmethod
+    def _points(cls, n=4):
+        return [{"dram_latency_ns": lat} for lat in cls.LATENCIES[:n]]
+
+    @staticmethod
+    def _assert_outcomes_equal(got, want):
+        assert got.n_iterations.tolist() == want.n_iterations.tolist()
+        for b, (ra, rb) in enumerate(zip(got.results, want.results)):
+            _assert_whole_results_equal(ra, rb, f"sim {b}")
+
+    def test_memory_gates_batch_of_four_latencies(self):
+        """The default `_build_sim` leaves the phase gates on under the
+        batch.  Every field of every `SimResults` and every sim's
+        iteration count are those of the explicit `phase_gate=False`
+        batch and of four sequential `Simulator.run()`s with the latency
+        in the config text; what differs is what the program skipped."""
+        trace = self._cell_stream()
+        sc = self._cell_config(core="iocoom")
+        gated = SweepRunner(sc, [trace], self._points(), layout="solo")
+        assert gated.sim.params.mem.phase_gate
+        assert not gated.sim.params.mem_gate
+        out = gated.run()
+        off = SweepRunner(sc, [trace], self._points(), layout="solo",
+                          phase_gate=False)
+        assert not off.sim.params.mem.phase_gate
+        out_off = off.run()
+        self._assert_outcomes_equal(out, out_off)
+        for b, lat in enumerate(self.LATENCIES):
+            sim = Simulator(self._cell_config(lat, core="iocoom"), trace)
+            _assert_whole_results_equal(out.results[b], sim.run(),
+                                        f"latency {lat}")
+            assert sim.last_n_iterations == out.n_iterations[b]
+        # the latencies spread the sims' finishing times
+        iters = out.n_iterations.tolist()
+        assert iters[0] < iters[3], iters
+        for b in range(4):
+            skips, base = out.phase_skips[b], out.base_skips[b]
+            assert all(0 < v < iters[b] for v in skips.values()), skips
+            assert 0 < base["base"] < iters[b] and base["flush"] == 0
+            assert not any(out_off.phase_skips[b].values())
+            assert not any(out_off.base_skips[b].values())
+        # sims that ran the same iterations counted the same skips: the
+        # counters count the batch's OR, not a sim's own predicate
+        same = [b for b in range(4) if iters[b] == iters[0]]
+        assert len(same) > 1
+        assert all(out.phase_skips[b] == out.phase_skips[0] for b in same)
+
+    def test_staged_target_flushes_under_a_scalar_flag(self):
+        """`dir_stage=True` under `vmap`: the per-block flush is gated in
+        place by a counter that rides the batched state, reduced over
+        the sims - both zero-or-one-trip loops of the program carry a
+        scalar flag, blocks skip their flush, and nothing a sim computes
+        differs from the un-gated staged batch."""
+        from graphite_tpu.analysis.walk import find_eqns
+
+        trace = self._cell_stream()
+        sc = self._cell_config()
+        staged = dict(layout="solo", dir_stage=True, inner_block=4)
+        gated = SweepRunner(sc, [trace], self._points(), **staged)
+        assert gated.sim.params.mem.dir_stage_cap
+        closed, _ = gated.lower()
+        in_place = [e for _, e in find_eqns(closed, "while")
+                    if "gt.mem" in str(e.source_info.name_stack)]
+        assert sorted(str(e.source_info.name_stack).split("/")[-1]
+                      for e in in_place) == ["gt.mem.base",
+                                             "gt.mem.stage_flush"]
+        assert [_loop_flag(e).shape for e in in_place] == [(), ()]
+        out = gated.run()
+        self._assert_outcomes_equal(out, SweepRunner(
+            sc, [trace], self._points(), phase_gate=False, **staged).run())
+        for b in range(4):
+            blocks = int(out.n_iterations[b]) // 4
+            assert 0 < out.base_skips[b]["flush"] < blocks
+
+    def test_shared_l2_batch_of_two(self):
+        """The shared-L2 engine's six phase conds take the same rule: a
+        B = 2 `pr_l1_sh_l2_msi` batch under the default build equals two
+        sequential runs, its conds keyed on scalars, skips counted."""
+        from graphite_tpu.analysis.walk import find_eqns
+        from graphite_tpu.memory.engine_shl2 import SHL2_PHASE_NAMES
+
+        trace = self._cell_stream(n=24)
+        kw = dict(protocol="pr_l1_sh_l2_msi")
+        sweep = SweepRunner(self._cell_config(**kw), [trace],
+                            self._points(2), layout="solo")
+        assert sweep.sim.params.mem.phase_gate
+        closed, _ = sweep.lower()
+        phase_conds = [
+            e for _, e in find_eqns(closed, "cond")
+            if str(e.source_info.name_stack).split("/")[-1]
+            in {"gt.mem." + n for n in SHL2_PHASE_NAMES}]
+        assert len(phase_conds) == 6
+        assert all(e.invars[0].aval.shape == () for e in phase_conds)
+        out = sweep.run()
+        for b, lat in enumerate(self.LATENCIES[:2]):
+            sim = Simulator(self._cell_config(lat, **kw), trace)
+            _assert_whole_results_equal(out.results[b], sim.run(),
+                                        f"latency {lat}")
+            assert sim.last_n_iterations == out.n_iterations[b]
+            assert all(v > 0 for v in out.phase_skips[b].values())
+        assert out.base_skips is None
 
 
 class TestKnobTracing:

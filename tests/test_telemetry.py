@@ -30,6 +30,7 @@ from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.obs import (
     CORE_SERIES, LEVEL_SERIES, Timeline, TelemetrySpec, available_series,
 )
+from graphite_tpu.obs.telemetry import SKIP_PREFIX
 from graphite_tpu.tools._template import config_text
 from graphite_tpu.trace import synthetic
 
@@ -460,15 +461,22 @@ class TestSweepDemux:
             tl = out.timelines[b]
             assert tl.data.shape[1] == n_series
             assert out.results[b].telemetry is tl
-            # bit-identical to this sim's own sequential telemetry run
-            # (the vmapped program runs ungated — match it)
+            # bit-identical to this sim's own sequential telemetry run,
+            # but for the skip_* series: the vmapped program's gates are
+            # keyed on the OR over its sims, so it skips a phase no more
+            # often than the sim's own gated run does
             solo = Simulator(_config(), traces[b],
                              mailbox_depth=sweep.mailbox_depth,
-                             phase_gate=False, mem_gate_bytes=0,
+                             mem_gate_bytes=0,
                              telemetry=_spec()).run().telemetry
             assert tl.n_total == solo.n_total
-            np.testing.assert_array_equal(tl.data, solo.data,
+            skips = np.array([n.startswith(SKIP_PREFIX)
+                              for n in tl.series])
+            np.testing.assert_array_equal(tl.data[:, ~skips],
+                                          solo.data[:, ~skips],
                                           err_msg=f"sim {b}")
+            assert (tl.data[:, skips] <= solo.data[:, skips]).all()
+            assert skips.any() and tl.data[:, skips].any()
 
     def test_shard_map_campaign_gathers_device_buffers(self):
         from graphite_tpu.sweep import SweepRunner
