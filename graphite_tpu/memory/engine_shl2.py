@@ -50,7 +50,7 @@ from graphite_tpu.memory.cache_array import (
 from graphite_tpu.memory.engine import (
     MemStepOut, RecView, _dir_set_field, _ID_MASK, _req_consume,
     _req_earliest, _row_earliest,
-    _rows_exchange, clear_bit, lowest_sharer, mem_net_fanout,
+    _rows_exchange, _run_if, clear_bit, lowest_sharer, mem_net_fanout,
     mem_net_latency_ps, mem_net_send, set_bit, test_bit, unpack_sharers,
 )
 from graphite_tpu.memory.params import MemParams
@@ -183,19 +183,26 @@ def _rowsh_update(dsh, way, mask, new_sh):
         dsh.shape[0], W2SW)
 
 
+@scope("gt.mem.dir_apply")
 def _dir_apply_rows(d: ShL2Dir, px: ParallelCtx, sets, dwd, dshd):
     """Scatter full-width embedded-directory ROW deltas block-locally:
     ONE add-a-delta scatter per array (per-lane rows unique, aliases in
     place).  Zero deltas — masked-off lanes, gated-off phases — add
-    nothing."""
+    nothing.  Under its own scope: a gated phase's plan lands OUTSIDE the
+    phase's cond, in every iteration, so the landing's device time is not
+    the phase's."""
     sets_l, dwd_l, dshd_l = px.lo((sets, dwd, dshd))
-    Tl = d.word.shape[0]
+    Tl, S, W = d.sharers.shape
     lt = jnp.arange(Tl, dtype=jnp.int32)
+    # the sharers store row-flat, as XLA lays it anyway: the scatter XLA
+    # makes of a two-index one carries no name, so its device time would
+    # read as the phase's (or nobody's) and not as this scope's
     return d.replace(
         word=d.word.at[lt, sets_l].add(
             dwd_l, unique_indices=True, indices_are_sorted=True),
-        sharers=d.sharers.at[lt, sets_l].add(
-            dshd_l, unique_indices=True, indices_are_sorted=True))
+        sharers=d.sharers.reshape(Tl * S, W).at[lt * S + sets_l].add(
+            dshd_l, unique_indices=True, indices_are_sorted=True
+        ).reshape(Tl, S, W))
 
 
 def _dir_scatter(d: ShL2Dir, px: ParallelCtx, sets, dw0, dw, dsh0, dsh,
@@ -256,8 +263,11 @@ def _cond_dir(pred, fn, ms, n_tiles, px):
     """Run a home-side shl2 phase under a scalar-predicate lax.cond: the
     embedded directory is read inside (cond input, no double-buffering)
     but written only through the `_RowAcc` delta plan the cond returns;
-    `_dir_apply_rows` lands the plan outside.  `fn(ms, acc) ->
-    (ms, progress)` must leave ms.dir untouched."""
+    `_dir_apply_rows` lands the plan outside, in place, and only where
+    the phase ran (`engine._run_if`: a skipped phase's plan is zero, and
+    on the chip a landing on the 1 GB sharers store costs 3 ms whatever
+    it adds).  `fn(ms, acc) -> (ms, progress)` must leave ms.dir
+    untouched."""
     d0 = ms.dir
 
     def run(m):
@@ -269,7 +279,8 @@ def _cond_dir(pred, fn, ms, n_tiles, px):
         return (m, jnp.zeros((), jnp.int32), _RowAcc.zero_pack(d0, n_tiles))
 
     ms2, prog, plan = jax.lax.cond(pred, run, skip, ms.replace(dir=None))
-    return ms2.replace(dir=_dir_apply_rows(d0, px, *plan)), prog
+    landed = _run_if(pred, lambda d: _dir_apply_rows(d, px, *plan), d0)
+    return ms2.replace(dir=landed), prog
 
 
 @struct.dataclass

@@ -38,6 +38,8 @@ SCOPES = (
     "gt.mem.base",          # memory engine outside its phases, its gate
 ) + tuple("gt.mem." + p for p in _MEM_PHASES) + (
     "gt.mem.stage_flush",   # dir_stage_flush, once per inner block
+    "gt.mem.dir_apply",     # shl2: a home phase's row plan landed on the
+                            #   embedded directory, outside its gate
     "gt.net.mailbox",       # SEND / NET_RECV rings
     "gt.net.route",         # NoC latency models, user + memory network
     "gt.sync.barrier",

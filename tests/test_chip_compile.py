@@ -253,6 +253,81 @@ def test_coh_1024_host_batch_compiles(one_chip):
     _fits(_report("coh-1024-host-batch", _compile_host_batch(sim, one_chip)))
 
 
+def _shl2_memstress(tiles):
+    """`shl2-mesi-1024-memstress` (benchmark/configs) at `tiles` tiles:
+    the cell's target text and generator, host-driven as the cell."""
+    from graphite_tpu.trace.synthetic import memory_stress_trace
+
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        tiles, core="simple", shared_mem=True, clock_scheme="lax_barrier",
+        network="emesh_hop_counter", protocol="pr_l1_sh_l2_mesi",
+        scheme="full_map")))
+    return Simulator(sc, memory_stress_trace(
+        tiles, n_accesses=64, working_set_bytes=32768, write_fraction=0.4,
+        shared_fraction=0.5, seed=7), barrier_host=True)
+
+
+@pytest.mark.parametrize("tiles", [
+    16, pytest.param(1024, marks=pytest.mark.slow)])
+def test_shl2_mesi_1024_host_batch_compiles(one_chip, tiles):
+    """The host-driven program of `memstress1024-shl2`, asked of the TPU
+    compiler (the cell's own size is `slow`, as every 1024-tile compile
+    here; 16 tiles is the same engine code in tier-1 time, under the
+    whole-engine `mem_gate` that the cell's 1.29 GB state switches off).
+    The shared-L2 engine's three home phases read the embedded directory
+    inside their `lax.cond` and return a compact row plan; the plan lands
+    outside (`gt.mem.dir_apply`), under the phase's predicate again but
+    in a zero-or-one-trip `while_loop` (`engine._run_if`: a loop's carry
+    is updated in place).  So: every one of the six phase gates
+    reaches the compiler as a `conditional`; none returns the sharers
+    store (a branch output is a fresh buffer: a store among them is
+    double-buffered every iteration, `engine_shl2.dir_store_avals`), nor
+    the directory's word store - what the three home phases do return of
+    that shape is the two u32 halves of the SLICE's int64 meta store,
+    which they update; and, at the cell's size, no loop of the program
+    copies the sharers store whole (1.07 GB): the three landings update
+    it in place."""
+    from graphite_tpu.analysis.loop_copies import (
+        conditionals, copies_of, loop_copies,
+    )
+    from graphite_tpu.memory.engine_shl2 import (
+        SHL2_PHASE_NAMES, dir_store_avals,
+    )
+
+    sim = _shl2_memstress(tiles)
+    assert sim.barrier_host and sim.params.mem.phase_gate
+    assert sim.params.mem_gate == (tiles < 1024)
+    compiled = _compile_host_batch(sim, one_chip)
+    _fits(_report(f"shl2-mesi-{tiles}-host-batch", compiled))
+    text = compiled.as_text()
+    phases = ["gt.mem." + p for p in SHL2_PHASE_NAMES]
+    gates = {c.op_name.split("/")[-2]: c for c in conditionals(text)
+             if c.op_name.count("/") and c.op_name.split("/")[-2] in phases}
+    assert sorted(gates) == sorted(phases), sorted(gates)
+    mem_state = sim.state.mem
+    (word, _), (sharers, _) = dir_store_avals(mem_state)
+    assert mem_state.l2.meta.shape == word
+    assert str(mem_state.l2.meta.dtype) == "int64"
+    for name, c in gates.items():
+        # (at 16 tiles a sharers row is one word wide and the store has
+        # the meta halves' shape and type: the count tells them apart)
+        home = name.split(".")[-1].startswith("home_")
+        stores = {a for shape in (word, sharers) for a in c.returns(shape)}
+        assert stores <= {("u32", word)}, (name, stores)
+        assert len(c.returns(word)) == (2 if home else 0), (name, c.outputs)
+    copies = loop_copies(text)
+    if not sim.params.mem_gate:
+        # (under the whole-engine gate the small program's `gt.mem.base`
+        # conditional returns every store: nothing to hold there)
+        whole = copies_of(copies, sharers, ("u32",))
+        assert not whole, [c.line[:200] for c in whole]
+    print({"program": f"shl2-mesi-{tiles}-host-batch",
+           "dir_apply_ops": text.count("gt.mem.dir_apply"),
+           "largest_in_loop_copies": sorted(
+               ((c.size, c.dtype, c.shape) for c in copies),
+               reverse=True)[:6]})
+
+
 @pytest.mark.slow
 def test_coh_1024_single_region_compiles(one_chip):
     """1024 tiles, full directory: the single-region lax_barrier

@@ -32,7 +32,9 @@ SCOPED_MODULES = (step, engine, engine_shl2, iocoom, px)
 # what each program must contain: everything but the scopes whose code it
 # does not run
 ONLY_SHARDED = {"gt.px"}
-MSI_SCOPES = [s for s in scopes.SCOPES if s not in ONLY_SHARDED]
+ONLY_SHL2 = {"gt.mem.dir_apply"}     # the embedded directory's landing
+MSI_SCOPES = [s for s in scopes.SCOPES
+              if s not in ONLY_SHARDED | ONLY_SHL2]
 SHL2_SCOPES = [s for s in scopes.SCOPES if s not in ONLY_SHARDED
                | {"gt.core.iocoom", "gt.mem.stage_flush", "gt.obs"}]
 
@@ -100,6 +102,10 @@ def test_shared_l2_program_names_scope(found, name):
 
 def test_sharded_program_names_the_exchange(found):
     assert "gt.px" in found("msi-sharded")
+
+
+def test_private_l2_program_has_no_embedded_directory_landing(found):
+    assert not ONLY_SHL2 & found("msi")
 
 
 def test_every_operation_with_a_path_is_scoped(found):
