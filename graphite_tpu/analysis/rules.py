@@ -80,7 +80,8 @@ import re
 import numpy as np
 
 from graphite_tpu.analysis.walk import (
-    aval_bytes, aval_sig, call_arg_maps, iter_eqns_with_site,
+    aval_bytes, aval_sig, call_arg_maps, is_platform_choice,
+    iter_eqns_with_site,
     make_scope, scatter_writer_proof, scope_from_closed, subjaxprs,
     taint_narrowing, used_invar_mask,
 )
@@ -141,12 +142,16 @@ def cond_payload(jaxpr, *, max_bytes: "int | None" = None,
     costs a full extra copy in HBM every iteration — the round-2
     pathology that round 6's `_DirAcc`/`_RowAcc` delta plans exist to
     avoid.  Checked for EVERY cond at EVERY nesting depth, not just the
-    one a test happens to sample.
+    one a test happens to sample — but for the choice of a lowering
+    platform (`walk.is_platform_choice`: resolved when the program is
+    lowered, so no branch output exists to double-buffer; the shared-L2
+    landing picks its TPU kernel that way, on the row-flat sharers
+    store).  Its branches are walked like any other sub-jaxpr.
     """
     forbidden = tuple((tuple(s), str(np.dtype(d))) for s, d in forbidden)
     out = []
     for site, eqn in iter_eqns_with_site(jaxpr):
-        if eqn.primitive.name != "cond":
+        if eqn.primitive.name != "cond" or is_platform_choice(eqn):
             continue
         for k, v in enumerate(eqn.outvars):
             sig = aval_sig(v.aval)

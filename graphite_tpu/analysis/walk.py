@@ -64,6 +64,15 @@ def subjaxprs(eqn):
                 yield tag, v
 
 
+def is_platform_choice(eqn) -> bool:
+    """A `cond` that `jax.lax.platform_dependent` made: its index is the
+    lowering platform's, so lowering keeps ONE branch and no conditional
+    reaches the compiler — its outputs are that branch's, not the fresh
+    double buffers of a run-time `lax.cond`."""
+    return (eqn.primitive.name == "cond"
+            and eqn.params.get("branches_platforms") is not None)
+
+
 def iter_eqns_with_site(jaxpr, _site=""):
     """Depth-first (eqn-order) walk yielding (site, eqn) at every
     nesting depth.  `site` is a readable path like
@@ -89,6 +98,16 @@ def find_eqns(jaxpr, primitive_name: str):
             if e.primitive.name == primitive_name]
 
 
+def _np_dtype(dtype):
+    """numpy's dtype, or None where numpy has no word for it — a Pallas
+    kernel's DMA semaphore (`dma_sem`), inside a `pallas_call`'s kernel
+    jaxpr: it names itself and holds no bytes of the program's state."""
+    try:
+        return np.dtype(dtype)
+    except TypeError:
+        return None
+
+
 def aval_bytes(aval) -> int:
     """Byte size of an abstract value (0 for non-array avals)."""
     shape = getattr(aval, "shape", None)
@@ -98,7 +117,8 @@ def aval_bytes(aval) -> int:
     n = 1
     for d in shape:
         n *= int(d)
-    return n * np.dtype(dtype).itemsize
+    dt = _np_dtype(dtype)
+    return n * dt.itemsize if dt is not None else 0
 
 
 def aval_sig(aval):
@@ -107,7 +127,7 @@ def aval_sig(aval):
     dtype = getattr(aval, "dtype", None)
     if shape is None or dtype is None:
         return None
-    return (tuple(int(d) for d in shape), str(np.dtype(dtype)))
+    return (tuple(int(d) for d in shape), str(_np_dtype(dtype) or dtype))
 
 
 def invar_path_strings(args) -> "list[str]":

@@ -37,12 +37,15 @@ Documented simplifications (same class as engine.py's):
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
 from graphite_tpu.memory import cache_array as ca
+from graphite_tpu.memory import row_landing
 from graphite_tpu.memory.cache_array import (
     EXCLUSIVE, INVALID, MODIFIED, SHARED,
     state_readable, state_writable,
@@ -183,26 +186,48 @@ def _rowsh_update(dsh, way, mask, new_sh):
         dsh.shape[0], W2SW)
 
 
+def _scatter_add_rows(store, rows, delta):
+    """`store[rows] += delta` as ONE XLA scatter-add (rows unique and
+    sorted: aliases in place).  On the chip it costs a pass over `store`
+    whatever it adds, and hardly less for fewer rows (PERF.md §6, PR 39)."""
+    return store.at[rows].add(
+        delta, unique_indices=True, indices_are_sorted=True)
+
+
 @scope("gt.mem.dir_apply")
 def _dir_apply_rows(d: ShL2Dir, px: ParallelCtx, sets, dwd, dshd):
-    """Scatter full-width embedded-directory ROW deltas block-locally:
-    ONE add-a-delta scatter per array (per-lane rows unique, aliases in
-    place).  Zero deltas — masked-off lanes, gated-off phases — add
-    nothing.  Under its own scope: a gated phase's plan lands OUTSIDE the
-    phase's cond, in every iteration, so the landing's device time is not
-    the phase's."""
+    """Land full-width embedded-directory ROW deltas block-locally, in
+    place: one add-a-delta per array (per-lane rows unique).  Zero
+    deltas — masked-off lanes — add nothing.  Under its own scope: a
+    gated phase's plan lands OUTSIDE the phase's cond, so the landing's
+    device time is not the phase's.
+
+    What a landing costs on the chip (v5e; `_hand/landing39.py`,
+    PERF.md §6 PR 39): as an XLA scatter-add, a pass over the store it
+    lands on — 3.5 ms on the 1.07 GB sharers store of 1,024 tiles at
+    1,024 rows, 3.2 ms at 128, 0.46 ms on a store an eighth the size.
+    So where the program is lowered for a TPU, has no sim axis and a
+    sharers row is lane-aligned (512 tiles and up under the default
+    8-way slice), the sharers plan lands through
+    `row_landing.land_rows`, which moves only the plan's rows: 0.085 ms
+    for the same 1,024.  Everywhere else, and for the int64 word store
+    (1/32 the bytes; Mosaic has no int64), the scatter-add."""
     sets_l, dwd_l, dshd_l = px.lo((sets, dwd, dshd))
     Tl, S, W = d.sharers.shape
     lt = jnp.arange(Tl, dtype=jnp.int32)
+    land = _scatter_add_rows
+    if px.sim_axis is None and row_landing.can_land(Tl, S, W):
+        land = functools.partial(
+            jax.lax.platform_dependent,
+            tpu=row_landing.land_rows, default=_scatter_add_rows)
     # the sharers store row-flat, as XLA lays it anyway: the scatter XLA
     # makes of a two-index one carries no name, so its device time would
     # read as the phase's (or nobody's) and not as this scope's
     return d.replace(
         word=d.word.at[lt, sets_l].add(
             dwd_l, unique_indices=True, indices_are_sorted=True),
-        sharers=d.sharers.reshape(Tl * S, W).at[lt * S + sets_l].add(
-            dshd_l, unique_indices=True, indices_are_sorted=True
-        ).reshape(Tl, S, W))
+        sharers=land(d.sharers.reshape(Tl * S, W), lt * S + sets_l,
+                     dshd_l).reshape(Tl, S, W))
 
 
 def _dir_scatter(d: ShL2Dir, px: ParallelCtx, sets, dw0, dw, dsh0, dsh,
@@ -265,8 +290,10 @@ def _cond_dir(pred, fn, ms, n_tiles, px):
     but written only through the `_RowAcc` delta plan the cond returns;
     `_dir_apply_rows` lands the plan outside, in place, and only where
     the phase ran (`engine._run_if`: a skipped phase's plan is zero, and
-    on the chip a landing on the 1 GB sharers store costs 3 ms whatever
-    it adds).  `fn(ms, acc) -> (ms, progress)` must leave ms.dir
+    a landing is not free — on the chip it is priced by the plan's rows
+    where `_dir_apply_rows` takes the row kernel, 0.085 ms at 1,024
+    tiles, and by the whole sharers store where it takes the
+    scatter-add, 3.5 ms there).  `fn(ms, acc) -> (ms, progress)` must leave ms.dir
     untouched."""
     d0 = ms.dir
 

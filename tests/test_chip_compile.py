@@ -267,6 +267,80 @@ def _shl2_memstress(tiles):
         shared_fraction=0.5, seed=7), barrier_host=True)
 
 
+def _landing_kernels(text):
+    """The compiled program's row-landing kernel calls
+    (`memory/row_landing.py`), each named under `gt.mem.dir_apply`."""
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "dir_row_landing" in ln]
+    for ln in calls:
+        assert "/gt.mem.dir_apply/" in ln.split("op_name=")[1], ln[:400]
+    return calls
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_shl2_landing_alone_is_in_place_at_the_cells_shape(topo, chips):
+    """One landing of `memstress1024-shl2`'s row plan, alone, as the
+    engine lands it - `_dir_apply_rows` inside `engine._run_if`'s
+    zero-or-one-trip loop - on the cell's embedded directory
+    (`u32[1024,1024,256]` sharers = 1.07 GB, 1,024 plan rows), asked of
+    the TPU compiler: the sharers plan lands through the row kernel
+    (`memory/row_landing.py`; as an XLA scatter-add it cost a pass over
+    the store, 3.3 ms), the kernel's output IS its operand, nothing
+    copies the store, no conditional returns it (the choice of the
+    lowering platform is resolved before the compiler), and the call is
+    named under `gt.mem.dir_apply` for the benchmark's scope reader.
+    Over four chips (`shard_map`, the tile axis split) each device lands
+    its own 256 rows on its own quarter of the store the same way."""
+    import jax.numpy as jnp
+
+    from graphite_tpu.analysis.loop_copies import conditionals
+    from graphite_tpu.memory.engine import _run_if
+    from graphite_tpu.memory.engine_shl2 import ShL2Dir, _dir_apply_rows
+    from graphite_tpu.parallel.mesh import TILE_AXIS, _shard_map
+    from graphite_tpu.parallel.px import ParallelCtx
+
+    T, S, W2, W = 1024, 1024, 8, 256
+    d = ShL2Dir(word=jax.ShapeDtypeStruct((T, S, W2), jnp.int64),
+                sharers=jax.ShapeDtypeStruct((T, S, W), jnp.uint32))
+    rest = (jax.ShapeDtypeStruct((), jnp.bool_),
+            jax.ShapeDtypeStruct((T,), jnp.int32),
+            jax.ShapeDtypeStruct((T, W2), jnp.int64),
+            jax.ShapeDtypeStruct((T, W), jnp.uint32))
+    px = ParallelCtx(axis=TILE_AXIS, n_dev=chips)
+
+    def landing(d, live, *plan):
+        return _run_if(live, lambda d: _dir_apply_rows(d, px, *plan), d)
+
+    if chips == 1:
+        where = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = Mesh(np.array(topo.devices), (TILE_AXIS,))
+        specs = (jax.tree.map(lambda _: P(TILE_AXIS), d),) + (P(),) * 4
+        landing = _shard_map(landing, mesh=mesh, in_specs=specs,
+                             out_specs=specs[0])
+        where = jax.tree.map(lambda p: NamedSharding(mesh, p), specs,
+                             is_leaf=lambda x: isinstance(x, P))
+    compiled = jax.jit(landing, donate_argnums=0).lower(
+        *_shapes((d, *rest), where)).compile()
+    row = _report(f"shl2-landing-alone-1024-x{chips}", compiled)
+    Tl = T // chips
+    assert row["alias_bytes"] == 4 * Tl * S * W + 8 * Tl * S * W2
+    # (under `shard_map` this harness's loop holds two more blocks of
+    # the store in `temp`, with the scatter-add as with the kernel)
+    assert chips > 1 or row["temp_bytes"] < 64 << 20, row
+    text = compiled.as_text()
+    kernel, = _landing_kernels(text)
+    assert f"u32[{Tl * S},{W}]" in kernel.split(" custom-call(")[0]
+    assert "output_to_operand_aliasing={{}: (1, {})}" in kernel
+    stores = (f"u32[{Tl * S},{W}]", f"u32[{Tl},{S},{W}]")
+    for ln in text.splitlines():
+        head = ln.split(" = ")[-1][:80]
+        assert not (head.startswith(stores)
+                    and (" copy(" in ln or " scatter(" in ln)), ln[:300]
+    assert not [c for c in conditionals(text)
+                if c.returns((Tl * S, W)) or c.returns((Tl, S, W))]
+
+
 @pytest.mark.parametrize("tiles", [
     16, pytest.param(1024, marks=pytest.mark.slow)])
 def test_shl2_mesi_1024_host_batch_compiles(one_chip, tiles):
@@ -286,7 +360,7 @@ def test_shl2_mesi_1024_host_batch_compiles(one_chip, tiles):
     that shape is the two u32 halves of the SLICE's int64 meta store,
     which they update; and, at the cell's size, no loop of the program
     copies the sharers store whole (1.07 GB): the three landings update
-    it in place."""
+    it in place, through the row kernel (PR 39)."""
     from graphite_tpu.analysis.loop_copies import (
         conditionals, copies_of, loop_copies,
     )
@@ -321,6 +395,12 @@ def test_shl2_mesi_1024_host_batch_compiles(one_chip, tiles):
         # conditional returns every store: nothing to hold there)
         whole = copies_of(copies, sharers, ("u32",))
         assert not whole, [c.line[:200] for c in whole]
+    # the landing's form follows the shapes: a lane-aligned sharers row
+    # (256 words at 1,024 tiles) lands through the row kernel, three
+    # landings a step, each under `gt.mem.dir_apply`; the 8-word row of
+    # 16 tiles through the scatter-add, and no kernel is in the program
+    kernels = _landing_kernels(text)
+    assert len(kernels) == (3 if sharers[2] % 128 == 0 else 0), kernels
     print({"program": f"shl2-mesi-{tiles}-host-batch",
            "dir_apply_ops": text.count("gt.mem.dir_apply"),
            "largest_in_loop_copies": sorted(
