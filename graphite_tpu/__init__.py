@@ -28,9 +28,13 @@ Simulated time is exact integer picoseconds throughout
 jax_enable_x64 at import.  Hot per-quantum deltas still use int32 internally.
 """
 
-import os
+import time as _time
 
-import jax
+_T_IMPORT = _time.perf_counter()    # the set-up span `import` starts here
+
+import os  # noqa: E402
+
+import jax  # noqa: E402
 
 # Picosecond-resolution simulated time needs 64-bit integers (a 1 GHz tile
 # overflows int32 picoseconds after ~2ms of simulated time).  TPUs emulate
@@ -56,3 +60,9 @@ __version__ = "0.1.0"
 
 from graphite_tpu.time_types import Time, Latency  # noqa: E402,F401
 from graphite_tpu.config import ConfigFile, SimConfig  # noqa: E402,F401
+from graphite_tpu.obs import trace as _trace  # noqa: E402
+
+# first line to last, jax with it (obs/trace.py: SETUP_SPANS); importing
+# `obs.trace` also installs the program ledger's one listener
+_trace.SETUP.record(_trace.SETUP_TRACE_ID, "import", _T_IMPORT,
+                    _time.perf_counter())

@@ -24,7 +24,7 @@ from graphite_tpu.engine.step import EngineParams
 from graphite_tpu.models.dvfs import module_freq_mhz
 from graphite_tpu.models.network_user import UserNetworkParams
 from graphite_tpu.obs.scopes import tagged
-from graphite_tpu.obs.trace import NO_SPANS, RunSpans
+from graphite_tpu.obs.trace import NO_SPANS, RunSpans, SetupSpans, constructs
 from graphite_tpu.time_types import cycles_to_ps, ns_to_ps, ps_to_ns
 from graphite_tpu.trace.schema import STATIC_COST_KEYS, Op, TraceBatch
 
@@ -171,6 +171,12 @@ class SimResults:
                 avg = self.total_packet_latency_ps[t] / self.packets_received[t] / 1000
                 out.append(f"    Average Packet Latency (in nanoseconds): {avg:.3f}")
         return "\n".join(out)
+
+
+def tree_bytes(tree) -> int:
+    """Bytes of a pytree's arrays, from their shapes (no device sync)."""
+    return sum(int(getattr(x, "nbytes", 0))
+               for x in jax.tree_util.tree_leaves(tree))
 
 
 def _mem_state_bytes(mp) -> int:
@@ -341,6 +347,7 @@ def _streamed_runner(params: EngineParams, quantum_ps, max_quanta: int,
 class Simulator:
     """Builds engine parameters from a SimConfig and runs a trace batch."""
 
+    @constructs
     def __init__(
         self,
         config: SimConfig | ConfigFile | str,
@@ -365,8 +372,16 @@ class Simulator:
         profile=None,
         dvfs=None,
         hist=None,
+        tracer=None,
     ):
-        """`dir_stage`: force the directory write-staging path on/off
+        """`tracer`: an `obs.Tracer`, attached from the start (as
+        `attach_tracer` would): construction and `warmup()` record their
+        set-up spans (obs/trace.py: SETUP_SPANS) in it instead of the
+        process-wide `obs.trace.SETUP`, each also a `gt:<name>`
+        annotation, and a mesh placement then ends in one
+        `block_until_ready`.  Host side only, like every tracer.
+
+        `dir_stage`: force the directory write-staging path on/off
         (None = auto: on for single-device private-L2 runs whose sharers
         store is >= 64 MB — the regime where XLA's dense scatter lowering
         dominates; see MemParams.dir_stage_cap).
@@ -666,78 +681,88 @@ class Simulator:
             "general/trigger_models_within_application", False
         )
         core_freq = module_freq_mhz(cfg, "CORE")
-        self.state: SimState = init_state(
-            n_tiles,
-            core_freq_mhz=core_freq,
-            bp_size=self.params.bp_size,
-            mailbox_depth=mailbox_depth,
-            n_barriers=n_barriers,
-            n_mutexes=n_mutexes,
-            n_conds=n_conds,
-            models_enabled=models_on,
-        )
-        if mem_params is not None:
-            from graphite_tpu.memory import init_mem_state
+        span = SetupSpans(tracer)
+        with span("init_state") as made:
+            self.state: SimState = init_state(
+                n_tiles,
+                core_freq_mhz=core_freq,
+                bp_size=self.params.bp_size,
+                mailbox_depth=mailbox_depth,
+                n_barriers=n_barriers,
+                n_mutexes=n_mutexes,
+                n_conds=n_conds,
+                models_enabled=models_on,
+            )
+            if mem_params is not None:
+                from graphite_tpu.memory import init_mem_state
 
-            if mem_params.protocol.startswith("pr_l1_sh_l2"):
-                from graphite_tpu.memory.engine_shl2 import init_shl2_state
+                if mem_params.protocol.startswith("pr_l1_sh_l2"):
+                    from graphite_tpu.memory.engine_shl2 import (
+                        init_shl2_state,
+                    )
 
-                self.state = self.state.replace(
-                    mem=init_shl2_state(mem_params))
-            else:
-                self.state = self.state.replace(
-                    mem=init_mem_state(mem_params))
-            if mem_params.net_hbh is not None:
-                # per-port queue state of the MEMORY NoC (`[network]
-                # memory = emesh_hop_by_hop`) — coherence messages route
-                # through it with per-hop contention (mem_net_send)
+                    self.state = self.state.replace(
+                        mem=init_shl2_state(mem_params))
+                else:
+                    self.state = self.state.replace(
+                        mem=init_mem_state(mem_params))
+                if mem_params.net_hbh is not None:
+                    # per-port queue state of the MEMORY NoC (`[network]
+                    # memory = emesh_hop_by_hop`) — coherence messages
+                    # route through it with per-hop contention
+                    # (mem_net_send)
+                    from graphite_tpu.models.network_hop_by_hop import (
+                        init_noc_state,
+                    )
+
+                    self.state = self.state.replace(
+                        mem=self.state.mem.replace(
+                            noc=init_noc_state(mem_params.net_hbh)))
+                elif mem_params.net_atac is not None:
+                    # ATAC hub-queue state of the MEMORY NoC (`[network]
+                    # memory = atac`) — coherence messages route over
+                    # the clusters/hubs/waveguide with hub contention
+                    from graphite_tpu.models.network_atac import (
+                        init_atac_state,
+                    )
+
+                    self.state = self.state.replace(
+                        mem=self.state.mem.replace(
+                            noc=init_atac_state(mem_params.net_atac)))
+            if user_hbh is not None:
                 from graphite_tpu.models.network_hop_by_hop import (
                     init_noc_state,
                 )
 
                 self.state = self.state.replace(
-                    mem=self.state.mem.replace(
-                        noc=init_noc_state(mem_params.net_hbh)))
-            elif mem_params.net_atac is not None:
-                # ATAC hub-queue state of the MEMORY NoC (`[network]
-                # memory = atac`) — coherence messages route over the
-                # clusters/hubs/waveguide with hub contention
-                from graphite_tpu.models.network_atac import (
-                    init_atac_state,
-                )
+                    noc_user=init_noc_state(user_hbh))
+            if user_atac is not None:
+                from graphite_tpu.models.network_atac import init_atac_state
 
                 self.state = self.state.replace(
-                    mem=self.state.mem.replace(
-                        noc=init_atac_state(mem_params.net_atac)))
-        if user_hbh is not None:
-            from graphite_tpu.models.network_hop_by_hop import init_noc_state
+                    noc_user=init_atac_state(user_atac))
+            if iocoom_params is not None:
+                from graphite_tpu.models.iocoom import init_iocoom_state
 
-            self.state = self.state.replace(noc_user=init_noc_state(user_hbh))
-        if user_atac is not None:
-            from graphite_tpu.models.network_atac import init_atac_state
+                self.state = self.state.replace(
+                    ioc=init_iocoom_state(n_tiles, iocoom_params))
+            from graphite_tpu.engine.state import DvfsState
 
-            self.state = self.state.replace(
-                noc_user=init_atac_state(user_atac))
-        if iocoom_params is not None:
-            from graphite_tpu.models.iocoom import init_iocoom_state
-
-            self.state = self.state.replace(
-                ioc=init_iocoom_state(n_tiles, iocoom_params))
-        from graphite_tpu.engine.state import DvfsState
-
-        nd = dvfs_params.n_domains
-        init_freqs = jnp.broadcast_to(
-            jnp.asarray(dvfs_params.domain_freq_mhz, jnp.int32)[None, :],
-            (n_tiles, nd)).copy()
-        init_volts = jnp.asarray(
-            [dvfs_params.min_voltage_mv(f)
-             for f in dvfs_params.domain_freq_mhz], jnp.int32)
-        self.state = self.state.replace(dvfs=DvfsState(
-            freq_mhz=init_freqs,
-            voltage_mv=jnp.broadcast_to(
-                init_volts[None, :], (n_tiles, nd)).copy(),
-            errors=jnp.zeros(n_tiles, jnp.int64),
-        ))
+            nd = dvfs_params.n_domains
+            init_freqs = jnp.broadcast_to(
+                jnp.asarray(dvfs_params.domain_freq_mhz,
+                            jnp.int32)[None, :],
+                (n_tiles, nd)).copy()
+            init_volts = jnp.asarray(
+                [dvfs_params.min_voltage_mv(f)
+                 for f in dvfs_params.domain_freq_mhz], jnp.int32)
+            self.state = self.state.replace(dvfs=DvfsState(
+                freq_mhz=init_freqs,
+                voltage_mv=jnp.broadcast_to(
+                    init_volts[None, :], (n_tiles, nd)).copy(),
+                errors=jnp.zeros(n_tiles, jnp.int64),
+            ))
+            made.attrs["bytes"] = tree_bytes(self.state)
         # streaming mode keeps the trace host-side; run_streamed() uploads
         # [T, W] windows on demand (bounded HBM regardless of trace size)
         self.stream = bool(stream)
@@ -753,30 +778,43 @@ class Simulator:
         if mesh is not None and spmd is None:
             spmd = "shard_map"
         self.spmd = spmd if mesh is not None else None
-        self.device_trace = None if stream else DeviceTrace.from_batch(trace)
+        self.device_trace = None
+        if not stream:
+            with span("encode_trace") as made:
+                self.device_trace = DeviceTrace.from_batch(trace)
+                made.attrs["bytes"] = tree_bytes(self.device_trace)
         if mesh is not None:
-            # Shard the tile axis over the device mesh (SURVEY §2.10): the
-            # TPU-native form of Graphite's process striping.  Streamed
-            # runs shard the state here and each [T, W] window at upload
-            # (run_streamed) — the two scale mechanisms compose: bounded-
-            # HBM traces on a multi-chip mesh.
-            if self.spmd == "shard_map":
-                from graphite_tpu.parallel.mesh import place_shard_map
+            with span("place", devices=mesh.size) as made:
+                # Shard the tile axis over the device mesh (SURVEY §2.10):
+                # the TPU-native form of Graphite's process striping.
+                # Streamed runs shard the state here and each [T, W]
+                # window at upload (run_streamed) — the two scale
+                # mechanisms compose: bounded-HBM traces on a multi-chip
+                # mesh.
+                if self.spmd == "shard_map":
+                    from graphite_tpu.parallel.mesh import place_shard_map
 
-                if stream:
-                    self.state = place_shard_map(self.state, mesh)
+                    if stream:
+                        self.state = place_shard_map(self.state, mesh)
+                    else:
+                        self.state, self.device_trace = place_shard_map(
+                            self.state, mesh, self.device_trace)
                 else:
-                    self.state, self.device_trace = place_shard_map(
-                        self.state, mesh, self.device_trace)
-            else:
-                from graphite_tpu.parallel.mesh import shard_sim, shard_state
-
-                if stream:
-                    self.state = shard_state(self.state, mesh)
-                else:
-                    self.state, self.device_trace = shard_sim(
-                        self.state, self.device_trace, mesh
+                    from graphite_tpu.parallel.mesh import (
+                        shard_sim, shard_state,
                     )
+
+                    if stream:
+                        self.state = shard_state(self.state, mesh)
+                    else:
+                        self.state, self.device_trace = shard_sim(
+                            self.state, self.device_trace, mesh
+                        )
+                if span.on:
+                    # the tracer's one sync: what the devices still owed
+                    jax.block_until_ready((self.state, self.device_trace))
+                made.attrs["bytes"] = tree_bytes(
+                    (self.state, self.device_trace))
         self.donate = bool(donate)
         # subquantum iterations executed by the last run (device loop
         # observability: wall / iterations = the engine's per-iteration
@@ -788,9 +826,10 @@ class Simulator:
         # run_streamed of this instance so far
         self.last_run_dispatches = 0
         self.n_dispatches = 0
-        # host span tracing of the drive loop (attach_tracer); None runs
-        # the loop with no span, no annotation and no extra device sync
-        self.tracer = None
+        # host span tracing of the drive loop (`tracer=`, attach_tracer);
+        # None runs the loop with no span, no annotation and no extra
+        # device sync
+        self.tracer = tracer
         self._runner = None
         self._runner_max_quanta = None
         self._hb_runner = None
@@ -830,7 +869,8 @@ class Simulator:
         """Attach (or, with None, detach) an `obs.Tracer`: every later
         `run()` / `run_chunk()` / `run_streamed()` records one `run-<n>`
         trace (or the caller's `trace_id`) of `obs.trace.RUN_SPANS`,
-        each also a `jax.profiler.TraceAnnotation("gt:<name>")`.  Host
+        each also a `jax.profiler.TraceAnnotation("gt:<name>")`, and a
+        later `warmup()` its set-up spans.  Host
         side only: no program changes and results are bit-equal.  With
         a tracer the loop adds ONE `block_until_ready` per dispatch (the
         `wait` span), so that waiting for the device is told apart from
@@ -1616,28 +1656,32 @@ class Simulator:
 
     def warmup(self, max_quanta: int = 1_000_000) -> None:
         """Compile (and execute once, discarding results) the full runner —
-        for benchmarking so timed runs exclude compilation."""
-        if self.donate:
-            # the donated run would delete self.state's buffers and the
-            # discarded output is the only live copy — a later run() would
-            # fail with an opaque "array has been deleted"
-            raise RuntimeError(
-                "warmup() is incompatible with donate=True (the warmup "
-                "run would consume self.state); warm a separate "
-                "non-donating instance and adopt_runner() from it")
-        if self.barrier_host:
-            # compile + execute one single-quantum batch (the program
-            # run() dispatches under barrier_host); the output is
-            # discarded, self.state stays untouched
-            import jax.numpy as jnp
-
-            out = self._hb_get_runner()(
-                self.state, jnp.asarray(0, jnp.int64),
-                jnp.asarray(1, jnp.int32))
-            jax.block_until_ready(out)
-            return
-        out = self._get_runner(max_quanta)(self.state)
-        jax.block_until_ready(out)
+        for benchmarking so timed runs exclude compilation.  Recorded as
+        the set-up span `warmup` over `first_dispatch` (the call into the
+        runner through `block_until_ready`), under which the program
+        ledger hangs what JAX traced, lowered, compiled or loaded."""
+        span = SetupSpans(self.tracer)
+        with span("warmup"):
+            if self.donate:
+                # the donated run would delete self.state's buffers and
+                # the discarded output is the only live copy — a later
+                # run() would fail with an opaque "array has been deleted"
+                raise RuntimeError(
+                    "warmup() is incompatible with donate=True (the "
+                    "warmup run would consume self.state); warm a "
+                    "separate non-donating instance and adopt_runner() "
+                    "from it")
+            if self.barrier_host:
+                # compile + execute one single-quantum batch (the program
+                # run() dispatches under barrier_host); the output is
+                # discarded, self.state stays untouched
+                runner = self._hb_get_runner()
+                args = (self.state, jnp.asarray(0, jnp.int64),
+                        jnp.asarray(1, jnp.int32))
+            else:
+                runner, args = self._get_runner(max_quanta), (self.state,)
+            with span("first_dispatch"):
+                jax.block_until_ready(runner(*args))
 
     def adopt_runner(self, other: "Simulator") -> None:
         """Reuse another instance's compiled runner.

@@ -52,7 +52,6 @@ class CacheEntry:
     max_quanta: int
     nbytes: int               # residency bill of the layout it serves
     shape_sig: tuple          # (B, n_tiles, pad_length)
-    hits: int = 0
     # host seconds the miss paid to lower + fingerprint + set up the
     # jit (round 14 observability — batch spans report it on hits too,
     # so "what did this program cost to build" survives the miss)
@@ -156,7 +155,6 @@ class ProgramCache:
                 "shape-bearing input (calling through would silently "
                 "recompile)")
         self._entries.move_to_end(key)
-        entry.hits += 1
         return entry
 
     def put(self, key, entry: CacheEntry, *,
@@ -181,11 +179,3 @@ class ProgramCache:
             self._entries.popitem(last=False)
             self.evictions += 1
         return entry
-
-    def stats(self) -> dict:
-        return {
-            "entries": len(self._entries),
-            "bytes": self.total_bytes,
-            "evictions": self.evictions,
-            "hits": sum(e.hits for e in self._entries.values()),
-        }

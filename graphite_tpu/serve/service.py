@@ -62,7 +62,7 @@ import time
 from graphite_tpu.obs.metrics import (
     DEFAULT_COUNT_BUCKETS, MetricsRegistry, RATIO_BUCKETS,
 )
-from graphite_tpu.obs.trace import NO_SPANS, RunSpans, Tracer
+from graphite_tpu.obs.trace import NO_SPANS, PROGRAMS, SetupSpans, Tracer
 from graphite_tpu.serve.admission import AdmissionController, JobClass, \
     Pending, QueueFullError
 from graphite_tpu.serve.cache import CacheEntry, ProgramCache, \
@@ -94,6 +94,11 @@ class BatchReport:
     # round 18: the device layout the batch ran under ("solo",
     # "1d-batch(d=N)", "2d(b=DB,t=DT)", ...)
     layout: str = "solo"
+    # the program ledger's delta over the batch (obs/trace.py:
+    # ProgramLedger.COUNTERS): what JAX traced, lowered, compiled or
+    # loaded for it — "which step recompiled"; None on a batch that
+    # failed before its run ended
+    programs: "dict | None" = None
 
 
 class CampaignService:
@@ -215,6 +220,7 @@ class CampaignService:
         self._last_cache_hit = False
         self._last_compile_s = 0.0
         self._last_layout = "solo"
+        self._last_programs: "dict | None" = None
         # the cache entry the last batch dispatched (`resident_program`)
         self._last_entry: "CacheEntry | None" = None
         # persistent AOT program store (round 17): the in-memory
@@ -328,11 +334,12 @@ class CampaignService:
         return self.tracer.span(trace_id, name, **attrs)
 
     def _batch_spans(self, batch_id: int):
-        """The span maker of one batch (`RunSpans` under `batch-<n>`:
-        tracer row + `gt:<name>` annotation), or the null one."""
+        """The span maker of one batch (`SetupSpans` under `batch-<n>`:
+        tracer row + `gt:<name>` annotation, and a parent to the set-up
+        spans and the program ledger's inside it), or the null one."""
         if self.tracer is None:
             return NO_SPANS
-        return RunSpans(self.tracer, f"batch-{batch_id}")
+        return SetupSpans(self.tracer, f"batch-{batch_id}")
 
     def resident_program(self) -> "ResidentProgram | None":
         """A handle to the cached program the last batch dispatched, or
@@ -540,7 +547,7 @@ class CampaignService:
             residency_total=self._last_residency,
             cache_hit=self._last_cache_hit,
             store_hit=self._last_store_hit, ok=True, wall_s=wall,
-            layout=self._last_layout))
+            layout=self._last_layout, programs=self._last_programs))
         if self.tracer is not None:
             self.tracer.record(
                 btid, "batch", t0, t0 + wall,
@@ -715,6 +722,7 @@ class CampaignService:
         self._last_store_hit = False
         self._last_deserialize_s = 0.0
         self._last_layout = "solo"
+        self._last_programs = None
         # pad to the class's FIXED capacity with replicas of job 0 so
         # every batch of this class shares one [B, T, L] program shape;
         # the replicas' rows are dropped below (the tail mask)
@@ -747,17 +755,20 @@ class CampaignService:
             layout_kw = {"layout": (cls.batch_shards, cls.tile_shards)}
         else:
             layout_kw = {"shard_batch": self.shard_batch}
+        programs0 = PROGRAMS.snapshot()
         with span("build", batch=batch_id):
             # what every batch pays before its program can run: a fresh
-            # runner (and the Simulator inside it), and the [B, ...]
-            # initial states and [B, T, L] traces placed on the device
+            # runner (and the Simulator inside it: `construct`), and the
+            # [B, ...] initial states and [B, T, L] traces placed on the
+            # device (`place`)
             runner = SweepRunner(
                 cls.config, pack, points,
                 mailbox_depth=cls.mailbox_depth,
                 hbm_budget_bytes=self.hbm_budget_bytes,
                 telemetry=cls.telemetry,
                 profile=cls.profile, dvfs=cls.dvfs,
-                hist=getattr(cls, "hist", None), **layout_kw)
+                hist=getattr(cls, "hist", None), tracer=self.tracer,
+                **layout_kw)
             runner._batched_inputs()
         self._last_layout = runner.layout_name
         self._last_residency = int(
@@ -776,14 +787,23 @@ class CampaignService:
                 f"admitted batch per-device residency {admitted} "
                 f"exceeds hbm_budget_bytes={self.hbm_budget_bytes}")
         with span("cache", batch=batch_id) as cspan:
+            before = PROGRAMS.snapshot()
             entry = self._resolve_program(cls, runner, B)
             if cspan is not None:
+                cost = PROGRAMS.since(before)
                 cspan.attrs.update(hit=self._last_cache_hit,
                                    compile_s=round(
                                        self._last_compile_s, 6),
                                    store_hit=self._last_store_hit,
                                    deserialize_s=round(
-                                       self._last_deserialize_s, 6))
+                                       self._last_deserialize_s, 6),
+                                   programs_compiled=cost[
+                                       "programs_compiled"],
+                                   programs_loaded=cost[
+                                       "programs_loaded"],
+                                   jax_compile_s=round(
+                                       cost["compile_s"]
+                                       + cost["load_s"], 6))
         # the program's dispatches are followed by the tracer attached
         # to its handle (`resident_program().attach_tracer`, a `run-<n>`
         # trace of its own), else by the service's, inside the batch's
@@ -798,6 +818,9 @@ class CampaignService:
         entry.last_n_iterations = runner.last_n_iterations
         entry.last_run_dispatches = runner.last_run_dispatches
         self._last_entry = entry
+        # what JAX traced, lowered, compiled or loaded for this batch,
+        # the lazy compile inside `execute` included (`BatchReport`)
+        self._last_programs = PROGRAMS.since(programs0)
         t_done = self._clock()
         if self.tracer is not None:
             # one execute span per member too, so a job trace alone

@@ -45,7 +45,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from graphite_tpu.obs.scopes import tagged
-from graphite_tpu.obs.trace import NO_SPANS, RunSpans
+from graphite_tpu.obs.trace import (
+    NO_SPANS, PROGRAMS, RunSpans, SetupSpans, constructs,
+)
 from graphite_tpu.sweep.knobs import Knobs
 from graphite_tpu.sweep.pack import PackedTraces, pack_traces
 
@@ -142,6 +144,9 @@ class SweepRunner:
     every sim, and a `dvfs_domain_mhz` knob axis then seeds each
     point's per-domain operating frequencies so ONE compiled program
     sweeps a whole domain-frequency grid (the race-to-idle study).
+    `tracer=obs.Tracer(...)` attaches a tracer from the start: the
+    set-up spans of construction and placement go to it (else to
+    `obs.trace.SETUP`), and `run()` is traced as under `attach_tracer`.
 
     Four batching programs, chosen by `layout` (or the legacy
     `shard_batch` kwarg):
@@ -189,11 +194,13 @@ class SweepRunner:
     which is exactly what lets a too-big-for-one-device sim run.
     """
 
+    @constructs
     def __init__(self, config, traces, points: "list[dict] | None" = None,
                  *, mailbox_depth: "int | None" = None,
                  shard_batch: "bool | None" = None,
                  layout=None,
-                 hbm_budget_bytes: "int | None" = None, **sim_kwargs):
+                 hbm_budget_bytes: "int | None" = None, tracer=None,
+                 **sim_kwargs):
         from graphite_tpu.engine.simulator import Simulator, \
             auto_mailbox_depth
 
@@ -254,6 +261,11 @@ class SweepRunner:
             layout = ("batch" if n_dev > 1 and B % n_dev == 0
                       else "solo")
         layout = self._normalize_layout(layout, B, n_dev)
+        # host span tracing (`tracer=`, attach_tracer): of construction
+        # and placement (obs/trace.py: SETUP_SPANS), which without one go
+        # to the process-wide `obs.trace.SETUP`, and of run(), which
+        # without one makes no span, no annotation and no device sync
+        self.tracer = tracer
         self._user_gating = {
             k: sim_kwargs[k] for k in ("phase_gate", "mem_gate_bytes")
             if k in sim_kwargs}
@@ -312,9 +324,6 @@ class SweepRunner:
         # `Simulator` keeps them: one batched dispatch per run()
         self.last_n_iterations = 0
         self.last_run_dispatches = 0
-        # host span tracing of run() (attach_tracer); None runs with no
-        # span, no annotation and no extra device sync
-        self.tracer = None
         self._runner = None
         self._runner_max_quanta = None
         self._dtr = None      # device-resident [B, T, L] traces (cached)
@@ -532,7 +541,7 @@ class SweepRunner:
             # would double-buffer — default it OFF (explicit kwargs win)
             kwargs.setdefault("mem_gate_bytes", 0)
         return Simulator(config, trace0, mailbox_depth=mbd,
-                         barrier_host=False, **kwargs)
+                         barrier_host=False, tracer=self.tracer, **kwargs)
 
     def _per_sim_bill(self, tile_shards: int = 1) -> int:
         """ONE sim's residency bill — whole (tile_shards=1) or its
@@ -756,7 +765,8 @@ class SweepRunner:
         """Attach (or, with None, detach) an `obs.Tracer`: every later
         `run()` records one `run-<n>` trace (or the caller's `trace_id`)
         of `obs.trace.RUN_SPANS`, each also a `gt:<name>`
-        TraceAnnotation, exactly as `Simulator.attach_tracer`.  Host side
+        TraceAnnotation, exactly as `Simulator.attach_tracer` (`tracer=`
+        at construction attaches it from the start).  Host side
         only; with a tracer run() adds ONE `block_until_ready` (the
         `wait` span)."""
         self.tracer = tracer
@@ -793,14 +803,29 @@ class SweepRunner:
     def _batched_inputs(self):
         """The [B, ...] initial states and [B, T, L] device traces,
         built once and cached so repeat run() calls (timed benchmark
-        loops) measure the program, not a host->device re-upload."""
+        loops) measure the program, not a host->device re-upload.
+        Building them is the set-up span `place` (its `programs`: what
+        JAX compiled or loaded for it); with a tracer it ends in one
+        `block_until_ready`, so that the span holds the device's part."""
+        from graphite_tpu.engine.simulator import tree_bytes
+
         self._sync_with_sim()
         if self._states0 is None:
             B = self.pack.n_sims
-            self._states0 = jax.tree_util.tree_map(
-                lambda x: jnp.broadcast_to(x[None], (B,) + x.shape),
-                self.sim.state)
-            self._dtr = self.pack.device_traces()
+            span = SetupSpans(self.tracer)
+            with span("place", sims=B) as made:
+                before = PROGRAMS.snapshot()
+                self._states0 = jax.tree_util.tree_map(
+                    lambda x: jnp.broadcast_to(x[None], (B,) + x.shape),
+                    self.sim.state)
+                self._dtr = self.pack.device_traces()
+                if span.on:
+                    jax.block_until_ready((self._states0, self._dtr))
+                cost = PROGRAMS.since(before)
+                made.attrs.update(
+                    bytes=tree_bytes((self._states0, self._dtr)),
+                    programs=cost["programs_compiled"]
+                    + cost["programs_loaded"])
         return self._states0, self._dtr
 
     def lower(self, max_quanta: int = 4096):

@@ -92,20 +92,25 @@ def _text_table(tl) -> "list[str]":
 
 
 def render_spans(path: str, fmt: str) -> "list[str]":
-    """Span JSON-lines -> per-job latency breakdown + batch table."""
+    """Span JSON-lines -> per-job latency breakdown + batch table + the
+    set-up table (top-level set-up spans, the program ledger's totals)."""
     from graphite_tpu.obs.trace import (
         BATCH_SPANS, BATCH_TRACE_PREFIX, JOB_SPANS, RUN_SPANS,
-        job_breakdown, load_jsonl,
+        job_breakdown, load_jsonl, setup_breakdown,
     )
 
     rows = load_jsonl(path)
     jobs = sorted(job_breakdown(rows), key=lambda r: r["job"])
+    setup, programs = setup_breakdown(rows)
     if fmt == "json":
         out = [json.dumps(r) for r in jobs]
         for r in rows:
             if r["trace"].startswith(BATCH_TRACE_PREFIX) \
                     and r["span"] == "batch":
                 out.append(json.dumps(r))
+        out += [json.dumps({"setup": r}) for r in setup]
+        if programs:
+            out.append(json.dumps({"programs": programs}))
         return out
     # aligned per-job table: lifecycle spans in canonical order, then
     # any extra recorded spans (split/retry/...), then status/total
@@ -146,6 +151,20 @@ def render_spans(path: str, fmt: str) -> "list[str]":
                  for r in batches]
         lines.append("")
         lines.extend(_align(bcols, brows))
+    if setup:
+        # what came before the first run (obs/trace.py: SETUP_SPANS):
+        # each top-level span with its self time, then the program
+        # ledger's totals
+        lines.append("")
+        lines.extend(_align(
+            ["setup", "trace", "count", "start_us", "dur_us", "self_us"],
+            [[r["span"], r["trace"], str(r["count"]), str(r["start_us"]),
+              str(r["dur_us"]), str(r["self_us"])] for r in setup]))
+        lines.append("")
+        lines.extend(_align(
+            ["programs", "count", "total_us"],
+            [[name, str(n), str(us)]
+             for name, (n, us) in sorted(programs.items())]))
     return lines
 
 

@@ -300,10 +300,34 @@ def main(argv=None) -> int:
     config_cache: dict = {}
     t0 = time.perf_counter()
 
+    n_reported = 0      # batch ids count up from 0
+
+    def emit_batches():
+        """One line per batch not yet reported: what JAX traced, lowered,
+        compiled or loaded for it (the program ledger's delta,
+        obs/trace.py) beside the service's own compile count — which
+        batch recompiled, and what."""
+        nonlocal n_reported
+        new = []
+        for b in reversed(service.batch_log):
+            if b.batch_id < n_reported:
+                break
+            new.append(b)
+        for b in reversed(new):
+            n_reported = b.batch_id + 1
+            print(json.dumps({
+                "batch": b.batch_id, "class": b.class_name, "ok": b.ok,
+                "cache_hit": b.cache_hit,
+                "compile_count": service.counters["compile_count"],
+                "programs": None if b.programs is None else {
+                    k: round(v, 6) for k, v in b.programs.items()}}),
+                flush=True)
+
     def emit(res):
         """One result line; --profile-out persists the per-tile ring
         (the envelope only carries a sample count) and names the file
         in the line so the heatmap render is one copy-paste away."""
+        emit_batches()
         row = res.to_json()
         if args.profile_out and res.profile is not None:
             os.makedirs(args.profile_out, exist_ok=True)

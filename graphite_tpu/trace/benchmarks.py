@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from graphite_tpu.trace.schema import Op, TraceBatch, TraceBuilder
+from graphite_tpu.trace.schema import Op, TraceBatch, TraceBuilder, generator
 
 # All generators use barrier id 0 (one barrier per app run, reused).
 _BAR = 0
@@ -50,6 +50,7 @@ def _barrier(builders):
         b.barrier_wait(_BAR)
 
 
+@generator
 def fft_trace(n_tiles: int, points_per_tile: int = 256,
               use_memory: bool = False,
               ops_per_point_per_stage: int = 6) -> TraceBatch:
@@ -129,6 +130,7 @@ def _fft_trace_with_memory(n_tiles, points_per_tile, fly_instr, msg_bytes):
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def radix_trace(n_tiles: int, keys_per_tile: int = 1024,
                 radix: int = 16) -> TraceBatch:
     """Radix sort iteration: local histogram, log-tree prefix sum
@@ -183,6 +185,7 @@ def radix_trace(n_tiles: int, keys_per_tile: int = 1024,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def blackscholes_trace(n_tiles: int, options_per_tile: int = 512,
                        sweeps: int = 4) -> TraceBatch:
     """Embarrassingly parallel pricing: ~200 fp ops per option (CNDF +
@@ -198,6 +201,7 @@ def blackscholes_trace(n_tiles: int, options_per_tile: int = 512,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def canneal_trace(n_tiles: int, footprint_lines: int = 4096,
                   swaps_per_tile: int = 64, seed: int = 1234,
                   use_memory: bool = True) -> TraceBatch:
@@ -231,6 +235,7 @@ BENCHMARKS = {
 }
 
 
+@generator
 def lu_trace(n_tiles: int, blocks_per_side: int | None = None,
              block: int = 16, use_memory: bool = False) -> TraceBatch:
     """Blocked dense LU factorization (SPLASH-2 `kernels/lu/lu.C`):
@@ -277,6 +282,7 @@ def lu_trace(n_tiles: int, blocks_per_side: int | None = None,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def ocean_trace(n_tiles: int, rows_per_tile: int = 64, cols: int = 64,
                 iterations: int = 4) -> TraceBatch:
     """Ocean current simulation (SPLASH-2 `apps/ocean`): red-black
@@ -306,6 +312,7 @@ def ocean_trace(n_tiles: int, rows_per_tile: int = 64, cols: int = 64,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def barnes_trace(n_tiles: int, bodies_per_tile: int = 64,
                  steps: int = 2, seed: int = 7,
                  use_memory: bool = False) -> TraceBatch:
@@ -339,6 +346,7 @@ def barnes_trace(n_tiles: int, bodies_per_tile: int = 64,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def water_nsquared_trace(n_tiles: int, molecules_per_tile: int = 32,
                          steps: int = 2) -> TraceBatch:
     """Water-NSquared molecular dynamics (SPLASH-2
@@ -366,6 +374,7 @@ def water_nsquared_trace(n_tiles: int, molecules_per_tile: int = 32,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def cholesky_trace(n_tiles: int, supernodes: int | None = None,
                    block: int = 16) -> TraceBatch:
     """Sparse Cholesky factorization (SPLASH-2 `kernels/cholesky`):
@@ -408,6 +417,7 @@ BENCHMARKS.update({
 })
 
 
+@generator
 def water_spatial_trace(n_tiles: int, molecules_per_tile: int = 32,
                         steps: int = 2) -> TraceBatch:
     """Water-Spatial molecular dynamics (SPLASH-2 `apps/water-spatial`):
@@ -445,6 +455,7 @@ def water_spatial_trace(n_tiles: int, molecules_per_tile: int = 32,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def volrend_trace(n_tiles: int, rays_per_tile: int = 128,
                   frames: int = 2, seed: int = 21,
                   use_memory: bool = False) -> TraceBatch:
@@ -479,6 +490,7 @@ def volrend_trace(n_tiles: int, rays_per_tile: int = 128,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def raytrace_trace(n_tiles: int, rays_per_tile: int = 128,
                    seed: int = 33, use_memory: bool = False) -> TraceBatch:
     """Ray tracing (SPLASH-2 `apps/raytrace`): a single frame of primary
@@ -513,6 +525,7 @@ def raytrace_trace(n_tiles: int, rays_per_tile: int = 128,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def radiosity_trace(n_tiles: int, patches_per_tile: int = 32,
                     iterations: int = 2, seed: int = 55) -> TraceBatch:
     """Hierarchical radiosity (SPLASH-2 `apps/radiosity`): per iteration
@@ -553,6 +566,7 @@ def radiosity_trace(n_tiles: int, patches_per_tile: int = 32,
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def fmm_trace(n_tiles: int, bodies_per_tile: int = 64,
               multipole_terms: int = 4) -> TraceBatch:
     """Fast Multipole Method N-body (SPLASH-2 `apps/fmm`): per step —

@@ -33,8 +33,11 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 
 import numpy as np
+
+from graphite_tpu.obs.trace import SetupSpans
 
 
 MAX_MEM_OPS = 2  # matches Pin operand scan (`pin/instruction_modeling.cc:33-124`)
@@ -242,6 +245,23 @@ class TraceBatch:
                 col = getattr(b, "_" + name)
                 arrays[name][t, : len(col)] = col
         return cls(**arrays)
+
+
+def generator(build):
+    """Decorator of a trace generator: the call is the set-up span
+    `build_trace` (obs/trace.py: SETUP_SPANS), with the batch's tiles and
+    records (every record but the NOP padding)."""
+
+    @functools.wraps(build)
+    def build_trace(*args, **kwargs):
+        with SetupSpans()("build_trace", generator=build.__name__) as span:
+            batch = build(*args, **kwargs)
+            span.attrs.update(
+                tiles=batch.n_tiles,
+                records=int(np.count_nonzero(batch.op != int(Op.NOP))))
+        return batch
+
+    return build_trace
 
 
 class TraceBuilder:

@@ -15,7 +15,7 @@ from __future__ import annotations
 import numpy as np
 
 from graphite_tpu.models.network_emesh import is_tile_count_permissible, mesh_dims
-from graphite_tpu.trace.schema import Op, TraceBatch, TraceBuilder
+from graphite_tpu.trace.schema import Op, TraceBatch, TraceBuilder, generator
 
 TRAFFIC_PATTERNS = (
     "uniform_random",
@@ -91,6 +91,7 @@ def destinations(pattern: str, n_tiles: int) -> np.ndarray:
     raise ValueError(f"unknown traffic pattern: {pattern}")
 
 
+@generator
 def network_traffic_trace(
     n_tiles: int,
     pattern: str = "uniform_random",
@@ -144,6 +145,7 @@ def network_traffic_trace(
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def memory_stress_trace(
     n_tiles: int,
     n_accesses: int = 1000,
@@ -184,6 +186,7 @@ def memory_stress_trace(
     return TraceBatch.from_builders(builders)
 
 
+@generator
 def ping_pong_trace(
     n_tiles: int = 2, n_rounds: int = 100, packet_size: int = 8
 ) -> TraceBatch:
@@ -232,6 +235,7 @@ def _batch_from_columns(op, *, flags=None, pc=None, aux0=None, aux1=None,
     )
 
 
+@generator
 def compute_mix_batch(
     n_tiles: int, n_instructions: int, seed: int = 0, branch_fraction: float = 0.1
 ) -> TraceBatch:
@@ -256,6 +260,7 @@ def compute_mix_batch(
     return _batch_from_columns(op, flags=flags, pc=pc)
 
 
+@generator
 def message_ring_batch(
     n_tiles: int,
     n_rounds: int,
@@ -308,6 +313,7 @@ def message_ring_batch(
     return _batch_from_columns(op, aux0=aux0, aux1=aux1)
 
 
+@generator
 def compute_mix_trace(
     n_tiles: int,
     n_instructions: int = 1000,

@@ -17,7 +17,8 @@ from graphite_tpu.config import ConfigFile, SimConfig
 from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.obs import scopes
 from graphite_tpu.obs.trace import (
-    BATCH_SPANS, JOB_SPANS, RUN_SPANS, Span, Tracer,
+    BATCH_SPANS, JOB_SPANS, RUN_SPANS, SETUP_SPANS, ProgramLedger, Span,
+    Tracer,
 )
 from graphite_tpu.serve.job import Job
 from graphite_tpu.serve.service import CampaignService
@@ -121,7 +122,8 @@ def test_batch_spans_nested_and_ordered(served, batch):
     spans = tracer.trace(f"batch-{batch}")
     assert all(not s.open for s in spans)
     by = {s.name: s for s in spans}
-    assert set(by) == set(BATCH_SPANS) | set(RUN_SPANS) | {"batch"}
+    assert set(by) - set(SETUP_SPANS) == \
+        set(BATCH_SPANS) | set(RUN_SPANS) | {"batch"}
     # around the run: pack, build, cache, execute, demux, in that order,
     # none overlapping the next, all inside `batch`
     seq = [by[n] for n in BATCH_SPANS]
@@ -140,6 +142,43 @@ def test_batch_spans_nested_and_ordered(served, batch):
         list(RUN_SPANS[:-1])
     for n in RUN_SPANS[1:]:
         assert run.t_start <= by[n].t_start and by[n].t_end <= run.t_end
+
+
+@pytest.mark.parametrize("batch", [0, 1])
+def test_batch_holds_its_construction_and_placement(served, batch):
+    """Inside `build`: the runner's `construct` over the Simulator's (over
+    `init_state` and `encode_trace`), then `place`, the [B, ...] inputs
+    through the one sync a tracer buys; the ledger's spans under whichever
+    span was open, and only in the batch that compiled."""
+    spans = served["svc"].tracer.trace(f"batch-{batch}")
+    setup = [s for s in spans if s.name in SETUP_SPANS
+             and not s.name.startswith("jax_")]
+    assert [(s.name, s.attrs["parent"]) for s in setup] == [
+        ("init_state", "construct"), ("encode_trace", "construct"),
+        ("construct", "construct"), ("construct", "build"),
+        ("place", "build")]
+    build = next(s for s in spans if s.name == "build")
+    assert all(build.t_start <= s.t_start and s.t_end <= build.t_end
+               for s in setup)
+    assert [s.attrs["of"] for s in setup if s.name == "construct"] == \
+        ["Simulator", "SweepRunner"]
+    place = setup[-1]
+    assert place.attrs["sims"] == 4 and place.attrs["bytes"] > 0
+    assert place.attrs["programs"] >= 0
+    ledger = [s for s in spans if s.name.startswith("jax_")]
+    assert {s.attrs["parent"] for s in ledger} <= {
+        "init_state", "encode_trace", "construct", "place", "build",
+        "cache", "execute"}
+    compiled = [s for s in ledger if s.name == "jax_compile"
+                and "campaign_" in s.attrs["fun_name"]]
+    assert len(compiled) == (1 if batch == 0 else 0)
+    cache = next(s for s in spans if s.name == "cache")
+    assert {"programs_compiled", "programs_loaded",
+            "jax_compile_s"} <= set(cache.attrs)
+    report_ = served["svc"].batch_log[batch]
+    assert set(report_.programs) == set(ProgramLedger.COUNTERS)
+    assert (report_.programs["programs_compiled"]
+            + report_.programs["programs_loaded"] >= 1) is (batch == 0)
 
 
 @pytest.mark.parametrize("s,lat", GRID)
@@ -240,10 +279,13 @@ def test_sweep_runner_spans_and_trace_once(monkeypatch):
         f"campaign_{scopes.CACHE_TAG}"
     # without a tracer: no span, no extra device sync, and the program
     # lower() traced is not traced again by run()
+    # (of the drive loop: `place` and what a first run compiles are
+    # set-up's spans, always on, and sync nothing without a tracer)
     real_init = Span.__init__
     monkeypatch.setattr(
         Span, "__init__",
-        lambda self, *a, **k: made.append(1) or real_init(self, *a, **k))
+        lambda self, *a, **k: real_init(self, *a, **k) or (
+            self.name in RUN_SPANS and made.append(1)) or None)
     real_sync = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda x: synced.append(1) or real_sync(x))

@@ -513,6 +513,11 @@ class TestServe:
         lines = [json.loads(ln) for ln in
                  capsys.readouterr().out.strip().splitlines()]
         row = next(r for r in lines if r.get("job") == "cli0")
+        # one line a batch: what JAX traced, lowered, compiled or loaded
+        # for it (the program ledger's delta) beside the compile count
+        (batch,) = [r for r in lines if "programs" in r]
+        assert (batch["batch"], batch["compile_count"]) == (0, 1)
+        assert batch["programs"]["programs_lowered"] >= 1
         path = row["hist_file"]
         assert path == str(out_dir / "cli0.npz")
         saved = Hist.load(path)

@@ -179,12 +179,38 @@ def test_trace_ids_count_per_tracer():
     assert tracer.trace_ids() == ["run-0", "run-1"]
 
 
+def test_tracer_from_construction_is_bit_equal(monkeypatch):
+    """`Simulator(tracer=)`: the set-up spans go to the caller's tracer
+    under `setup`, the run's under `run-0`, and no statistic moves."""
+    plain = make().run()
+    tracer = Tracer(clock=Clock())
+    sim = make(tracer=tracer)
+    assert sim.tracer is tracer
+    sim.warmup()
+    res = sim.run()
+    assert equal(res, plain)
+    assert tracer.trace_ids() == [obs_trace.SETUP_TRACE_ID, "run-0"]
+    assert {s.name for s in tracer.trace(obs_trace.SETUP_TRACE_ID)} == {
+        "construct", "init_state", "encode_trace", "warmup",
+        "first_dispatch", "jax_trace", "jax_lower", "jax_compile"}
+    assert [s.name for s in tracer.trace("run-0")] == [
+        "dispatch", "wait", "fetch", "results", "run"]
+    # host-driven too
+    hb = make(barrier_host=True, barrier_batch=2, tracer=Tracer())
+    hb.warmup()
+    assert equal(hb.run(), plain)
+
+
 def test_no_tracer_no_span_no_sync(monkeypatch):
+    """Without a tracer `run()` / `run_chunk()` / `run_streamed()` make no
+    span of the drive loop and no device sync; what a run compiles on its
+    first call is the program ledger's to record (always on, set-up's)."""
     made, synced = [], []
     real_init = Span.__init__
 
     def counting_init(self, *a, **k):
-        made.append(1)
+        if (k.get("name") or a[1]) in RUN_SPANS + ("refill",):
+            made.append(1)
         real_init(self, *a, **k)
 
     monkeypatch.setattr(Span, "__init__", counting_init)
