@@ -818,8 +818,12 @@ class Simulator:
         self.donate = bool(donate)
         # subquantum iterations executed by the last run (device loop
         # observability: wall / iterations = the engine's per-iteration
-        # cost, the number PERF.md's floor analysis tracks)
+        # cost, the number PERF.md's floor analysis tracks), and those of
+        # them in which nothing advanced: one a quantum, which is how
+        # the quantum loop learns that the quantum is over
+        # (engine/step._quantum_loop)
         self.last_n_iterations = 0
+        self.last_idle_iterations = 0
         # launch counters (plain ints, always on): programs the drive loop
         # launched — by the last COMPLETED run() (either path; run_chunk
         # and warmup leave it alone), and by every run / run_chunk /
@@ -1171,7 +1175,9 @@ class Simulator:
         working-set gather and merged scatter did not run (a
         whole-engine mem_gate skip counts), "flush": inner blocks whose
         staging flush did not run}.  Denominators: `last_n_iterations`
-        and that over `inner_block`.  None when the run has no memory
+        and the run's blocks (a quantum's last block stops at its idle
+        iteration, so there are more of them than `last_n_iterations`
+        over `inner_block`).  None when the run has no memory
         subsystem or its engine has no such gate (shared-L2)."""
         skips = getattr(self.state.mem, "base_skips", None)
         if skips is None:
@@ -1289,16 +1295,17 @@ class Simulator:
             nq, all_done = self._host_barrier_loop(n_quanta, span)
             return all_done, nq
         with span("dispatch", parent="run"):
-            state, n_quanta_dev, deadlock_dev, n_iters = self._get_runner(
-                n_quanta)(self.state)
+            state, n_quanta_dev, deadlock_dev, n_iters, n_idle = (
+                self._get_runner(n_quanta)(self.state))
             self.n_dispatches += 1
         if span.on:
             with span("wait", parent="dispatch"):
                 jax.block_until_ready((n_quanta_dev, deadlock_dev, n_iters))
         with span("fetch", parent="wait"):
-            nq, deadlock, overflow, done, self.last_n_iterations = (
-                jax.device_get((n_quanta_dev, deadlock_dev,
-                                state.net.overflow, state.done, n_iters)))
+            (nq, deadlock, overflow, done, self.last_n_iterations,
+             self.last_idle_iterations) = jax.device_get((
+                 n_quanta_dev, deadlock_dev, state.net.overflow,
+                 state.done, n_iters, n_idle))
         if bool(overflow):
             raise MailboxOverflowError(
                 "a (dst,src) mailbox ring overflowed; re-run with a "
@@ -1363,21 +1370,21 @@ class Simulator:
         state = self.state
         prev_qend = jnp.asarray(0, jnp.int64)
         n = 0
-        total_iters = 0
+        total_iters = total_idle = 0
         batch = 0
         done = jax.device_get(state.done)
         while n < max_quanta and not done.all():
             budget = min(self.barrier_batch, max_quanta - n)
             with span("dispatch", parent="run", batch=batch):
-                state, prev_qend, nq_d, deadlock_d, iters_d = runner(
+                state, prev_qend, nq_d, deadlock_d, iters_d, idle_d = runner(
                     state, prev_qend, jnp.asarray(budget, jnp.int32))
                 self.n_dispatches += 1
             if span.on:
                 with span("wait", parent="dispatch", batch=batch):
                     jax.block_until_ready((nq_d, deadlock_d, iters_d))
             with span("fetch", parent="wait", batch=batch) as fetched:
-                nq, deadlock, iters, done, overflow = jax.device_get(
-                    (nq_d, deadlock_d, iters_d, state.done,
+                nq, deadlock, iters, idle, done, overflow = jax.device_get(
+                    (nq_d, deadlock_d, iters_d, idle_d, state.done,
                      state.net.overflow))
                 if fetched is not None:
                     fetched.attrs.update(quanta=int(nq),
@@ -1385,6 +1392,7 @@ class Simulator:
             batch += 1
             n += int(nq)
             total_iters += int(iters)
+            total_idle += int(idle)
             if bool(overflow):
                 raise MailboxOverflowError(
                     "a (dst,src) mailbox ring overflowed; re-run with a "
@@ -1402,6 +1410,7 @@ class Simulator:
                     "flag")
         self.state = state
         self.last_n_iterations = total_iters
+        self.last_idle_iterations = total_idle
         return n, bool(done.all())
 
     @staticmethod
@@ -1594,7 +1603,7 @@ class Simulator:
         prefetch_bases = None
         prefetch = None
         prefetch_on = True  # lockstep so far; first miss turns it off
-        n_quanta = 0
+        n_quanta = total_iters = total_idle = 0
         for w in range(max_windows):
             with span("dispatch", parent="run", window=w):
                 out = runner(state, window, dev_bases)
@@ -1611,15 +1620,18 @@ class Simulator:
                                      guess)
             else:
                 prefetch_bases = None
-            state, nq_dev, deadlock_dev, n_iters_dev = out
+            state, nq_dev, deadlock_dev, iters_dev, idle_dev = out
             if span.on:
                 with span("wait", parent="dispatch", window=w):
                     jax.block_until_ready((nq_dev, deadlock_dev))
             with span("fetch", parent="wait", window=w):
-                done, idx, deadlock, overflow = jax.device_get(
-                    (state.done, state.core.idx, deadlock_dev,
-                     state.net.overflow))
-                n_quanta += int(nq_dev)
+                done, idx, deadlock, overflow, nq, iters, idle = (
+                    jax.device_get((state.done, state.core.idx,
+                                    deadlock_dev, state.net.overflow,
+                                    nq_dev, iters_dev, idle_dev)))
+                n_quanta += int(nq)
+                total_iters += int(iters)
+                total_idle += int(idle)
             if bool(overflow):
                 raise MailboxOverflowError(
                     "a (dst,src) mailbox ring overflowed; re-run with a "
@@ -1652,6 +1664,8 @@ class Simulator:
         else:
             raise RuntimeError(f"exceeded max_windows={max_windows}")
         self.state = state
+        self.last_n_iterations = total_iters
+        self.last_idle_iterations = total_idle
         return self._results_from_state(n_quanta, span)
 
     def warmup(self, max_quanta: int = 1_000_000) -> None:
@@ -1754,8 +1768,8 @@ class Simulator:
 
     def _run_one_region(self, max_quanta: int, span) -> SimResults:
         with span("dispatch", parent="run"):
-            state, n_quanta_dev, deadlock_dev, n_iters = self._get_runner(
-                max_quanta)(self.state)
+            state, n_quanta_dev, deadlock_dev, n_iters, n_idle = (
+                self._get_runner(max_quanta)(self.state))
             self.n_dispatches += 1
         if span.on:
             with span("wait", parent="dispatch"):
@@ -1769,10 +1783,11 @@ class Simulator:
             host = jax.device_get((
                 n_quanta_dev, deadlock_dev, state.net.overflow, state.done,
                 state.core, net_part, mem_part, ioc_part, tel_part,
-                prof_part, hist_part, n_iters,
+                prof_part, hist_part, n_iters, n_idle,
             ))
         (n_quanta, deadlock, overflow, done, core_h, net_h, mem_h,
-         ioc_h, tel_h, prof_h, hist_h, self.last_n_iterations) = host
+         ioc_h, tel_h, prof_h, hist_h, self.last_n_iterations,
+         self.last_idle_iterations) = host
         if bool(overflow):
             raise MailboxOverflowError(
                 "a (dst,src) mailbox ring overflowed; re-run with a "

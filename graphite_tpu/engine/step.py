@@ -81,7 +81,7 @@ class EngineParams:
     bp_size: int = 1024
     bp_mispredict_penalty: int = 14
     mailbox_depth: int = 8
-    inner_block: int = 32      # trace records per tile per scan
+    inner_block: int = 32      # most iterations between staging flushes
     n_conds: int = 64          # cond-variable id space (sync tables)
     syscall_rt_ps: int = 2000  # SYSTEM-net round trip to the MCP (2 cyc @1GHz)
     # iocoom core model (None = simple 1-IPC in-order model)
@@ -776,10 +776,23 @@ def subquantum_iteration(
             # it), so that a neighbour's sync record cannot move it.
             psig = jnp.where(mc_own, psig, sync.cond_sig_time_ps)
             pbc = jnp.where(mc_own, pbc, sync.cond_bcast_time_ps)
+        # The block moves state that no lane's commit shows: a COND_WAIT
+        # arrival joins the FIFO and releases its mutex, a pending signal
+        # wakes a waiter or is dropped, a lock request registers — each
+        # can enable a commit in a LATER iteration, so an iteration that
+        # only did that is not idle (`_quantum_loop` ends a quantum at
+        # the first idle one).  Owner, handoff time, arrival and wake
+        # times change only together with one of these.
+        moved = (jnp.any(mutex_locked != sync.mutex_locked)
+                 | jnp.any(mutex_waiting != sync.mutex_waiting)
+                 | jnp.any(cond_waiting != sync.cond_waiting)
+                 | jnp.any(cond_signaled != sync.cond_signaled)
+                 | jnp.any(psig != sync.cond_sig_time_ps)
+                 | jnp.any(pbc != sync.cond_bcast_time_ps))
         return (mutex_locked, mutex_owner, mutex_time, mutex_waiting,
                 granted, mutex_wait_ps, cond_waiting, cond_signaled,
                 cond_arrival, cond_wake, psig, pbc,
-                sig_post | bc_post)
+                sig_post | bc_post, moved)
 
     def _mutex_cond_skip(_):
         return (sync.mutex_locked, sync.mutex_owner, sync.mutex_time_ps,
@@ -787,7 +800,7 @@ def subquantum_iteration(
                 jnp.zeros((T,), I64), sync.cond_waiting, sync.cond_signaled,
                 sync.cond_arrival_ps, sync.cond_wake_ps,
                 sync.cond_sig_time_ps, sync.cond_bcast_time_ps,
-                jnp.zeros((T,), jnp.bool_))
+                jnp.zeros((T,), jnp.bool_), jnp.asarray(False))
 
     with scope("gt.sync.mutex_cond"):
         mc_own = jnp.any(
@@ -798,7 +811,7 @@ def subquantum_iteration(
         (mutex_locked, mutex_owner, mutex_time, mutex_waiting, granted,
          mutex_wait_ps, cond_waiting, cond_signaled, cond_arrival_ps,
          cond_wake_ps, cond_sig_time_ps, cond_bcast_time_ps,
-         cond_post_commit) = lax.cond(
+         cond_post_commit, sync_moved) = lax.cond(
             px.any_sim(mc_own), _mutex_cond_block, _mutex_cond_skip, None)
 
     # --- published cond signals + COND_JOIN (co-located split form) ------
@@ -1233,40 +1246,59 @@ def subquantum_iteration(
         dvfs_rt=new_rt,
         hist=new_hist,
     )
-    return new_state, jnp.sum(advance, dtype=jnp.int32) + mem_progress
+    return new_state, (jnp.sum(advance, dtype=jnp.int32) + mem_progress
+                       + sync_moved.astype(jnp.int32))
 
 
 def _quantum_loop(params, trace, state, qend, trace_base=None, px=IDENT,
                   knobs=None, dvfs=None, hist=None):
-    """Blocks of `inner_block` iterations until no tile makes progress.
-    Returns (state, total_progress, n_iterations)."""
+    """One quantum: iterations until one in which no tile makes progress,
+    in blocks of at most `inner_block` (the staging flush's cadence).
+    Returns (state, total_progress, n_iterations, idle_iterations); the
+    last counts the iterations in which nothing advanced.
 
-    def block(state, progress):
-        # Bounded while_loop, NOT a lax.scan: both lower to the same HLO
-        # While with a static trip count, but a scan's body is multiplied
-        # by `length` in the static cost model's dense-iteration view
-        # (analysis/cost.py) — the budgeted kernels_per_iter then priced
-        # a 32-iteration BLOCK, not the protocol iteration it is named
-        # for.  The while form makes the per-iteration base the unit the
-        # budget ratchet tracks.  Trip count, flush cadence, and every
-        # carried value are identical to the scan, so the swap is
-        # bit-exact (regress rung + golden interpreters pin it).
+    An iteration that advances nothing leaves the state at a fixed point
+    (every later one would advance nothing either), so a quantum of N
+    working iterations runs N + 1: the block stops after the first idle
+    iteration and the quantum stops with it.  Under a sim axis the block
+    runs while ANY sim of the program advanced (`px.any_sim`, one scalar
+    trip count a program); each sim's quantum still ends on its own idle
+    iteration.
+
+    `lax_p2p` is the exception: `p2p_round` advances every iteration and
+    redraws every tile's partner, so an idle iteration is no fixed point
+    there.  It keeps whole blocks of `inner_block` iterations and ends a
+    quantum on a block whose summed progress is 0."""
+    whole_blocks = params.p2p_slack_ps is not None
+
+    def block(state):
+        # A while_loop, NOT a lax.scan: a scan's body is multiplied by
+        # `length` in the static cost model's dense-iteration view
+        # (analysis/cost.py), which would price a 32-iteration BLOCK
+        # where the budgets name the protocol iteration; a while body
+        # counts once.
         def body(carry):
-            st, prog, i = carry
+            st, prog, idle, _, _, i = carry
             st, adv = subquantum_iteration(params, trace, st, qend,
                                            trace_base, px=px, knobs=knobs,
                                            dvfs=dvfs, hist=hist)
-            return st, prog + adv, i + 1
+            return (st, prog + adv, idle + (adv == 0), adv,
+                    px.any_sim(adv > 0), i + 1)
+
+        def more(carry):
+            *_, live, i = carry
+            in_block = i < params.inner_block
+            return in_block if whole_blocks else in_block & live
 
         staged = (params.mem is not None
                   and getattr(params.mem, "dir_stage_cap", 0))
         flush_gate = staged and params.mem.phase_gate
         if flush_gate:
             base_skips0 = state.mem.base_skips[0]
-        state, progress, _ = lax.while_loop(
-            lambda c: c[2] < params.inner_block, body,
-            (state, progress, jnp.asarray(0, jnp.int32)),
-        )
+        zero = jnp.asarray(0, jnp.int32)
+        state, progress, idle, last_adv, _, trips = lax.while_loop(
+            more, body,
+            (state, zero, zero, zero, jnp.asarray(True), zero))
         if staged:
             # One amortized dense pass applies the block's staged
             # directory writes (memory/engine.dir_stage_flush); capacity
@@ -1277,11 +1309,12 @@ def _quantum_loop(params, trace, state, qend, trace_base=None, px=IDENT,
             # (engine._run_if), by the home-activity gate: a slot is
             # staged only by a home phase, a home phase runs only in an
             # iteration whose base ran, and the table is empty at block
-            # entry (every block ends here) — so a block whose
-            # inner_block iterations ALL skipped the base staged
-            # nothing, and its flush would drop every slot.  The counter
-            # is replicated control state (unlike the block-local `sn`),
-            # so every device of a mesh takes the same arm.  Under a sim
+            # entry (every block ends here) — so a block whose `trips`
+            # iterations (fewer than `inner_block` where it stopped at
+            # an idle one) ALL skipped the base staged nothing, and its
+            # flush would drop every slot.  The counter is replicated
+            # control state (unlike the block-local `sn`), so every
+            # device of a mesh takes the same arm.  Under a sim
             # axis it counts the program's skips (the OR-ed `home_live`),
             # but it rides the batched state: reduced over the sims so
             # the in-place loop's predicate stays a scalar.
@@ -1293,28 +1326,31 @@ def _quantum_loop(params, trace, state, qend, trace_base=None, px=IDENT,
             flush_live = None
             if flush_gate:
                 flush_live = px.any_sim(mem.base_skips[0] - base_skips0
-                                        < params.inner_block)
+                                        < trips)
                 mem = mem.replace(base_skips=mem.base_skips + jnp.where(
                     flush_live, 0, FLUSH_SKIPPED))
             with scope("gt.mem.stage_flush"):
                 state = state.replace(mem=mem.replace(
                     directory=dir_stage_flush(mem.directory, flush_live)))
-        return state, progress
+        # what the quantum goes on for: the block's whole progress under
+        # lax_p2p, else its last iteration's
+        return (state, progress, idle, progress if whole_blocks else last_adv,
+                trips)
 
     def cond(carry):
-        _, _, blk_prog, _ = carry
-        return blk_prog > 0
+        _, _, again, _, _ = carry
+        return again > 0
 
     def body(carry):
-        st, total, _, iters = carry
-        st, blk = block(st, jnp.asarray(0, jnp.int32))
-        return st, total + blk, blk, iters + params.inner_block
+        st, total, _, iters, idle = carry
+        st, blk, blk_idle, again, trips = block(st)
+        return st, total + blk, again, iters + trips, idle + blk_idle
 
-    state, total, _, iters = lax.while_loop(
+    state, total, _, iters, idle = lax.while_loop(
         cond, body,
         (state, jnp.asarray(0, jnp.int32), jnp.asarray(1, jnp.int32),
-         jnp.asarray(0, jnp.int64)))
-    return state, total, iters
+         jnp.asarray(0, jnp.int64), jnp.asarray(0, jnp.int64)))
+    return state, total, iters, idle
 
 
 def run_quantum(
@@ -1322,17 +1358,16 @@ def run_quantum(
 ) -> SimState:
     """Run one lax-barrier quantum as a single compiled XLA region.
 
-    Runs blocks of `inner_block` subquantum iterations under a while_loop
-    until no tile makes progress (all done, all past the quantum boundary,
-    or — transiently — all blocked on messages that can only arrive next
-    quantum).  This is the quantum of `clock_skew_management/lax_barrier`
-    (`carbon_sim.cfg:92-97`).  Deliberately NOT a module-level
+    Runs subquantum iterations under a while_loop until one in which no
+    tile makes progress (all done, all past the quantum boundary, or —
+    transiently — all blocked on messages that can only arrive next
+    quantum); see `_quantum_loop`.  The quantum of `clock_skew_management/
+    lax_barrier` (`carbon_sim.cfg:92-97`).  Deliberately NOT a module-level
     `jit(static_argnums=0)`: jitting here with dataclass static args hits a
     jax-0.9 dispatch bug (constant-buffer miscount after topology changes);
     callers jit a closure instead (see `make_simulation_runner`).
     """
-    state, _, _ = _quantum_loop(params, trace, state, qend)
-    return state
+    return _quantum_loop(params, trace, state, qend)[0]
 
 
 def run_simulation(
@@ -1364,9 +1399,12 @@ def run_simulation(
     boundary, zero-progress/deadlock detection, overflow) is computed on
     device; the host reads back one final state.
 
-    Returns (state, n_quanta, deadlock flag) — deadlock means a quantum made
-    zero progress while some tile was eligible to run (same condition the
-    reference debugs with its progress trace, `pin/progress_trace.cc`).
+    Returns (state, n_quanta, deadlock flag, n_iterations,
+    idle_iterations) — deadlock means a quantum made zero progress while
+    some tile was eligible to run (same condition the reference debugs
+    with its progress trace, `pin/progress_trace.cc`); the two counts are
+    the subquantum iterations run and those of them in which nothing
+    advanced (`_quantum_loop`).
 
     `telemetry` (a RESOLVED obs.TelemetrySpec; state.telemetry must hold
     the matching TelemetryState) appends one row to the device-resident
@@ -1420,7 +1458,7 @@ def run_simulation(
         return (clock // qps + 1) * qps
 
     def cond(carry):
-        st, qend, n, deadlock, stalled, _ = carry
+        st, qend, n, deadlock, stalled, _, _ = carry
         return (
             ~jnp.all(st.done)
             & ~st.net.overflow
@@ -1430,7 +1468,7 @@ def run_simulation(
         )
 
     def body(carry):
-        st, prev_qend, n, deadlock, stalled, iters = carry
+        st, prev_qend, n, deadlock, stalled, iters, idle = carry
         clocks = st.core.clock_ps
         not_done = ~st.done
         min_pending = jnp.min(jnp.where(not_done, clocks, jnp.asarray(2**62, I64)))
@@ -1438,10 +1476,9 @@ def run_simulation(
             qend = INF_QEND
         else:
             qend = jnp.maximum(prev_qend + qps, next_boundary(min_pending))
-        st2, progress, blk_iters = _quantum_loop(params, trace, st, qend,
-                                                 trace_base, px=px,
-                                                 knobs=knobs, dvfs=dvfs,
-                                                 hist=hist)
+        st2, progress, blk_iters, blk_idle = _quantum_loop(
+            params, trace, st, qend, trace_base, px=px, knobs=knobs,
+            dvfs=dvfs, hist=hist)
         if dvfs is not None and dvfs.governor is not None:
             # reactive governor: step the governed domains' V/f level on
             # the utilization window — masked arithmetic only (the
@@ -1502,14 +1539,16 @@ def run_simulation(
             qend_next = qend
             deadlock = zero & ~paused
             stalled = zero & paused
-        return st2, qend_next, n + 1, deadlock, stalled, iters + blk_iters
+        return (st2, qend_next, n + 1, deadlock, stalled, iters + blk_iters,
+                idle + blk_idle)
 
     with scope("gt.quantum"):
-        state, _, n_quanta, deadlock, _, n_iters = lax.while_loop(
+        state, _, n_quanta, deadlock, _, n_iters, n_idle = lax.while_loop(
             cond, body,
             (state, jnp.asarray(0, I64), jnp.asarray(0, jnp.int32),
-             jnp.asarray(False), jnp.asarray(False), jnp.asarray(0, jnp.int64)))
-    return state, n_quanta, deadlock, n_iters
+             jnp.asarray(False), jnp.asarray(False), jnp.asarray(0, I64),
+             jnp.asarray(0, I64)))
+    return state, n_quanta, deadlock, n_iters, n_idle
 
 
 def barrier_host_batch(
@@ -1538,9 +1577,9 @@ def barrier_host_batch(
     zero-progress quantum with a tile beyond the boundary jumps the
     window up to it (`lax_barrier_sync_server.h:12-36`).
 
-    Returns (state, prev_qend, n_quanta, deadlock, n_iterations); the
-    host threads prev_qend into the next dispatch so boundary progression
-    is seamless across batches.
+    Returns (state, prev_qend, n_quanta, deadlock, n_iterations,
+    idle_iterations); the host threads prev_qend into the next dispatch
+    so boundary progression is seamless across batches.
 
     `telemetry` / `profile` sample the device-resident rings exactly as
     in `run_simulation`; the sampling cursors ride the state carry, so
@@ -1562,7 +1601,7 @@ def barrier_host_batch(
         return (clock // qps + 1) * qps
 
     def cond(carry):
-        st, _, n, deadlock, _ = carry
+        st, _, n, deadlock, _, _ = carry
         return (
             ~jnp.all(st.done)
             & ~st.net.overflow
@@ -1571,13 +1610,13 @@ def barrier_host_batch(
         )
 
     def body(carry):
-        st, prev, n, deadlock, iters = carry
+        st, prev, n, deadlock, iters, idle = carry
         clocks = st.core.clock_ps
         min_pending = jnp.min(jnp.where(~st.done, clocks,
                                         jnp.asarray(2**62, I64)))
         qend = jnp.maximum(prev + qps, next_boundary(min_pending))
-        st2, progress, blk_iters = _quantum_loop(params, trace, st, qend,
-                                                 dvfs=dvfs, hist=hist)
+        st2, progress, blk_iters, blk_idle = _quantum_loop(
+            params, trace, st, qend, dvfs=dvfs, hist=hist)
         if dvfs is not None and dvfs.governor is not None:
             with scope("gt.dvfs"):
                 rt2 = governor_tick(dvfs.governor, params.dvfs,
@@ -1609,14 +1648,15 @@ def barrier_host_batch(
         qend_next = jnp.where(zero & have_ahead,
                               next_boundary(ahead_clock) - qps, qend)
         deadlock = zero & ~have_ahead
-        return st2, qend_next, n + 1, deadlock, iters + blk_iters
+        return (st2, qend_next, n + 1, deadlock, iters + blk_iters,
+                idle + blk_idle)
 
     with scope("gt.quantum"):
-        state, prev_qend, n, deadlock, iters = lax.while_loop(
+        state, prev_qend, n, deadlock, iters, idle = lax.while_loop(
             cond, body,
             (state, jnp.asarray(prev_qend, I64), jnp.asarray(0, jnp.int32),
-             jnp.asarray(False), jnp.asarray(0, jnp.int64)))
-    return state, prev_qend, n, deadlock, iters
+             jnp.asarray(False), jnp.asarray(0, I64), jnp.asarray(0, I64)))
+    return state, prev_qend, n, deadlock, iters, idle
 
 
 def make_simulation_runner(params: EngineParams, trace: DeviceTrace,

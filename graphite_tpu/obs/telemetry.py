@@ -73,6 +73,14 @@ ENERGY_SERIES = ("energy_pj",)
 SKIP_PREFIX = "skip_"
 
 
+def counts_the_program(series: str) -> bool:
+    """Whether `series` counts what the PROGRAM ran rather than the sim:
+    the gates' skips and the iterations.  Under a campaign's sim axis
+    the gates and the block's exit are keyed on the OR over the batch
+    (`ParallelCtx.any_sim`), so no solo run is these series' oracle."""
+    return series == "iterations" or series.startswith(SKIP_PREFIX)
+
+
 @dataclasses.dataclass(frozen=True)
 class EnergyPrices:
     """Per-event energy prices in integer picojoules — the static

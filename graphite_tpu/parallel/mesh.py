@@ -228,7 +228,7 @@ def make_shard_map_runner(params, quantum_ps, max_quanta: int, mesh: Mesh,
         sm = _shard_map(
             body, mesh=mesh,
             in_specs=(state_specs, trace_specs, P()),
-            out_specs=(state_specs, P(), P(), P()))
+            out_specs=(state_specs, P(), P(), P(), P()))
         return jax.jit(sm)
 
     def body(st, tr):
@@ -237,7 +237,7 @@ def make_shard_map_runner(params, quantum_ps, max_quanta: int, mesh: Mesh,
     sm = _shard_map(
         body, mesh=mesh,
         in_specs=(state_specs, trace_specs),
-        out_specs=(state_specs, P(), P(), P()))
+        out_specs=(state_specs, P(), P(), P(), P()))
     return jax.jit(sm)
 
 

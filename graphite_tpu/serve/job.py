@@ -238,6 +238,9 @@ class JobResult:
     knob_point: "dict | None" = None
     n_quanta: "int | None" = None
     n_iterations: "int | None" = None
+    # of `n_iterations`, those in which this job advanced nothing
+    # (`SweepOutcome.idle_iterations`; a device counter, in no digest)
+    idle_iterations: "int | None" = None
     # what the memory engine's gates skipped in this job's lanes of the
     # batch's program (`SweepOutcome.phase_skips` / `.base_skips`: of
     # `n_iterations`; device counters, not simulated statistics, in no

@@ -846,6 +846,7 @@ class CampaignService:
                     seed=p.job.seed, knob_point=dict(p.job.knobs),
                     n_quanta=int(out.n_quanta[b]),
                     n_iterations=int(out.n_iterations[b]),
+                    idle_iterations=int(out.idle_iterations[b]),
                     phase_skips=ps, base_skips=bs))
         return results
 

@@ -89,6 +89,10 @@ class SweepOutcome:
     # per-sim {"base", "flush"} skip counts of the private-L2 engine's
     # home-activity gate (memory/engine.BASE_SKIP_NAMES), or None
     base_skips: "list[dict] | None" = None
+    # int64[B]: of `n_iterations`, those in which the sim advanced
+    # nothing (engine/step._quantum_loop) — one a quantum, plus what a
+    # sim waited for the rest of its batch
+    idle_iterations: "np.ndarray | None" = None
 
     def json_rows(self) -> "list[dict]":
         """One JSON-able dict per sim (the CLI's output lines)."""
@@ -705,7 +709,7 @@ class SweepRunner:
                 per_cell, mesh=mesh,
                 in_specs=(state_specs, trace_specs, knob_specs),
                 out_specs=(state_specs, P("batch"), P("batch"),
-                           P("batch")))
+                           P("batch"), P("batch")))
 
         if not self.shard_batch:
             return over_sims()
@@ -882,7 +886,7 @@ class SweepRunner:
         # B identical initial states (same config/geometry -> same init)
         states0, dtr = self._batched_inputs()
         with span("dispatch", parent="run"):
-            state, nq_d, deadlock_d, iters_d = self._get_runner(
+            state, nq_d, deadlock_d, iters_d, idle_d = self._get_runner(
                 max_quanta)(states0, dtr, self.knobs)
         if span.on:
             with span("wait", parent="dispatch"):
@@ -895,10 +899,10 @@ class SweepRunner:
         # counter, the rings and the gates' skip counts
         with span("fetch", parent="wait"):
             (nq, deadlock, overflow, done, core_h, net_h, mem_h, ioc_h,
-             tel_h, prof_h, hist_h, iters, skips_h) = jax.device_get((
+             tel_h, prof_h, hist_h, iters, idle, skips_h) = jax.device_get((
                 nq_d, deadlock_d, state.net.overflow, state.done,
                 state.core, net_part, mem_part, ioc_part, tel_part,
-                prof_part, hist_part, iters_d, skips_d))
+                prof_part, hist_part, iters_d, idle_d, skips_d))
         if overflow.any():
             raise MailboxOverflowError(
                 f"mailbox ring overflow in sim(s) "
@@ -918,10 +922,10 @@ class SweepRunner:
         self.last_n_iterations = int(np.max(iters))
         self.last_run_dispatches = 1
         with span("results", parent="fetch"):
-            return self._outcome(nq, iters, core_h, net_h, mem_h, ioc_h,
-                                 tel_h, prof_h, hist_h, skips_h)
+            return self._outcome(nq, iters, idle, core_h, net_h, mem_h,
+                                 ioc_h, tel_h, prof_h, hist_h, skips_h)
 
-    def _outcome(self, nq, iters, core_h, net_h, mem_h, ioc_h, tel_h,
+    def _outcome(self, nq, iters, idle, core_h, net_h, mem_h, ioc_h, tel_h,
                  prof_h, hist_h, skips_h) -> SweepOutcome:
         """Demux the fetched host arrays into B SimResults."""
         B = self.pack.n_sims
@@ -986,6 +990,7 @@ class SweepRunner:
                             n_quanta=np.asarray(nq),
                             phase_skips=phase_skips,
                             base_skips=base_skips,
+                            idle_iterations=np.asarray(idle),
                             seeds=self.pack.seeds,
                             quantum_valid=self.sim.quantum_ps is not None,
                             timelines=timelines,

@@ -258,7 +258,7 @@ def test_time_dtype_threads_through_real_engine():
     params, qps = sim.params, sim.quantum_ps
 
     def bad(st, tr):
-        out_st, nq, dl, it = run_simulation(params, tr, st, qps, 256)
+        out_st, *_ = run_simulation(params, tr, st, qps, 256)
         return out_st.core.clock_ps.astype(jnp.int32)  # the violation
 
     closed = jax.make_jaxpr(bad)(sim.state, sim.device_trace)

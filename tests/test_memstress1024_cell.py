@@ -120,7 +120,8 @@ def test_traced_slice_is_live_and_chunked_equals_whole(pair):
     iters = int(host.last_n_iterations)
     delta = {k: v - before[k] for k, v in
              {**host.last_phase_skips, **host.last_base_skips}.items()}
-    assert iters >= 32 * n
+    # every quantum of the slice works, and ends on its one idle iteration
+    assert iters >= 2 * n and host.last_idle_iterations == n
     # a phase that ran in an iteration did not count a skip there
     for phase in ("requester", "home_start", "sharer", "requester_fill",
                   "base"):

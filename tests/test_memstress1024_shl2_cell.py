@@ -139,7 +139,8 @@ def test_traced_slice_is_live_and_chunked_equals_whole(pair):
     assert not done and more == n
     iters = int(host.last_n_iterations)
     delta = {k: v - before[k] for k, v in host.last_phase_skips.items()}
-    assert iters >= 32 * n
+    # every quantum of the slice works, and ends on its one idle iteration
+    assert iters >= 2 * n and host.last_idle_iterations == n
     # a phase that ran in an iteration did not count a skip there; no L1
     # line is evicted at 64 accesses a tile, so home_evict never runs
     for phase in ("requester", "sharer", "home_start", "home_finish",

@@ -173,7 +173,8 @@ def test_phase_skip_counts():
     assert sum(skips.values()) > 0
 
 
-def test_base_skip_counts_equal_host_count():
+@pytest.mark.parametrize("tail", [9, 0], ids=["idle-tail", "store-tail"])
+def test_base_skip_counts_equal_host_count(tail):
     """`last_base_skips` against a count made on the host.  The home-
     activity predicate is true exactly where one of phases 2-5 fires
     (it is a superset of pred2 | pred3 | pred5 by the argument beside
@@ -182,7 +183,11 @@ def test_base_skip_counts_equal_host_count():
     stepping the program ONE iteration at a time and reading the phase
     skip vector after each gives the count independently of the
     counter: an iteration skipped its base iff it skipped phases 2-5,
-    and a block skipped its flush iff all its iterations did."""
+    and a block skipped its flush iff all its iterations did — the
+    iterations it RAN: the last block of a quantum stops at the idle
+    iteration, and its gate compares with that trip count (`tail` sizes
+    the closing stretch so that the short block is all idle, or holds
+    the stores' home phases)."""
     from graphite_tpu.engine.step import subquantum_iteration
 
     K = 4
@@ -192,39 +197,41 @@ def test_base_skip_counts_equal_host_count():
         for _ in range(3 * K + t):            # idle stretches
             b.instr(Op.IALU)
         b.store(0x100000 + ((t + 1) % 4) * 64, 8)   # a neighbour's line
-        for _ in range(2 * K):
+        for _ in range(tail):
             b.instr(Op.IALU)
     batch = TraceBatch.from_builders(bs)
     # lax: one unbounded quantum, so the run is blocks of K iterations
-    # until a block makes no progress — the loop below
+    # up to the first iteration that makes no progress — the loop below
     sc = make_config(4, extra="[clock_skew_management]\nscheme = lax\n")
     sim = Simulator(sc, batch, phase_gate=True, mem_gate_bytes=0,
                     dir_stage=True, inner_block=K)
     assert sim.quantum_ps is None
     state0 = sim.state
-    sim.run()
+    res = sim.run()
+    assert res.n_quanta == 1
     iters = int(sim.last_n_iterations)
     got = sim.last_base_skips
 
     qend = jnp.asarray(2**61, jnp.int64)
     step = jax.jit(lambda st: subquantum_iteration(
         sim.params, sim.device_trace, st, qend))
-    st, idle = state0, []
-    while True:                       # run_simulation's block structure
-        blk = 0
-        for _ in range(K):
+    st, blocks, adv = state0, [], 1
+    while adv:                        # _quantum_loop's block structure
+        blocks.append([])
+        while adv and len(blocks[-1]) < K:
             before = np.asarray(st.mem.phase_skips)
             st, adv = step(st)
             fired = 1 - (np.asarray(st.mem.phase_skips) - before)
-            idle.append(not fired[1:5].any())
-            blk += int(adv)
-        if blk == 0:
-            break
-    assert len(idle) == iters
-    blocks = np.asarray(idle).reshape(-1, K)
-    want = {"base": int(np.sum(idle)), "flush": int(blocks.all(axis=1).sum())}
+            blocks[-1].append(not fired[1:5].any())
+    assert sum(map(len, blocks)) == iters
+    assert sim.last_idle_iterations == 1
+    want = {"base": sum(map(sum, blocks)), "flush": sum(map(all, blocks))}
     assert got == want, (got, want)
     assert 0 < want["base"] < iters and 0 < want["flush"] < len(blocks)
+    # the short block: all idle under the idle tail, and skipped; with a
+    # home phase in it under the stores' tail, and flushed
+    assert len(blocks[-1]) < K
+    assert all(blocks[-1]) == (tail > 0)
 
 
 def test_whole_engine_gate_leaves_home_gate_out():

@@ -26,7 +26,7 @@ from graphite_tpu.analysis.registry import ProgramRecord
 from graphite_tpu.config import ConfigFile, SimConfig
 from graphite_tpu.engine.simulator import DeadlockError, Simulator
 from graphite_tpu.obs import TelemetrySpec
-from graphite_tpu.obs.telemetry import SKIP_PREFIX
+from graphite_tpu.obs.telemetry import SKIP_PREFIX, counts_the_program
 from graphite_tpu.serve import (
     AdmissionController, CacheEntry, CampaignService, Job, JobResult,
     ProgramCache, ProgramCacheError, QueueFullError, STATUS_OK,
@@ -427,9 +427,10 @@ class TestServeTelemetryAndSchemes:
             svc.submit(Job(f"t{i}", _config(), _trace(s), telemetry=tel))
         out = {r.job_id: r for r in svc.drain()}
         for i, s in enumerate((1, 2)):
-            # the skip_* series count what the BATCH's program skipped
-            # (the gates' predicates are OR-ed over its sims): no solo
-            # run is their oracle, every other series has one
+            # the skip_* series and the iterations count what the
+            # BATCH's program ran (the gates' predicates and the block's
+            # exit are OR-ed over its sims): no solo run is their
+            # oracle, every other series has one
             solo = Simulator(_config(), _trace(s), mem_gate_bytes=0,
                              telemetry=tel).run()
             tl = out[f"t{i}"].telemetry
@@ -438,10 +439,16 @@ class TestServeTelemetryAndSchemes:
             skips = np.array([n.startswith(SKIP_PREFIX)
                               for n in tl.series])
             assert skips.any() and not skips.all()
-            np.testing.assert_array_equal(tl.data[:, ~skips],
-                                          solo.telemetry.data[:, ~skips])
-            assert (tl.data[:, skips] <= solo.telemetry.data[:, skips]
-                    ).all() and tl.data[:, skips].any()
+            own = np.array([not counts_the_program(n) for n in tl.series])
+            np.testing.assert_array_equal(tl.data[:, own],
+                                          solo.telemetry.data[:, own])
+            assert tl.data[:, skips].any()
+            # the batch's program runs a phase in no fewer iterations
+            # than the job's own gated run does
+            it = tl.series.index("iterations")
+            ran, ran_solo = (t.data[:, it].sum() - t.data[:, skips].sum(0)
+                             for t in (tl, solo.telemetry))
+            assert (ran >= ran_solo).all(), (ran, ran_solo)
             _assert_results_equal(out[f"t{i}"].results, solo, msg=f"t{i}")
 
     def test_clock_scheme_axis_batches_separately(self):

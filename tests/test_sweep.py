@@ -374,8 +374,24 @@ class TestActivityGatesUnderTheSimAxis:
         assert [e.invars[0].aval.shape for e in conds] == [()] * len(conds)
         over_sims = [e for e in iter_eqns(closed)
                      if e.primitive.name == "pmax"]
-        assert [e.params["axes"] for e in over_sims] == [(0,)] * 14
+        assert [e.params["axes"] for e in over_sims] == [(0,)] * 15
         assert all(e.outvars[0].aval.shape == () for e in over_sims)
+        # the fifteenth is the block's exit (`_quantum_loop`: it runs
+        # while ANY sim advanced): the innermost of the three nested
+        # loops tests scalars alone - its trip count and that flag - so
+        # `vmap` leaves its carry unselected; the quantum's own test,
+        # above it, is per sim
+        loops = sorted(
+            ((site.count("while"), e)
+             for site, e in find_eqns(closed, "while")
+             if "gt.mem" not in str(e.source_info.name_stack)),
+            key=lambda de: de[0])
+        assert len(loops) == 3
+        tests = [e.params["cond_jaxpr"].jaxpr for _, e in loops]
+        rank = [max(len(v.aval.shape) for q in t.eqns for v in q.invars)
+                for t in tests]
+        assert rank == [2, 1, 0], rank
+        assert [q.primitive.name for q in tests[2].eqns] == ["lt", "and"]
         in_place = [e for _, e in find_eqns(closed, "while")
                     if "gt.mem" in str(e.source_info.name_stack)]
         assert [_loop_flag(e).shape for e in in_place] == [()]
@@ -468,7 +484,11 @@ class TestActivityGatesUnderTheSimAxis:
             sim = Simulator(self._cell_config(lat, core="iocoom"), trace)
             _assert_whole_results_equal(out.results[b], sim.run(),
                                         f"latency {lat}")
-            assert sim.last_n_iterations == out.n_iterations[b]
+            # (a block runs while ANY sim of the batch advanced: a sim
+            # sits through its neighbours' iterations beside its own)
+            assert sim.last_n_iterations - sim.last_idle_iterations \
+                == out.n_iterations[b] - out.idle_iterations[b]
+            assert sim.last_n_iterations <= out.n_iterations[b]
         # the latencies spread the sims' finishing times
         iters = out.n_iterations.tolist()
         assert iters[0] < iters[3], iters
@@ -492,7 +512,23 @@ class TestActivityGatesUnderTheSimAxis:
         differs from the un-gated staged batch."""
         from graphite_tpu.analysis.walk import find_eqns
 
-        trace = self._cell_stream()
+        # a compute stretch that every tile enters at once (a barrier
+        # before it): whole blocks in which no home phase runs
+        rng = np.random.default_rng(0)
+        bs = [TraceBuilder() for _ in range(self.MEM_TILES)]
+        bs[0].barrier_init(0, self.MEM_TILES)
+        for t, b in enumerate(bs):
+            for part in range(2):
+                for _ in range(8):
+                    line = int(rng.integers(0, 64)) * 64
+                    addr = (0x400000 if rng.random() < 0.5
+                            else 0x100000 + t * 8192) + line
+                    (b.store if rng.random() < 0.4 else b.load)(addr, 8)
+                if part == 0:
+                    b.barrier_wait(0)
+                    for _ in range(16):
+                        b.instr(Op.IALU)
+        trace = TraceBatch.from_builders(bs)
         sc = self._cell_config()
         staged = dict(layout="solo", dir_stage=True, inner_block=4)
         gated = SweepRunner(sc, [trace], self._points(), **staged)
@@ -508,6 +544,7 @@ class TestActivityGatesUnderTheSimAxis:
         self._assert_outcomes_equal(out, SweepRunner(
             sc, [trace], self._points(), phase_gate=False, **staged).run())
         for b in range(4):
+            # (at least: a quantum's last block may be shorter)
             blocks = int(out.n_iterations[b]) // 4
             assert 0 < out.base_skips[b]["flush"] < blocks
 
@@ -535,7 +572,8 @@ class TestActivityGatesUnderTheSimAxis:
             sim = Simulator(self._cell_config(lat, **kw), trace)
             _assert_whole_results_equal(out.results[b], sim.run(),
                                         f"latency {lat}")
-            assert sim.last_n_iterations == out.n_iterations[b]
+            assert sim.last_n_iterations - sim.last_idle_iterations \
+                == out.n_iterations[b] - out.idle_iterations[b]
             assert all(v > 0 for v in out.phase_skips[b].values())
         assert out.base_skips is None
 
@@ -569,7 +607,7 @@ class TestKnobTracing:
         for p in points:
             kn = jax.tree_util.tree_map(
                 lambda x: x[0], Knobs.stack(base, [p]))
-            st, nq, deadlock, _ = runner(state0, kn)
+            st, nq, deadlock, *_ = runner(state0, kn)
             assert not bool(deadlock)
             got.append((np.asarray(st.core.clock_ps), int(nq),
                         np.asarray(st.mem.counters.dram_total_lat_ps)))
@@ -588,7 +626,7 @@ class TestKnobTracing:
                 **{k: v for k, v in p.items() if k != "quantum_ps"})
             params2 = dataclasses.replace(params, mem=mp2)
             q2 = p.get("quantum_ps", qps)
-            st2, nq2, _, _ = jax.jit(
+            st2, nq2, *_ = jax.jit(
                 lambda st: run_simulation(params2, trace, st, q2,
                                           100_000))(state0)
             np.testing.assert_array_equal(

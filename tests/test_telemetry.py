@@ -30,7 +30,7 @@ from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.obs import (
     CORE_SERIES, LEVEL_SERIES, Timeline, TelemetrySpec, available_series,
 )
-from graphite_tpu.obs.telemetry import SKIP_PREFIX
+from graphite_tpu.obs.telemetry import SKIP_PREFIX, counts_the_program
 from graphite_tpu.tools._template import config_text
 from graphite_tpu.trace import synthetic
 
@@ -462,21 +462,26 @@ class TestSweepDemux:
             assert tl.data.shape[1] == n_series
             assert out.results[b].telemetry is tl
             # bit-identical to this sim's own sequential telemetry run,
-            # but for the skip_* series: the vmapped program's gates are
-            # keyed on the OR over its sims, so it skips a phase no more
-            # often than the sim's own gated run does
+            # but for the skip_* series and the iterations: the vmapped
+            # program's gates and its block's exit are keyed on the OR
+            # over its sims, so it runs no fewer iterations than the
+            # sim's own gated run, and each phase in no fewer of them
             solo = Simulator(_config(), traces[b],
                              mailbox_depth=sweep.mailbox_depth,
                              mem_gate_bytes=0,
                              telemetry=_spec()).run().telemetry
             assert tl.n_total == solo.n_total
+            own = np.array([not counts_the_program(n) for n in tl.series])
+            np.testing.assert_array_equal(tl.data[:, own], solo.data[:, own],
+                                          err_msg=f"sim {b}")
             skips = np.array([n.startswith(SKIP_PREFIX)
                               for n in tl.series])
-            np.testing.assert_array_equal(tl.data[:, ~skips],
-                                          solo.data[:, ~skips],
-                                          err_msg=f"sim {b}")
-            assert (tl.data[:, skips] <= solo.data[:, skips]).all()
             assert skips.any() and tl.data[:, skips].any()
+            it = tl.series.index("iterations")
+            assert tl.data[:, it].sum() >= solo.data[:, it].sum()
+            ran, ran_solo = (t.data[:, it].sum() - t.data[:, skips].sum(0)
+                             for t in (tl, solo))
+            assert (ran >= ran_solo).all(), (ran, ran_solo)
 
     def test_shard_map_campaign_gathers_device_buffers(self):
         from graphite_tpu.sweep import SweepRunner
