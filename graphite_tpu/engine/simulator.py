@@ -22,6 +22,7 @@ from graphite_tpu.config.simconfig import SimConfig
 from graphite_tpu.engine.state import DeviceTrace, SimState, init_state
 from graphite_tpu.engine.step import EngineParams
 from graphite_tpu.models.dvfs import module_freq_mhz
+from graphite_tpu.models.network_hop_by_hop import NocState, noc_counters
 from graphite_tpu.models.network_user import UserNetworkParams
 from graphite_tpu.obs.scopes import tagged
 from graphite_tpu.obs.trace import NO_SPANS, RunSpans, SetupSpans, constructs
@@ -60,6 +61,12 @@ class SimResults:
     # memory-subsystem counters (per-tile arrays), None when no memory model
     mem_counters: "dict | None" = None
     func_errors: int = 0
+    # per-port event counters of the USER NoC's contention model
+    # ({name: int64[n_tiles, 6]}, ports RIGHT LEFT UP DOWN SELF INJECT:
+    # `models/network_hop_by_hop.NOC_COUNTERS`), None unless the user
+    # network is emesh_hop_by_hop — the reference's router models keep
+    # the same four (`router_model.h:15-79`)
+    noc_counters: "dict | None" = None
     # iocoom detailed stall breakdown (`iocoom_core_model.cc:64-77`),
     # None for the simple core model
     detailed_stalls: "dict | None" = None
@@ -170,6 +177,18 @@ class SimResults:
             if self.packets_received[t]:
                 avg = self.total_packet_latency_ps[t] / self.packets_received[t] / 1000
                 out.append(f"    Average Packet Latency (in nanoseconds): {avg:.3f}")
+            if self.noc_counters is not None:
+                # per-port contention counters (`router_model.cc`
+                # outputSummary analog), summed over the tile's six ports
+                nc = self.noc_counters
+                out.append(
+                    f"    Port Requests: {int(nc['requests'][t].sum())}")
+                out.append("    Port Utilization (in cycles): "
+                           f"{int(nc['utilization_cycles'][t].sum())}")
+                out.append("    Total Contention Delay (in cycles): "
+                           f"{int(nc['delay_cycles'][t].sum())}")
+                out.append("    Analytical Model Used: "
+                           f"{int(nc['analytical_reads'][t].sum())}")
         return "\n".join(out)
 
 
@@ -1280,6 +1299,18 @@ class Simulator:
             args = (self.state, self.device_trace)
         return fn, args
 
+    @property
+    def state(self) -> SimState:
+        return self._state
+
+    @state.setter
+    def state(self, state: SimState) -> None:
+        """A state handed in from outside (construction, a restored
+        initial state, a checkpoint) is at no known quantum boundary: the
+        host-driven loop's boundary floor goes back to 0 with it."""
+        self._state = state
+        self._hb_prev_qend = None
+
     def run_chunk(self, n_quanta: int, *, trace_id=None):
         """Run at most `n_quanta` quanta (for sampled/checkpointed runs).
 
@@ -1332,6 +1363,7 @@ class Simulator:
         mailbox overflow, deadlock), so each host round trip is
         amortized over up to K quanta instead of one."""
         before = self.n_dispatches
+        self._hb_prev_qend = None       # a run() starts its windows at 0
         n, all_done = self._host_barrier_loop(max_quanta, span)
         if not all_done:
             raise RuntimeError(f"exceeded max_quanta={max_quanta}")
@@ -1364,11 +1396,15 @@ class Simulator:
         all_done).  Mutates self.state.  The budget rides as a DYNAMIC
         operand, so run_chunk-style partial budgets never recompile and
         never overshoot.  Each batch is one `dispatch` / `wait` /
-        `fetch` triple of `span`."""
+        `fetch` triple of `span`.  The last quantum's boundary stays on
+        the instance beside the state it belongs to, so a later call
+        (`run_chunk` again and again) goes on from it; assigning `state`
+        clears it."""
 
         runner = self._hb_get_runner()
         state = self.state
-        prev_qend = jnp.asarray(0, jnp.int64)
+        prev_qend = (jnp.asarray(0, jnp.int64)
+                     if self._hb_prev_qend is None else self._hb_prev_qend)
         n = 0
         total_iters = total_idle = 0
         batch = 0
@@ -1409,6 +1445,7 @@ class Simulator:
                     "host-barrier batch made no progress and raised no "
                     "flag")
         self.state = state
+        self._hb_prev_qend = prev_qend
         self.last_n_iterations = total_iters
         self.last_idle_iterations = total_idle
         return n, bool(done.all())
@@ -1435,8 +1472,12 @@ class Simulator:
             }
             if state.ioc is not None else None
         )
+        # the user NoC's port queues ride whole (a slice here would be a
+        # device program of its own, compiled inside the first run())
         net_part = (state.net.packets_sent, state.net.packets_received,
-                    state.net.total_latency_ps)
+                    state.net.total_latency_ps,
+                    state.noc_user.queues.data
+                    if isinstance(state.noc_user, NocState) else None)
         tel_part = (
             (state.telemetry.buf, state.telemetry.count)
             if state.telemetry is not None else None
@@ -1826,7 +1867,7 @@ class Simulator:
                 for f in _dc.fields(counters_h)
             }
             func_errors = int(func_errors_h)
-        packets_sent, packets_received, total_latency_ps = net_h
+        packets_sent, packets_received, total_latency_ps, noc_h = net_h
         return SimResults(
             n_tiles=self.params.n_tiles,
             completion_time_ps=int(clock.max()),
@@ -1846,6 +1887,8 @@ class Simulator:
             n_quanta=n_quanta,
             mem_counters=mem_counters,
             func_errors=func_errors,
+            noc_counters=(None if noc_h is None else noc_counters(
+                np.asarray(noc_h), self.params.n_tiles)),
             detailed_stalls=(
                 {k: np.asarray(v) for k, v in ioc_h.items()}
                 if ioc_h is not None else None),

@@ -72,6 +72,10 @@ class GoldenResult:
     dvfs_errors: np.ndarray | None = None
     # per-tile final CORE-domain frequency after in-trace retunes
     core_freq_mhz: np.ndarray | None = None
+    # per-port event counters of the user NoC ({name: int64[T, 6]}, the
+    # engine's `SimResults.noc_counters`), None unless the user network
+    # is emesh_hop_by_hop
+    noc_counters: dict | None = None
 
 
 class _Net:
@@ -109,25 +113,27 @@ class _HbhNet:
 
     def _queue(self, qid):
         return self.q.setdefault(qid, dict(
-            qt=0, ws=0, sum_st=0, sum_st2=0, n=0, newest=0))
+            qt=0, ws=0, sum_st=0, sum_st2=0, n=0, newest=0,
+            requests=0, utilization_cycles=0, delay_cycles=0,
+            analytical_reads=0))
 
     def _delay(self, qid, t, proc):
         s = self._queue(qid)
         qp = self.p.queue
         if qp.kind in ("history_list", "history_tree"):
             if qp.analytical_enabled and (t + proc) < s["ws"]:
-                # M/G/1 fallback from running moments (mirrors
-                # queue_models._mg1_wait)
-                import math
-
+                # M/G/1 fallback from the running moments: the formula of
+                # `queue_model_m_g_1.cc:18-47` with mu = n / sum_st,
+                # lambda = min(n / newest, 0.999 mu) and 1 / mu^2 + var =
+                # sum_st2 / n, as an exact fraction (no backend's floats)
                 if s["n"] == 0:
                     return 0, True
-                mean = s["sum_st"] / s["n"]
-                var = s["sum_st2"] / s["n"] - mean * mean
-                mu = 1.0 / max(mean, 1e-12)
-                lam = min(s["n"] / max(s["newest"], 1e-12), 0.999 * mu)
-                w = 0.5 * mu * lam * (1.0 / (mu * mu) + var) / (mu - lam)
-                return int(math.ceil(w)), True
+                st = max(s["sum_st"], 1)
+                if 1000 * st <= 999 * s["newest"]:
+                    w = _ceil_div(s["sum_st2"], 2 * (s["newest"] - st))
+                else:       # arrivals capped at 0.999 of the service rate
+                    w = _ceil_div(999 * s["sum_st2"], 2 * st)
+                return w, True
             return max(s["qt"] - t, 0), False
         return max(s["qt"] - t, 0), False
 
@@ -145,6 +151,24 @@ class _HbhNet:
         s["sum_st2"] += proc * proc
         s["n"] += 1
         s["newest"] = max(s["newest"], t + delay + proc)
+        # event counters (`updateQueueUtilizationCounters`)
+        s["requests"] += 1
+        s["utilization_cycles"] += proc
+        s["delay_cycles"] += delay
+        s["analytical_reads"] += not in_window
+
+    def port_counters(self) -> dict:
+        """{name: int64[n_tiles, 6]} over the mesh's port queues."""
+        from graphite_tpu.models.network_hop_by_hop import (
+            NOC_COUNTERS, NUM_PORTS,
+        )
+
+        out = {name: np.zeros((self.p.n_tiles, NUM_PORTS), np.int64)
+               for name, _ in NOC_COUNTERS}
+        for qid, s in self.q.items():
+            for name in out:
+                out[name][divmod(qid, NUM_PORTS)] = s[name]
+        return out
 
     def route(self, src, dst, payload_bytes, t_send_ps, enabled):
         """Returns the arrival time in ps (absolute)."""
@@ -782,4 +806,6 @@ def run_golden(sim_config, batch: TraceBatch,
             if mem is not None else None),
         dvfs_errors=np.asarray(dvfs_errors, np.int64),
         core_freq_mhz=np.asarray(core_freq, np.int64),
+        noc_counters=(net.port_counters()
+                      if net_kind == "emesh_hop_by_hop" else None),
     )

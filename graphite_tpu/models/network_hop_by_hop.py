@@ -43,14 +43,22 @@ import jax.numpy as jnp
 from flax import struct
 from jax import lax
 
+from graphite_tpu.models import queue_models as qm
 from graphite_tpu.models.queue_models import (
     QueueArrays, QueueParams, make_queues,
 )
+from graphite_tpu.obs.scopes import scope
 from graphite_tpu.time_types import cycles_to_ps, ps_to_cycles
 
 I64 = jnp.int64
 NUM_PORTS = 6
 PORT_RIGHT, PORT_LEFT, PORT_UP, PORT_DOWN, PORT_SELF, PORT_INJECT = range(6)
+# the per-port event counters a run reports (`SimResults.noc_counters`),
+# each with its QueueArrays column (`router_model.h:15-79`)
+NOC_COUNTERS = (("requests", qm.COL_REQS),
+                ("utilization_cycles", qm.COL_UTIL),
+                ("delay_cycles", qm.COL_DELAY),
+                ("analytical_reads", qm.COL_ANA))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,6 +108,13 @@ class NocState:
 
 def init_noc_state(p: HopByHopParams) -> NocState:
     return NocState(queues=make_queues(p.n_tiles * NUM_PORTS + 1, p.queue))
+
+
+def noc_counters(data, n_tiles: int) -> dict:
+    """{name: [n_tiles, 6]} from a fetched `NocState.queues.data`
+    (host-side: the scratch queue's row is dropped)."""
+    ports = data[: n_tiles * NUM_PORTS].reshape(n_tiles, NUM_PORTS, qm.N_COLS)
+    return {name: ports[..., col] for name, col in NOC_COUNTERS}
 
 
 def _xy_next(p: HopByHopParams, cur: jax.Array, dst: jax.Array):
@@ -206,172 +221,181 @@ def _dense_contention(p, q, live, flits, t0, sx, sy, dx, dy, dist):
     hop recurrence), and occupancy commits exactly (max of arrivals,
     then the sum of every processing time).
     """
-    L = live.shape[0]
-    w, h = p.mesh_width, p.mesh_height
-    step_cyc = jnp.asarray(p.router_delay + p.link_delay, I64)
-    X = jnp.arange(w, dtype=jnp.int32)[None, None, :]     # [1, 1, w]
-    Y = jnp.arange(h, dtype=jnp.int32)[None, :, None]     # [1, h, 1]
-    sx_, sy_ = sx[:, None, None], sy[:, None, None]
-    dx_, dy_ = dx[:, None, None], dy[:, None, None]
-    live_ = live[:, None, None]
-    t0_ = t0[:, None, None]
-    proc = flits[:, None, None]
+    # two scopes, the halves a perf_opt PR would treat differently: the scan
+    # is elementwise over [L, h, w], the commit reduces over the packet axis
+    with scope("gt.net.hbh.scan"):
+        L = live.shape[0]
+        w, h = p.mesh_width, p.mesh_height
+        step_cyc = jnp.asarray(p.router_delay + p.link_delay, I64)
+        X = jnp.arange(w, dtype=jnp.int32)[None, None, :]     # [1, 1, w]
+        Y = jnp.arange(h, dtype=jnp.int32)[None, :, None]     # [1, h, 1]
+        sx_, sy_ = sx[:, None, None], sy[:, None, None]
+        dx_, dy_ = dx[:, None, None], dy[:, None, None]
+        live_ = live[:, None, None]
+        t0_ = t0[:, None, None]
+        proc = flits[:, None, None]
 
-    # port state as dense [h, w, 10] grids per direction
-    from graphite_tpu.models import queue_models as qm
+        # port state as dense [h, w, 10] grids per direction
+        grid = q.data[: w * h * NUM_PORTS].reshape(h, w, NUM_PORTS, qm.N_COLS)
 
-    grid = q.data[: w * h * NUM_PORTS].reshape(h, w, NUM_PORTS, qm.N_COLS)
+        def port_state(d):
+            return grid[None, :, :, d, :]       # [1, h, w, 10] broadcast over L
 
-    def port_state(d):
-        return grid[None, :, :, d, :]       # [1, h, w, 10] broadcast over L
+        windowed = p.queue.kind in ("history_list", "history_tree")
+        if windowed:
+            # the M/G/1 wait of every port, from the pre-call moments: one
+            # evaluation for the six planes (an int64 division is the
+            # dearest thing the TPU compiler builds here)
+            mg1_all = qm._mg1_wait(
+                grid[..., qm.COL_N_ARR], grid[..., qm.COL_SUM_ST],
+                grid[..., qm.COL_SUM_ST2], grid[..., qm.COL_NEWEST])
 
-    def delay_at(d, arr, member):
-        """Queue delay for member cells of port-plane d at arrival arr."""
-        st = port_state(d)
-        qt = st[..., qm.COL_QT]
-        if p.queue.kind in ("history_list", "history_tree"):
-            too_old = p.queue.analytical_enabled & (
-                (arr + proc) < st[..., qm.COL_WS])
-            mg1 = qm._mg1_wait(
-                st[..., qm.COL_N_ARR], st[..., qm.COL_SUM_ST],
-                st[..., qm.COL_SUM_ST2], st[..., qm.COL_NEWEST])
-            dly = jnp.where(too_old, mg1, jnp.maximum(qt - arr, 0))
-        else:
-            too_old = jnp.zeros(arr.shape, bool)
-            dly = jnp.maximum(qt - arr, 0)
-        return jnp.where(member, dly, 0), too_old
+        def delay_at(d, arr, member):
+            """Queue delay for member cells of port-plane d at arrival arr."""
+            st = port_state(d)
+            qt = st[..., qm.COL_QT]
+            if windowed:
+                too_old = p.queue.analytical_enabled & (
+                    (arr + proc) < st[..., qm.COL_WS])
+                dly = jnp.where(too_old, mg1_all[None, :, :, d],
+                                jnp.maximum(qt - arr, 0))
+            else:
+                too_old = jnp.zeros(arr.shape, bool)
+                dly = jnp.maximum(qt - arr, 0)
+            return jnp.where(member, dly, 0), too_old
 
-    # ---- cell membership + hop index (steps from src) per plane ---------
-    on_row = Y == sy_
-    on_col = X == dx_
-    m_right = live_ & on_row & (X >= sx_) & (X < dx_)
-    m_left = live_ & on_row & (X <= sx_) & (X > dx_)
-    m_up = live_ & on_col & (Y >= sy_) & (Y < dy_)
-    m_down = live_ & on_col & (Y <= sy_) & (Y > dy_)
-    m_self = live_ & (X == dx_) & (Y == dy_)
-    m_inject = live_ & (X == sx_) & (Y == sy_)
-    steps_h = jnp.abs(X - sx_).astype(I64)                 # horizontal run
-    steps_v = (jnp.abs(dx_ - sx_) + jnp.abs(Y - sy_)).astype(I64)
-    steps_self = dist[:, None, None]
+        # ---- cell membership + hop index (steps from src) per plane ---------
+        on_row = Y == sy_
+        on_col = X == dx_
+        m_right = live_ & on_row & (X >= sx_) & (X < dx_)
+        m_left = live_ & on_row & (X <= sx_) & (X > dx_)
+        m_up = live_ & on_col & (Y >= sy_) & (Y < dy_)
+        m_down = live_ & on_col & (Y <= sy_) & (Y > dy_)
+        m_self = live_ & (X == dx_) & (Y == dy_)
+        m_inject = live_ & (X == sx_) & (Y == sy_)
+        steps_h = jnp.abs(X - sx_).astype(I64)                 # horizontal run
+        steps_v = (jnp.abs(dx_ - sx_) + jnp.abs(Y - sy_)).astype(I64)
+        steps_self = dist[:, None, None]
 
-    planes = (
-        (PORT_RIGHT, m_right, steps_h, "x+"),
-        (PORT_LEFT, m_left, steps_h, "x-"),
-        (PORT_UP, m_up, steps_v, "y+"),
-        (PORT_DOWN, m_down, steps_v, "y-"),
-        (PORT_SELF, m_self, steps_self, None),
-        (PORT_INJECT, m_inject, None, None),
-    )
+        planes = (
+            (PORT_RIGHT, m_right, steps_h, "x+"),
+            (PORT_LEFT, m_left, steps_h, "x-"),
+            (PORT_UP, m_up, steps_v, "y+"),
+            (PORT_DOWN, m_down, steps_v, "y-"),
+            (PORT_SELF, m_self, steps_self, None),
+            (PORT_INJECT, m_inject, None, None),
+        )
 
-    # ---- EXACT per-packet arrivals via a max-plus scan ------------------
-    # The serial hop recurrence t_{j+1} = step + max(t_j, qt_j) has the
-    # closed form t_j = s_j*step + max(base, max_{i<j}(qt_i - s_i*step)),
-    # so each cell's read time is a directional EXCLUSIVE cummax of
-    # (qt - steps*step) along the path — bit-identical to the serial loop
-    # for in-window traffic.  The M/G/1 too-old fallback substitutes its
-    # analytical wait at the scanned read time; its (rare, deep-backlog)
-    # downstream compounding is approximate — documented with the
-    # windowed-tail queue model itself.
-    NEG = -(2**61)
+        # ---- EXACT per-packet arrivals via a max-plus scan ------------------
+        # The serial hop recurrence t_{j+1} = step + max(t_j, qt_j) has the
+        # closed form t_j = s_j*step + max(base, max_{i<j}(qt_i - s_i*step)),
+        # so each cell's read time is a directional EXCLUSIVE cummax of
+        # (qt - steps*step) along the path — bit-identical to the serial loop
+        # for in-window traffic.  The M/G/1 too-old fallback substitutes its
+        # analytical wait at the scanned read time; its (rare, deep-backlog)
+        # downstream compounding is approximate — documented with the
+        # windowed-tail queue model itself.
+        NEG = -(2**61)
 
-    def qt_of(d):
-        return port_state(d)[..., qm.COL_QT]
+        def qt_of(d):
+            return port_state(d)[..., qm.COL_QT]
 
-    # injection: read at t0 (one cell per packet)
-    d_inj_cells, too_inj = delay_at(
-        PORT_INJECT, jnp.broadcast_to(t0_, m_inject.shape), m_inject)
-    base = t0_ + p.router_delay + d_inj_cells.sum((1, 2))[:, None, None]
+        # injection: read at t0 (one cell per packet)
+        d_inj_cells, too_inj = delay_at(
+            PORT_INJECT, jnp.broadcast_to(t0_, m_inject.shape), m_inject)
+        base = t0_ + p.router_delay + d_inj_cells.sum((1, 2))[:, None, None]
 
-    going_right = (dx > sx)[:, None, None]
-    going_up = (dy > sy)[:, None, None]
+        going_right = (dx > sx)[:, None, None]
+        going_up = (dy > sy)[:, None, None]
 
-    def excl_cummax(v, axis, forward):
-        c = lax.cummax(v, axis=axis, reverse=not forward)
-        # shift one along the direction to make it exclusive
-        pad = [(0, 0)] * v.ndim
-        pad[axis] = (1, 0) if forward else (0, 1)
-        sl = [slice(None)] * v.ndim
-        sl[axis] = slice(0, -1) if forward else slice(1, None)
-        return jnp.pad(c[tuple(sl)], pad, constant_values=NEG)
+        def excl_cummax(v, axis, forward):
+            c = lax.cummax(v, axis=axis, reverse=not forward)
+            # shift one along the direction to make it exclusive
+            pad = [(0, 0)] * v.ndim
+            pad[axis] = (1, 0) if forward else (0, 1)
+            sl = [slice(None)] * v.ndim
+            sl[axis] = slice(0, -1) if forward else slice(1, None)
+            return jnp.pad(c[tuple(sl)], pad, constant_values=NEG)
 
-    # horizontal field (each packet uses RIGHT xor LEFT)
-    qt_h = jnp.where(m_right, qt_of(PORT_RIGHT),
-                     jnp.where(m_left, qt_of(PORT_LEFT), NEG))
-    v_h = jnp.where(m_right | m_left, qt_h - steps_h * step_cyc, NEG)
-    excl_h = jnp.where(going_right, excl_cummax(v_h, 2, True),
-                       excl_cummax(v_h, 2, False))
-    t_read_h = steps_h * step_cyc + jnp.maximum(base, excl_h)
-    h_all = jnp.max(v_h, axis=(1, 2), keepdims=True)
+        # horizontal field (each packet uses RIGHT xor LEFT)
+        qt_h = jnp.where(m_right, qt_of(PORT_RIGHT),
+                         jnp.where(m_left, qt_of(PORT_LEFT), NEG))
+        v_h = jnp.where(m_right | m_left, qt_h - steps_h * step_cyc, NEG)
+        excl_h = jnp.where(going_right, excl_cummax(v_h, 2, True),
+                           excl_cummax(v_h, 2, False))
+        t_read_h = steps_h * step_cyc + jnp.maximum(base, excl_h)
+        h_all = jnp.max(v_h, axis=(1, 2), keepdims=True)
 
-    # vertical field (UP xor DOWN), carrying the whole horizontal segment
-    qt_v = jnp.where(m_up, qt_of(PORT_UP),
-                     jnp.where(m_down, qt_of(PORT_DOWN), NEG))
-    v_v = jnp.where(m_up | m_down, qt_v - steps_v * step_cyc, NEG)
-    carry_v = jnp.maximum(base, h_all)
-    excl_v = jnp.where(going_up, excl_cummax(v_v, 1, True),
-                       excl_cummax(v_v, 1, False))
-    t_read_v = steps_v * step_cyc + jnp.maximum(carry_v, excl_v)
-    v_all = jnp.max(v_v, axis=(1, 2), keepdims=True)
+        # vertical field (UP xor DOWN), carrying the whole horizontal segment
+        qt_v = jnp.where(m_up, qt_of(PORT_UP),
+                         jnp.where(m_down, qt_of(PORT_DOWN), NEG))
+        v_v = jnp.where(m_up | m_down, qt_v - steps_v * step_cyc, NEG)
+        carry_v = jnp.maximum(base, h_all)
+        excl_v = jnp.where(going_up, excl_cummax(v_v, 1, True),
+                           excl_cummax(v_v, 1, False))
+        t_read_v = steps_v * step_cyc + jnp.maximum(carry_v, excl_v)
+        v_all = jnp.max(v_v, axis=(1, 2), keepdims=True)
 
-    # SELF delivery cell: everything upstream
-    t_read_s = steps_self * step_cyc + jnp.maximum(carry_v, v_all)
+        # SELF delivery cell: everything upstream
+        t_read_s = steps_self * step_cyc + jnp.maximum(carry_v, v_all)
 
-    d1 = {}
-    arrs = {}
-    for d, member, steps, order in planes:
-        if d == PORT_INJECT:
-            arr = jnp.broadcast_to(t0_, member.shape)
-            dly, too_old = d_inj_cells, too_inj
-        else:
-            arr = (t_read_h if order in ("x+", "x-")
-                   else t_read_v if order in ("y+", "y-") else t_read_s)
-            dly, too_old = delay_at(d, arr, member)
-        d1[d] = dly
-        arrs[d] = (arr, too_old, member)
+        d1 = {}
+        arrs = {}
+        for d, member, steps, order in planes:
+            if d == PORT_INJECT:
+                arr = jnp.broadcast_to(t0_, member.shape)
+                dly, too_old = d_inj_cells, too_inj
+            else:
+                arr = (t_read_h if order in ("x+", "x-")
+                       else t_read_v if order in ("y+", "y-") else t_read_s)
+                dly, too_old = delay_at(d, arr, member)
+            d1[d] = dly
+            arrs[d] = (arr, too_old, member)
 
-    # ---- commit occupancy per port plane (dense reductions over L) ------
-    new_grid = grid
-    span = p.queue.history_span
-    for d, member, steps, order in planes:
-        arr, too_old, _ = arrs[d]
-        in_win = member & ~too_old
-        st = grid[:, :, d, :]                          # [h, w, 10]
-        qt = st[..., qm.COL_QT]
-        any_win = in_win.any(axis=0)
-        arr_max = jnp.max(jnp.where(in_win, arr, -(2**62)), axis=0)
-        proc_sum = jnp.sum(jnp.where(in_win, proc, 0), axis=0)
-        qt_new = jnp.where(
-            any_win, jnp.maximum(qt, arr_max) + proc_sum, qt)
-        end = arr + d1[d] + proc
-        newest = jnp.maximum(
-            st[..., qm.COL_NEWEST],
-            jnp.max(jnp.where(member, end, 0), axis=0))
-        ws_new = jnp.where(
-            any_win,
-            jnp.maximum(st[..., qm.COL_WS], qt_new - span),
-            st[..., qm.COL_WS])
+    with scope("gt.net.hbh.commit"):
+        # ---- commit occupancy per port plane (dense reductions over L) ------
+        new_grid = grid
+        span = p.queue.history_span
+        for d, member, steps, order in planes:
+            arr, too_old, _ = arrs[d]
+            in_win = member & ~too_old
+            st = grid[:, :, d, :]                          # [h, w, 10]
+            qt = st[..., qm.COL_QT]
+            any_win = in_win.any(axis=0)
+            arr_max = jnp.max(jnp.where(in_win, arr, -(2**62)), axis=0)
+            proc_sum = jnp.sum(jnp.where(in_win, proc, 0), axis=0)
+            qt_new = jnp.where(
+                any_win, jnp.maximum(qt, arr_max) + proc_sum, qt)
+            end = arr + d1[d] + proc
+            newest = jnp.maximum(
+                st[..., qm.COL_NEWEST],
+                jnp.max(jnp.where(member, end, 0), axis=0))
+            ws_new = jnp.where(
+                any_win,
+                jnp.maximum(st[..., qm.COL_WS], qt_new - span),
+                st[..., qm.COL_WS])
 
-        def msum(v):
-            return jnp.sum(jnp.where(member, v, 0), axis=0)
+            def msum(v):
+                return jnp.sum(jnp.where(member, v, 0), axis=0)
 
-        cols = jnp.stack([
-            qt_new,
-            ws_new,
-            newest,
-            st[..., qm.COL_SUM_ST] + msum(jnp.broadcast_to(
-                proc, member.shape)),
-            st[..., qm.COL_SUM_ST2] + msum(jnp.broadcast_to(
-                proc * proc, member.shape)),
-            st[..., qm.COL_N_ARR] + member.sum(axis=0, dtype=I64),
-            st[..., qm.COL_REQS] + member.sum(axis=0, dtype=I64),
-            st[..., qm.COL_UTIL] + msum(jnp.broadcast_to(
-                proc, member.shape)),
-            st[..., qm.COL_DELAY] + msum(d1[d]),
-            st[..., qm.COL_ANA] + (member & too_old).sum(axis=0, dtype=I64),
-        ], axis=-1)
-        new_grid = new_grid.at[:, :, d, :].set(cols)
+            cols = jnp.stack([
+                qt_new,
+                ws_new,
+                newest,
+                st[..., qm.COL_SUM_ST] + msum(jnp.broadcast_to(
+                    proc, member.shape)),
+                st[..., qm.COL_SUM_ST2] + msum(jnp.broadcast_to(
+                    proc * proc, member.shape)),
+                st[..., qm.COL_N_ARR] + member.sum(axis=0, dtype=I64),
+                st[..., qm.COL_REQS] + member.sum(axis=0, dtype=I64),
+                st[..., qm.COL_UTIL] + msum(jnp.broadcast_to(
+                    proc, member.shape)),
+                st[..., qm.COL_DELAY] + msum(d1[d]),
+                st[..., qm.COL_ANA] + (member & too_old).sum(axis=0, dtype=I64),
+            ], axis=-1)
+            new_grid = new_grid.at[:, :, d, :].set(cols)
 
-    data = q.data.at[: w * h * NUM_PORTS].set(
-        new_grid.reshape(w * h * NUM_PORTS, qm.N_COLS))
-    contention = sum(d1[d].sum((1, 2)) for d in range(NUM_PORTS))
+        data = q.data.at[: w * h * NUM_PORTS].set(
+            new_grid.reshape(w * h * NUM_PORTS, qm.N_COLS))
+        contention = sum(d1[d].sum((1, 2)) for d in range(NUM_PORTS))
     return q.replace(data=data), contention

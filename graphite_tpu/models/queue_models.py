@@ -48,7 +48,6 @@ import jax.numpy as jnp
 from flax import struct
 
 I64 = jnp.int64
-F64 = jnp.float64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,19 +174,50 @@ def make_queues(n: int, params: QueueParams) -> QueueArrays:
 
 def _mg1_wait(n_arrivals, sum_st, sum_st2, newest_arrival) -> jax.Array:
     """`queue_model_m_g_1.cc:18-47` waiting-time formula, elementwise over
-    running moments (shared by the lane-per-queue and scatter paths)."""
-    n = n_arrivals.astype(F64)
+    running moments (shared by the lane-per-queue and scatter paths),
+    evaluated EXACTLY in integers.
+
+    With mu = n / sum_st, lambda = min(n / newest, 0.999 mu) and
+    1 / mu^2 + var = sum_st2 / n, the reference's
+    ceil(0.5 mu lambda (1 / mu^2 + var) / (mu - lambda)) is
+
+        ceil(sum_st2 / (2 (newest - sum_st)))   while 1000 sum_st <= 999 newest
+        ceil(999 sum_st2 / (2 sum_st))          saturated (the 0.999 cap)
+
+    and the two agree where the cap sets in.  The reference evaluates it
+    in doubles; so did this function until PR 42, whose first chip run
+    found the TPU's emulated float64 a cycle off the CPU backend's on some
+    reads (PERF.md section 6) - a statistic that depends on the backend is
+    no statistic.  Where the exact value is a whole number the double
+    version mostly read one cycle more (its roundings land just above)."""
     have = n_arrivals > 0
-    n_safe = jnp.where(have, n, 1.0)
-    mean_st = sum_st.astype(F64) / n_safe
-    var_st = sum_st2.astype(F64) / n_safe - mean_st * mean_st
-    service_rate = 1.0 / jnp.maximum(mean_st, 1e-12)
-    arrival_rate = n / jnp.maximum(newest_arrival.astype(F64), 1e-12)
-    arrival_rate = jnp.minimum(arrival_rate, 0.999 * service_rate)
-    wait = 0.5 * service_rate * arrival_rate * (
-        1.0 / (service_rate * service_rate) + var_st
-    ) / (service_rate - arrival_rate)
-    return jnp.where(have, jnp.ceil(wait), 0.0).astype(I64)
+    st = jnp.maximum(sum_st, 1)
+    below_cap = 1000 * st <= 999 * newest_arrival
+    num = jnp.where(below_cap, sum_st2, 999 * sum_st2)
+    den = jnp.where(below_cap, 2 * (newest_arrival - st), 2 * st)
+    return jnp.where(have, _ceil_div_bounded(num, den), 0).astype(I64)
+
+
+_WAIT_BITS = 32
+
+
+def _ceil_div_bounded(num, den) -> jax.Array:
+    """ceil(num / den) for int64 num >= 0, den >= 1 and a quotient below
+    2^32 (a wait is at most 500 times the longest service time: 2^32
+    cycles would take a packet of 2^23 flits), saturating there: restoring
+    division over the quotient's 32 bits, shifts, compares and subtracts
+    only.  XLA's own int64 division is emulated on the TPU at a cost the
+    compiler shows (six of them: 2.7 times the compile of this program,
+    +668 KB of code: PERF.md section 6)."""
+    num = num + den - 1
+    over = (num >> _WAIT_BITS) >= den
+    q = jnp.zeros_like(num)
+    r = num
+    for bit in reversed(range(_WAIT_BITS)):
+        fits = (r >> bit) >= den
+        r = jnp.where(fits, r - (den << bit), r)
+        q = jnp.where(fits, q | (1 << bit), q)
+    return jnp.where(over, (1 << _WAIT_BITS) - 1, q)
 
 
 def _mg1_delay(q: QueueArrays) -> jax.Array:

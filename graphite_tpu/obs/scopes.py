@@ -18,8 +18,9 @@ to some scope; what is left unscoped is what XLA added on its own
 (copies on the loop carry, parameter moves).
 
 Applied in engine/step.py, memory/engine.py, memory/engine_shl2.py,
-models/iocoom.py and parallel/px.py, at the granularity of a layer a
-`perf_opt` PR would work on — not of a helper function.
+models/iocoom.py, models/network_hop_by_hop.py and parallel/px.py, at the
+granularity of a layer a `perf_opt` PR would work on — not of a helper
+function.
 """
 
 import hashlib
@@ -42,6 +43,9 @@ SCOPES = (
                             #   embedded directory, outside its gate
     "gt.net.mailbox",       # SEND / NET_RECV rings
     "gt.net.route",         # NoC latency models, user + memory network
+    "gt.net.hbh.scan",      # emesh_hop_by_hop, inside gt.net.route: path
+                            #   masks, max-plus scan, per-cell delays
+    "gt.net.hbh.commit",    # ... and the port occupancies' commit
     "gt.sync.barrier",
     "gt.sync.mutex_cond",   # mutex + cond block, published cond signals
     "gt.sync.join",

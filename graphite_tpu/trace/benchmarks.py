@@ -130,6 +130,31 @@ def _fft_trace_with_memory(n_tiles, points_per_tile, fly_instr, msg_bytes):
     return TraceBatch.from_builders(builders)
 
 
+def _prefix_tree(builders, n_tiles, radix):
+    """RADIX's tree prefix-sum over the per-tile histograms: an up-sweep
+    and a down-sweep over log2(T) rounds of point-to-point messages
+    (`radix * 4` bytes each).  In a round every sender's XY path is its
+    own (senders lie 2 * stride apart and send `stride` tiles away), so
+    no port sees two packets of one round."""
+    levels = max(1, int(np.log2(max(2, n_tiles))))
+    for lvl in range(levels):
+        stride = 1 << lvl
+        for t, b in enumerate(builders):
+            if (t % (stride * 2)) == 0 and t + stride < n_tiles:
+                b.recv(t + stride, radix * 4)
+            elif (t % (stride * 2)) == stride:
+                b.send(t - stride, radix * 4)
+        for b in builders:
+            b.bblock(radix, radix)
+    for lvl in reversed(range(levels)):
+        stride = 1 << lvl
+        for t, b in enumerate(builders):
+            if (t % (stride * 2)) == 0 and t + stride < n_tiles:
+                b.send(t + stride, radix * 4)
+            elif (t % (stride * 2)) == stride:
+                b.recv(t - stride, radix * 4)
+
+
 @generator
 def radix_trace(n_tiles: int, keys_per_tile: int = 1024,
                 radix: int = 16) -> TraceBatch:
@@ -155,24 +180,7 @@ def radix_trace(n_tiles: int, keys_per_tile: int = 1024,
         for b in builders:
             b.bblock(keys_per_tile * 2 + radix, keys_per_tile * 2 + radix)
         _barrier(builders)
-        # tree prefix-sum: up-sweep + down-sweep over log2(T) rounds
-        levels = max(1, int(np.log2(max(2, n_tiles))))
-        for lvl in range(levels):
-            stride = 1 << lvl
-            for t, b in enumerate(builders):
-                if (t % (stride * 2)) == 0 and t + stride < n_tiles:
-                    b.recv(t + stride, radix * 4)
-                elif (t % (stride * 2)) == stride:
-                    b.send(t - stride, radix * 4)
-            for b in builders:
-                b.bblock(radix, radix)
-        for lvl in reversed(range(levels)):
-            stride = 1 << lvl
-            for t, b in enumerate(builders):
-                if (t % (stride * 2)) == 0 and t + stride < n_tiles:
-                    b.send(t + stride, radix * 4)
-                elif (t % (stride * 2)) == stride:
-                    b.recv(t - stride, radix * 4)
+        _prefix_tree(builders, n_tiles, radix)
         _barrier(builders)
         # permutation: measured ~4.1 records per key (load, digit
         # extract, address arithmetic, ranked store) alongside the

@@ -227,13 +227,31 @@ def test_ref_default_64_compiles(one_chip):
     _fits(_report("ref-default-64", _compile_run(sim, one_chip)))
 
 
-@pytest.mark.slow
-def test_hop_by_hop_256_compiles(one_chip):
-    """Graduated config 3: 256-tile emesh_hop_by_hop RADIX."""
+def _hbh256(barrier_host):
+    """Graduated config 3, 256-tile emesh_hop_by_hop RADIX at SPLASH-2's
+    size: `hbh-256-radix` (benchmark/configs), target text and trace."""
     sc = SimConfig(ConfigFile.from_string(config_text(
         256, network="emesh_hop_by_hop")))
-    sim = Simulator(sc, radix_trace(256, keys_per_tile=1024))
-    _fits(_report("hbh-256", _compile_run(sim, one_chip)))
+    return Simulator(sc, radix_trace(256, keys_per_tile=4096, radix=1024),
+                     barrier_host=barrier_host)
+
+
+@pytest.mark.slow
+def test_hop_by_hop_256_compiles(one_chip):
+    """The single region `tools/graduated.py` config 3 dispatches."""
+    _fits(_report("hbh-256", _compile_run(_hbh256(None), one_chip)))
+
+
+@pytest.mark.slow
+def test_hbh256_radix_host_batch_compiles(one_chip):
+    """The host-driven program the cell `hbh256-radix` dispatches: the
+    dense contention under both of its scopes, no memory engine."""
+    sim = _hbh256(True)
+    assert sim.barrier_host and sim.params.mem is None
+    compiled = _compile_host_batch(sim, one_chip)
+    _fits(_report("hbh-256-host-batch", compiled))
+    text = compiled.as_text()
+    assert "gt.net.hbh.scan" in text and "gt.net.hbh.commit" in text
 
 
 def _coh1024(barrier_host):

@@ -12,7 +12,8 @@ import pytest
 
 from graphite_tpu.config import ConfigFile
 from graphite_tpu.models.queue_models import (
-    QueueParams, compute_queue_delay, make_queues,
+    QueueParams, _ceil_div_bounded, _mg1_wait, compute_queue_delay,
+    make_queues,
 )
 
 
@@ -83,6 +84,44 @@ class TestMG1:
         expect = 0.5 * mu * lam_exp * (1 / mu**2) / (mu - lam_exp)
         tail = delays[-5:]
         assert all(abs(d - expect) <= 2 for d in tail), (tail, expect)
+
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_wait_is_the_formulas_exact_value(self, seed):
+        """The waiting time is integer arithmetic (PR 42: the TPU's
+        emulated float64 read another cycle than the CPU's on some
+        inputs), equal to the reference's formula taken as a fraction -
+        the double evaluation reads one cycle more on most inputs whose
+        exact value is a whole number."""
+        from fractions import Fraction
+        from math import ceil
+
+        rng = np.random.default_rng(seed)
+        n = rng.integers(1, 3000, 400)
+        procs = [rng.choice([2, 9, 65, 513], k) for k in n]
+        st = np.array([p.sum() for p in procs])
+        st2 = np.array([(p * p).sum() for p in procs])
+        newest = (st * rng.uniform(0.3, 3.0, 400)).astype(np.int64)
+        got = np.asarray(_mg1_wait(*map(jnp.asarray, (n, st, st2, newest))))
+        for i in range(400):
+            mu = Fraction(int(n[i]), int(st[i]))
+            lam = min(Fraction(int(n[i]), max(int(newest[i]), 1))
+                      if newest[i] else 2 * mu, Fraction(999, 1000) * mu)
+            var = Fraction(int(st2[i]), int(n[i])) - 1 / (mu * mu)
+            want = ceil(Fraction(1, 2) * mu * lam * (1 / (mu * mu) + var)
+                        / (mu - lam))
+            assert got[i] == want, (i, n[i], st[i], st2[i], newest[i])
+        assert int(_mg1_wait(*map(jnp.asarray, ([0], [0], [0], [0])))[0]) == 0
+
+    def test_bounded_division(self):
+        rng = np.random.default_rng(3)
+        num = rng.integers(0, 2**44, 4000)
+        den = np.concatenate([rng.integers(1, 1000, 2000),
+                              rng.integers(1, 2**34, 2000)])
+        got = np.asarray(_ceil_div_bounded(jnp.asarray(num), jnp.asarray(den)))
+        want = np.minimum(-(-num // den), 2**32 - 1)
+        np.testing.assert_array_equal(got, want)
+        assert (want == 2**32 - 1).any() and (want < 1000).any()
 
 
 class TestHistoryWindowed:

@@ -18,28 +18,39 @@ from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.memory import engine, engine_shl2
 from graphite_tpu.memory.engine import PHASE_NAMES
 from graphite_tpu.memory.engine_shl2 import SHL2_PHASE_NAMES
-from graphite_tpu.models import iocoom
+from graphite_tpu.models import iocoom, network_hop_by_hop
 from graphite_tpu.obs import TelemetrySpec, scopes
 from graphite_tpu.parallel import px
 from graphite_tpu.tools._template import config_text
-from graphite_tpu.trace.benchmarks import fft_trace
+from graphite_tpu.trace.benchmarks import fft_trace, radix_trace
 
 TILES = 16
 MSI = "pr_l1_pr_l2_dram_directory_msi"
 SHL2 = "pr_l1_sh_l2_msi"
-SCOPED_MODULES = (step, engine, engine_shl2, iocoom, px)
+SCOPED_MODULES = (step, engine, engine_shl2, iocoom, network_hop_by_hop, px)
 
 # what each program must contain: everything but the scopes whose code it
 # does not run
 ONLY_SHARDED = {"gt.px"}
 ONLY_SHL2 = {"gt.mem.dir_apply"}     # the embedded directory's landing
+# the two halves of emesh_hop_by_hop's dense contention (PR 42)
+ONLY_HBH = {"gt.net.hbh.scan", "gt.net.hbh.commit"}
 MSI_SCOPES = [s for s in scopes.SCOPES
-              if s not in ONLY_SHARDED | ONLY_SHL2]
-SHL2_SCOPES = [s for s in scopes.SCOPES if s not in ONLY_SHARDED
+              if s not in ONLY_SHARDED | ONLY_SHL2 | ONLY_HBH]
+SHL2_SCOPES = [s for s in scopes.SCOPES if s not in ONLY_SHARDED | ONLY_HBH
                | {"gt.core.iocoom", "gt.mem.stage_flush", "gt.obs"}]
+# the memoryless hop-by-hop target (`hbh256-radix`'s, at 16 tiles): the
+# core, the mailboxes, the route with its two halves, the barrier
+HBH_SCOPES = ["gt.quantum", "gt.fetch", "gt.core", "gt.net.mailbox",
+              "gt.net.route", "gt.sync.barrier"] + sorted(ONLY_HBH)
 
 
 def build(program: str) -> Simulator:
+    if program == "hbh":
+        text = config_text(TILES, network="emesh_hop_by_hop")
+        return Simulator(SimConfig(ConfigFile.from_string(text)),
+                         radix_trace(TILES, keys_per_tile=64),
+                         barrier_host=True)
     batch = fft_trace(n_tiles=TILES, points_per_tile=64, use_memory=True)
     if program == "shl2":
         text = config_text(TILES, shared_mem=True, protocol=SHL2)
@@ -100,6 +111,16 @@ def test_shared_l2_program_names_scope(found, name):
     assert name in found("shl2")
 
 
+@pytest.mark.parametrize("name", HBH_SCOPES)
+def test_hop_by_hop_program_names_scope(found, name):
+    assert name in found("hbh")
+
+
+@pytest.mark.parametrize("program", ["msi", "shl2"])
+def test_hop_counter_programs_have_no_hop_by_hop_half(found, program):
+    assert not ONLY_HBH & found(program)
+
+
 def test_sharded_program_names_the_exchange(found):
     assert "gt.px" in found("msi-sharded")
 
@@ -149,6 +170,11 @@ def test_cache_tag_follows_the_registry():
     ("jit(run)/gt.quantum/while/body/gt.core/gt.mem.requester_fill/add",
      "gt.mem.requester_fill"),
     ("jit(run)/gt.quantum/while/body/gt.core/gt.typo/add", "gt.core"),
+    ("jit(qrun)/gt.quantum/while/body/gt.core/gt.net.mailbox/cond/"
+     "branch_1_fun/gt.net.route/gt.net.hbh.scan/cummax", "gt.net.hbh.scan"),
+    ("jit(qrun)/gt.quantum/while/body/gt.core/gt.net.mailbox/cond/"
+     "branch_1_fun/gt.net.route/gt.net.hbh.commit/reduce_max",
+     "gt.net.hbh.commit"),
     ("jit(run)/while/body/add", None),
     ("", None),
 ])
@@ -170,7 +196,7 @@ def scopes_off(monkeypatch):
         yield
 
 
-@pytest.mark.parametrize("program", ["msi", "shl2"])
+@pytest.mark.parametrize("program", ["msi", "shl2", "hbh"])
 def test_scopes_change_no_equation(monkeypatch, program):
     scoped = build(program).lower()[0]
     with scopes_off(monkeypatch):
