@@ -58,6 +58,7 @@ from flax import struct
 from graphite_tpu.intmath import nn_div, nn_mod
 
 from graphite_tpu.memory import cache_array as ca
+from graphite_tpu.memory import row_landing
 from graphite_tpu.memory.cache_array import (
     INVALID, MODIFIED, OWNED, SHARED, state_readable, state_writable,
 )
@@ -676,7 +677,9 @@ def mem_idle_out(mp: MemParams, ms, rec: "RecView", enabled,
 # rows (`_stage_put`); the engine's sharers reads overlay them
 # (`_stage_overlay_rows`); `dir_stage_flush` applies the rows to the big
 # store once per inner_block iterations (engine/step._quantum_loop), one
-# amortized dense pass instead of 3*inner_block.
+# amortized dense pass instead of 3*inner_block — and, where the program
+# is lowered for a TPU, no pass at all: a kernel lands the staged slots'
+# tiles alone (`row_landing.flush_staged`, PR 43).
 #
 # The table is [T, c] per home lane (c = writes_per_iter *
 # inner_block).  Every directory write is home-lane-local, so a put is
@@ -745,7 +748,7 @@ def _stage_overlay_rows(d, sets, rows):
     return out.reshape(T, K, DW * SW)
 
 
-def dir_stage_flush(d, live=None):
+def dir_stage_flush(d, live=None, px: ParallelCtx = IDENT):
     """Apply the staging rows to the big sharers store and reset them.
 
     `live` (a scalar bool, or None = forced live) gates the whole flush
@@ -753,47 +756,20 @@ def dir_stage_flush(d, live=None):
     only where no slot was staged since the last flush, and then every
     key is -1, every slot is dropped and the reset writes what is there.
 
-    ROW-form add-a-delta: gather each staged slot's whole [DW*SW] set
-    row (structured [t, s] row indexing — the fast TPU gather path; the
-    3D element-index form measured 90 ms/flush, PERF.md round-5), expand
-    the slot's delta into its way's column, and scatter-add rows back.
-    Only each key's LAST slot within its lane row applies (later slots
+    Only each key's LAST slot within its lane row counts (later slots
     overwrite earlier ones, the append-order analog of the old layout's
-    in-place overwrite); two applied slots in the same set touch
-    disjoint way columns, so duplicate (t, s) row adds stay exact; empty
-    and superseded slots add zero out of bounds (dropped).  The add
-    aliases the loop-carried buffer in place."""
+    in-place overwrite).  `row_landing.flush_staged` owns the two forms
+    and the choice between them: a scatter-add of row deltas, a pass
+    over the store whatever was staged (19 ms at 1,024 tiles), and where
+    the program is lowered for a TPU a kernel that moves the staged
+    slots' tiles alone."""
     if d.skey is None:
         return d
-    T, DS, DW = d.entry.shape
-    SW = d.sval.shape[2]
-    C = d.skey.shape[1]
-    tiles = np.arange(T, dtype=np.int32)[:, None]
 
     def flush(stores):
         sharers, skey, sn = stores
-        valid = skey >= 0                                     # [T, c]
-        key = jnp.where(valid, skey, 0)
-        w = nn_mod(key, DW)
-        s = nn_div(key, DW)
-        # a slot applies iff no LATER slot in its lane row stages the
-        # same key
-        later = (valid[:, :, None] & valid[:, None, :]
-                 & (key[:, :, None] == key[:, None, :])
-                 & (np.arange(C)[None, None, :]
-                    > np.arange(C)[None, :, None]))
-        is_last = valid & ~later.any(axis=2)
-        row = sharers[tiles, s]                               # [T, c, DW*SW]
-        row3 = row.reshape(T, C, DW, SW)
-        cur = jnp.take_along_axis(
-            row3, w[:, :, None, None], axis=2)[:, :, 0]
-        delta = jnp.where(is_last[..., None], d.sval - cur, jnp.uint32(0))
-        onehot = (np.arange(DW, dtype=np.int32)[None, None, :, None]
-                  == w[:, :, None, None])
-        row_delta = jnp.where(onehot, delta[:, :, None, :],
-                              jnp.uint32(0)).reshape(T, C, DW * SW)
-        s_oob = jnp.where(is_last, s, DS)          # dropped when superseded
-        return (sharers.at[tiles, s_oob].add(row_delta, mode="drop"),
+        return (row_landing.flush_staged(sharers, skey, d.sval, sn,
+                                         sim_axis=px.sim_axis),
                 jnp.full_like(skey, -1), jnp.zeros_like(sn))
 
     sharers, skey, sn = _run_if(live, flush, (d.sharers, d.skey, d.sn))

@@ -1,5 +1,9 @@
-"""Land a plan of row deltas on a resident row-flat store, touching only
-the plan's rows: the Pallas TPU kernel behind `engine_shl2._dir_apply_rows`.
+"""Land sparse updates on a resident store, touching only the rows they
+name: the Pallas TPU kernels behind `engine_shl2._dir_apply_rows`
+(`land_rows`: a plan of row deltas, one row a slab) and behind
+`engine.dir_stage_flush` (`flush_staged`, further down: the private-L2
+directory's staging table, with the XLA form it replaces on the chip and
+the choice between the two).
 
 An XLA scatter-add of 1,024 rows of 1 KB onto the `u32[1048576, 256]`
 sharers store is in place and still costs what streaming the 1.07 GB
@@ -14,10 +18,15 @@ delta added in VMEM, one group DMA back.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from graphite_tpu.intmath import nn_div, nn_mod
 
 GROUP = 8   # rows of one (8, 128) HBM tile: the unit a DMA can move
 
@@ -121,3 +130,233 @@ def land_rows(store, rows, delta, *, rows_per_step=ROWS_PER_STEP,
         name="dir_row_landing",
         interpret=interpret,
     )(rows.astype(jnp.int32), store, delta)
+
+
+# ---------------------------------------------------------------------------
+# the private-L2 directory's staging flush (engine.dir_stage_flush)
+#
+# As one XLA scatter-add of row deltas (`scatter_staged`) a flush of the
+# `u32[1024, 1024, 512]` sharers store gathers and expands a 2 KB row for
+# each of the table's 98,304 slots (201 MB each) and passes over the 2.1 GB
+# store: 19-20 ms on a v5e whatever was staged, an empty table too
+# (`_hand/flush43.py`; PERF.md §6, PR 43).  A block of `memstress1024-coh`
+# stages ~2,100 slots.  `land_staged` moves those alone: a slot's way is 32
+# words of one (8, 128) HBM tile, so one 4 KB tile DMA in, the way's words
+# overwritten in VMEM, one tile DMA back - 0.28 ms for 2,161 slots, 0.41 ms
+# for 3,577, 0.08 ms + 90 ns a slot.  A lane's slots may share a tile (two
+# ways of a set, two sets of a group) and a key may repeat, the LATEST slot
+# winning; lanes never share one.  So slot index c runs outermost and in
+# turn - step c drained before step c + 1 fetches - and lanes go together
+# within a step.
+# ---------------------------------------------------------------------------
+
+# home lanes landed per grid step: the VMEM scratch is [lanes * GROUP, 128]
+# (4 MB at 1,024), under the v5e's 16 MB scoped limit
+LANES_PER_STEP = 1024
+
+
+def can_land_staged(n_lanes, n_sets, n_ways, way_width) -> bool:
+    """Whether `land_staged` takes a `[n_lanes, C]` staging table of
+    `way_width`-word slots for a `[n_lanes, n_sets, n_ways * way_width]`
+    sharers store: lane-aligned rows, ways that tile a 128-word column
+    exactly, and lanes of whole groups, so that no two LANES share a
+    group (a lane's own slots do: the kernel takes them in turn)."""
+    return ((n_ways * way_width) % 128 == 0 and 128 % way_width == 0
+            and n_sets % GROUP == 0
+            and (n_lanes <= LANES_PER_STEP
+                 or n_lanes % LANES_PER_STEP == 0))
+
+
+def _staged_kernel(count_ref, store_ref, code_ref, val_ref, out_ref, buf,
+                   sem_in, sem_out, *, n_ways, way_width):
+    del store_ref   # aliased to `out_ref`
+    # (x64 is on package-wide: every Python int is wrapped, see above)
+    i32 = jnp.int32
+    lanes = i32(val_ref.shape[1])
+    c, j = pl.program_id(0), pl.program_id(1)
+    zero = i32(0)
+    # the lanes come sorted by how many slots they staged, so the lanes
+    # with a slot `c` are the first `count[c]`
+    n = jnp.clip(count_ref[c] - j * lanes, zero, lanes)
+    ways_per_col = i32(128 // way_width)
+
+    def entry(r):
+        # slot r's flat entry index -> (row of the row-flat store, way)
+        code = code_ref[0, 0, r]
+        return jax.lax.div(code, i32(n_ways)), jax.lax.rem(code, i32(n_ways))
+
+    def tile(r):
+        # the (GROUP, 128) HBM tile slot r's way lies in
+        row, way = entry(r)
+        col = jax.lax.div(way, ways_per_col) * i32(128)
+        return (pl.ds(pl.multiple_of(row - jax.lax.rem(row, i32(GROUP)),
+                                     GROUP), GROUP),
+                pl.ds(pl.multiple_of(col, 128), 128))
+
+    def slot(r):
+        return buf.at[pl.ds(pl.multiple_of(r * i32(GROUP), GROUP), GROUP)]
+
+    def fetch(r):
+        # from the OUTPUT, which is the store (aliased): a later slot of a
+        # lane has to find the earlier ones landed
+        return pltpu.make_async_copy(out_ref.at[tile(r)], slot(r), sem_in)
+
+    def write(r):
+        return pltpu.make_async_copy(slot(r), out_ref.at[tile(r)], sem_out)
+
+    def loop(body):
+        jax.lax.fori_loop(zero, n, lambda r, carry: (body(r), carry)[1], zero)
+
+    # a step's tiles all in flight on ONE semaphore, then all waited for
+    # (every copy is one tile: a wait is told apart by its size alone);
+    # the step's writes are drained before the next step fetches, so slot
+    # c + 1 of a lane finds slot c landed
+    loop(lambda r: fetch(r).start())
+    loop(lambda r: fetch(zero).wait())
+
+    way_of_word = jax.lax.div(
+        jax.lax.broadcasted_iota(i32, (1, 128), 1), i32(way_width))
+
+    def overwrite(r):
+        row, way = entry(r)
+        at = pl.ds(r * i32(GROUP) + jax.lax.rem(row, i32(GROUP)), 1)
+        buf[at, :] = jnp.where(
+            way_of_word == jax.lax.rem(way, ways_per_col),
+            val_ref[0, pl.ds(r, 1), :], buf[at, :])
+
+    loop(overwrite)
+    loop(lambda r: write(r).start())
+    loop(lambda r: write(zero).wait())
+
+
+def scatter_staged(sharers, skey, sval):
+    """The staging table applied as ONE scatter-add of row deltas: the
+    XLA form, a pass over the store whatever was staged.
+
+    ROW-form add-a-delta: gather each staged slot's whole [DW*SW] set
+    row (structured [t, s] row indexing — the fast TPU gather path; the
+    3D element-index form measured 90 ms/flush, PERF.md round-5), expand
+    the slot's delta into its way's column, and scatter-add rows back.
+    Only each key's LAST slot within its lane row applies; two applied
+    slots in the same set touch disjoint way columns, so duplicate
+    (t, s) row adds stay exact; empty and superseded slots add zero out
+    of bounds (dropped).  The add aliases the loop-carried buffer in
+    place."""
+    T, DS, _ = sharers.shape
+    C, SW = sval.shape[1:]
+    DW = sharers.shape[2] // SW
+    tiles = np.arange(T, dtype=np.int32)[:, None]
+    valid = skey >= 0                                     # [T, c]
+    key = jnp.where(valid, skey, 0)
+    w = nn_mod(key, DW)
+    s = nn_div(key, DW)
+    # a slot applies iff no LATER slot in its lane row stages the
+    # same key
+    later = (valid[:, :, None] & valid[:, None, :]
+             & (key[:, :, None] == key[:, None, :])
+             & (np.arange(C)[None, None, :]
+                > np.arange(C)[None, :, None]))
+    is_last = valid & ~later.any(axis=2)
+    row = sharers[tiles, s]                               # [T, c, DW*SW]
+    row3 = row.reshape(T, C, DW, SW)
+    cur = jnp.take_along_axis(
+        row3, w[:, :, None, None], axis=2)[:, :, 0]
+    delta = jnp.where(is_last[..., None], sval - cur, jnp.uint32(0))
+    onehot = (np.arange(DW, dtype=np.int32)[None, None, :, None]
+              == w[:, :, None, None])
+    row_delta = jnp.where(onehot, delta[:, :, None, :],
+                          jnp.uint32(0)).reshape(T, C, DW * SW)
+    s_oob = jnp.where(is_last, s, DS)          # dropped when superseded
+    return sharers.at[tiles, s_oob].add(row_delta, mode="drop")
+
+
+def land_staged(sharers, skey, sval, sn, *, lanes_per_step=LANES_PER_STEP,
+                interpret=False):
+    """The sharers store with the staging table applied: every staged
+    slot's `way_width` words overwrite its way's column of its set row,
+    slot index outermost and in turn, so a key's LATEST slot wins — in
+    place, priced by the slots staged.
+
+    sharers: u32[T, DS, DW * SW]; skey: int32[T, C] (set * DW + way, the
+    first `sn[t]` of a lane's slots live); sval: u32[T, C, SW]; sn:
+    int32[T].  `can_land_staged(T, DS, DW, SW)` must hold."""
+    n_lanes, n_sets, width = sharers.shape
+    cap, way_width = sval.shape[1:]
+    n_ways = width // way_width
+    step = min(n_lanes, lanes_per_step)
+    if (not can_land_staged(n_lanes, n_sets, n_ways, way_width)
+            or n_lanes % step):
+        raise ValueError(f"land_staged: store {sharers.shape}, table "
+                         f"{sval.shape}")
+    i32 = jnp.int32
+    # lanes by falling `sn` (a stable sort: a function of the table
+    # alone), so that step c's live lanes are a prefix
+    order = jnp.argsort(-sn, stable=True).astype(i32)
+    count = jnp.sum(sn[None, :] > jnp.arange(cap, dtype=i32)[:, None],
+                    axis=1, dtype=i32)
+    # a slot's flat ENTRY index: (lane * DS + set) * DW + way
+    code = (order[:, None] * i32(n_sets * n_ways) + skey[order]).T
+    # a slot's words, repeated across a 128-word column: the kernel keeps
+    # the way's copy
+    val = jnp.tile(jnp.swapaxes(sval[order], 0, 1), (1, 1, 128 // way_width))
+
+    def block(c, j, count):
+        # a step with no live lane reads nothing: all of them name block
+        # (0, 0), so past the deepest lane the pipeline fetches no more
+        live = count[c] > j * i32(step)
+        return jnp.where(live, c, i32(0)), jnp.where(live, j, i32(0))
+
+    def code_block(c, j, count):
+        c, j = block(c, j, count)
+        return c, i32(0), j
+
+    def val_block(c, j, count):
+        return (*block(c, j, count), i32(0))
+
+    flat = pl.pallas_call(
+        functools.partial(_staged_kernel, n_ways=n_ways,
+                          way_width=way_width),
+        out_shape=jax.ShapeDtypeStruct((n_lanes * n_sets, width),
+                                       sharers.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(cap, n_lanes // step),
+            in_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),
+                # (a step's codes, not the table's: all [C, T] of them
+                # as a scalar-prefetch operand are 393 KB of the 1 MB of
+                # SMEM at the cell's shape, and over it at C = 384)
+                pl.BlockSpec((1, 1, step), code_block,
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, step, 128), val_block),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((step * GROUP, 128), sharers.dtype),
+                pltpu.SemaphoreType.DMA(()),
+                pltpu.SemaphoreType.DMA(()),
+            ],
+        ),
+        # operand 0 is the scalar-prefetched `count`
+        input_output_aliases={1: 0},
+        name="dir_stage_landing",
+        interpret=interpret,
+    )(count, sharers.reshape(n_lanes * n_sets, width), code[:, None, :], val)
+    return flat.reshape(sharers.shape)
+
+
+def flush_staged(sharers, skey, sval, sn, *, sim_axis=None):
+    """The sharers store after a staging flush, in place: where the
+    program is lowered for a TPU, has no sim axis and the table's shape
+    allows it (`can_land_staged`: 512 tiles and up under the default
+    directory), the kernel, priced by the slots staged; everywhere else
+    the scatter-add, priced by the store."""
+    n_lanes, n_sets, width = sharers.shape
+    way_width = sval.shape[2]
+    if sim_axis is not None or not can_land_staged(
+            n_lanes, n_sets, width // way_width, way_width):
+        return scatter_staged(sharers, skey, sval)
+    return jax.lax.platform_dependent(
+        sharers, skey, sval, sn, tpu=land_staged,
+        default=lambda sharers, skey, sval, sn: scatter_staged(
+            sharers, skey, sval))

@@ -262,13 +262,97 @@ def _coh1024(barrier_host):
                      barrier_host=barrier_host)
 
 
+def _flush_moves_the_staged_slots_alone(text, sharers, cap):
+    """PR 43, of a staged program compiled for the chip: the flush is ONE
+    `dir_stage_landing` call whose output IS the sharers store; nothing
+    under `gt.mem.stage_flush` computes an array of the store's shape any
+    more (the scatter-add's pass over it, `fusion u32[1048576,512]`), nor
+    a row a table slot (`[T, C, DW*SW]`: the 201 MB gather and
+    expansion); and no loop copies the store."""
+    from graphite_tpu.analysis.loop_copies import copies_of, loop_copies
+
+    T, DS, W = sharers
+    kernel, = _landing_kernels(text, "dir_stage_landing",
+                               "gt.mem.stage_flush")
+    assert f"u32[{T * DS},{W}]" in kernel.split(" custom-call(")[0]
+    assert "output_to_operand_aliasing={{}: (1, {})}" in kernel
+    stores = (f"u32[{T * DS},{W}]", f"u32[{T},{DS},{W}]")
+    for ln in text.splitlines():
+        if "gt.mem.stage_flush" not in ln or " = " not in ln:
+            continue
+        head = ln.split(" = ", 1)[1][:80]
+        assert not (head.startswith(stores) and " fusion(" in ln), ln[:300]
+        assert not head.startswith(f"u32[{T},{cap},{W}]"), ln[:300]
+    whole = copies_of(loop_copies(text), sharers, ("u32",))
+    assert not whole, [c.line[:200] for c in whole]
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_stage_flush_alone_is_in_place_at_the_cells_shape(topo, chips):
+    """One staging flush of `memstress1024-coh`'s directory, alone, as
+    the engine runs it - `dir_stage_flush` under its in-place gate - on
+    the cell's `u32[1024,1024,512]` sharers store (2.1 GB) and `[1024,
+    96]` table, asked of the TPU compiler: the table is applied through
+    the kernel (as an XLA scatter-add a flush is a pass over the store
+    and two 201 MB temporaries, 19 ms), in place, under the flush's scope.
+    Over four chips (`shard_map`, explicit `dir_stage=True` on a mesh:
+    no cell) each device flushes its own 256 lanes the same way."""
+    import jax.numpy as jnp
+
+    from graphite_tpu.memory.engine import dir_stage_flush
+    from graphite_tpu.memory.state import DirectoryArrays
+    from graphite_tpu.obs.scopes import scope
+    from graphite_tpu.parallel.mesh import TILE_AXIS, _shard_map
+    from graphite_tpu.parallel.px import ParallelCtx
+
+    T, DS, DW, SW, C = 1024, 1024, 16, 32, 96
+    d = DirectoryArrays(
+        entry=jax.ShapeDtypeStruct((T, DS, DW), jnp.int64),
+        sharers=jax.ShapeDtypeStruct((T, DS, DW * SW), jnp.uint32),
+        skey=jax.ShapeDtypeStruct((T, C), jnp.int32),
+        sval=jax.ShapeDtypeStruct((T, C, SW), jnp.uint32),
+        sn=jax.ShapeDtypeStruct((T,), jnp.int32))
+    live = jax.ShapeDtypeStruct((), jnp.bool_)
+    px = ParallelCtx(axis=TILE_AXIS, n_dev=chips)
+
+    def flush(d, live):
+        with scope("gt.mem.stage_flush"):
+            return dir_stage_flush(d, live, px=px)
+
+    if chips == 1:
+        where = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = Mesh(np.array(topo.devices), (TILE_AXIS,))
+        specs = (jax.tree.map(lambda _: P(TILE_AXIS), d), P())
+        flush = _shard_map(flush, mesh=mesh, in_specs=specs,
+                           out_specs=specs[0])
+        where = jax.tree.map(lambda p: NamedSharding(mesh, p), specs,
+                             is_leaf=lambda x: isinstance(x, P))
+    compiled = jax.jit(flush, donate_argnums=0).lower(
+        *_shapes((d, live), where)).compile()
+    row = _report(f"stage-flush-alone-1024-x{chips}", compiled)
+    Tl = T // chips
+    # what the kernel reads beside the store: the table sorted by lane
+    # and its slots' words repeated across a 128-word column (50 MB each
+    # at the cell's shape; 134 MB of temporaries in all, where the XLA
+    # flush's two `[T, C, DW*SW]` rows and its compare take 490 MB)
+    assert chips > 1 or row["temp_bytes"] < 160 << 20, row
+    _flush_moves_the_staged_slots_alone(compiled.as_text(),
+                                        (Tl, DS, DW * SW), C)
+
+
 @pytest.mark.slow
 def test_coh_1024_host_batch_compiles(one_chip):
     """1024 tiles, full directory: the bounded region the selection
-    rule picks (engine/step.barrier_host_batch)."""
+    rule picks (engine/step.barrier_host_batch); its staging flush goes
+    through the kernel (PR 43)."""
     sim = _coh1024(None)
     assert sim.barrier_host
-    _fits(_report("coh-1024-host-batch", _compile_host_batch(sim, one_chip)))
+    compiled = _compile_host_batch(sim, one_chip)
+    _fits(_report("coh-1024-host-batch", compiled))
+    d = sim.state.mem.directory
+    _flush_moves_the_staged_slots_alone(compiled.as_text(),
+                                        d.sharers.shape, d.skey.shape[1])
 
 
 def _shl2_memstress(tiles):
@@ -285,13 +369,14 @@ def _shl2_memstress(tiles):
         shared_fraction=0.5, seed=7), barrier_host=True)
 
 
-def _landing_kernels(text):
-    """The compiled program's row-landing kernel calls
-    (`memory/row_landing.py`), each named under `gt.mem.dir_apply`."""
+def _landing_kernels(text, name="dir_row_landing", scope="gt.mem.dir_apply"):
+    """The compiled program's calls of a `memory/row_landing.py` kernel
+    (the shared-L2 row landing, or `dir_stage_landing`), each named under
+    its scope."""
     calls = [ln for ln in text.splitlines()
-             if "tpu_custom_call" in ln and "dir_row_landing" in ln]
+             if "tpu_custom_call" in ln and name in ln]
     for ln in calls:
-        assert "/gt.mem.dir_apply/" in ln.split("op_name=")[1], ln[:400]
+        assert f"/{scope}/" in ln.split("op_name=")[1], ln[:400]
     return calls
 
 
@@ -431,7 +516,11 @@ def test_coh_1024_single_region_compiles(one_chip):
     """1024 tiles, full directory: the single-region lax_barrier
     program the selection rule avoids."""
     sim = _coh1024(False)
-    _fits(_report("coh-1024-single-region", _compile_run(sim, one_chip)))
+    compiled = _compile_run(sim, one_chip)
+    _fits(_report("coh-1024-single-region", compiled))
+    d = sim.state.mem.directory
+    _flush_moves_the_staged_slots_alone(compiled.as_text(),
+                                        d.sharers.shape, d.skey.shape[1])
 
 
 @pytest.mark.slow

@@ -396,12 +396,15 @@ def test_cell_reports_what_the_manifest_lists():
             assert os.path.exists(os.path.join(
                 BENCH, "layer_metrics", m["name"] + ".py")), m["name"]
     # appended: the new cell is the last of every list it joined, and the
-    # four new metrics are the manifest's last four
+    # four new metrics came last, in this order (PR 43's follows them)
     assert MANIFEST["workloads"][-1]["name"] == CELL_NAME
     assert MANIFEST["configs"][-1]["name"] == NAME
-    assert [m["name"] for m in MANIFEST["per_layer"][-4:]] == [
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    first = names.index("hbh_scan_busy_share")
+    assert names[first:first + 4] == [
         "hbh_scan_busy_share", "hbh_commit_busy_share",
         "noc_contention_share", "noc_fallback_share"]
+    assert names[first + 4:] == ["stage_flush_busy_share"]
     for m in MANIFEST["per_layer"] + MANIFEST["end_to_end"]:
         if CELL_NAME in m.get("workloads", []):
             assert m["workloads"][-1] == CELL_NAME, m["name"]

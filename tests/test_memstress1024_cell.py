@@ -200,6 +200,35 @@ def test_layer_metric_readers(name, own, want, runs):
     assert getattr(own.get("sim"), "runs", 0) == runs
 
 
+@pytest.mark.parametrize("scoped,want", [
+    (True, 5.0),        # 1.0 s under `gt.mem.stage_flush` of 20.0 s busy
+    (False, None),      # a program that stages nothing: no such scope
+    (None, None),       # a run without a scope trace
+], ids=["staged", "unstaged", "untraced"])
+def test_stage_flush_reader(scoped, want):
+    """PR 43's `stage_flush_busy_share`: the flush's scope by itself,
+    in the staged cells (`mem_ungated_busy_share` holds it with
+    `gt.mem.base`); nothing where the program has no such scope."""
+    entry, = [m for m in MANIFEST["per_layer"]
+              if m["name"] == "stage_flush_busy_share"]
+    assert entry["workloads"] == [CELL_NAME, "a2a1024-fftskel"]
+    assert (entry["moves"], entry["better"]) == ("sim_records_per_s",
+                                                 "lower")
+    assert entry == MANIFEST["per_layer"][-1]       # appended
+    ctx = _ctx()
+    if scoped is None:
+        ctx.own["scope_trace"] = None
+    elif not scoped:
+        del ctx.own["scope_trace"]["busy_s"]["gt.mem.stage_flush"]
+    sys.path.insert(0, BENCH)
+    try:
+        got = paths.load_module(
+            "layer_metrics", "stage_flush_busy_share").read(ctx)
+    finally:
+        sys.path.remove(BENCH)
+    assert got == (None if want is None else pytest.approx(want))
+
+
 def test_readers_find_nothing_in_an_older_program():
     """The driver runs the benchmark's files over the parent too: where
     the program has no such counter or results, a reader returns None."""

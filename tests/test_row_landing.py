@@ -21,6 +21,20 @@ TPU compiler is asked about it in `tests/test_chip_compile.py`.
 - the auditor accepts the program: its walkers read through a
   `pallas_call`, and the cond-payload rule tells the choice of a lowering
   platform from a run-time `lax.cond`.
+
+The private-L2 directory's staging flush (PR 43): `land_staged` against
+the XLA flush (`scatter_staged`) it replaces on the chip, the same way -
+
+- after a flush through the kernel the sharers store, `skey` and `sn` are
+  bit for bit the XLA flush's: a key repeated in a lane (the latest slot
+  wins), two ways of one set, two sets of one 8-row group, an empty table,
+  a full lane, more than one grid step, the gate open and closed;
+- a staged private-L2 run (16 tiles, a 128-way directory so that a sharers
+  row is lane-aligned) with every flush through the kernel ends in the
+  state the XLA flush ends in;
+- at 16 tiles with the default directory, and under a sim axis, the flush's
+  jaxpr is the parent's letter for letter; at a lane-aligned shape the
+  default arm is, and the CPU lowers it and a TPU target the kernel.
 """
 
 import dataclasses
@@ -34,10 +48,11 @@ import pytest
 from graphite_tpu.config import ConfigFile, SimConfig
 from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.memory import engine_shl2, row_landing
-from graphite_tpu.memory.engine import _run_if
+from graphite_tpu.memory.engine import _run_if, dir_stage_flush
 from graphite_tpu.memory.engine_shl2 import (
     ShL2Dir, _dir_apply_rows, _scatter_add_rows,
 )
+from graphite_tpu.memory.state import DirectoryArrays
 from graphite_tpu.parallel.px import IDENT
 from graphite_tpu.tools._template import config_text
 from graphite_tpu.trace.synthetic import memory_stress_trace
@@ -227,3 +242,289 @@ def test_cond_payload_tells_a_platform_choice_from_a_cond():
     assert not cond_payload(jax.make_jaxpr(chosen)(big), **kw)
     found = cond_payload(jax.make_jaxpr(gated)(big, True), **kw)
     assert [f.rule for f in found] == ["cond-payload"]
+
+
+# ---------------------------------------------------------------------------
+# the staging flush of the private-L2 directory (PR 43)
+# ---------------------------------------------------------------------------
+
+# 16 lanes of 16 sets, 8 ways of 16 words: a sharers row is one lane tile
+# and a 128-word column holds all 8 ways; a table of 12 slots a lane
+FT, FDS, FDW, FSW, FC = 16, 16, 8, 16, 12
+
+
+def _table(case):
+    """(skey, sn) of a staging table as `_stage_put` leaves one: a lane's
+    first `sn` slots live, the rest -1."""
+    rng = np.random.default_rng(43)
+    sn = np.zeros(FT, np.int32)
+    skey = np.full((FT, FC), -1, np.int32)
+
+    def put(lane, keys):
+        sn[lane] = len(keys)
+        skey[lane, :len(keys)] = keys
+
+    key = lambda s, w: s * FDW + w                      # noqa: E731
+    if case == "repeated_key":
+        put(2, [key(5, 3), key(9, 1), key(5, 3), key(5, 3)])
+        put(7, [key(0, 0), key(0, 0)])
+    elif case == "two_ways_one_set":
+        put(4, [key(6, 0), key(6, 7), key(6, 2)])
+    elif case == "two_sets_one_group":
+        put(1, [key(8, 5), key(9, 5), key(15, 5), key(8, 4)])
+        put(2, [key(0, 1), key(7, 1)])
+    elif case == "full_lane":
+        put(3, rng.integers(0, 24, FC))     # 24 keys: sets 0-2, repeats
+        put(11, [key(2, 2)])
+    elif case == "random":
+        for lane in range(FT):
+            put(lane, rng.integers(0, FDS * FDW, rng.integers(0, FC + 1)))
+    else:
+        assert case == "empty_table"
+    return skey, sn
+
+
+def _staged_dir(case):
+    rng = np.random.default_rng(4300)
+    skey, sn = _table(case)
+    return DirectoryArrays(
+        entry=jnp.zeros((FT, FDS, FDW), jnp.int64),
+        sharers=jnp.asarray(rng.integers(
+            0, 2**32, (FT, FDS, FDW * FSW), dtype=np.uint32)),
+        skey=jnp.asarray(skey), sn=jnp.asarray(sn),
+        # (dead slots hold what earlier blocks staged: noise)
+        sval=jnp.asarray(rng.integers(0, 2**32, (FT, FC, FSW),
+                                      dtype=np.uint32)))
+
+
+def _flush_through(form, monkeypatch):
+    """`engine.dir_stage_flush`, jitted, with the store's update forced
+    through `form` (the chooser stepped over)."""
+    monkeypatch.setattr(
+        row_landing, "flush_staged",
+        lambda sharers, skey, sval, sn, sim_axis=None: form(
+            sharers, skey, sval, sn))
+    return jax.jit(dir_stage_flush)
+
+
+@pytest.mark.parametrize("case,step,gate", [
+    ("repeated_key", row_landing.LANES_PER_STEP, None),
+    ("two_ways_one_set", row_landing.LANES_PER_STEP, None),
+    ("two_sets_one_group", row_landing.LANES_PER_STEP, None),
+    ("empty_table", row_landing.LANES_PER_STEP, None),
+    ("full_lane", row_landing.LANES_PER_STEP, None),
+    ("random", 8, None),                   # two grid steps a slot index
+    ("random", row_landing.LANES_PER_STEP, True),
+    ("random", row_landing.LANES_PER_STEP, False),
+])
+def test_staged_kernel_lands_what_the_xla_flush_lands(case, step, gate,
+                                                      monkeypatch):
+    d = _staged_dir(case)
+    live = None if gate is None else jnp.asarray(gate)
+    want = _flush_through(
+        lambda sharers, skey, sval, sn: row_landing.scatter_staged(
+            sharers, skey, sval), monkeypatch)(d, live)
+    got = _flush_through(functools.partial(
+        row_landing.land_staged, lanes_per_step=step, interpret=True),
+        monkeypatch)(d, live)
+    if gate is False:
+        want = d        # a closed gate leaves store and table as they are
+    else:
+        assert not np.asarray(want.sn).any()
+        assert (np.asarray(want.skey) == -1).all()
+        touched = (np.asarray(want.sharers) != np.asarray(d.sharers)).any()
+        assert touched == (case != "empty_table")
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if case == "repeated_key":
+        # the LATEST slot of the key is what the row holds
+        s, w = 5, 3
+        np.testing.assert_array_equal(
+            np.asarray(got.sharers)[2, s, w * FSW:(w + 1) * FSW],
+            np.asarray(d.sval)[2, 3])
+
+
+def test_staged_kernel_refuses_what_it_cannot_land():
+    assert row_landing.can_land_staged(1024, 1024, 16, 32)   # the cell
+    assert row_landing.can_land_staged(256, 1024, 16, 32)    # its quarter
+    assert row_landing.can_land_staged(FT, FDS, FDW, FSW)
+    assert not row_landing.can_land_staged(64, 64, 16, 2)    # 64 tiles
+    assert not row_landing.can_land_staged(FT, 12, FDW, FSW)  # cut group
+    assert not row_landing.can_land_staged(FT, FDS, 16, 24)  # way / column
+    assert not row_landing.can_land_staged(1536, FDS, FDW, FSW)
+    d = _staged_dir("random")
+    with pytest.raises(ValueError):
+        row_landing.land_staged(d.sharers[:, :, :64], d.skey,
+                                d.sval[:, :, :8], d.sn)
+
+
+# a directory of 8 sets x 128 ways a slice: at 16 tiles (one sharer word a
+# way) a sharers row of 128 words - the smallest lane-aligned private-L2
+# directory
+WIDE_DIRECTORY = "[dram_directory]\ntotal_entries = 1024\nassociativity = 128\n"
+
+
+def _staged_sim(**kw):
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        16, core="simple", shared_mem=True, clock_scheme="lax_barrier")
+        + WIDE_DIRECTORY))
+    return Simulator(sc, memory_stress_trace(
+        16, n_accesses=24, working_set_bytes=8192, write_fraction=0.4,
+        shared_fraction=0.5, seed=7), mem_gate_bytes=0, dir_stage=True,
+        inner_block=4, **kw)
+
+
+def test_staged_engine_through_the_kernel_ends_where_the_xla_flush_ends(
+        monkeypatch):
+    """On the CPU `platform_dependent` lowers its default branch, the XLA
+    flush; with the chooser swapped for the interpreted kernel every flush
+    of a run goes through the kernel's body."""
+    plain = _staged_sim()
+    d = plain.state.mem.directory
+    assert d.sharers.shape == (16, 8, 128) and d.skey.shape == (16, 12)
+    assert row_landing.can_land_staged(16, 8, 128, 1)
+    res_plain = plain.run()
+    calls = []
+
+    def through_kernel(sharers, skey, sval, sn, sim_axis=None):
+        calls.append((sharers.shape, sim_axis))
+        return row_landing.land_staged(sharers, skey, sval, sn,
+                                       interpret=True)
+
+    monkeypatch.setattr(row_landing, "flush_staged", through_kernel)
+    kernel = _staged_sim()
+    res_kernel = kernel.run()
+    assert calls and set(calls) == {((16, 8, 128), None)}
+    assert plain.last_base_skips["flush"] < plain.last_n_iterations // 4
+    assert int(np.asarray(plain.state.mem.directory.sharers).any())
+    assert res_plain.mem_counters["dir_accesses"].sum() > 0
+    for f in dataclasses.fields(res_plain):     # every statistic
+        a, b = getattr(res_plain, f.name), getattr(res_kernel, f.name)
+        for k in (a if isinstance(a, dict) else [None]):
+            np.testing.assert_array_equal(
+                np.asarray(a if k is None else a[k]),
+                np.asarray(b if k is None else b[k]), err_msg=f.name)
+    for a, b in zip(jax.tree.leaves(plain.state),
+                    jax.tree.leaves(kernel.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_auditor_reads_through_the_staged_kernel():
+    """The flush's choice of a lowering platform returns the sharers
+    store - which no run-time `lax.cond` may - and the cond-payload rule
+    needs no case for it beyond `walk.is_platform_choice` (PR 39's)."""
+    from graphite_tpu.analysis import fingerprint, iter_eqns
+    from graphite_tpu.analysis.audit import (
+        audit_program, spec_from_simulator,
+    )
+
+    spec = spec_from_simulator("coh-16-wide-staged",
+                               _staged_sim(barrier_host=True))
+    names = [e.primitive.name for e in iter_eqns(spec.closed)]
+    assert names.count("pallas_call") == 1 and "dma_start" in names
+    bad = [(r.rule, f.message) for r in audit_program(spec)
+           for f in r.findings]
+    assert not bad, bad
+    assert fingerprint(spec.closed).startswith("gfp1:")
+
+
+def _parents_scatter(sharers, skey, sval, DW):
+    """The store's update in `engine.dir_stage_flush` as the parent of
+    PR 43 wrote it."""
+    from graphite_tpu.intmath import nn_div, nn_mod
+
+    T, DS, _ = sharers.shape
+    C, SW = sval.shape[1:]
+    tiles = np.arange(T, dtype=np.int32)[:, None]
+    valid = skey >= 0
+    key = jnp.where(valid, skey, 0)
+    w = nn_mod(key, DW)
+    s = nn_div(key, DW)
+    later = (valid[:, :, None] & valid[:, None, :]
+             & (key[:, :, None] == key[:, None, :])
+             & (np.arange(C)[None, None, :]
+                > np.arange(C)[None, :, None]))
+    is_last = valid & ~later.any(axis=2)
+    row = sharers[tiles, s]
+    row3 = row.reshape(T, C, DW, SW)
+    cur = jnp.take_along_axis(
+        row3, w[:, :, None, None], axis=2)[:, :, 0]
+    delta = jnp.where(is_last[..., None], sval - cur, jnp.uint32(0))
+    onehot = (np.arange(DW, dtype=np.int32)[None, None, :, None]
+              == w[:, :, None, None])
+    row_delta = jnp.where(onehot, delta[:, :, None, :],
+                          jnp.uint32(0)).reshape(T, C, DW * SW)
+    s_oob = jnp.where(is_last, s, DS)
+    return sharers.at[tiles, s_oob].add(row_delta, mode="drop")
+
+
+def _parents_flush(d, live=None):
+    """`engine.dir_stage_flush` as the parent of PR 43 wrote it."""
+    def flush(stores):
+        sharers, skey, sn = stores
+        return (_parents_scatter(sharers, skey, d.sval, d.entry.shape[2]),
+                jnp.full_like(skey, -1), jnp.zeros_like(sn))
+
+    sharers, skey, sn = _run_if(live, flush, (d.sharers, d.skey, d.sn))
+    return d.replace(sharers=sharers, skey=skey, sn=sn)
+
+
+def _flush_args(ways, way_width):
+    return DirectoryArrays(
+        entry=jnp.zeros((FT, FDS, ways), jnp.int64),
+        sharers=jnp.zeros((FT, FDS, ways * way_width), U32),
+        skey=jnp.full((FT, FC), -1, jnp.int32),
+        sval=jnp.zeros((FT, FC, way_width), U32),
+        sn=jnp.zeros(FT, jnp.int32)), jnp.asarray(True)
+
+
+@pytest.mark.parametrize("ways,way_width,px", [
+    (16, 1, IDENT),                                       # 16 tiles
+    (FDW, FSW, dataclasses.replace(IDENT, sim_axis="sims")),
+], ids=["16-tiles", "sim-axis"])
+def test_flush_fallback_is_the_parents_flush_letter_for_letter(
+        ways, way_width, px):
+    args = _flush_args(ways, way_width)
+    got = jax.make_jaxpr(lambda d, live: dir_stage_flush(d, live, px=px))(
+        *args)
+    want = jax.make_jaxpr(_parents_flush)(*args)
+    assert str(got) == str(want)
+    assert "pallas_call" not in str(got) and "platform_index" not in str(got)
+
+
+def test_lane_aligned_flush_follows_the_lowering_target():
+    from graphite_tpu.analysis import iter_eqns
+    from graphite_tpu.obs.scopes import scope
+
+    d, live = _flush_args(FDW, FSW)
+
+    def flush(d, live):
+        with scope("gt.mem.stage_flush"):
+            return dir_stage_flush(d, live)
+
+    traced = jax.jit(flush).trace(d, live)
+    assert "platform_index" in str(traced.jaxpr)
+    # the default arm IS the parent's flush: the choice's last branch,
+    # letter for letter, is the parent's scatter-add of row deltas
+    choice, = [e for e in iter_eqns(traced.jaxpr)
+               if e.primitive.name == "cond"
+               and e.params.get("branches_platforms") is not None]
+    assert choice.params["branches_platforms"][-1] is None
+    parent = jax.make_jaxpr(
+        lambda sharers, skey, sval, sn: _parents_scatter(
+            sharers, skey, sval, FDW))(d.sharers, d.skey, d.sval, d.sn)
+
+    def letters(jaxpr):
+        # (a branch takes its constants as inputs, a traced function
+        # closes over them: the `;` between the two lists moves)
+        return " ".join(str(jaxpr).replace(";", " ").split())
+
+    assert letters(choice.params["branches"][-1].jaxpr) == letters(
+        parent.jaxpr)
+    scatter, kernel = '"stablehlo.scatter"(', "@tpu_custom_call("
+    cpu = traced.lower().as_text()
+    assert (cpu.count(scatter), cpu.count(kernel)) == (1, 0)
+    tpu = traced.lower(lowering_platforms=("tpu",))
+    assert (tpu.as_text().count(scatter), tpu.as_text().count(kernel)) == (0, 1)
+    assert ("/gt.mem.stage_flush/while/body/cond/branch_0_fun/"
+            "dir_stage_landing/pallas_call" in tpu.as_text(debug_info=True))
