@@ -107,6 +107,11 @@ class ConfigFile:
     def has(self, path: str) -> bool:
         return path.strip("/") in self._values
 
+    def has_section(self, section: str) -> bool:
+        """Whether any key lies under `[section]` or a sub-section."""
+        prefix = section.strip("/") + "/"
+        return any(k.startswith(prefix) for k in self._values)
+
     def get_string(self, path: str, default: Any = _MISSING) -> str:
         v = self._raw(path, default)
         if not isinstance(v, str):

@@ -83,6 +83,15 @@ class SimResults:
     # built with a HistSpec, else None.  Same pure-observability
     # contract (pinned in tests/test_hist.py)
     hist: "object | None" = None
+    # per-tile energy in integer picojoules ({component: int64[n_tiles]},
+    # `TileEnergyMonitor.tile_energy_j`'s components and "total"),
+    # integrated interval by interval at the operating point in force
+    # (power/accounting.py); None unless [general] enable_power_modeling
+    energy_pj: "dict | None" = None
+    # the per-tile V/f table a run ends with ({"freq_mhz", "voltage_mv":
+    # int[n_tiles, n_domains], "errors": int64[n_tiles] rejected DVFS_SET
+    # requests}); None unless the configuration has a [dvfs] section
+    dvfs_counters: "dict | None" = None
 
     @property
     def total_instructions(self) -> int:
@@ -189,6 +198,21 @@ class SimResults:
                            f"{int(nc['delay_cycles'][t].sum())}")
                 out.append("    Analytical Model Used: "
                            f"{int(nc['analytical_reads'][t].sum())}")
+            if self.dvfs_counters is not None:
+                # `dvfs_manager.cc` per-domain operating points
+                dc = self.dvfs_counters
+                out.append("  DVFS Summary:")
+                for d in range(dc["freq_mhz"].shape[1]):
+                    out.append(
+                        f"    Domain {d}: "
+                        f"{int(dc['freq_mhz'][t, d]) / 1000:g} GHz at "
+                        f"{int(dc['voltage_mv'][t, d]) / 1000:g} V")
+                out.append(
+                    f"    Rejected Requests: {int(dc['errors'][t])}")
+        if self.energy_pj is not None:
+            from graphite_tpu.power.accounting import output_summary
+
+            out.append(output_summary(self.energy_pj))
         return "\n".join(out)
 
 
@@ -597,6 +621,20 @@ class Simulator:
         from graphite_tpu.models.dvfs import DvfsParams
 
         dvfs_params = DvfsParams.from_config(cfg)
+        # energy as a statistic of the run, under the reference's key
+        energy_params = None
+        if config.enable_power_modeling:
+            from graphite_tpu.power.accounting import EnergyParams
+
+            if mesh is not None:
+                raise NotImplementedError(
+                    "[general] enable_power_modeling on a device mesh: "
+                    "the energy accumulators have no shard spec yet")
+            energy_params = EnergyParams.from_config(
+                config, dvfs_params, mem_params)
+        # the V/f table is reported only where the configuration speaks
+        # of DVFS itself
+        self._report_dvfs = cfg.has_section("dvfs")
         self.params = EngineParams(
             n_tiles=n_tiles,
             static_cost_cycles=costs,
@@ -616,6 +654,7 @@ class Simulator:
             iocoom=iocoom_params,
             iocoom_tiles=iocoom_tiles,
             dvfs=dvfs_params,
+            energy=energy_params,
             mem=mem_params,
             user_hbh=user_hbh,
             user_atac=user_atac,
@@ -781,6 +820,16 @@ class Simulator:
                     init_volts[None, :], (n_tiles, nd)).copy(),
                 errors=jnp.zeros(n_tiles, jnp.int64),
             ))
+            if energy_params is not None:
+                from graphite_tpu.engine.state import EnergyState
+
+                self.state = self.state.replace(energy=EnergyState(
+                    acc=jnp.zeros(
+                        (n_tiles, len(energy_params.columns)), jnp.int64),
+                    last_raw=jnp.zeros(
+                        (n_tiles, len(energy_params.raw)), jnp.int64),
+                    last_clock_ps=jnp.zeros(n_tiles, jnp.int64),
+                ))
             made.attrs["bytes"] = tree_bytes(self.state)
         # streaming mode keeps the trace host-side; run_streamed() uploads
         # [T, W] windows on demand (bounded HBM regardless of trace size)
@@ -1493,6 +1542,46 @@ class Simulator:
         return (net_part, mem_part, ioc_part, tel_part, prof_part,
                 hist_part)
 
+    def _power_part(self, state: SimState):
+        """Device-side leaves for `dvfs_counters` and `energy_pj` (None
+        where the run reports neither: nothing more is fetched)."""
+        if not (self._report_dvfs or self.params.energy is not None):
+            return None
+        return (state.dvfs, state.energy)
+
+    def _power_host(self, power_h, core, net_h, mem_h):
+        """(energy_pj, dvfs_counters) from already-fetched leaves.  The
+        interval every tile still has open is closed here, on the host,
+        in the integers the device closes with: the state is not
+        touched, so results can be read again (or mid-run)."""
+        if power_h is None:
+            return None, None
+        dvfs_h, energy_h = power_h
+        dvfs_counters = None
+        if self._report_dvfs:
+            dvfs_counters = {
+                "freq_mhz": np.asarray(dvfs_h.freq_mhz),
+                "voltage_mv": np.asarray(dvfs_h.voltage_mv),
+                "errors": np.asarray(dvfs_h.errors),
+            }
+        energy_pj = None
+        ep = self.params.energy
+        if ep is not None and energy_h is not None:
+            from graphite_tpu.power.accounting import (
+                close_interval, raw_counts, to_pj,
+            )
+
+            raw_now = raw_counts(
+                np, ep, core, net_h[0],
+                None if mem_h is None else mem_h[0])
+            acc = np.asarray(energy_h.acc) + close_interval(
+                np, ep, raw_now, np.asarray(core.clock_ps),
+                np.asarray(dvfs_h.voltage_mv),
+                np.asarray(energy_h.last_raw),
+                np.asarray(energy_h.last_clock_ps))
+            energy_pj = to_pj(ep, acc)
+        return energy_pj, dvfs_counters
+
     def _timeline_host(self, tel_h):
         """Demux an already-fetched (buf, count) pair into a Timeline —
         keeps the ring inside run()'s ONE batched device→host fetch
@@ -1538,17 +1627,17 @@ class Simulator:
         (net_part, mem_part, ioc_part, tel_part, prof_part,
          hist_part) = self._result_parts(state)
         with span("fetch", parent="run"):
-            core_h, net_h, mem_h, ioc_h, tel_h, prof_h, hist_h = \
-                jax.device_get((
-                    state.core, net_part, mem_part, ioc_part, tel_part,
-                    prof_part, hist_part,
-                ))
+            (core_h, net_h, mem_h, ioc_h, tel_h, prof_h, hist_h,
+             power_h) = jax.device_get((
+                 state.core, net_part, mem_part, ioc_part, tel_part,
+                 prof_part, hist_part, self._power_part(state),
+             ))
         with span("results", parent="fetch"):
             return self._results_host(
                 core_h, net_h, mem_h, n_quanta, ioc_h,
                 telemetry=self._timeline_host(tel_h),
                 profile=self._profile_host(prof_h),
-                hist=self._hist_host(hist_h))
+                hist=self._hist_host(hist_h), power_h=power_h)
 
     def write_output(self, results: SimResults,
                      output_dir: str = "results") -> str:
@@ -1825,10 +1914,11 @@ class Simulator:
                 n_quanta_dev, deadlock_dev, state.net.overflow, state.done,
                 state.core, net_part, mem_part, ioc_part, tel_part,
                 prof_part, hist_part, n_iters, n_idle,
+                self._power_part(state),
             ))
         (n_quanta, deadlock, overflow, done, core_h, net_h, mem_h,
          ioc_h, tel_h, prof_h, hist_h, self.last_n_iterations,
-         self.last_idle_iterations) = host
+         self.last_idle_iterations, power_h) = host
         if bool(overflow):
             raise MailboxOverflowError(
                 "a (dst,src) mailbox ring overflowed; re-run with a "
@@ -1849,13 +1939,16 @@ class Simulator:
                 core_h, net_h, mem_h, int(n_quanta), ioc_h,
                 telemetry=self._timeline_host(tel_h),
                 profile=self._profile_host(prof_h),
-                hist=self._hist_host(hist_h))
+                hist=self._hist_host(hist_h), power_h=power_h)
 
     def _results_host(self, core, net_h, mem_h, n_quanta: int,
                       ioc_h=None, telemetry=None,
-                      profile=None, hist=None) -> SimResults:
+                      profile=None, hist=None,
+                      power_h=None) -> SimResults:
         """Assemble SimResults from already-fetched host arrays."""
         clock = np.asarray(core.clock_ps)
+        energy_pj, dvfs_counters = self._power_host(
+            power_h, core, net_h, mem_h)
         mem_counters = None
         func_errors = 0
         if mem_h is not None:
@@ -1895,5 +1988,7 @@ class Simulator:
             telemetry=telemetry,
             profile=profile,
             hist=hist,
+            energy_pj=energy_pj,
+            dvfs_counters=dvfs_counters,
         )
 

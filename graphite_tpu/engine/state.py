@@ -127,6 +127,17 @@ class DvfsState:
 
 
 @struct.dataclass
+class EnergyState:
+    """Per-tile energy accumulators and the open interval's start
+    (`power/accounting.py`: columns, units, the interval rule).  Carried
+    only with `[general] enable_power_modeling = true`."""
+
+    acc: jax.Array            # int64[T, C] — fJ (dynamic) / uW*ps (static)
+    last_raw: jax.Array       # int64[T, R] — event counts at the last close
+    last_clock_ps: jax.Array  # int64[T] — the tile's clock at the last close
+
+
+@struct.dataclass
 class SimState:
     core: CoreState
     net: UserNetState
@@ -164,6 +175,10 @@ class SimState:
     # the run records distributions; None (no pytree leaves — same
     # bit-identity contract as telemetry/profile) otherwise
     hist: "object" = None
+    # per-tile energy accumulators (EnergyState) under [general]
+    # enable_power_modeling; None (no pytree leaves — same bit-identity
+    # contract) otherwise
+    energy: "object" = None
 
 
 @struct.dataclass

@@ -115,6 +115,9 @@ class EngineParams:
     # stays quantum-bounded like the per-iteration active check).
     # Simple-core memoryless runs only; 1 = off.
     plain_unroll: int = 1
+    # energy price list (power/accounting.EnergyParams) under [general]
+    # enable_power_modeling, else None: no leaf, no operation
+    energy: "object" = None
     # lax_p2p clock-skew scheme (`lax_p2p_sync_client.h:13-83`): when set,
     # each iteration every tile draws a pseudorandom partner and advances
     # only if its clock is within `slack` of the partner's — the
@@ -966,6 +969,12 @@ def subquantum_iteration(
     # level gate), so dvfs=None lowers the historical cond byte-identically
     want_rt = dvfs is not None and state.dvfs_rt is not None
     new_rt = state.dvfs_rt
+    # energy accounting: a tile whose request succeeds closes its open
+    # interval at the operating point that WAS in force — inside the
+    # taken arm, so an iteration without a request pays nothing (same
+    # python-level gate: power off lowers the historical cond)
+    want_energy = params.energy is not None and state.energy is not None
+    new_energy = state.energy
     if params.dvfs is not None and state.dvfs is not None:
         dvp = params.dvfs
         ND = dvp.n_domains
@@ -1001,6 +1010,24 @@ def subquantum_iteration(
             out = (freq2, volt2, errs2, core_set, req)
             if want_rt:
                 out = out + (dmask,)
+            if want_energy:
+                from graphite_tpu.power.accounting import (
+                    close_interval, raw_counts,
+                )
+
+                with scope("gt.energy"):
+                    en = state.energy
+                    raw_now = raw_counts(
+                        jnp, params.energy, core, net.packets_sent,
+                        None if state.mem is None else state.mem.counters)
+                    closed = close_interval(
+                        jnp, params.energy, raw_now, core.clock_ps,
+                        state.dvfs.voltage_mv, en.last_raw,
+                        en.last_clock_ps)
+                    out = out + (
+                        en.acc + jnp.where(ok[:, None], closed, 0),
+                        jnp.where(ok[:, None], raw_now, en.last_raw),
+                        jnp.where(ok, core.clock_ps, en.last_clock_ps))
             return out
 
         def _dvfs_skip(_):
@@ -1009,6 +1036,9 @@ def subquantum_iteration(
                    jnp.zeros((T,), aux1.dtype))
             if want_rt:
                 out = out + (jnp.zeros((T, ND), jnp.bool_),)
+            if want_energy:
+                en = state.energy
+                out = out + (en.acc, en.last_raw, en.last_clock_ps)
             return out
 
         with scope("gt.dvfs"):
@@ -1018,6 +1048,10 @@ def subquantum_iteration(
         (dv_freq, dv_volt, dv_errs, dvfs_core_set, dvfs_req) = dvfs_out[:5]
         new_dvfs = state.dvfs.replace(
             freq_mhz=dv_freq, voltage_mv=dv_volt, errors=dv_errs)
+        if want_energy:
+            new_energy = state.energy.replace(
+                acc=dvfs_out[-3], last_raw=dvfs_out[-2],
+                last_clock_ps=dvfs_out[-1])
         if want_rt:
             from graphite_tpu.dvfs.runtime import (
                 core_freq_tiles, elect_domains,
@@ -1245,6 +1279,7 @@ def subquantum_iteration(
         profile=state.profile,
         dvfs_rt=new_rt,
         hist=new_hist,
+        energy=new_energy,
     )
     return new_state, (jnp.sum(advance, dtype=jnp.int32) + mem_progress
                        + sync_moved.astype(jnp.int32))

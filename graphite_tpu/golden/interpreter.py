@@ -31,8 +31,18 @@ Scope: core timing + sync/messaging as above, plus — when shared memory
 is enabled and the trace touches memory — the full private-L1/L2
 dram-directory hierarchy via `golden.memory_model.GoldenMemory` (an
 independent sequential implementation; see its docstring for the
-ordering discipline and the exact-vs-envelope test contract).  DVFS
-retuning remains out of scope — run with a fixed frequency.
+ordering discipline and the exact-vs-envelope test contract).
+
+ - DVFS_SET: the per-tile V/f table (AUTO / HOLD, the rc codes as an
+   error count); a CORE-domain retune moves the tile's core AND its
+   caches' clock (`GoldenMemory.freq` follows), directory and networks
+   keep their domain's frequency, as the engine's per-tile path has it.
+   Out of scope: the chip-global runtime spec (`dvfs/runtime.py`).
+ - energy, under `[general] enable_power_modeling`: a tile's interval is
+   closed at the operating point that was in force when its DVFS_SET
+   succeeds and once more at the end, by the rule of
+   `power/accounting.py` in plain Python integers (the price list is
+   the configuration's, `EnergyParams`; the loop is this file's).
 """
 
 from __future__ import annotations
@@ -68,6 +78,8 @@ class GoldenResult:
     # per-tile memory-hierarchy counters ({name: np.ndarray[T]}), None
     # when the run had no memory model
     mem_counters: dict | None = None
+    # barrier / mutex / cond waits charged (engine: `core.sync_stall_ps`)
+    sync_stall_ps: np.ndarray | None = None
     # per-tile rejected DVFS_SET requests (engine: `dvfs.errors`)
     dvfs_errors: np.ndarray | None = None
     # per-tile final CORE-domain frequency after in-trace retunes
@@ -76,6 +88,12 @@ class GoldenResult:
     # engine's `SimResults.noc_counters`), None unless the user network
     # is emesh_hop_by_hop
     noc_counters: dict | None = None
+    # the engine's `SimResults.energy_pj` ({component: int64[T] pJ}),
+    # None unless [general] enable_power_modeling
+    energy_pj: dict | None = None
+    # the engine's `SimResults.dvfs_counters`, None unless the
+    # configuration has a [dvfs] section
+    dvfs_counters: dict | None = None
 
 
 class _Net:
@@ -413,7 +431,8 @@ class _Tile:
         self.done = False
         self.blocked = None  # None | ("recv", src) | ("barrier", b)
         #                       | ("mutex", m) | ("join", t) | ("cond", c, m)
-        self.counts = dict(instr=0, recv=0, sync=0, bp_ok=0, bp_bad=0)
+        self.counts = dict(instr=0, recv=0, sync=0, bp_ok=0, bp_bad=0,
+                           sync_ps=0, sent=0)
 
 
 def run_golden(sim_config, batch: TraceBatch,
@@ -514,6 +533,53 @@ def run_golden(sim_config, batch: TraceBatch,
     sig_seq: dict[int, int] = {}      # cond id -> published signals so far
     sig_time: dict[tuple, int] = {}   # (cond id, seq) -> publish time
 
+    # energy (power/accounting.py's rule; its price list, this loop)
+    ep = None
+    if sim_config.enable_power_modeling:
+        from graphite_tpu.power.accounting import EnergyParams
+
+        ep = EnergyParams.from_config(
+            sim_config, dvp, mem.mp if mem is not None else None)
+        energy_acc = [[0] * len(ep.columns) for _ in range(T)]
+        energy_last = [dict.fromkeys(ep.raw, 0) for _ in range(T)]
+        energy_last_clock = [0] * T
+
+    def close_energy(t: _Tile):
+        if ep is None:
+            return
+        c, tid = t.counts, t.tid
+        now = dict(instructions=c["instr"] + c["recv"] + c["sync"],
+                   mem_ops=0, branches=c["bp_ok"] + c["bp_bad"],
+                   packets_sent=c["sent"])
+        if ep.has_mem:
+            mc = {k: int(mem.counters[k][tid]) for k in (
+                "l1i_hits", "l1i_misses", "l1d_read_hits", "l1d_write_hits",
+                "l1d_read_misses", "l1d_write_misses", "l2_hits",
+                "l2_misses", "dram_reads", "dram_writes")}
+            misses = mc["l1d_read_misses"] + mc["l1d_write_misses"]
+            now.update(
+                mem_ops=mc["l1d_read_hits"] + mc["l1d_write_hits"] + misses,
+                l1i_hits=mc["l1i_hits"], l1i_misses=mc["l1i_misses"],
+                l1d_read_hits=mc["l1d_read_hits"],
+                l1d_write_hits=mc["l1d_write_hits"], l1d_misses=misses,
+                l2_hits=mc["l2_hits"], l2_misses=mc["l2_misses"],
+                dram_accesses=mc["dram_reads"] + mc["dram_writes"])
+        d = {k: now[k] - energy_last[tid][k] for k in ep.raw}
+        d["int_ops"] = max(
+            d["instructions"] - d["mem_ops"] - d["branches"], 0)
+        dt = t.clock - energy_last_clock[tid]
+        for k, (static, dom, price) in enumerate(
+                zip(ep.static, ep.domains, ep.prices)):
+            lvl = 0 if dom < 0 else ep.voltages_mv.index(
+                dvfs_volt[tid][dom])
+            if static:
+                energy_acc[tid][k] += dt * price[lvl]
+            else:
+                energy_acc[tid][k] += sum(
+                    d[name] * table[lvl] for name, table in price)
+        energy_last[tid] = {k: now[k] for k in ep.raw}
+        energy_last_clock[tid] = t.clock
+
     def runnable(t: _Tile) -> bool:
         if t.done or t.blocked is not None:
             return False
@@ -535,6 +601,7 @@ def run_golden(sim_config, batch: TraceBatch,
         new_clock = max(eff_clock, mx["handoff"], wake)
         if new_clock > t.clock and enabled[0]:
             t.counts["sync"] += 1
+            t.counts["sync_ps"] += new_clock - t.clock
         t.clock = new_clock
         t.blocked = None
 
@@ -573,6 +640,7 @@ def run_golden(sim_config, batch: TraceBatch,
                 rel = bar_release.get((b, gen), 0)
                 if rel > t.clock and enabled[0]:
                     t.counts["sync"] += 1
+                    t.counts["sync_ps"] += rel - t.clock
                 t.clock = max(t.clock, rel)
                 t.blocked = None
                 t.idx += 1
@@ -582,6 +650,7 @@ def run_golden(sim_config, batch: TraceBatch,
                 st = sig_time.get((c, k), 0)
                 if st > t.clock and enabled[0]:
                     t.counts["sync"] += 1
+                    t.counts["sync_ps"] += st - t.clock
                 t.clock = max(t.clock, st)
                 t.blocked = None
                 t.idx += 1
@@ -638,6 +707,7 @@ def run_golden(sim_config, batch: TraceBatch,
                 t.clock += cycles_to_ps(aux1, core_freq[t.tid]) + acc
                 t.counts["instr"] += aux0
         elif op == Op.SEND:
+            t.counts["sent"] += 1
             if isinstance(net, _HbhNet):
                 arrival = net.route(t.tid, aux0, aux1, t.clock, enabled[0])
             else:
@@ -670,6 +740,7 @@ def run_golden(sim_config, batch: TraceBatch,
                     tx = tiles[x]
                     if release > tx.clock and enabled[0]:
                         tx.counts["sync"] += 1
+                        tx.counts["sync_ps"] += release - tx.clock
                     tx.clock = max(tx.clock, release)
                     tx.blocked = None
                 b["arrived"] = []
@@ -763,10 +834,15 @@ def run_golden(sim_config, batch: TraceBatch,
                 ok = valid_dom and auto_mv >= 0
                 new_mv = auto_mv
             if ok:
+                # the interval closes at the OLD operating point
+                close_energy(t)
                 dvfs_freq[t.tid][dom] = req
                 dvfs_volt[t.tid][dom] = new_mv
                 if dom == dvp.core_domain:
                     core_freq[t.tid] = req
+                    if mem is not None:
+                        # the caches run on their tile's core clock
+                        mem.freq[t.tid] = req
             else:
                 dvfs_errors[t.tid] += 1
         else:
@@ -790,7 +866,25 @@ def run_golden(sim_config, batch: TraceBatch,
         t = min(run, key=lambda x: (x.clock, x.tid))
         step(t)
 
+    energy_pj = None
+    if ep is not None:
+        from graphite_tpu.power.accounting import to_pj
+
+        for t in tiles:
+            close_energy(t)
+        energy_pj = to_pj(ep, np.asarray(energy_acc, np.int64))
+    dvfs_counters = None
+    if cfg.has_section("dvfs"):
+        dvfs_counters = {
+            "freq_mhz": np.asarray(dvfs_freq, np.int32),
+            "voltage_mv": np.asarray(dvfs_volt, np.int32),
+            "errors": np.asarray(dvfs_errors, np.int64),
+        }
     return GoldenResult(
+        energy_pj=energy_pj,
+        dvfs_counters=dvfs_counters,
+        sync_stall_ps=np.asarray(
+            [t.counts["sync_ps"] for t in tiles], np.int64),
         clock_ps=np.asarray([t.clock for t in tiles], np.int64),
         instruction_count=np.asarray(
             [t.counts["instr"] for t in tiles], np.int64),

@@ -181,6 +181,13 @@ class DvfsParams:
                 max(module_domain_index(cfg, m), 0) for m in DVFS_MODULES),
         )
 
+    @property
+    def levels_text(self) -> str:
+        """The V/f table on one line, `<mV>@<MHz>` per level, descending:
+        what a configuration file can state and compare as one string."""
+        return " ".join(f"{v}@{f}" for v, f in zip(self.voltages_mv,
+                                                    self.max_freq_mhz))
+
     def min_voltage_mv(self, freq_mhz: int) -> int:
         """Lowest voltage supporting `freq_mhz` (`getMinVoltage`), or -1."""
         best = -1

@@ -51,6 +51,8 @@ SCOPES = (
     "gt.sync.join",
     "gt.obs",               # telemetry / profile / hist ticks
     "gt.dvfs",
+    "gt.energy",            # inside gt.dvfs's taken arm: a tile's energy
+                            #   interval closed at the old operating point
     "gt.px",                # the packed shard_map exchange
 )
 

@@ -209,7 +209,17 @@ class DSENTInterface:
 class TileEnergyMonitor:
     """Aggregate per-tile energy over a run
     (`tile_energy_monitor.h:17-128`): core + caches + network dynamic
-    energy from the run's counters, plus leakage over completion time."""
+    energy from the run's counters, plus leakage over completion time.
+
+    A host pass over the FINAL counters, in floats, that prices the whole
+    run at the ONE voltage it is given: right for a run without a DVFS
+    transition, an over-estimate where tiles spent time at lower levels
+    (the reference closes an interval at the old operating point on every
+    `setDVFS`).  For runs with transitions read `SimResults.energy_pj`
+    (`[general] enable_power_modeling = true`; power/accounting.py): the
+    same terms, integrated interval by interval in integers; the two
+    agree to 0.1 % where no transition happens
+    (tests/test_canneal_dvfs.py)."""
 
     def __init__(self, sim, results, node_nm: int | None = None):
         self.node_nm = node_nm or sim.config.technology_node

@@ -277,6 +277,13 @@ class SweepRunner:
                           dict(sim_kwargs))
         self._has_mem = bool(has_mem[0])
         self.sim = self._build_sim(layout)
+        if self.sim.params.energy is not None:
+            # the demux hands no energy leaves on: a campaign would
+            # carry the accumulators and report nothing
+            raise NotImplementedError(
+                "[general] enable_power_modeling: a campaign reports no "
+                "energy_pj yet (SimResults.energy_pj is a solo run's; "
+                "ROADMAP, Cells to add)")
         self.mailbox_depth = mailbox_depth
         base = Knobs.from_params(self.sim.params,
                                  self.sim.quantum_ps)

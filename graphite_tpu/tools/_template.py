@@ -13,15 +13,31 @@ def config_text(tiles: int, *, core: str = "simple",
                 protocol: str = "pr_l1_pr_l2_dram_directory_msi",
                 scheme: str = "full_map", max_hw_sharers: int = 2,
                 clock_scheme: str = "lax_barrier",
-                dvfs: bool = False) -> str:
-    dvfs_section = """
-[dvfs]
-technology_node = 22
-max_frequency = 1.0
+                dvfs: bool = False, dvfs_domains: str | None = None,
+                power: bool = False) -> str:
+    """`dvfs` writes a `[dvfs]` section: `dvfs_domains` (the reference's
+    `<f_ghz, MODULE, ...>` list form, `carbon_sim.cfg:147-155`) or, left
+    out, the one domain `carbon_sim.cfg` ships.  `power` writes the
+    reference's own `[general] enable_power_modeling = true`: the run
+    then integrates per-tile energy (`SimResults.energy_pj`).  Either
+    writes `technology_node` under `[general]`, where both of its readers
+    look (`models/dvfs.load_levels`, `SimConfig.technology_node`).  With
+    all three at their defaults the text is what it always was."""
+    if dvfs_domains is not None and not dvfs:
+        raise ValueError("dvfs_domains needs dvfs=True")
+    extra = ""
+    if dvfs or power:
+        extra += "\n[general]\ntechnology_node = 22\n"
+        if power:
+            extra += "enable_power_modeling = true\n"
+    if dvfs:
+        domains = dvfs_domains or (
+            "<1.0, CORE, L1_ICACHE, L1_DCACHE, L2_CACHE, DIRECTORY, "
+            "NETWORK_USER, NETWORK_MEMORY>")
+        extra += f"""[dvfs]
 synchronization_delay = 2
-[dvfs/domains]
-domains = "<1.0, CORE, L1_ICACHE, L1_DCACHE, L2_CACHE, DIRECTORY, NETWORK_USER, NETWORK_MEMORY>"
-""" if dvfs else ""
+domains = "{domains}"
+"""
     return f"""
 [general]
 total_cores = {tiles}
@@ -65,7 +81,7 @@ size = 1024
 scheme = {clock_scheme}
 [clock_skew_management/lax_barrier]
 quantum = 1000
-{dvfs_section}
+{extra}
 """
 
 

@@ -209,29 +209,66 @@ def blackscholes_trace(n_tiles: int, options_per_tile: int = 512,
     return TraceBatch.from_builders(builders)
 
 
+# `canneal_trace(dvfs_schedule=...)`: which frequency a tile asks for at a
+# temperature step's start, as a function of (tile, step).  The levels are
+# the maximal frequencies of `technology/dvfs_levels_22nm.cfg` at
+# `[general] max_frequency` 1.0 (models/dvfs.py keeps the table).
+def _rotate_levels(tile: int, step: int) -> int:
+    from graphite_tpu.models.dvfs import _BUILTIN_LEVELS
+
+    levels = _BUILTIN_LEVELS[22]
+    return int(round(1000 * levels[(tile + step) % len(levels)][1]))
+
+
+DVFS_SCHEDULES = {"rotate-levels": _rotate_levels}
+
+
 @generator
 def canneal_trace(n_tiles: int, footprint_lines: int = 4096,
                   swaps_per_tile: int = 64, seed: int = 1234,
-                  use_memory: bool = True) -> TraceBatch:
+                  use_memory: bool = True, temperature_steps: int = 1,
+                  dvfs_schedule: str | None = None) -> TraceBatch:
     """Simulated-annealing element swaps: random-access loads over a large
     shared footprint (cache-hostile), ~60 int/fp ops to evaluate each swap,
     a taken/not-taken accept branch, and occasional stores (PARSEC canneal
-    netlist swap loop)."""
+    netlist swap loop).
+
+    PARSEC's annealer runs `swaps_per_temp / nthreads` moves a thread
+    between barriers, once per temperature step: `temperature_steps` of
+    `swaps_per_tile` swaps each, a barrier after every step.  With a
+    `dvfs_schedule` every tile opens every step with the reference's
+    `CarbonSetDVFS(own tile, CORE domain, &f, AUTO)` (`dvfs.h:42-48`; a
+    DVFS_SET record on domain 0, which holds CORE in every domain list the
+    repo writes) - PARSEC's canneal calls no DVFS API, the calls are this
+    generator's.  "rotate-levels": `f` is the maximal frequency of level
+    `(tile + step) mod 6` of the 22 nm table, so every level is in force
+    on a sixth of the tiles in every step and every tile changes level at
+    every step.  With the defaults the records are what they always
+    were."""
+    schedule = None
+    if dvfs_schedule is not None:
+        if dvfs_schedule not in DVFS_SCHEDULES:
+            raise ValueError(f"unknown dvfs_schedule {dvfs_schedule!r} "
+                             f"(known: {sorted(DVFS_SCHEDULES)})")
+        schedule = DVFS_SCHEDULES[dvfs_schedule]
     rng = np.random.default_rng(seed)
     builders = [TraceBuilder() for _ in range(n_tiles)]
     builders[0].barrier_init(_BAR, n_tiles)
-    for t, b in enumerate(builders):
-        for s in range(swaps_per_tile):
-            if use_memory:
-                a1 = int(rng.integers(footprint_lines)) * 64
-                a2 = int(rng.integers(footprint_lines)) * 64
-                b.load(a1)
-                b.load(a2)
-            b.bblock(60, 60)
-            b.branch(bool(rng.integers(2)), pc=s & 0x3FF)
-            if use_memory and rng.random() < 0.3:
-                b.store(int(rng.integers(footprint_lines)) * 64)
-    _barrier(builders)
+    for step in range(temperature_steps):
+        for t, b in enumerate(builders):
+            if schedule is not None:
+                b.dvfs_set(0, schedule(t, step))
+            for s in range(swaps_per_tile):
+                if use_memory:
+                    a1 = int(rng.integers(footprint_lines)) * 64
+                    a2 = int(rng.integers(footprint_lines)) * 64
+                    b.load(a1)
+                    b.load(a2)
+                b.bblock(60, 60)
+                b.branch(bool(rng.integers(2)), pc=s & 0x3FF)
+                if use_memory and rng.random() < 0.3:
+                    b.store(int(rng.integers(footprint_lines)) * 64)
+        _barrier(builders)
     return TraceBatch.from_builders(builders)
 
 

@@ -511,6 +511,40 @@ def test_shl2_mesi_1024_host_batch_compiles(one_chip, tiles):
                reverse=True)[:6]})
 
 
+def _canneal_dvfs(tiles):
+    """`canneal-dvfs-1024` (benchmark/configs) at `tiles` tiles: two DVFS
+    domains, power modelling on, the stepped canneal with the rotating
+    schedule, host-driven as the cell."""
+    from graphite_tpu.trace.benchmarks import canneal_trace
+
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        tiles, shared_mem=True, dvfs=True, power=True,
+        dvfs_domains="<1.0, CORE, L1_ICACHE, L1_DCACHE, L2_CACHE> "
+        "<1.0, DIRECTORY, NETWORK_USER, NETWORK_MEMORY>")))
+    return Simulator(
+        sc, canneal_trace(tiles, footprint_lines=max(64, 15625 * tiles
+                                                     // 1024),
+                          swaps_per_tile=9, temperature_steps=5,
+                          dvfs_schedule="rotate-levels"),
+        barrier_host=True)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tiles", [16, 1024])
+def test_canneal_dvfs_host_batch_compiles(one_chip, tiles):
+    """The host-batch program of `canneal1024-dvfs` asked of the TPU
+    compiler: the DVFS arm with the energy interval's close in it (int64
+    products by per-level price gathers), and the core and memory
+    blocks' divisions by a per-tile frequency.  Both sizes are `slow`:
+    16 tiles take the TPU compiler 87 s here (16 MB of code), and tier-1
+    has no such room (ISSUE 44: 1,355 s of 1,470)."""
+    sim = _canneal_dvfs(tiles)
+    assert sim.params.energy is not None and sim.params.dvfs.n_domains == 2
+    compiled = _compile_host_batch(sim, one_chip)
+    _fits(_report(f"canneal-dvfs-{tiles}-host-batch", compiled))
+    assert "gt.energy" in compiled.as_text()
+
+
 @pytest.mark.slow
 def test_coh_1024_single_region_compiles(one_chip):
     """1024 tiles, full directory: the single-region lax_barrier

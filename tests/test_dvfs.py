@@ -25,8 +25,6 @@ max_frequency = {max_freq}
 technology_node = 22
 [dvfs]
 synchronization_delay = 2
-[dvfs/domains]
-[dvfs]
 domains = "<1.0, CORE, L1_ICACHE, L1_DCACHE, L2_CACHE, DIRECTORY> \
 <1.0, NETWORK_USER, NETWORK_MEMORY>"
 [network]

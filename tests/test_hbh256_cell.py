@@ -395,16 +395,20 @@ def test_cell_reports_what_the_manifest_lists():
         if m["name"] in listed:
             assert os.path.exists(os.path.join(
                 BENCH, "layer_metrics", m["name"] + ".py")), m["name"]
-    # appended: the new cell is the last of every list it joined, and the
-    # four new metrics came last, in this order (PR 43's follows them)
-    assert MANIFEST["workloads"][-1]["name"] == CELL_NAME
-    assert MANIFEST["configs"][-1]["name"] == NAME
+    # appended: the new cell was the last of every list it joined (PR 44's
+    # `canneal1024-dvfs` follows it), and the four new metrics came last,
+    # in this order (PR 43's follows them)
+    assert [w["name"] for w in MANIFEST["workloads"]][5] == CELL_NAME
+    assert [c["name"] for c in MANIFEST["configs"]][5] == NAME
     names = [m["name"] for m in MANIFEST["per_layer"]]
     first = names.index("hbh_scan_busy_share")
     assert names[first:first + 4] == [
         "hbh_scan_busy_share", "hbh_commit_busy_share",
         "noc_contention_share", "noc_fallback_share"]
-    assert names[first + 4:] == ["stage_flush_busy_share"]
+    assert names[first + 4:] == [
+        "stage_flush_busy_share", "dvfs_busy_share", "energy_busy_share",
+        "dvfs_sets_per_run"]
     for m in MANIFEST["per_layer"] + MANIFEST["end_to_end"]:
         if CELL_NAME in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL_NAME, m["name"]
+            later = m["workloads"][m["workloads"].index(CELL_NAME) + 1:]
+            assert later in ([], ["canneal1024-dvfs"]), m["name"]

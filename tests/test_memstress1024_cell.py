@@ -186,9 +186,11 @@ READERS = [
 @pytest.mark.parametrize("name,own,want,runs", READERS)
 def test_layer_metric_readers(name, own, want, runs):
     entry, = [m for m in MANIFEST["per_layer"] if m["name"] == name]
-    # (PR 38 appended `memstress1024-shl2` to the directory counter's)
+    # (PR 38 appended `memstress1024-shl2` to the directory counter's,
+    # PR 44 `canneal1024-dvfs` to those its program reports)
     assert entry["workloads"][0] == CELL_NAME
-    assert set(entry["workloads"]) <= {CELL_NAME, "memstress1024-shl2"}
+    assert set(entry["workloads"]) <= {CELL_NAME, "memstress1024-shl2",
+                                       "canneal1024-dvfs"}
     assert entry["moves"] == "sim_records_per_s"
     sys.path.insert(0, BENCH)
     try:
@@ -211,10 +213,13 @@ def test_stage_flush_reader(scoped, want):
     `gt.mem.base`); nothing where the program has no such scope."""
     entry, = [m for m in MANIFEST["per_layer"]
               if m["name"] == "stage_flush_busy_share"]
-    assert entry["workloads"] == [CELL_NAME, "a2a1024-fftskel"]
+    assert entry["workloads"] == [CELL_NAME, "a2a1024-fftskel",
+                                  "canneal1024-dvfs"]
     assert (entry["moves"], entry["better"]) == ("sim_records_per_s",
                                                  "lower")
-    assert entry == MANIFEST["per_layer"][-1]       # appended
+    # appended (PR 44's three metrics follow it)
+    assert [m["name"] for m in MANIFEST["per_layer"]].index(
+        "stage_flush_busy_share") == len(MANIFEST["per_layer"]) - 4
     ctx = _ctx()
     if scoped is None:
         ctx.own["scope_trace"] = None

@@ -74,9 +74,10 @@ def test_shares_sum_to_100(red):
         and s not in scope_trace.MEM_UNGATED,
         lambda s: s in scope_trace.MEM_UNGATED,
         lambda s: s == scope_trace.UNSCOPED,
-        lambda s: s in ("gt.quantum", "gt.obs", "gt.dvfs", "gt.px"),
+        lambda s: s in ("gt.quantum", "gt.obs", "gt.px"),
+        lambda s: s in ("gt.dvfs", "gt.energy"),    # `dvfs_busy_share`
     ]
-    # the six metrics' groups and the four printed-only scopes part the
+    # the seven metrics' groups and the three printed-only scopes part the
     # registry: every scope is in exactly one
     for name in scopes.SCOPES + (scope_trace.UNSCOPED,):
         assert sum(bool(g(name)) for g in groups) == 1, name

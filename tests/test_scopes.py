@@ -22,7 +22,9 @@ from graphite_tpu.models import iocoom, network_hop_by_hop
 from graphite_tpu.obs import TelemetrySpec, scopes
 from graphite_tpu.parallel import px
 from graphite_tpu.tools._template import config_text
-from graphite_tpu.trace.benchmarks import fft_trace, radix_trace
+from graphite_tpu.trace.benchmarks import (
+    canneal_trace, fft_trace, radix_trace,
+)
 
 TILES = 16
 MSI = "pr_l1_pr_l2_dram_directory_msi"
@@ -35,14 +37,24 @@ ONLY_SHARDED = {"gt.px"}
 ONLY_SHL2 = {"gt.mem.dir_apply"}     # the embedded directory's landing
 # the two halves of emesh_hop_by_hop's dense contention (PR 42)
 ONLY_HBH = {"gt.net.hbh.scan", "gt.net.hbh.commit"}
+# an energy interval's close inside the DVFS arm: only with [general]
+# enable_power_modeling (PR 44)
+ONLY_POWER = {"gt.energy"}
 MSI_SCOPES = [s for s in scopes.SCOPES
-              if s not in ONLY_SHARDED | ONLY_SHL2 | ONLY_HBH]
-SHL2_SCOPES = [s for s in scopes.SCOPES if s not in ONLY_SHARDED | ONLY_HBH
+              if s not in ONLY_SHARDED | ONLY_SHL2 | ONLY_HBH | ONLY_POWER]
+SHL2_SCOPES = [s for s in scopes.SCOPES
+               if s not in ONLY_SHARDED | ONLY_HBH | ONLY_POWER
                | {"gt.core.iocoom", "gt.mem.stage_flush", "gt.obs"}]
 # the memoryless hop-by-hop target (`hbh256-radix`'s, at 16 tiles): the
 # core, the mailboxes, the route with its two halves, the barrier
 HBH_SCOPES = ["gt.quantum", "gt.fetch", "gt.core", "gt.net.mailbox",
               "gt.net.route", "gt.sync.barrier"] + sorted(ONLY_HBH)
+# `canneal1024-dvfs`'s target at 16 tiles: the private-L2 program with
+# the simple core, two DVFS domains and power modelling on
+DVFS_SCOPES = [s for s in MSI_SCOPES if s not in {
+    "gt.core.iocoom", "gt.mem.stage_flush", "gt.obs"}] + sorted(ONLY_POWER)
+TWO_DOMAINS = ("<1.0, CORE, L1_ICACHE, L1_DCACHE, L2_CACHE> "
+               "<1.0, DIRECTORY, NETWORK_USER, NETWORK_MEMORY>")
 
 
 def build(program: str) -> Simulator:
@@ -51,6 +63,15 @@ def build(program: str) -> Simulator:
         return Simulator(SimConfig(ConfigFile.from_string(text)),
                          radix_trace(TILES, keys_per_tile=64),
                          barrier_host=True)
+    if program == "dvfs":
+        text = config_text(TILES, shared_mem=True, protocol=MSI, dvfs=True,
+                           dvfs_domains=TWO_DOMAINS, power=True)
+        return Simulator(
+            SimConfig(ConfigFile.from_string(text)),
+            canneal_trace(TILES, footprint_lines=200, swaps_per_tile=2,
+                          temperature_steps=2,
+                          dvfs_schedule="rotate-levels"),
+            barrier_host=True)
     batch = fft_trace(n_tiles=TILES, points_per_tile=64, use_memory=True)
     if program == "shl2":
         text = config_text(TILES, shared_mem=True, protocol=SHL2)
@@ -116,6 +137,17 @@ def test_hop_by_hop_program_names_scope(found, name):
     assert name in found("hbh")
 
 
+def test_dvfs_power_program_names_its_scopes(found):
+    """One test, not one a name: the program is lowered once (tier-1's
+    clock, ISSUE 44)."""
+    assert set(DVFS_SCOPES) <= found("dvfs")
+
+
+@pytest.mark.parametrize("program", ["msi", "shl2", "hbh"])
+def test_programs_without_power_modelling_close_no_interval(found, program):
+    assert not ONLY_POWER & found(program)
+
+
 @pytest.mark.parametrize("program", ["msi", "shl2"])
 def test_hop_counter_programs_have_no_hop_by_hop_half(found, program):
     assert not ONLY_HBH & found(program)
@@ -175,6 +207,10 @@ def test_cache_tag_follows_the_registry():
     ("jit(qrun)/gt.quantum/while/body/gt.core/gt.net.mailbox/cond/"
      "branch_1_fun/gt.net.route/gt.net.hbh.commit/reduce_max",
      "gt.net.hbh.commit"),
+    ("jit(qrun)/gt.quantum/while/body/gt.core/gt.dvfs/cond/branch_1_fun/"
+     "gt.energy/mul", "gt.energy"),
+    ("jit(qrun)/gt.quantum/while/body/gt.core/gt.dvfs/cond/branch_1_fun/"
+     "select_n", "gt.dvfs"),
     ("jit(run)/while/body/add", None),
     ("", None),
 ])
@@ -196,7 +232,7 @@ def scopes_off(monkeypatch):
         yield
 
 
-@pytest.mark.parametrize("program", ["msi", "shl2", "hbh"])
+@pytest.mark.parametrize("program", ["msi", "shl2", "hbh", "dvfs"])
 def test_scopes_change_no_equation(monkeypatch, program):
     scoped = build(program).lower()[0]
     with scopes_off(monkeypatch):
