@@ -626,8 +626,9 @@ def _run_if(live, fn, stores):
 
 def dir_store_avals(ms) -> tuple:
     """(shape, dtype) signatures of the big directory stores — the
-    [T, DS, DW] packed entry words and [T, DS, DW*SW] sharers bitvector
-    — that a gated home phase must NEVER return as lax.cond outputs
+    packed entry words (int64 [T, DS, DW], or u32 [T, 2*DW, DS]:
+    memory/state.py) and the [T, DS, DW*SW] sharers bitvector — that a
+    gated home phase must NEVER return as lax.cond outputs
     (they'd be double-buffered; the `_DirAcc` delta plan exists so the
     cond carries compact per-lane deltas instead).  The program
     auditor's cond-payload rule (analysis/rules.py) enforces this for
@@ -672,8 +673,9 @@ def mem_idle_out(mp: MemParams, ms, rec: "RecView", enabled,
 # per-lane scatter on the big [T, DS, DW*SW] sharers store as a
 # FULL-ARRAY dense pass (measured ~8 ms each at 1024 tiles, three per
 # iteration — the coherence-storm floor, PERF.md round-4 findings; the
-# same writes on the small [T, DS, DW] entry arrays cost little and stay
-# direct).  Staged mode: writes land in the small per-LANE (skey, sval)
+# same writes on the [T, DS, DW] entry words stay direct, one merged
+# landing an iteration: `_entry_land`).  Staged mode: writes land in the
+# small per-LANE (skey, sval)
 # rows (`_stage_put`); the engine's sharers reads overlay them
 # (`_stage_overlay_rows`); `dir_stage_flush` applies the rows to the big
 # store once per inner_block iterations (engine/step._quantum_loop), one
@@ -925,7 +927,7 @@ class _DirWorkingSet:
         gate closed no home phase runs, so no view is read: selection,
         gather and staging overlay are all skipped and the rows are
         zeros."""
-        self._dw = d.entry.shape[2]
+        self._dw = mp.dir_ways
         self._dir_sets = mp.dir_sets
 
         def rows(lo=lambda x: x):
@@ -935,7 +937,7 @@ class _DirWorkingSet:
                  for ln in lines], axis=1)                    # [T, 3]
             sets = lo(sets3)
             lt = np.arange(d.entry.shape[0], dtype=np.int32)[:, None]
-            ew = d.entry[lt, sets]                            # [Tl, 3, DW]
+            ew = _entry_rows(d.entry, lt, sets)               # [Tl, 3, DW]
             sh = d.sharers[lt, sets]                          # [Tl, 3, DW*SW]
             if d.skey is not None:
                 sh = _stage_overlay_rows(d, sets, sh)
@@ -988,6 +990,41 @@ class _DirWorkingSet:
         return _DirRowView(line, sets, ew, sh, self._dw)
 
 
+# The entry store has two forms (memory/state.py: DirectoryArrays), told
+# apart by dtype: these two functions are all of the engine that knows.
+
+
+def _entry_rows(entry, lanes, sets):
+    """The int64[Tl, K, DW] packed words of each lane's set rows `sets`
+    (int32[Tl, K]; `lanes` the block's int32[Tl, 1] lane indices): rows
+    out of the store.  On the u32 words a set is a column - a lane's low
+    words over its high words - and the int64 word is assembled from the
+    gathered columns alone, register math."""
+    if entry.dtype != U32:
+        return entry[lanes, sets]
+    cols = entry[lanes, :, sets]                          # [Tl, K, 2 * DW]
+    dw = cols.shape[2] // 2
+    return cols[..., :dw].astype(I64) | (cols[..., dw:].astype(I64) << 32)
+
+
+def _entry_land(entry, t_e, s_all, w_all, ed_all, px: ParallelCtx):
+    """The folded plan's deltas `ed_all` added to the words (t_e, s_all,
+    w_all), distinct or out of bounds (`t_e` = Tl: folded away): a plan
+    into the store.  int64: one scatter-add, a pass over the store on a
+    TPU.  u32 words: `row_landing.apply_entry` - where the program is
+    lowered for a TPU and has no sim axis a kernel that moves the tiles
+    of the plan's nonzero words alone, a lane's phases in turn."""
+    if entry.dtype != U32:
+        return entry.at[t_e, s_all, w_all].add(
+            ed_all, mode="drop", unique_indices=True)
+    Tl = entry.shape[0]
+    with scope("gt.mem.entry_land"):
+        return row_landing.apply_entry(
+            entry, s_all.reshape(-1, Tl), w_all.reshape(-1, Tl),
+            ed_all.reshape(-1, Tl), (t_e < Tl).reshape(-1, Tl),
+            sim_axis=px.sim_axis)
+
+
 def _dir_apply_merged(d, px: ParallelCtx, packs, live=None):
     """ONE merged scatter per big directory store per iteration: the
     home phases' delta plans land together at the end of
@@ -996,8 +1033,10 @@ def _dir_apply_merged(d, px: ParallelCtx, packs, live=None):
     slot redirected out of bounds, so the scatters keep unique indices
     (in-place friendly) and the summed deltas stay exact — each phase's
     delta was computed against the forwarded view, so the fold telescopes
-    to final-minus-initial.  Sharers deltas apply only in unstaged mode
-    (staged writes ride the per-lane table and flush per block).
+    to final-minus-initial.  The entry store's plan lands through
+    `_entry_land`, by the store's form.  Sharers deltas apply only in
+    unstaged mode (staged writes ride the per-lane table and flush per
+    block).
 
     `live` (the home-activity gate, or None = forced live) gates the
     fold and the scatters in place (`_run_if`): where it is false every
@@ -1030,8 +1069,7 @@ def _dir_apply_merged(d, px: ParallelCtx, packs, live=None):
         s_all = jnp.concatenate(sets)
         w_all = jnp.concatenate(way)
         ed_all = jnp.concatenate(ed)
-        entry = stores[0].at[t_e, s_all, w_all].add(
-            ed_all, mode="drop", unique_indices=True)
+        entry = _entry_land(stores[0], t_e, s_all, w_all, ed_all, px)
         if staged:
             return (entry,)
         t_s = jnp.concatenate([jnp.where(dr, Tl, t) for dr in drop_s])
@@ -2588,7 +2626,7 @@ def line_census(ms: MemState, mp: MemParams, lines) -> dict:
     l1d_st = np.asarray(ms.l1d.state)
     l2_tag = np.asarray(ms.l2.tags)
     l2_st = np.asarray(ms.l2.state)
-    entry = np.asarray(ms.directory.entry)
+    entry = np.asarray(row_landing.entry_int64(ms.directory.entry))
     sharers = np.asarray(ms.directory.sharers)
     cdata_line = np.asarray(ms.txn.cdata_line)
     cdata_valid = np.asarray(ms.txn.cdata_valid)

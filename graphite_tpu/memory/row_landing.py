@@ -1,9 +1,9 @@
-"""Land sparse updates on a resident store, touching only the rows they
+"""Land sparse updates on a resident store, touching only the tiles they
 name: the Pallas TPU kernels behind `engine_shl2._dir_apply_rows`
-(`land_rows`: a plan of row deltas, one row a slab) and behind
-`engine.dir_stage_flush` (`flush_staged`, further down: the private-L2
-directory's staging table, with the XLA form it replaces on the chip and
-the choice between the two).
+(`land_rows`: row deltas, one row a slab), `engine.dir_stage_flush`
+(`flush_staged`: the private-L2 staging table) and `engine._entry_land`
+(`apply_entry`, at the end: the entry store's TWO forms, int64 | u32 words,
+chosen by `state.entry_as_words`) - each with its XLA form and the choice.
 
 An XLA scatter-add of 1,024 rows of 1 KB onto the `u32[1048576, 256]`
 sharers store is in place and still costs what streaming the 1.07 GB
@@ -360,3 +360,237 @@ def flush_staged(sharers, skey, sval, sn, *, sim_axis=None):
         sharers, skey, sval, sn, tpu=land_staged,
         default=lambda sharers, skey, sval, sn: scatter_staged(
             sharers, skey, sval))
+
+
+# ---------------------------------------------------------------------------
+# the private-L2 directory's entry words (engine._dir_apply_merged)
+#
+# The home phases' plan is at most three 8-byte words a lane, a few hundred
+# live ones an iteration.  As an XLA scatter-add onto the `int64[1024, 1024,
+# 16]` entry store it costs five passes over a 64 MB half: the store is two
+# u32 halves tiled (8, 128), an element scatter wants them linear, so each is
+# copied flat, scattered on and reshaped back - 0.97 ms an open iteration on
+# a v5e (`_hand/entry45.py`; PERF.md section 6, PR 45).  Mosaic has no int64
+# and a split in front of a kernel brings the passes back, so where this
+# kernel may engage the store is CARRIED as u32 words, `u32[T, 2 * DW, DS]`
+# (`entry_words`): ways on sublanes, sets on lanes, a lane's low words in
+# rows 0..DW-1 and its high words in rows DW..2*DW-1 - the layout XLA gave
+# the halves anyway, so the working-set gather reads what it read.  A plan
+# word (t, set, way) is lane `set % 128` of sublane `way % 8` of TWO tiles;
+# `land_entry` moves those alone and adds the 64-bit delta as a u32 pair
+# with carry.  Only LIVE words travel (a nonzero delta that no earlier
+# phase folded): they are sorted to a prefix of their phase and the
+# kernel's loops run to the count.  Lanes never share a tile (a lane owns
+# whole groups of rows); a lane's phases may (two ways of one group, two
+# sets of one 128-lane column).  So the phase index runs outermost and in
+# turn - phase p drained before phase p + 1 fetches - and lanes go together
+# within a phase.
+# ---------------------------------------------------------------------------
+
+# live words landed per grid step: the VMEM scratch is two tiles a word,
+# [lanes * 2 * GROUP, 128] (4 MB at 512), under the v5e's 16 MB scoped limit
+ENTRY_LANES_PER_STEP = 512
+
+
+def entry_words(entry):
+    """An `int64[T, DS, DW]` entry store as the u32 words `land_entry`
+    lands on: `u32[T, 2 * DW, DS]`, low words above high words."""
+    word = jnp.swapaxes(entry, 1, 2)
+    return jnp.concatenate([word.astype(jnp.uint32),
+                            (word >> 32).astype(jnp.uint32)], axis=1)
+
+
+def entry_int64(entry):
+    """The `int64[..., DS, DW]` words of an entry store in either form:
+    `entry_words`' inverse on u32, the identity on int64."""
+    if entry.dtype != jnp.uint32:
+        return entry
+    n_ways = entry.shape[-2] // 2
+    lo = entry[..., :n_ways, :].astype(jnp.int64)
+    hi = entry[..., n_ways:, :].astype(jnp.int64)
+    return jnp.swapaxes(lo | (hi << 32), -1, -2)
+
+
+def can_land_entry(n_lanes, n_sets, n_ways) -> bool:
+    """Whether `land_entry` takes a `[P, n_lanes]` plan for the
+    `u32[n_lanes, 2 * n_ways, n_sets]` words of an entry store: sets that
+    fill whole 128-word columns and ways that fill whole groups, so that
+    a word's halves lie in two tiles and no two LANES share one (a lane's
+    own phases do: the kernel takes them in turn)."""
+    return (n_sets % 128 == 0 and n_ways % GROUP == 0
+            and (n_lanes <= ENTRY_LANES_PER_STEP
+                 or n_lanes % ENTRY_LANES_PER_STEP == 0))
+
+
+def _entry_kernel(count_ref, row_ref, col_ref, dlo_ref, dhi_ref, store_ref,
+                  out_ref, buf, sem_in, sem_out, *, n_lanes, n_ways):
+    del store_ref   # aliased to `out_ref`
+    # (x64 is on package-wide: every Python int is wrapped, see above)
+    i32 = jnp.int32
+    step = i32(buf.shape[0] // (2 * GROUP))
+    p, j = pl.program_id(0), pl.program_id(1)
+    zero = i32(0)
+    # phase p's live words come first: the first `count[p]` of its row
+    n = jnp.clip(count_ref[p] - j * step, zero, step)
+    base = p * i32(n_lanes) + j * step
+
+    def tile(r, half):
+        # the (GROUP, 128) HBM tile word r's low (0) / high (1) half lies in
+        row = row_ref[base + r] + i32(half * n_ways)
+        col = col_ref[base + r]
+        return (pl.ds(pl.multiple_of(row - jax.lax.rem(row, i32(GROUP)),
+                                     GROUP), GROUP),
+                pl.ds(pl.multiple_of(col - jax.lax.rem(col, i32(128)), 128),
+                      128))
+
+    def slot(r, half):
+        return buf.at[pl.ds(pl.multiple_of((r * i32(2) + i32(half))
+                                           * i32(GROUP), GROUP), GROUP)]
+
+    def fetch(r, half):
+        # from the OUTPUT, which is the store (aliased): a later phase of
+        # a lane has to find the earlier ones landed
+        return pltpu.make_async_copy(out_ref.at[tile(r, half)],
+                                     slot(r, half), sem_in)
+
+    def write(r, half):
+        return pltpu.make_async_copy(slot(r, half),
+                                     out_ref.at[tile(r, half)], sem_out)
+
+    def loop(body):
+        jax.lax.fori_loop(zero, n, lambda r, carry: (body(r), carry)[1], zero)
+
+    def both(copy):
+        return lambda r: (copy(r, 0), copy(r, 1))
+
+    # a phase's tiles all in flight on ONE semaphore, then all waited for
+    # (every copy is one tile: a wait is told apart by its size alone);
+    # the phase's writes are drained before the next phase fetches
+    loop(both(lambda r, half: fetch(r, half).start()))
+    loop(both(lambda r, half: fetch(zero, 0).wait()))
+
+    lane = jax.lax.broadcasted_iota(i32, (1, 128), 1)
+
+    def add(r):
+        # the 64-bit add as a u32 pair with carry, under a one-lane mask
+        sub = jax.lax.rem(row_ref[base + r], i32(GROUP))
+        at_lo = pl.ds(r * i32(2 * GROUP) + sub, 1)
+        at_hi = pl.ds(r * i32(2 * GROUP) + i32(GROUP) + sub, 1)
+        here = lane == jax.lax.rem(col_ref[base + r], i32(128))
+        lo = buf[at_lo, :]
+        new_lo = lo + jnp.where(here, dlo_ref[base + r],
+                                zero).astype(jnp.uint32)
+        buf[at_lo, :] = new_lo
+        buf[at_hi, :] = (buf[at_hi, :]
+                         + jnp.where(here, dhi_ref[base + r],
+                                     zero).astype(jnp.uint32)
+                         + (new_lo < lo).astype(jnp.uint32))
+
+    loop(add)
+    loop(both(lambda r, half: write(r, half).start()))
+    loop(both(lambda r, half: write(zero, 0).wait()))
+
+
+def _entry_plan(store, sets, way, delta, live):
+    """(lanes, ways, which words travel) of a `[P, T]` plan on `store`:
+    a word whose delta is zero is as good as folded away."""
+    n_lanes = store.shape[0]
+    lanes = jnp.broadcast_to(jnp.arange(n_lanes, dtype=jnp.int32),
+                             sets.shape)
+    return lanes, way.astype(jnp.int32), live & (delta != 0)
+
+
+def pack_entry_plan(store, sets, way, delta, live):
+    """What the kernel reads of a plan, five int32 arrays: each phase's
+    count of live words, and `[P, T]` rows of the words' low-half row in
+    the row-flat store, their column and their delta's halves, the live
+    words a prefix of their phase (ONE stable sort: a function of the
+    plan alone, the coordinates and halves riding along)."""
+    i32 = jnp.int32
+    lanes, way, live = _entry_plan(store, sets, way, delta, live)
+    _, row, col, dlo, dhi = jax.lax.sort(
+        ((~live).astype(i32), lanes * i32(store.shape[1]) + way,
+         sets.astype(i32), delta.astype(i32), (delta >> 32).astype(i32)),
+        dimension=1, is_stable=True, num_keys=1)
+    return jnp.sum(live, axis=1, dtype=i32), row, col, dlo, dhi
+
+
+def scatter_entry(store, sets, way, delta, live):
+    """The plan landed on the u32 words by XLA: the plan's current words
+    gathered, the deltas added in int64, both halves set (the live words'
+    indices are unique; the others go out of bounds and are dropped).  A
+    pass over the store on a TPU, whatever the plan holds."""
+    n_lanes, rows, _ = store.shape
+    n_ways = rows // 2
+    lanes, way, live = _entry_plan(store, sets, way, delta, live)
+    new = (store[lanes, way, sets].astype(jnp.int64)
+           | (store[lanes, way + n_ways, sets].astype(jnp.int64) << 32)
+           ) + delta
+    lanes = jnp.where(live, lanes, n_lanes)
+
+    def both(lo, hi):
+        return jnp.concatenate([lo.ravel(), hi.ravel()])
+
+    return store.at[both(lanes, lanes), both(way, way + n_ways),
+                    both(sets, sets)].set(
+        both(new.astype(jnp.uint32), (new >> 32).astype(jnp.uint32)),
+        mode="drop", unique_indices=True)
+
+
+def land_entry(store, sets, way, delta, live, *,
+               lanes_per_step=ENTRY_LANES_PER_STEP, interpret=False):
+    """`delta[p, t]` added to the 64-bit entry word (t, sets[p, t],
+    way[p, t]) wherever `live[p, t]`, phase p = 0, 1, ... in turn - in
+    place, priced by the live words.
+
+    store: u32[T, 2 * DW, DS] (`entry_words`); sets, way: int32[P, T];
+    delta: int64[P, T]; live: bool[P, T], the live words of a lane naming
+    distinct words.  `can_land_entry(T, DS, DW)` must hold.  Exact where
+    a lane's words share a tile because phases land in turn, each drained
+    before the next fetches; within a phase every word is another lane's
+    and lanes share no tile."""
+    n_lanes, rows, n_sets = store.shape
+    n_ways = rows // 2
+    n_plans = sets.shape[0]
+    step = min(n_lanes, lanes_per_step)
+    if (not can_land_entry(n_lanes, n_sets, n_ways) or n_lanes % step
+            or sets.shape != (n_plans, n_lanes)):
+        raise ValueError(f"land_entry: store {store.shape}, plan "
+                         f"{sets.shape}")
+    count, *words = pack_entry_plan(store, sets, way, delta, live)
+    flat = pl.pallas_call(
+        functools.partial(_entry_kernel, n_lanes=n_lanes, n_ways=n_ways),
+        out_shape=jax.ShapeDtypeStruct((n_lanes * rows, n_sets),
+                                       store.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n_plans, n_lanes // step),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((step * 2 * GROUP, 128), store.dtype),
+                pltpu.SemaphoreType.DMA(()),
+                pltpu.SemaphoreType.DMA(()),
+            ],
+        ),
+        # operands 0..4 are the scalar-prefetched plan
+        input_output_aliases={5: 0},
+        name="dir_entry_landing",
+        interpret=interpret,
+    )(count, *(w.ravel() for w in words),
+      store.reshape(n_lanes * rows, n_sets))
+    return flat.reshape(store.shape)
+
+
+def apply_entry(store, sets, way, delta, live, *, sim_axis=None):
+    """The u32 entry words with a plan landed, in place: where the
+    program is lowered for a TPU, has no sim axis and the store's shape
+    allows it (`can_land_entry`), the kernel, priced by the live words;
+    everywhere else `scatter_entry`, priced by the store."""
+    n_lanes, rows, n_sets = store.shape
+    if sim_axis is not None or not can_land_entry(n_lanes, n_sets,
+                                                  rows // 2):
+        return scatter_entry(store, sets, way, delta, live)
+    return jax.lax.platform_dependent(
+        store, sets, way, delta, live, tpu=land_entry,
+        default=scatter_entry)

@@ -32,6 +32,7 @@ from flax import struct
 
 from graphite_tpu.memory.cache_array import CacheArrays, make_cache
 from graphite_tpu.memory.params import MemParams
+from graphite_tpu.memory.row_landing import can_land_entry
 
 I64 = jnp.int64
 
@@ -90,10 +91,27 @@ class DirectoryArrays:
     (entry-major, large minor dim) was built and measured 1.6x SLOWER —
     the computed-column gathers lower worse than structured indexing,
     and the whole-array copies it targeted barely moved (PERF.md
-    round-3 findings)."""
+    round-3 findings).
+
+    The entry store has TWO forms, chosen once, where the state is built
+    (`entry_as_words`), and told apart by dtype ever after
+    (`engine._entry_rows`, `engine._entry_land`, `row_landing.entry_int64`
+    are all that look):
+     - int64[T, DS, DW]: one scatter-add an iteration lands the home
+       phases' plan.  On a TPU the compiler keeps an int64 array as two
+       u32 halves tiled (8, 128) and an element scatter linearises them:
+       five passes over a half an open iteration, cheap on a small store
+       (8 MB at 64 tiles) and 0.97 ms on the 128 MiB one (PR 45);
+     - u32[T, 2 * DW, DS], the same words as the halves already lay (ways
+       on sublanes, sets on lanes; a lane's low words in rows 0..DW-1,
+       its high words in rows DW..2*DW-1): where a program is lowered for
+       a TPU with no sim axis, a kernel lands the plan's live words' tiles
+       alone (`row_landing.land_entry`); elsewhere an XLA gather-and-set
+       on the same words.  The int64 word exists only in the gathered
+       rows."""
 
     # packed (tag, dstate, owner, nsharers) word per entry — layout above
-    entry: jax.Array     # int64[T, DS, DW]
+    entry: jax.Array     # int64[T, DS, DW] | uint32[T, 2*DW, DS]
     # full-map bitvector, stored set-row-major [T, DS, DW*SW] (way w's
     # words at [.., w*SW:(w+1)*SW]): a [T, DS, DW, SW] layout pads SW up
     # to the 128-lane tile on TPU (4x physical at 1024 tiles — PERF.md
@@ -341,6 +359,17 @@ MT_WORDS = MT_BITS // 32
 MT_FETCHED, MT_EVICTED, MT_INVALIDATED = 0, 1, 2
 
 
+def entry_as_words(mp: MemParams) -> bool:
+    """Whether the directory's entry store is carried as u32 words
+    (DirectoryArrays): where a pass over a big store an iteration is
+    what the program cannot afford — the programs that stage their
+    sharers writes for the same reason (a sharers store of 64 MB and up,
+    or staging asked for) — and the words' shape lets the kernel land on
+    them.  The others keep the int64 store and the programs they had."""
+    return bool(mp.dir_stage_cap) and can_land_entry(
+        mp.n_tiles, mp.dir_sets, mp.dir_ways)
+
+
 def init_mem_state(mp: MemParams) -> MemState:
     T = mp.n_tiles
     SW = mp.sharer_words
@@ -350,7 +379,9 @@ def init_mem_state(mp: MemParams) -> MemState:
         return jnp.zeros(T, I64)
 
     directory = DirectoryArrays(
-        entry=jnp.zeros((T, DS, DW), I64),
+        # (the all-zero word is the free entry in either form)
+        entry=(jnp.zeros((T, 2 * DW, DS), jnp.uint32) if entry_as_words(mp)
+               else jnp.zeros((T, DS, DW), I64)),
         sharers=jnp.zeros((T, DS, DW * SW), jnp.uint32),
         skey=(jnp.full((T, mp.dir_stage_cap), -1, jnp.int32)
               if mp.dir_stage_cap else None),

@@ -39,6 +39,8 @@ SCOPES = (
     "gt.mem.base",          # memory engine outside its phases, its gate
 ) + tuple("gt.mem." + p for p in _MEM_PHASES) + (
     "gt.mem.stage_flush",   # dir_stage_flush, once per inner block
+    "gt.mem.entry_land",    # inside gt.mem.base: the home phases' plan
+                            #   landed on the u32 entry words
     "gt.mem.dir_apply",     # shl2: a home phase's row plan landed on the
                             #   embedded directory, outside its gate
     "gt.net.mailbox",       # SEND / NET_RECV rings

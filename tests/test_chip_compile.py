@@ -219,12 +219,15 @@ def test_served_b4_16_gates_return_no_directory_store(one_chip):
     body = loop_copies(text, under="gt.mem.requester/")
     halves = copies_of(body, mem_state.l2.meta.shape, ("u32", "s64"))
     assert not halves, [c.line[:200] for c in halves]
+    _carries_the_int64_entry_store(runner.sim, text)
 
 
 @pytest.mark.slow
 def test_ref_default_64_compiles(one_chip):
     sim = _ref_default(64, points=64)
-    _fits(_report("ref-default-64", _compile_run(sim, one_chip)))
+    compiled = _compile_run(sim, one_chip)
+    _fits(_report("ref-default-64", compiled))
+    _carries_the_int64_entry_store(sim, compiled.as_text())
 
 
 def _hbh256(barrier_host):
@@ -285,6 +288,53 @@ def _flush_moves_the_staged_slots_alone(text, sharers, cap):
         assert not head.startswith(f"u32[{T},{cap},{W}]"), ln[:300]
     whole = copies_of(loop_copies(text), sharers, ("u32",))
     assert not whole, [c.line[:200] for c in whole]
+
+
+def _entry_words_land_through_the_kernel(text, entry):
+    """PR 45, of a program whose entry store is carried as u32 words,
+    compiled for the chip: the home phases' plan lands through
+    `dir_entry_landing`, under `gt.mem.entry_land`, whose output IS the
+    store; the int64 scatter's five passes over a 64 MB half are gone
+    from the program (its linearising copy, its fusion on the flat half,
+    the reshape back), no loop copies the store, and memory-space
+    assignment moves no piece of it (`slice-start` / `copy-start`: what
+    it did to two separate halves)."""
+    from graphite_tpu.analysis.loop_copies import (
+        computations, copies_of, loop_copies, loops,
+    )
+
+    T, rows, DS = entry
+    kernel, = _landing_kernels(text, "dir_entry_landing",
+                               "gt.mem.entry_land")
+    assert f"u32[{T * rows},{DS}]" in kernel.split(" custom-call(")[0]
+    assert "output_to_operand_aliasing={{}: (5, {})}" in kernel
+    half = T * DS * rows // 2
+    gone = (f"u32[{half}]", f"u32[{2 * half}]",
+            f"u32[{half // 8192},8,8,128]", f"u32[{half // 4096},8,8,128]",
+            f"u32[{T},{rows // 2},{DS}]")
+    stores = (f"u32[{T},{rows},{DS}]", f"u32[{T * rows},{DS}]")
+    comps = computations(text)
+    in_loops = set().union(*(lp.comps for lp in loops(comps).values()))
+    assert any(kernel in comps[c] for c in in_loops)
+    for ln in text.splitlines():
+        if " = " in ln:
+            head = ln.split(" = ", 1)[1].lstrip("(")[:60]
+            assert not head.startswith(gone), ln[:300]
+    for ln in (ln for c in in_loops for ln in comps[c] if " = " in ln):
+        if ln.split(" = ", 1)[1].lstrip("(")[:60].startswith(stores):
+            assert not any(op in ln for op in (
+                " copy-start(", " slice-start(", " copy(", " fusion(",
+                " reshape(")), ln[:300]
+    whole = copies_of(loop_copies(text), entry, ("u32",))
+    assert not whole, [c.line[:200] for c in whole]
+
+
+def _carries_the_int64_entry_store(sim, text):
+    """A program PR 45 leaves as it was: the int64 store, no kernel."""
+    d = sim.state.mem.directory
+    assert str(d.entry.dtype) == "int64" and d.entry.ndim >= 3
+    assert "dir_entry_landing" not in text
+    assert "gt.mem.entry_land" not in text
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -353,6 +403,7 @@ def test_coh_1024_host_batch_compiles(one_chip):
     d = sim.state.mem.directory
     _flush_moves_the_staged_slots_alone(compiled.as_text(),
                                         d.sharers.shape, d.skey.shape[1])
+    _entry_words_land_through_the_kernel(compiled.as_text(), d.entry.shape)
 
 
 def _shl2_memstress(tiles):
@@ -543,6 +594,11 @@ def test_canneal_dvfs_host_batch_compiles(one_chip, tiles):
     compiled = _compile_host_batch(sim, one_chip)
     _fits(_report(f"canneal-dvfs-{tiles}-host-batch", compiled))
     assert "gt.energy" in compiled.as_text()
+    if tiles == 1024:
+        _entry_words_land_through_the_kernel(
+            compiled.as_text(), sim.state.mem.directory.entry.shape)
+    else:
+        _carries_the_int64_entry_store(sim, compiled.as_text())
 
 
 @pytest.mark.slow

@@ -57,6 +57,11 @@ PINNED = {
                      "l2_cloc": 0,          # [2]
                      "directory.entry": 0, "directory.sharers": 0,
                      "func_mem": 2},
+    # the staged program carries the entry store as u32 words (PR 45):
+    # gathered by column, landed by `row_landing.scatter_entry` here
+    "solo-staged": {"l2.meta": 0, "l2_cloc": 0,
+                    "directory.entry": 0, "directory.sharers": 0,
+                    "func_mem": 0},
     "campaign-b2": {"l2.meta": 0,           # [2]
                     "l2_cloc": 0,
                     "directory.entry": 0, "directory.sharers": 0,
@@ -64,6 +69,7 @@ PINNED = {
 }
 SOLO = {"solo-gated": {},
         "solo-phase-gated": {"mem_gate_bytes": 0},
+        "solo-staged": {"mem_gate_bytes": 0, "dir_stage": True},
         "solo-ungated": {"phase_gate": False, "mem_gate_bytes": 0}}
 
 

@@ -202,33 +202,41 @@ def test_layer_metric_readers(name, own, want, runs):
     assert getattr(own.get("sim"), "runs", 0) == runs
 
 
+@pytest.mark.parametrize("name,scope,from_end", [
+    ("stage_flush_busy_share", "gt.mem.stage_flush", 5),
+    ("entry_land_busy_share", "gt.mem.entry_land", 1),
+])
 @pytest.mark.parametrize("scoped,want", [
-    (True, 5.0),        # 1.0 s under `gt.mem.stage_flush` of 20.0 s busy
-    (False, None),      # a program that stages nothing: no such scope
+    (True, 5.0),        # 1.0 s under the scope of 20.0 s busy
+    (False, None),      # a program without it: no such scope
     (None, None),       # a run without a scope trace
-], ids=["staged", "unstaged", "untraced"])
-def test_stage_flush_reader(scoped, want):
-    """PR 43's `stage_flush_busy_share`: the flush's scope by itself,
-    in the staged cells (`mem_ungated_busy_share` holds it with
-    `gt.mem.base`); nothing where the program has no such scope."""
-    entry, = [m for m in MANIFEST["per_layer"]
-              if m["name"] == "stage_flush_busy_share"]
+], ids=["scoped", "unscoped", "untraced"])
+def test_staged_scope_readers(name, scope, from_end, scoped, want):
+    """PR 43's `stage_flush_busy_share` and PR 45's
+    `entry_land_busy_share`: the flush's / the entry words' landing's
+    scope by itself, in the staged cells (`mem_ungated_busy_share` holds
+    the first with `gt.mem.base`, and not the second: a scope trace
+    counts an operation for its deepest scope); nothing where the
+    program has no such scope."""
+    entry, = [m for m in MANIFEST["per_layer"] if m["name"] == name]
     assert entry["workloads"] == [CELL_NAME, "a2a1024-fftskel",
                                   "canneal1024-dvfs"]
     assert (entry["moves"], entry["better"]) == ("sim_records_per_s",
                                                  "lower")
-    # appended (PR 44's three metrics follow it)
+    # appended (PR 44's three metrics follow the flush's, PR 45's them)
     assert [m["name"] for m in MANIFEST["per_layer"]].index(
-        "stage_flush_busy_share") == len(MANIFEST["per_layer"]) - 4
+        name) == len(MANIFEST["per_layer"]) - from_end
     ctx = _ctx()
+    busy = ctx.own["scope_trace"]["busy_s"]
+    if scope not in busy:       # the landing's second, out of the base's
+        busy[scope], busy["gt.mem.base"] = 1.0, busy["gt.mem.base"] - 1.0
     if scoped is None:
         ctx.own["scope_trace"] = None
     elif not scoped:
-        del ctx.own["scope_trace"]["busy_s"]["gt.mem.stage_flush"]
+        del ctx.own["scope_trace"]["busy_s"][scope]
     sys.path.insert(0, BENCH)
     try:
-        got = paths.load_module(
-            "layer_metrics", "stage_flush_busy_share").read(ctx)
+        got = paths.load_module("layer_metrics", name).read(ctx)
     finally:
         sys.path.remove(BENCH)
     assert got == (None if want is None else pytest.approx(want))

@@ -363,6 +363,31 @@ associativity = 4
         + "\n".join(str(f) for f in findings))
 
 
+def test_staged_conds_carry_no_entry_words():
+    """A staged program carries its entry store as u32 words (PR 45):
+    `dir_store_avals` names that form, the landing's choice of a lowering
+    platform returns it (`walk.is_platform_choice`: no run-time cond), and
+    no phase's cond does."""
+    from graphite_tpu.analysis.rules import cond_payload
+    from graphite_tpu.engine.step import subquantum_iteration
+    from graphite_tpu.memory.engine import dir_store_avals
+
+    batch = synthetic.memory_stress_trace(
+        8, n_accesses=8, working_set_bytes=1 << 12, write_fraction=0.4,
+        shared_fraction=0.6, seed=11)
+    sim = Simulator(make_config(8), batch, dir_stage=True, inner_block=4,
+                    mem_gate_bytes=0)
+    mp = sim.params.mem
+    (entry, dtype), _ = avals = dir_store_avals(sim.state.mem)
+    assert (entry, dtype) == ((8, 2 * mp.dir_ways, mp.dir_sets), "uint32")
+    closed = jax.make_jaxpr(
+        lambda st: subquantum_iteration(
+            sim.params, sim.device_trace, st,
+            jnp.asarray(2**61, jnp.int64)))(sim.state)
+    assert "platform_index" in str(closed)
+    assert not cond_payload(closed, forbidden=avals)
+
+
 # ---- batched host-barrier dispatch ----------------------------------------
 
 

@@ -35,6 +35,24 @@ the XLA flush (`scatter_staged`) it replaces on the chip, the same way -
 - at 16 tiles with the default directory, and under a sim axis, the flush's
   jaxpr is the parent's letter for letter; at a lane-aligned shape the
   default arm is, and the CPU lowers it and a TPU target the kernel.
+
+The private-L2 directory's entry words (PR 45): `land_entry` on the u32
+form of the entry store against the int64 scatter-add it replaces -
+
+- the words after a plan through the kernel are bit for bit the int64
+  store's after the scatter-add: no live word, one, a lane's three phases
+  in one tile, two ways of one group, a carry out of the low word, a
+  negative delta, every word of the plan live, more than one grid step,
+  the gate open and closed; and `scatter_entry`, the XLA form on the same
+  words, lands the same;
+- `_dir_apply_merged` on an int64 store is the parent's, letter for
+  letter; on u32 words under a sim axis it is the XLA form with no choice
+  of a platform, and with none the CPU lowers that form and a TPU target
+  the kernel, named under `gt.mem.entry_land`;
+- a staged private-L2 run (16 tiles, the default directory: the smallest
+  geometry that takes the u32 form) ends in the same statistics and the
+  same `line_census` whichever form its state was built in, and with every
+  landing through the kernel.
 """
 
 import dataclasses
@@ -48,7 +66,10 @@ import pytest
 from graphite_tpu.config import ConfigFile, SimConfig
 from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.memory import engine_shl2, row_landing
-from graphite_tpu.memory.engine import _run_if, dir_stage_flush
+from graphite_tpu.memory import state as mem_state
+from graphite_tpu.memory.engine import (
+    _dir_apply_merged, _run_if, dir_stage_flush, line_census,
+)
 from graphite_tpu.memory.engine_shl2 import (
     ShL2Dir, _dir_apply_rows, _scatter_add_rows,
 )
@@ -528,3 +549,266 @@ def test_lane_aligned_flush_follows_the_lowering_target():
     assert (tpu.as_text().count(scatter), tpu.as_text().count(kernel)) == (0, 1)
     assert ("/gt.mem.stage_flush/while/body/cond/branch_0_fun/"
             "dir_stage_landing/pallas_call" in tpu.as_text(debug_info=True))
+
+
+# ---------------------------------------------------------------------------
+# the entry words of the private-L2 directory (PR 45)
+# ---------------------------------------------------------------------------
+
+# 16 lanes of 256 sets (two 128-word columns) and 16 ways (two groups): a
+# lane's words are four groups of rows, low ways 0-7, 8-15, high 0-7, 8-15
+ET, EDS, EDW, EP = 16, 256, 16, 3
+I64 = jnp.int64
+
+
+def _entry_plan(case):
+    """(entry, sets, way, delta, live): an int64 store and a `[3, T]`
+    plan as `_dir_apply_merged` folds one - a lane's live words distinct."""
+    rng = np.random.default_rng(45)
+    entry = rng.integers(0, 2**63, (ET, EDS, EDW), dtype=np.int64)
+    sets = rng.integers(0, EDS, (EP, ET))
+    way = (rng.integers(0, EDW, ET)[None, :] + np.arange(EP)[:, None]) % EDW
+    delta = rng.integers(-2**62, 2**62, (EP, ET), dtype=np.int64)
+    live = np.zeros((EP, ET), bool)
+    if case == "one_word":
+        live[1, 5] = True
+    elif case == "three_phases_one_tile":
+        # lane 3: sets 130, 140, 255 of column 1, ways 8, 9, 15 of group 1
+        sets[:, 3], way[:, 3], live[:, 3] = (130, 140, 255), (8, 9, 15), True
+        # lane 9: ONE set, three ways of it
+        sets[:, 9], way[:, 9], live[:, 9] = 7, (0, 1, 2), True
+    elif case == "two_ways_one_group":
+        sets[:2, 6], way[:2, 6], live[:2, 6] = 64, (2, 5), True
+        sets[1:, 12], way[1:, 12], live[1:, 12] = (0, 127), 15, True
+    elif case == "carry":
+        # low words near 2**32, small positive deltas: the carry is all
+        # the high word gets; and a borrow: a negative delta on a low 0
+        entry[:, :, :] = (entry & ~np.int64(0xFFFFFFFF)) | 0xFFFFFFF0
+        delta[:] = rng.integers(0x10, 0x100, delta.shape)
+        entry[2, sets[0, 2], way[0, 2]] = np.int64(5) << 32
+        delta[0, 2] = -1
+        live[:] = True
+    elif case == "all_live":
+        live[:] = True
+    elif case == "random":
+        live = rng.random((EP, ET)) < 0.4
+        delta[rng.random((EP, ET)) < 0.2] = 0       # a phase that wrote
+        #                                             what was there
+    else:
+        assert case == "no_live_word"
+    return (jnp.asarray(entry), jnp.asarray(sets, jnp.int32),
+            jnp.asarray(way, jnp.int32), jnp.asarray(delta),
+            jnp.asarray(live))
+
+
+def _int64_scatter(entry, sets, way, delta, live):
+    """The parent's landing: one scatter-add on the int64 store."""
+    lanes = jnp.where(live, jnp.arange(ET, dtype=jnp.int32)[None, :], ET)
+    return entry.at[lanes.ravel(), sets.ravel(), way.ravel()].add(
+        delta.ravel(), mode="drop", unique_indices=True)
+
+
+@pytest.mark.parametrize("case,step,gate", [
+    ("no_live_word", row_landing.ENTRY_LANES_PER_STEP, None),
+    ("one_word", row_landing.ENTRY_LANES_PER_STEP, None),
+    ("three_phases_one_tile", row_landing.ENTRY_LANES_PER_STEP, None),
+    ("two_ways_one_group", row_landing.ENTRY_LANES_PER_STEP, None),
+    ("carry", row_landing.ENTRY_LANES_PER_STEP, None),
+    ("all_live", row_landing.ENTRY_LANES_PER_STEP, None),
+    ("all_live", 8, None),                 # two grid steps a phase
+    ("random", 8, None),
+    ("random", row_landing.ENTRY_LANES_PER_STEP, True),
+    ("random", row_landing.ENTRY_LANES_PER_STEP, False),
+])
+def test_entry_kernel_lands_what_the_int64_scatter_lands(case, step, gate):
+    entry, *plan = _entry_plan(case)
+    want = _int64_scatter(entry, *plan)
+    assert bool((want != entry).any()) == (case != "no_live_word")
+    if case == "carry":
+        # every landed low word wrapped, and the borrow took one from 5
+        hit = np.asarray(want != entry)
+        assert (np.asarray(want)[hit] & 0xFFFFFFFF < 0x100).sum() == 47
+        assert int(want[2, plan[0][0, 2], plan[1][0, 2]]) == (
+            (4 << 32) | 0xFFFFFFFF)
+    words = row_landing.entry_words(entry)
+    assert words.shape == (ET, 2 * EDW, EDS) and words.dtype == U32
+    np.testing.assert_array_equal(row_landing.entry_int64(words), entry)
+    land = functools.partial(row_landing.land_entry, lanes_per_step=step,
+                             interpret=True)
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def run(form, words, plan, live):
+        return _run_if(live, lambda s: form(s, *plan), words)
+
+    live = None if gate is None else jnp.asarray(gate)
+    for form in (land, row_landing.scatter_entry):
+        got = row_landing.entry_int64(run(form, words, plan, live))
+        np.testing.assert_array_equal(got, entry if gate is False else want)
+
+
+def test_entry_kernel_refuses_what_it_cannot_land():
+    assert row_landing.can_land_entry(1024, 1024, 16)       # the cell
+    assert row_landing.can_land_entry(256, 1024, 16)        # its quarter
+    assert row_landing.can_land_entry(ET, EDS, EDW)
+    assert not row_landing.can_land_entry(ET, 64, EDW)      # cut column
+    assert not row_landing.can_land_entry(ET, EDS, 4)       # cut group
+    assert not row_landing.can_land_entry(768, EDS, EDW)    # no whole steps
+    entry, *plan = _entry_plan("random")
+    with pytest.raises(ValueError):
+        row_landing.land_entry(
+            row_landing.entry_words(entry[:, :64]), *plan)
+    with pytest.raises(ValueError):
+        row_landing.land_entry(row_landing.entry_words(entry),
+                               *(x[:, :8] for x in plan))
+
+
+def _parents_apply_merged(d, packs, live=None):
+    """`engine._dir_apply_merged` as the parent of PR 45 wrote it
+    (staged: the sharers deltas ride the staging table)."""
+    Tl = d.entry.shape[0]
+    t = np.arange(Tl, dtype=np.int32)
+
+    def land(stores):
+        sets = [p[0] for p in packs]
+        way = [p[1] for p in packs]
+        ed = [p[2] for p in packs]
+        shd = [p[3] for p in packs]
+        n = len(packs)
+        drop_e = [jnp.zeros(Tl, jnp.bool_) for _ in range(n)]
+        drop_s = [jnp.zeros(Tl, jnp.bool_) for _ in range(n)]
+        for j in range(1, n):
+            for i in range(j):
+                eq_e = ((sets[i] == sets[j]) & (way[i] == way[j])
+                        & ~drop_e[i] & ~drop_e[j])
+                ed[i] = ed[i] + jnp.where(eq_e, ed[j], 0)
+                drop_e[j] = drop_e[j] | eq_e
+                eq_s = (sets[i] == sets[j]) & ~drop_s[i] & ~drop_s[j]
+                shd[i] = shd[i] + jnp.where(eq_s[:, None], shd[j],
+                                            jnp.zeros_like(shd[j]))
+                drop_s[j] = drop_s[j] | eq_s
+        t_e = jnp.concatenate([jnp.where(dr, Tl, t) for dr in drop_e])
+        s_all = jnp.concatenate(sets)
+        w_all = jnp.concatenate(way)
+        ed_all = jnp.concatenate(ed)
+        return (stores[0].at[t_e, s_all, w_all].add(
+            ed_all, mode="drop", unique_indices=True),)
+
+    return d.replace(entry=_run_if(live, land, (d.entry,))[0])
+
+
+def _apply_args(entry):
+    d = DirectoryArrays(
+        entry=entry, sharers=jnp.zeros((ET, EDS, EDW * 1), U32),
+        skey=jnp.full((ET, FC), -1, jnp.int32),
+        sval=jnp.zeros((ET, FC, 1), U32), sn=jnp.zeros(ET, jnp.int32))
+    _, sets, way, delta, _ = _entry_plan("random")
+    # phase 2 names phase 0's word in the even lanes: folded, dropped
+    sets = sets.at[2].set(jnp.where(jnp.arange(ET) % 2 == 0, sets[0],
+                                    sets[2]))
+    way = way.at[2].set(jnp.where(jnp.arange(ET) % 2 == 0, way[0], way[2]))
+    packs = [(sets[p], way[p], delta[p], jnp.zeros((ET, EDW), U32))
+             for p in range(EP)]
+    return d, packs, jnp.asarray(True)
+
+
+def test_int64_store_keeps_the_parents_landing_letter_for_letter():
+    entry = _entry_plan("random")[0]
+    args = _apply_args(entry)
+    got = jax.make_jaxpr(
+        lambda d, packs, live: _dir_apply_merged(d, IDENT, packs, live))(
+        *args)
+    want = jax.make_jaxpr(_parents_apply_merged)(*args)
+    assert str(got) == str(want)
+    assert "pallas_call" not in str(got) and "platform_index" not in str(got)
+    # and the words land what it lands, folded duplicates and all
+    d, packs, live = args
+    words = _dir_apply_merged(
+        d.replace(entry=row_landing.entry_words(entry)), IDENT, packs, live)
+    np.testing.assert_array_equal(
+        row_landing.entry_int64(words.entry),
+        _parents_apply_merged(d, packs, live).entry)
+    assert bool((words.entry != row_landing.entry_words(entry)).any())
+
+
+def test_entry_landing_follows_the_lowering_target():
+    d, packs, live = _apply_args(
+        row_landing.entry_words(_entry_plan("random")[0]))
+    sims = dataclasses.replace(IDENT, sim_axis="sims")
+    served = str(jax.make_jaxpr(
+        lambda d, packs, live: _dir_apply_merged(d, sims, packs, live))(
+        d, packs, live))
+    assert "pallas_call" not in served and "platform_index" not in served
+    traced = jax.jit(
+        lambda d, packs, live: _dir_apply_merged(d, IDENT, packs, live)
+    ).trace(d, packs, live)
+    assert "platform_index" in str(traced.jaxpr)
+    scatter, kernel = '"stablehlo.scatter"(', "@tpu_custom_call("
+    cpu = traced.lower().as_text()
+    assert (cpu.count(scatter), cpu.count(kernel)) == (1, 0)
+    tpu = traced.lower(lowering_platforms=("tpu",))
+    assert (tpu.as_text().count(scatter), tpu.as_text().count(kernel)) == (0, 1)
+    assert ("/while/body/gt.mem.entry_land/cond/branch_0_fun/"
+            "dir_entry_landing/pallas_call" in tpu.as_text(debug_info=True))
+
+
+def _entry_sim(**kw):
+    """16 tiles, staged, the default directory (1,024 sets of 16 ways): the
+    smallest geometry whose state is built with the u32 words."""
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        16, core="simple", shared_mem=True, clock_scheme="lax_barrier")))
+    return Simulator(sc, memory_stress_trace(
+        16, n_accesses=24, working_set_bytes=8192, write_fraction=0.4,
+        shared_fraction=0.5, seed=7), mem_gate_bytes=0, dir_stage=True,
+        inner_block=4, **kw)
+
+
+def test_entry_engine_ends_the_same_in_either_form_and_through_the_kernel(
+        monkeypatch):
+    words = _entry_sim()
+    d = words.state.mem.directory
+    assert d.entry.shape == (16, 32, 1024) and d.entry.dtype == U32
+    assert mem_state.entry_as_words(words.params.mem)
+    res_words = words.run()
+
+    calls = []
+
+    def through_kernel(store, sets, way, delta, live, sim_axis=None):
+        calls.append((store.shape, sets.shape, sim_axis))
+        return row_landing.land_entry(store, sets, way, delta, live,
+                                      interpret=True)
+
+    with monkeypatch.context() as m:
+        m.setattr(row_landing, "apply_entry", through_kernel)
+        kernel = _entry_sim()
+        res_kernel = kernel.run()
+    assert calls and set(calls) == {((16, 32, 1024), (3, 16), None)}
+
+    # the same geometry with its state built in the int64 form: the
+    # parent's program
+    monkeypatch.setattr(mem_state, "entry_as_words", lambda mp: False)
+    int64 = _entry_sim()
+    assert int64.state.mem.directory.entry.shape == (16, 1024, 16)
+    assert int64.state.mem.directory.entry.dtype == jnp.int64
+    res_int64 = int64.run()
+
+    assert res_words.mem_counters["dir_accesses"].sum() > 0
+    assert int(np.asarray(d.entry).any()) == 0      # (the initial state)
+    assert np.asarray(words.state.mem.directory.entry).any()
+    mp = words.params.mem
+    lines = range(0, 8192 // 64 + 64)
+    census = line_census(words.state.mem, mp, lines)
+    assert any(v["dir"] is not None for v in census.values())
+    for sim, res in ((kernel, res_kernel), (int64, res_int64)):
+        assert line_census(sim.state.mem, mp, lines) == census
+        for f in dataclasses.fields(res_words):     # every statistic
+            a, b = getattr(res_words, f.name), getattr(res, f.name)
+            for k in (a if isinstance(a, dict) else [None]):
+                np.testing.assert_array_equal(
+                    np.asarray(a if k is None else a[k]),
+                    np.asarray(b if k is None else b[k]), err_msg=f.name)
+    np.testing.assert_array_equal(
+        row_landing.entry_int64(words.state.mem.directory.entry),
+        int64.state.mem.directory.entry)
+    for a, b in zip(jax.tree.leaves(words.state),
+                    jax.tree.leaves(kernel.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -40,19 +40,22 @@ ONLY_HBH = {"gt.net.hbh.scan", "gt.net.hbh.commit"}
 # an energy interval's close inside the DVFS arm: only with [general]
 # enable_power_modeling (PR 44)
 ONLY_POWER = {"gt.energy"}
+# the landing on the u32 entry words: only in a STAGED private-L2 program
+# (PR 45; `msi` below is one)
+ONLY_STAGED = {"gt.mem.stage_flush", "gt.mem.entry_land"}
 MSI_SCOPES = [s for s in scopes.SCOPES
               if s not in ONLY_SHARDED | ONLY_SHL2 | ONLY_HBH | ONLY_POWER]
 SHL2_SCOPES = [s for s in scopes.SCOPES
                if s not in ONLY_SHARDED | ONLY_HBH | ONLY_POWER
-               | {"gt.core.iocoom", "gt.mem.stage_flush", "gt.obs"}]
+               | ONLY_STAGED | {"gt.core.iocoom", "gt.obs"}]
 # the memoryless hop-by-hop target (`hbh256-radix`'s, at 16 tiles): the
 # core, the mailboxes, the route with its two halves, the barrier
 HBH_SCOPES = ["gt.quantum", "gt.fetch", "gt.core", "gt.net.mailbox",
               "gt.net.route", "gt.sync.barrier"] + sorted(ONLY_HBH)
 # `canneal1024-dvfs`'s target at 16 tiles: the private-L2 program with
 # the simple core, two DVFS domains and power modelling on
-DVFS_SCOPES = [s for s in MSI_SCOPES if s not in {
-    "gt.core.iocoom", "gt.mem.stage_flush", "gt.obs"}] + sorted(ONLY_POWER)
+DVFS_SCOPES = [s for s in MSI_SCOPES if s not in ONLY_STAGED | {
+    "gt.core.iocoom", "gt.obs"}] + sorted(ONLY_POWER)
 TWO_DOMAINS = ("<1.0, CORE, L1_ICACHE, L1_DCACHE, L2_CACHE> "
                "<1.0, DIRECTORY, NETWORK_USER, NETWORK_MEMORY>")
 
