@@ -675,13 +675,26 @@ def mem_idle_out(mp: MemParams, ms, rec: "RecView", enabled,
 # iteration — the coherence-storm floor, PERF.md round-4 findings; the
 # same writes on the [T, DS, DW] entry words stay direct, one merged
 # landing an iteration: `_entry_land`).  Staged mode: writes land in the
-# small per-LANE (skey, sval)
-# rows (`_stage_put`); the engine's sharers reads overlay them
-# (`_stage_overlay_rows`); `dir_stage_flush` applies the rows to the big
+# small per-LANE (skey, sval) rows (`_stage_put`); the engine's sharers
+# reads overlay them; `dir_stage_flush` applies the rows to the big
 # store once per inner_block iterations (engine/step._quantum_loop), one
 # amortized dense pass instead of 3*inner_block — and, where the program
 # is lowered for a TPU, no pass at all: a kernel lands the staged slots'
 # tiles alone (`row_landing.flush_staged`, PR 43).
+#
+# The overlay has two halves (PR 46).  The INDEX — for every way of the
+# three gathered set rows a lane, the latest staged slot: a compare and a
+# max over `skey` (`_stage_index`) — is taken once an iteration, where
+# the working set is gathered.  The VALUE is fetched out of `sval` where
+# a home phase reads a way (`_DirRowView.sharers_at` → `_stage_fetch`:
+# one gather of T rows, inside the phase's cond): a phase reads ONE way
+# a lane, so three or four `[T]`-row gathers replace the `T * 3 * DW`
+# rows an eager overlay fetches (49,152 rows of 128 bytes at 1,024 tiles:
+# 0.56 ms an open iteration, priced by the row).  Slots a phase appends
+# lie at or past the cursor the index was taken at, so a slot the index
+# names holds the head-of-iteration value whatever ran in between.  Under
+# shard_map the rows ride one collective that no cond may hold, so the
+# sharded working set keeps the eager form (`_stage_overlay_rows`).
 #
 # The table is [T, c] per home lane (c = writes_per_iter *
 # inner_block).  Every directory write is home-lane-local, so a put is
@@ -718,30 +731,50 @@ def _stage_put(d, sets, way, mask, new_sh, dw: int):
         sn=d.sn + mask.astype(jnp.int32))
 
 
-def _stage_overlay_rows(d, sets, rows):
-    """Overlay each lane's staged writes onto gathered sharers SET rows.
-
-    `sets` int32[T, K] (the gathered rows' set indices), `rows`
-    uint32[T, K, DW*SW].  For every way of every gathered row the
-    LATEST staged slot matching (lane, set, way) wins — append order is
-    program order, so a later write overwrites an earlier one.  Cost
-    scales with the per-lane capacity c."""
-    if d.skey is None:
-        return rows
-    T, C = d.skey.shape
-    SW = d.sval.shape[2]
-    K = sets.shape[1]
-    DW = rows.shape[2] // SW
+def _stage_index(d, sets, dw: int):
+    """int32[T, K, DW]: for every way of each lane's set rows `sets`
+    (int32[T, K]) ONE PLUS its LATEST staged slot, 0 where it has none —
+    append order is program order, so a later write overwrites an earlier
+    one.  A compare and a max over the per-lane capacity c; no value
+    leaves `sval`."""
+    C = d.skey.shape[1]
     valid = d.skey >= 0                                       # [T, c]
     key = jnp.where(valid, d.skey, 0)
-    s_of = nn_div(key, DW)
-    w_of = nn_mod(key, DW)
+    s_of = nn_div(key, dw)
+    w_of = nn_mod(key, dw)
     m = valid[:, None, :] & (s_of[:, None, :] == sets[:, :, None])
     mw = m[:, :, None, :] & (
         w_of[:, None, None, :]
-        == np.arange(DW, dtype=np.int32)[None, None, :, None])
+        == np.arange(dw, dtype=np.int32)[None, None, :, None])
     rank = np.arange(1, C + 1, dtype=np.int32)
-    best = jnp.max(jnp.where(mw, rank, 0), axis=3)            # [T, K, DW]
+    return jnp.max(jnp.where(mw, rank, 0), axis=3)
+
+
+def _stage_fetch(sval, best):
+    """uint32[T, SW]: each lane's staged value at slot `best` (int32[T],
+    as `_stage_index` numbers them), zeros where it names none: ONE
+    gather of T rows of the table."""
+    has = best > 0
+    vals = sval[np.arange(sval.shape[0], dtype=np.int32),
+                jnp.where(has, best - 1, 0)]
+    return jnp.where(has[:, None], vals, jnp.zeros_like(vals))
+
+
+def _stage_overlay_rows(d, sets, rows):
+    """Overlay each lane's staged writes onto gathered sharers SET rows,
+    eagerly: the value of every way of every row that has a staged slot
+    is fetched (`T * K * DW` rows of `sval`, whatever is read of them).
+    The sharded working set's form, and the oracle of the lazy one.
+
+    `sets` int32[T, K] (the gathered rows' set indices), `rows`
+    uint32[T, K, DW*SW]."""
+    if d.skey is None:
+        return rows
+    T = d.skey.shape[0]
+    SW = d.sval.shape[2]
+    K = sets.shape[1]
+    DW = rows.shape[2] // SW
+    best = _stage_index(d, sets, DW)                          # [T, K, DW]
     has = best > 0
     idx = jnp.where(has, best - 1, 0)
     vals = d.sval[np.arange(T, dtype=np.int32)[:, None, None], idx]
@@ -856,17 +889,29 @@ class _DirAcc:
 class _DirRowView:
     """ONE pre-gathered (and delta-forwarded) directory set row per home
     lane — all a home phase reads of the directory; the big stores are
-    gathered once an iteration (`_DirWorkingSet`).  Staged writes were
-    already overlaid at gather time (`_stage_overlay_rows`), and earlier
-    phases' pending deltas were forwarded in (`_DirWorkingSet.view`), so
-    `lookup()`, `rows()` and `entry()` are pure register math."""
+    gathered once an iteration (`_DirWorkingSet`).  Earlier phases'
+    pending deltas were forwarded in (`_DirWorkingSet.view`), so
+    `lookup()` and `rows()` are pure register math.
 
-    def __init__(self, line, sets, entry_row, sharers_row, dw):
+    The sharers row has two forms.  Eager (`best` None: an unstaged
+    program, or a sharded one, whose staged writes were overlaid at
+    gather time): the row is current as it stands.  Lazy (a staged
+    single-device program): the row holds ZERO at every way with a
+    staged slot and `best` int32[T, DW] names that slot
+    (`_stage_index`); the current value of a way is the row's plus the
+    slot's, fetched out of the phase's own `d.sval` when the way is read
+    (`sharers_at`) — once a `way` operand, as `_DirAcc._bind` pins
+    operands by identity, so a phase that reads and updates one way
+    fetches once."""
+
+    def __init__(self, line, sets, entry_row, sharers_row, dw, best=None):
         self.sets = sets
         self._line = line
         self._word = entry_row      # int64[T, DW]
         self._sh = sharers_row      # uint32[T, DW*SW]
         self._dw = dw
+        self._best = best           # int32[T, DW] | None (eager)
+        self._staged = None         # (way, uint32[T, SW]) last fetched
 
     def rows(self):
         """(tag_row, nsharers_row) — the [T, DW] set rows the allocation
@@ -887,10 +932,42 @@ class _DirRowView:
     def sharers_row3(self):
         return self._sh.reshape(self._sh.shape[0], self._dw, -1)
 
-    def entry(self, way):
-        """(tags, dstate, owner, sharers, nsh) at `way`."""
+    def _staged_at(self, d, way):
+        """The staged value of `way` (zeros where it has no slot),
+        fetched from `d.sval` once per `way` operand."""
+        if self._staged is None or self._staged[0] is not way:
+            with scope("gt.mem.stage_overlay"):
+                # (a masked max, not a take_along_axis: one small gather
+                # operation less a fetch on a TPU)
+                at_way = (np.arange(self._dw, dtype=np.int32)[None, :]
+                          == way[:, None])
+                best = jnp.max(jnp.where(at_way, self._best, 0), axis=1)
+                self._staged = (way, _stage_fetch(d.sval, best))
+        return self._staged[1]
+
+    def sharers_at(self, d, way):
+        """uint32[T, SW]: the current sharers of `way` (`d`: the phase's
+        directory, for its staging table)."""
         sharers = jnp.take_along_axis(
             self.sharers_row3(), way[:, None, None], axis=1)[:, 0]
+        if self._best is None:
+            return sharers
+        return sharers + self._staged_at(d, way)
+
+    def current_row3(self, d, way):
+        """The [T, DW, SW] row `_dir_update` takes its delta against:
+        current at `way` (the other ways of a lazy row are never read)."""
+        row3 = self.sharers_row3()
+        if self._best is None:
+            return row3
+        at_way = (np.arange(self._dw, dtype=np.int32)[None, :, None]
+                  == way[:, None, None])
+        return row3 + jnp.where(at_way, self._staged_at(d, way)[:, None, :],
+                                jnp.zeros_like(row3))
+
+    def entry(self, d, way):
+        """(tags, dstate, owner, sharers, nsh) at `way`."""
+        sharers = self.sharers_at(d, way)
         word = self.word_at(way)
         return (dir_tag(word), dir_state(word), dir_owner(word),
                 sharers, dir_nsh(word))
@@ -911,10 +988,12 @@ class _DirWorkingSet:
 
     ONE packed [T, 3, DW] entry-row + [T, 3, DW*SW] sharers-row gather
     (one collective under shard_map, with the per-lane staging rows
-    overlaid block-locally first) serves all three phases; each phase's
-    view forwards the pending delta plans of the phases before it, and
-    `_dir_apply_merged` lands every plan in ONE scatter per store at
-    the end of the iteration.  This is the packed CacheRow exchange
+    overlaid block-locally first; in a staged single-device program the
+    staging table's INDEX alone is taken here, `best_rows`, and a view
+    fetches the staged value of the way its phase reads) serves all
+    three phases; each phase's view forwards the pending delta plans of
+    the phases before it, and `_dir_apply_merged` lands every plan in
+    ONE scatter per store at the end of the iteration.  This is the packed CacheRow exchange
     form promoted to the iteration's working set: the six phases
     operate on rows-in-registers, and the big stores see exactly one
     gather and one scatter per iteration."""
@@ -925,8 +1004,8 @@ class _DirWorkingSet:
         earliest REQUEST's or the saved original, the transaction's);
         `live` is the home-activity gate (None = forced live).  With the
         gate closed no home phase runs, so no view is read: selection,
-        gather and staging overlay are all skipped and the rows are
-        zeros."""
+        gather and the staging table's index are all skipped and the
+        rows are zeros."""
         self._dw = mp.dir_ways
         self._dir_sets = mp.dir_sets
 
@@ -939,15 +1018,25 @@ class _DirWorkingSet:
             lt = np.arange(d.entry.shape[0], dtype=np.int32)[:, None]
             ew = _entry_rows(d.entry, lt, sets)               # [Tl, 3, DW]
             sh = d.sharers[lt, sets]                          # [Tl, 3, DW*SW]
-            if d.skey is not None:
-                sh = _stage_overlay_rows(d, sets, sh)
-            return lines, sets3, (ew, sh)
+            if d.skey is None:
+                return lines, sets3, (ew, sh, None)
+            if px.sharded:
+                return lines, sets3, (ew, _stage_overlay_rows(d, sets, sh),
+                                      None)
+            with scope("gt.mem.stage_overlay"):
+                # the index alone; a way with a staged slot reads zero
+                # in the row, and its value is fetched where it is read
+                best = _stage_index(d, sets, self._dw)        # [T, 3, DW]
+                sh = jnp.where(
+                    (best > 0)[..., None], jnp.zeros((), U32),
+                    sh.reshape(best.shape + (-1,))).reshape(sh.shape)
+            return lines, sets3, (ew, sh, best)
 
         if px.sharded:
             # the rows ride one collective, which must not sit inside a
             # lax.cond (engine/step.py, the whole-engine gate): ungated
             self.lines, self.sets3, local = rows(px.lo)
-            self.entry_rows, self.sharer_rows = px.ag(local)
+            self.entry_rows, self.sharer_rows, self.best_rows = px.ag(local)
             return
         if live is None:
             out = rows()
@@ -957,7 +1046,8 @@ class _DirWorkingSet:
             out = jax.lax.cond(
                 live, rows,
                 lambda: jax.tree.map(jnp.zeros_like, jax.eval_shape(rows)))
-        self.lines, self.sets3, (self.entry_rows, self.sharer_rows) = out
+        self.lines, self.sets3, (self.entry_rows, self.sharer_rows,
+                                 self.best_rows) = out
 
     def _forward(self, sets, ew, sh, packs):
         """Add earlier phases' pending deltas where their target set is
@@ -977,17 +1067,20 @@ class _DirWorkingSet:
     def view(self, k: int, line, packs) -> _DirRowView:
         ew, sh = self._forward(self.sets3[:, k], self.entry_rows[:, k],
                                self.sharer_rows[:, k], packs)
-        return _DirRowView(line, self.sets3[:, k], ew, sh, self._dw)
+        best = None if self.best_rows is None else self.best_rows[:, k]
+        return _DirRowView(line, self.sets3[:, k], ew, sh, self._dw, best)
 
     def view_finish(self, line, packs) -> _DirRowView:
         sets = nn_mod(line, self._dir_sets).astype(jnp.int32)
         use1 = sets == self.sets3[:, 1]
-        ew = jnp.where(use1[:, None], self.entry_rows[:, 1],
-                       self.entry_rows[:, 2])
-        sh = jnp.where(use1[:, None], self.sharer_rows[:, 1],
-                       self.sharer_rows[:, 2])
-        ew, sh = self._forward(sets, ew, sh, packs)
-        return _DirRowView(line, sets, ew, sh, self._dw)
+
+        def pick(rows):
+            return jnp.where(use1[:, None], rows[:, 1], rows[:, 2])
+
+        ew, sh = self._forward(sets, pick(self.entry_rows),
+                               pick(self.sharer_rows), packs)
+        best = None if self.best_rows is None else pick(self.best_rows)
+        return _DirRowView(line, sets, ew, sh, self._dw, best)
 
 
 # The entry store has two forms (memory/state.py: DirectoryArrays), told
@@ -1140,7 +1233,8 @@ def _dir_update(d, sets, way, mask, *, view: _DirRowView, acc: _DirAcc,
 
     Add-a-delta (new = cur + (new - cur) under mask): the current values
     are read from the phase's forwarded working-set row `view` (the big
-    stores are detached from a gated phase's cond entirely), and the
+    stores are detached from a gated phase's cond entirely; a staged
+    way's value comes out of `d.sval`: `_DirRowView.current_row3`), and the
     entry-word and sharers-row deltas are accumulated in `acc`,
     replicated full-width — `_dir_apply_merged` lands every phase's plan
     in one scatter per store at the end of the iteration.  The sharers
@@ -1164,7 +1258,7 @@ def _dir_update(d, sets, way, mask, *, view: _DirRowView, acc: _DirAcc,
         acc.add_entry(sets, way, delta)
     if sharers is not None:
         DW = view._dw
-        row3 = view.sharers_row3()
+        row3 = view.current_row3(d, way)
         onehot = (np.arange(DW, dtype=np.int32)[None, :, None]
                   == way[:, None, None]) & mask[:, None, None]
         new3 = jnp.where(onehot, sharers[:, None, :], row3)
@@ -1924,7 +2018,7 @@ def _home_evictions(mp, ms: MemState, dir_access_ps, enabled, progress,
     sets = dsv.sets
     dfound, way = dsv.lookup()
     apply = found & dfound
-    _, dstate, owner, sharers, nsh = dsv.entry(way)
+    _, dstate, owner, sharers, nsh = dsv.entry(d, way)
 
     was_sharer = test_bit(sharers, src)
     new_sharers = clear_bit(sharers, src, apply)
@@ -2039,7 +2133,7 @@ def _home_acks_and_finish(mp, ms: MemState, dram_lat_ps, dir_access_ps,
     # alias costs a whole-array copy per iteration (the [T, DS, DW, SW]
     # sharers tensor is 2 GB at 1024 tiles — see PERF.md).
     exf = finish & is_ex & dfound
-    _, cur_dstate, cur_owner, cur_sharers, cur_nsh = dsv.entry(way)
+    _, cur_dstate, cur_owner, cur_sharers, cur_nsh = dsv.entry(d, way)
     shf = finish & is_sh & dfound
     had = test_bit(cur_sharers, r)
     if mp.is_mosi:
@@ -2168,7 +2262,7 @@ def _home_starts(mp, ms: MemState, dram_lat_ps, dir_access_ps,
     need_nullify = starting & ~dfound & ~any_free
 
     # victim entry contents (for the NULLIFY transaction)
-    v_line, v_dstate, v_owner, v_sharers, v_nsh = dsv.entry(alloc_way)
+    v_line, v_dstate, v_owner, v_sharers, v_nsh = dsv.entry(d, alloc_way)
 
     # the new entry's install (the reference's `replaceDirectoryEntry`
     # immediate swap) is merged into the immediate-finish update below —

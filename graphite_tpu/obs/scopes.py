@@ -41,6 +41,9 @@ SCOPES = (
     "gt.mem.stage_flush",   # dir_stage_flush, once per inner block
     "gt.mem.entry_land",    # inside gt.mem.base: the home phases' plan
                             #   landed on the u32 entry words
+    "gt.mem.stage_overlay",  # staged programs: the staging table's index
+                            #   at the working set's gather, and a home
+                            #   phase's fetch of the staged value it reads
     "gt.mem.dir_apply",     # shl2: a home phase's row plan landed on the
                             #   embedded directory, outside its gate
     "gt.net.mailbox",       # SEND / NET_RECV rings

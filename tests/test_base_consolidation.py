@@ -313,6 +313,175 @@ def test_staged_matches_unstaged_randomized():
     _assert_results_equal(r_staged, r_uns)
 
 
+# ---- the staging overlay's value, fetched where a way is read (PR 46) ------
+
+
+def _staged_directory(rng, T, DS, DW, SW, C):
+    """A directory with a staging table as a block leaves one half way:
+    keys drawn from a few sets, so they REPEAT in a lane (the latest slot
+    wins) and meet the gathered sets; -1 beyond each lane's cursor."""
+    from graphite_tpu.memory.state import DirectoryArrays
+
+    sn = rng.integers(0, C - 3, T).astype(np.int32)
+    hot = rng.integers(0, DS, (T, 3))
+    key = (np.take_along_axis(hot, rng.integers(0, 3, (T, C)), 1) * DW
+           + rng.integers(0, DW, (T, C))).astype(np.int32)
+    skey = np.where(np.arange(C)[None, :] < sn[:, None], key, -1)
+    d = DirectoryArrays(
+        entry=jnp.asarray(rng.integers(0, 2**40, (T, DS, DW))),
+        sharers=jnp.asarray(rng.integers(0, 2**32, (T, DS, DW * SW),
+                                         dtype=np.uint32)),
+        skey=jnp.asarray(skey, jnp.int32),
+        sval=jnp.asarray(rng.integers(0, 2**32, (T, C, SW),
+                                      dtype=np.uint32)),
+        sn=jnp.asarray(sn))
+    return d, hot
+
+
+@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("finish", [False, True], ids=["view", "finish"])
+def test_lazy_staged_read_equals_the_eager_overlay(seed, finish):
+    """PR 46: a staged single-device working set takes the staging
+    table's INDEX at gather time and a view fetches the staged VALUE of
+    the way it reads; `entry(way)` and the current value `_dir_update`
+    takes its delta against must equal the eager overlay's row
+    (`_stage_overlay_rows`, what the sharded working set keeps) at that
+    way — with repeated keys in a lane, slots appended AFTER the index
+    was taken (on the very key that is read too), earlier phases' deltas
+    forwarded onto the same set, and `view_finish`'s choice of row."""
+    import types
+
+    from graphite_tpu.memory import engine
+    from graphite_tpu.parallel.px import IDENT
+
+    T, DS, DW, SW, C = 8, 8, 4, 2, 16
+    rng = np.random.default_rng(100 + seed)
+    d, hot = _staged_directory(rng, T, DS, DW, SW, C)
+    mp = types.SimpleNamespace(dir_ways=DW, dir_sets=DS)
+    # three lines a lane, mostly in the lane's staged sets; some coincide
+    sets3 = np.take_along_axis(hot, rng.integers(0, 3, (T, 3)), 1)
+    lines = [jnp.asarray(sets3[:, k] + DS * rng.integers(0, 5, T),
+                         jnp.int32) for k in range(3)]
+    lazy = engine._DirWorkingSet(IDENT, d, mp, lambda: lines)
+    eager_px = types.SimpleNamespace(sharded=True, lo=lambda x: x,
+                                     ag=lambda x: x)
+    eager = engine._DirWorkingSet(eager_px, d, mp, lambda: lines)
+    assert lazy.best_rows is not None and eager.best_rows is None
+    lt = np.arange(T, dtype=np.int32)[:, None]
+    np.testing.assert_array_equal(
+        np.asarray(eager.sharer_rows),
+        np.asarray(engine._stage_overlay_rows(
+            d, jnp.asarray(sets3, jnp.int32), d.sharers[lt, sets3])))
+    assert int(jnp.sum(lazy.best_rows > 0)) > 0     # something is staged
+
+    # an earlier phase's plan on view 1's set in half the lanes
+    pway = jnp.asarray(rng.integers(0, DW, T), jnp.int32)
+    psets = jnp.where(jnp.asarray(rng.random(T) < 0.5),
+                      jnp.asarray(sets3[:, 1], jnp.int32),
+                      jnp.asarray(rng.integers(0, DS, T), jnp.int32))
+    onehot = np.arange(DW)[None, :, None] == np.asarray(pway)[:, None, None]
+    pshd = jnp.asarray(np.where(
+        onehot, rng.integers(0, 2**32, (T, 1, SW), dtype=np.uint32),
+        np.uint32(0)).reshape(T, DW * SW))
+    packs = [(psets, pway, jnp.asarray(rng.integers(0, 99, T)), pshd)]
+
+    # the phases before this one appended slots since the index was
+    # taken: on the keys that will be read, and on others
+    d2 = d
+    for _ in range(2):
+        d2 = engine._stage_put(
+            d2, jnp.asarray(sets3[:, 1], jnp.int32),
+            jnp.asarray(rng.integers(0, DW, T), jnp.int32),
+            jnp.asarray(rng.random(T) < 0.7),
+            jnp.asarray(rng.integers(0, 2**32, (T, SW), dtype=np.uint32)),
+            DW)
+
+    if finish:
+        # the transaction's line: row 1's set or row 2's, lane by lane
+        line = jnp.where(jnp.asarray(rng.random(T) < 0.5), lines[1],
+                         lines[2])
+        vl, ve = (ws.view_finish(line, packs) for ws in (lazy, eager))
+    else:
+        vl, ve = (ws.view(1, lines[1], packs) for ws in (lazy, eager))
+    np.testing.assert_array_equal(np.asarray(vl.sets), np.asarray(ve.sets))
+    ways = [jnp.full(T, w, jnp.int32) for w in range(DW)]
+    ways.append(jnp.asarray(rng.integers(0, DW, T), jnp.int32))
+    for way in ways:
+        got = vl.entry(d2, way)
+        want = ve.entry(d, way)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        # the row delta of a masked write at that way
+        mask = jnp.asarray(rng.random(T) < 0.6)
+        new = jnp.asarray(rng.integers(0, 2**32, (T, SW), dtype=np.uint32))
+        deltas = []
+        for view, dd in ((vl, d2), (ve, d)):
+            acc = engine._DirAcc()
+            engine._dir_update(dd, view.sets, way, mask, view=view, acc=acc,
+                               sharers=new)
+            deltas.append(np.asarray(acc.sharers_delta))
+        np.testing.assert_array_equal(deltas[0], deltas[1])
+        cur = np.asarray(want[3])
+        at = (np.arange(DW)[None, :, None] == np.asarray(way)[:, None, None]
+              ) & np.asarray(mask)[:, None, None]
+        np.testing.assert_array_equal(
+            deltas[0].reshape(T, DW, SW),
+            np.where(at, (np.asarray(new) - cur)[:, None, :], np.uint32(0)))
+
+
+def test_a_phase_that_reads_and_updates_one_way_fetches_once():
+    """The staged value is memoised per `way` OPERAND: `entry(way)` and
+    every `_dir_update` on the same operand share ONE gather of T rows
+    of `sval`; another operand fetches again."""
+    import types
+
+    from graphite_tpu.memory import engine
+    from graphite_tpu.parallel.px import IDENT
+
+    T, DS, DW, SW, C = 8, 8, 4, 2, 16
+    rng = np.random.default_rng(7)
+    d, hot = _staged_directory(rng, T, DS, DW, SW, C)
+    mp = types.SimpleNamespace(dir_ways=DW, dir_sets=DS)
+    lines = [jnp.asarray(hot[:, k], jnp.int32) for k in range(3)]
+
+    def phase(d, way, other):
+        view = engine._DirWorkingSet(IDENT, d, mp, lambda: lines).view(
+            0, lines[0], [])
+        acc = engine._DirAcc()
+        sharers = view.entry(d, way)[3]
+        for k in range(2):
+            d = engine._dir_update(d, view.sets, way, sharers[:, 0] % 2 == k,
+                                   view=view, acc=acc, sharers=sharers + 1)
+        return acc.sharers_delta, view.sharers_at(d, other)
+
+    way = jnp.zeros(T, jnp.int32)
+    closed = jax.make_jaxpr(phase)(d, way, way + 1)
+    sig = (tuple(d.sval.shape), str(d.sval.dtype))
+    gathers, _ = _store_ops(closed, sig)
+    assert gathers == 2, gathers      # `way` once, `other` once
+
+
+def test_staged_iteration_fetches_a_way_a_phase_1024_shape():
+    """At the cells' shape the staged iteration gathers `sval` three
+    times, `[T, SW]` rows each and each inside its home phase's cond —
+    never the `[T, 3, DW, SW]` of the eager overlay (49,152 rows an open
+    iteration: PERF.md section 6, PR 46)."""
+    from graphite_tpu.analysis.walk import aval_sig, iter_eqns_with_site
+
+    sim = _big_shape_sim(dir_stage=True, inner_block=4)
+    closed = _iteration_jaxpr(sim)
+    d = sim.state.mem.directory
+    sig = (tuple(d.sval.shape), str(d.sval.dtype))
+    T, _, SW = d.sval.shape
+    outs = [(tuple(eqn.outvars[0].aval.shape), _depth(site))
+            for site, eqn in iter_eqns_with_site(closed)
+            if eqn.primitive.name == "gather" and eqn.invars
+            and not isinstance(eqn.invars[0], Literal)
+            and aval_sig(eqn.invars[0].aval) == sig]
+    assert [shape for shape, _ in outs] == [(T, SW)] * 3, outs
+    assert all(depth >= 1 for _, depth in outs), outs   # inside a cond
+
+
 # ---- sharded staging: the standing dir_stage gap, closed ------------------
 
 

@@ -330,11 +330,54 @@ def _entry_words_land_through_the_kernel(text, entry):
 
 
 def _carries_the_int64_entry_store(sim, text):
-    """A program PR 45 leaves as it was: the int64 store, no kernel."""
+    """A program PR 45 and PR 46 leave as it was: the int64 store, no
+    kernel; unstaged, so no staging overlay either."""
     d = sim.state.mem.directory
     assert str(d.entry.dtype) == "int64" and d.entry.ndim >= 3
     assert "dir_entry_landing" not in text
     assert "gt.mem.entry_land" not in text
+    assert d.skey is None and "gt.mem.stage_overlay" not in text
+
+
+def _overlay_fetches_a_way_a_phase(text, d):
+    """PR 46, of a staged single-device program compiled for the chip:
+    the loops hold no `u32[T*3*DW, SW]` - the eager overlay's gather of
+    the staged value of EVERY way of the three set rows a lane, 49,152
+    rows of 128 bytes and 0.56 ms an open iteration at 1,024 tiles -;
+    what gathers out of the staging table `sval` is `[T, SW]` rows, under
+    `gt.mem.stage_overlay` inside a home phase, one a phase; and the
+    table is relaid no more often than the parent's program relays it
+    (twice: round the flush's kernel, once a block)."""
+    from graphite_tpu.analysis.loop_copies import computations, loops
+
+    T, C, SW = d.sval.shape
+    DW = d.sharers.shape[2] // SW
+    comps = computations(text)
+    in_loops = set().union(*(lp.comps for lp in loops(comps).values()))
+    lines = [ln for c in in_loops if "fused_computation" not in c
+             for ln in comps[c] if " = " in ln]
+    table = f"u32[{T},{C},{SW}]"
+    names = {ln.split(" = ", 1)[0].split()[-1] for ln in lines
+             if ln.split(" = ", 1)[1].startswith(table)}
+    fetches = []
+    for ln in lines:
+        head, rest = ln.split(" = ", 1)
+        assert not rest.startswith(f"u32[{T * 3 * DW},{SW}]"), ln[:300]
+        operands = rest.split(" fusion(", 1)[-1].split(")", 1)[0]
+        if (" fusion(" in ln and "/gather" in ln
+                and "gt.mem.stage_flush" not in ln   # the flush's sort
+                and names & set(operands.split(", "))):
+            fetches.append(ln)
+    assert len(fetches) == 3, [f[:200] for f in fetches]
+    for ln in fetches:
+        assert ln.split(" = ", 1)[1].startswith(f"u32[{T},{SW}]"), ln[:300]
+        assert "/gt.mem.stage_overlay/gather" in ln, ln[:400]
+        assert any(f"/gt.mem.{p}/cond/" in ln for p in (
+            "home_evict", "home_start", "home_finish")), ln[:400]
+    relaid = [ln for ln in text.splitlines()
+              if " = " in ln and ln.split(" = ", 1)[1].startswith(table)
+              and " copy(" in ln]
+    assert len(relaid) <= 2, [r[:200] for r in relaid]
 
 
 @pytest.mark.parametrize("chips", [1, 4])
@@ -404,6 +447,7 @@ def test_coh_1024_host_batch_compiles(one_chip):
     _flush_moves_the_staged_slots_alone(compiled.as_text(),
                                         d.sharers.shape, d.skey.shape[1])
     _entry_words_land_through_the_kernel(compiled.as_text(), d.entry.shape)
+    _overlay_fetches_a_way_a_phase(compiled.as_text(), d)
 
 
 def _shl2_memstress(tiles):
@@ -595,8 +639,10 @@ def test_canneal_dvfs_host_batch_compiles(one_chip, tiles):
     _fits(_report(f"canneal-dvfs-{tiles}-host-batch", compiled))
     assert "gt.energy" in compiled.as_text()
     if tiles == 1024:
-        _entry_words_land_through_the_kernel(
-            compiled.as_text(), sim.state.mem.directory.entry.shape)
+        d = sim.state.mem.directory
+        _entry_words_land_through_the_kernel(compiled.as_text(),
+                                             d.entry.shape)
+        _overlay_fetches_a_way_a_phase(compiled.as_text(), d)
     else:
         _carries_the_int64_entry_store(sim, compiled.as_text())
 

@@ -397,7 +397,7 @@ def test_cell_reports_what_the_manifest_lists():
                 BENCH, "layer_metrics", m["name"] + ".py")), m["name"]
     # appended: the new cell was the last of every list it joined (PR 44's
     # `canneal1024-dvfs` follows it), and the four new metrics came last,
-    # in this order (PR 43's, PR 44's three and PR 45's follow them)
+    # in this order (PR 43's, PR 44's three, PR 45's and PR 46's follow them)
     assert [w["name"] for w in MANIFEST["workloads"]][5] == CELL_NAME
     assert [c["name"] for c in MANIFEST["configs"]][5] == NAME
     names = [m["name"] for m in MANIFEST["per_layer"]]
@@ -407,7 +407,8 @@ def test_cell_reports_what_the_manifest_lists():
         "noc_contention_share", "noc_fallback_share"]
     assert names[first + 4:] == [
         "stage_flush_busy_share", "dvfs_busy_share", "energy_busy_share",
-        "dvfs_sets_per_run", "entry_land_busy_share"]
+        "dvfs_sets_per_run", "entry_land_busy_share",
+        "stage_overlay_busy_share"]
     for m in MANIFEST["per_layer"] + MANIFEST["end_to_end"]:
         if CELL_NAME in m.get("workloads", []):
             later = m["workloads"][m["workloads"].index(CELL_NAME) + 1:]

@@ -203,8 +203,9 @@ def test_layer_metric_readers(name, own, want, runs):
 
 
 @pytest.mark.parametrize("name,scope,from_end", [
-    ("stage_flush_busy_share", "gt.mem.stage_flush", 5),
-    ("entry_land_busy_share", "gt.mem.entry_land", 1),
+    ("stage_flush_busy_share", "gt.mem.stage_flush", 6),
+    ("entry_land_busy_share", "gt.mem.entry_land", 2),
+    ("stage_overlay_busy_share", "gt.mem.stage_overlay", 1),
 ])
 @pytest.mark.parametrize("scoped,want", [
     (True, 5.0),        # 1.0 s under the scope of 20.0 s busy
@@ -212,10 +213,11 @@ def test_layer_metric_readers(name, own, want, runs):
     (None, None),       # a run without a scope trace
 ], ids=["scoped", "unscoped", "untraced"])
 def test_staged_scope_readers(name, scope, from_end, scoped, want):
-    """PR 43's `stage_flush_busy_share` and PR 45's
-    `entry_land_busy_share`: the flush's / the entry words' landing's
+    """PR 43's `stage_flush_busy_share`, PR 45's `entry_land_busy_share`
+    and PR 46's `stage_overlay_busy_share`: the flush's / the entry
+    words' landing's / the staging table's index and value fetches'
     scope by itself, in the staged cells (`mem_ungated_busy_share` holds
-    the first with `gt.mem.base`, and not the second: a scope trace
+    the first with `gt.mem.base`, and not the other two: a scope trace
     counts an operation for its deepest scope); nothing where the
     program has no such scope."""
     entry, = [m for m in MANIFEST["per_layer"] if m["name"] == name]
@@ -223,7 +225,8 @@ def test_staged_scope_readers(name, scope, from_end, scoped, want):
                                   "canneal1024-dvfs"]
     assert (entry["moves"], entry["better"]) == ("sim_records_per_s",
                                                  "lower")
-    # appended (PR 44's three metrics follow the flush's, PR 45's them)
+    # appended (PR 44's three metrics follow the flush's, PR 45's them,
+    # PR 46's that)
     assert [m["name"] for m in MANIFEST["per_layer"]].index(
         name) == len(MANIFEST["per_layer"]) - from_end
     ctx = _ctx()

@@ -40,9 +40,11 @@ ONLY_HBH = {"gt.net.hbh.scan", "gt.net.hbh.commit"}
 # an energy interval's close inside the DVFS arm: only with [general]
 # enable_power_modeling (PR 44)
 ONLY_POWER = {"gt.energy"}
-# the landing on the u32 entry words: only in a STAGED private-L2 program
-# (PR 45; `msi` below is one)
-ONLY_STAGED = {"gt.mem.stage_flush", "gt.mem.entry_land"}
+# the landing on the u32 entry words (PR 45) and the staging table's index
+# and value fetches (PR 46): only in a STAGED private-L2 program (`msi`
+# below is one)
+ONLY_STAGED = {"gt.mem.stage_flush", "gt.mem.entry_land",
+               "gt.mem.stage_overlay"}
 MSI_SCOPES = [s for s in scopes.SCOPES
               if s not in ONLY_SHARDED | ONLY_SHL2 | ONLY_HBH | ONLY_POWER]
 SHL2_SCOPES = [s for s in scopes.SCOPES
