@@ -21,7 +21,7 @@ Layer map (mirrors SURVEY.md §1, reference layers L0–L7):
                 multi-chip program) + legacy GSPMD specs, over ICI
     power/      McPAT/DSENT-equivalent energy models fed by event counters
     system/     host-side MCP analogs: threads, syscalls, stats, checkpoint
-    tools/      drivers (graduated runner, regress sweep, output parsing)
+    tools/      drivers (graduated runner, sweep, serve, output parsing)
 
 Simulated time is exact integer picoseconds throughout
 (reference: `common/misc/time_types.h:31-78`), so the package enables

@@ -1,6 +1,6 @@
 """The program auditor: lower a program, run every applicable lint.
 
-`audit()` is the API the tests and `tools/regress.py --smoke` call;
+`audit()` is the API the tests (`tests/test_analysis.py`) call;
 `python -m graphite_tpu.tools.audit` is the CLI wrapper that emits the
 report as JSON lines.  A ProgramSpec bundles one lowered program (a
 ClosedJaxpr straight from `jax.make_jaxpr` — no compile needed, so the

@@ -68,7 +68,7 @@ Host side (round 14, consumed by serve/service.py):
 
 Both take an injectable monotonic clock, so tests pin exact latencies
 on a fake clock; neither ever touches a traced program (tracing on/off
-serve results are bit-equal, regress-pinned).
+serve results are bit-equal: `tests/test_obs_service.py`).
 """
 
 from graphite_tpu.obs.hist import (  # noqa: F401

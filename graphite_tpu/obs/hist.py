@@ -36,7 +36,7 @@ counter (`conservation_totals`): the recording masks are bit-identical
 to the counter increments in `engine/step.py`, so on a completed run
 with constant `models_enabled` the total count equals the counter —
 the distribution analogue of round-16's cross-ring sum invariant,
-asserted by tests/test_hist.py and regress rung 15.
+asserted by tests/test_hist.py.
 
 `hist=None` (the default everywhere) constant-folds the recording away
 to a bit-identical program — the same contract as `telemetry=None`

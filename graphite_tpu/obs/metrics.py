@@ -292,8 +292,8 @@ def _fmt(v: float) -> str:
 def parse_exposition(text: str) -> dict:
     """Parse a `MetricsRegistry.exposition()` dump back into
     `{name: {"type": ..., "value"/...}}` — the round-trip check the
-    tests (and regress rung 9) run on exporter output.  Histograms come
-    back with their per-bucket cumulative counts, sum and count, so a
+    tests (`tests/test_obs_service.py`) run on exporter output.  Histograms
+    come back with their per-bucket cumulative counts, sum and count, so a
     registry rebuilt from the text proves the dump lossless (up to the
     +Inf tail's true max, which the text format cannot carry)."""
     out: dict = {}

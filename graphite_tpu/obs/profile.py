@@ -21,8 +21,8 @@ access/miss and directory-op deltas, USER-net packets in/out, and the
 opt-in per-tile `energy_pj` priced through the same `EnergyPrices`
 table the scalar series uses.
 
-Cross-ring consistency is free by construction and regress-asserted
-(`tools/regress.py --smoke` rung 10): a delta series shared with the
+Cross-ring consistency is free by construction and asserted by
+`tests/test_profile.py::TestRecording`: a delta series shared with the
 scalar ring sums over T to exactly the scalar column, and
 `max(clock_skew_ps) + clock_min_ps == clock_max_ps` sample for sample.
 
@@ -55,7 +55,7 @@ PROFILE_LEVEL_SERIES = ("clock_skew_ps", "freq_mhz")
 # Always-available per-tile series (state the core carry already holds
 # as [T] lanes).  Names shared with the scalar telemetry ring
 # (instructions, sync_stall_ps, packets_sent, ...) sum over T to the
-# scalar series — the cross-ring invariant the regress rung asserts.
+# scalar series — the cross-ring invariant tests/test_profile.py asserts.
 PROFILE_CORE_SERIES = (
     "clock_skew_ps",     # tile clock minus the fleet-minimum clock
     "instructions",      # committed instructions, this tile

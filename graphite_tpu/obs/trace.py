@@ -15,8 +15,8 @@ Contracts:
    fake clock and assert exact span durations.
  - **Terminal completeness.**  Every job trace must end in exactly one
    terminal span (`emit`, `reject`, or `failed`).  `missing_terminal()`
-   names the jobs that don't — the regress rung's span-set-complete
-   check.
+   names the jobs that don't — the span-set-complete check of
+   `tests/test_obs_service.py` and `tests/test_campaign_cell.py`.
  - **JSON-lines export.**  `export_jsonl()` writes one span per line
    (`tools/serve.py --trace-out`); `load_jsonl()` reads it back for
    `tools/report.py --spans`.  Timestamps export as integer
@@ -28,7 +28,7 @@ Contracts:
 
 Tracing is strictly host-side observability: no traced program ever
 sees the tracer, so serve results are bit-equal with tracing on or off
-(regress-pinned).
+(`tests/test_obs_service.py::TestEndToEnd`).
 
 The same tracer follows `Simulator`'s drive loop (`attach_tracer`): one
 `run-<n>` trace per `run()` / `run_chunk()` / `run_streamed()` call with
@@ -184,8 +184,8 @@ class Tracer:
 
     def missing_terminal(self, trace_ids) -> "list[str]":
         """The given traces that lack a terminal span — must be empty
-        for every submitted job id once the service drained (the
-        regress rung-9 completeness check)."""
+        for every submitted job id once the service drained
+        (`tests/test_obs_service.py`'s completeness check)."""
         done = {s.trace_id for s in self.spans
                 if s.name in TERMINAL_SPANS}
         return [str(t) for t in trace_ids if str(t) not in done]

@@ -29,7 +29,7 @@ Graceful degradation is structural, not best-effort:
    split/retry recursion provably terminates.
 
 The bit-exact sequential path (`Simulator.run`) remains the equivalence
-oracle: `tools/regress.py --smoke`'s serve rung replays a mixed-
+oracle: `tests/test_serve.py::TestServiceEndToEnd` replays a mixed-
 geometry job set both ways and requires identical results + telemetry.
 
 Observability (round 14) is built in, not bolted on: every rate the
@@ -50,7 +50,8 @@ TraceAnnotation so that under a profiler they lie on the device's
 clock.
 Both ride an injectable monotonic clock (`clock=`) so tests pin exact
 latencies; neither ever touches a traced program, so serve results are
-bit-equal with tracing on or off (regress rung 9).
+bit-equal with tracing on or off (`tests/test_obs_service.py::
+TestEndToEnd`).
 """
 
 from __future__ import annotations
@@ -118,7 +119,7 @@ class CampaignService:
     `max_quanta`: the batch programs' quantum bound (part of the
     compiled program, hence of the cache key); `verify_hits`: re-lower
     every cache hit and re-prove fingerprint equality (a retrace, never
-    a recompile — the belt-and-braces mode the regress rung runs);
+    a recompile — the belt-and-braces mode `tests/test_serve.py` runs);
     `validate`: run `trace/validate.py` on every submitted trace;
     `max_history`: newest result envelopes / batch reports retained on
     the service (`results` / `batch_log`) — streaming consumers use

@@ -177,7 +177,7 @@ class SweepRunner:
        for sims whose per-sim residency bill exceeds ONE device's
        `hbm_budget_bytes`: the bill splits into per-device tile blocks
        (`device_breakdown()`).  Results are bit-identical to solo runs
-       (regress rung 12).
+       (`tests/test_mesh2d.py`).
 
     `layout=None` picks automatically from `residency_breakdown` + the
     device count: a campaign whose PER-SIM bill exceeds the per-device

@@ -1,4 +1,4 @@
-"""Shared config-INI template for the tools' drivers (regress, graduated).
+"""Shared config-INI template for the tools' drivers (graduated, the tests' targets).
 
 One source of truth for the sweep/benchmark configuration surface so knob
 changes land in every driver at once.

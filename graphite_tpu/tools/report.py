@@ -402,7 +402,7 @@ def perfetto_events(*, spans: "str | None" = None, timelines=(),
     event whose args hold the deterministic count/p50/p95/p99 summary
     (`obs.Hist.summary` — the shared bucket_quantile definition).
     Events are sorted (pid, ts), so every track's stamps are monotone
-    — the invariant tools/regress.py's perfetto rung asserts."""
+    — the invariant `tests/test_hist.py::TestPerfetto` asserts."""
     events = [
         {"ph": "M", "pid": HOST_PID, "tid": 0, "ts": 0,
          "name": "process_name",

@@ -43,8 +43,8 @@ Usage:
       # (maintain the store with tools/store.py ls|verify|gc|evict)
 
 `--dryrun` pins JAX to CPU and serves a built-in mixed-geometry,
-mixed-knob demo job set — the smoke shape `tools/regress.py --smoke`'s
-serve rung also exercises.
+mixed-knob demo job set — the smoke shape
+`tests/test_serve.py::TestServiceEndToEnd` also exercises.
 
 Observability (round 14): `--trace-out spans.jsonl` records every
 job's lifecycle spans (submit → validate → admit → queue dwell →

@@ -14,7 +14,7 @@ Usage:
 
 Knob axes cross-multiply (grid_points); seeds replicate the grid per
 trace.  `--dryrun` pins JAX to CPU and shrinks the workload — the
-smoke-test shape regress.py --smoke also exercises.
+smoke-test shape `tests/test_sweep.py` also exercises.
 """
 
 from __future__ import annotations

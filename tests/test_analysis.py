@@ -544,8 +544,7 @@ def test_audit_default_programs_clean():
     multi-domain DVFS campaign (round 19), the histogram-recording
     gated engine (round 21) AND the per-phase-gated 2D campaign
     (round 22) all pass every rule — the same call
-    `tools/regress.py --smoke` and
-    `python -m graphite_tpu.tools.audit` make."""
+    `python -m graphite_tpu.tools.audit` makes."""
     report = audit(tiles=8)
     assert {r.program for r in report.results} == {
         "gated-msi", "ungated-msi", "shl2-mesi", "sweep-b4",

@@ -18,51 +18,15 @@ from jax.extend.core import Literal
 import numpy as np
 import pytest
 
-from graphite_tpu.config import ConfigFile, SimConfig
 from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.golden import run_golden
 from graphite_tpu.trace import synthetic
 from graphite_tpu.trace.schema import TraceBatch, TraceBuilder
 
-MSI = "pr_l1_pr_l2_dram_directory_msi"
-MOSI = "pr_l1_pr_l2_dram_directory_mosi"
-SHL2_MESI = "pr_l1_sh_l2_mesi"
-
-
-def make_config(n_tiles, proto=MSI, extra=""):
-    text = f"""
-[general]
-total_cores = {n_tiles}
-mode = lite
-max_frequency = 1.0
-enable_shared_mem = true
-[network]
-user = magic
-memory = magic
-[caching_protocol]
-type = {proto}
-[core/static_instruction_costs]
-mov = 1
-ialu = 1
-{extra}
-"""
-    return SimConfig(ConfigFile.from_string(text))
-
-
-def mutex_rmw(n, rounds, base=0x900000, lines=2):
-    bs = [TraceBuilder() for _ in range(n)]
-    bs[0].mutex_init(0)
-    bs[0].barrier_init(9, n)
-    for b in bs:
-        b.barrier_wait(9)
-    for r in range(n * rounds):
-        t = r % n
-        addr = base + (r % lines) * 64
-        bs[t].mutex_lock(0)
-        bs[t].load(addr, 8)
-        bs[t].store(addr, 8)
-        bs[t].mutex_unlock(0)
-    return TraceBatch.from_builders(bs)
+import targets
+from targets import (
+    MOSI, MSI, SHL2_MESI, memory_config as make_config, mutex_rmw,
+)
 
 
 def _assert_results_equal(ra, rb):
@@ -290,9 +254,8 @@ def test_gated_matches_ungated_randomized(proto, seed):
     un-gated one (every phase, the gather and the merged scatter run
     every iteration — what a campaign compiles)."""
     sc = make_config(8, proto)
-    batch = synthetic.memory_stress_trace(
-        8, n_accesses=40, working_set_bytes=1 << 12,
-        write_fraction=0.4, shared_fraction=0.6, seed=seed)
+    batch = targets.stress_trace(8, seed=seed, n_accesses=40,
+                                 shared_fraction=0.6)
     r_gated = Simulator(sc, batch, phase_gate=True, mem_gate_bytes=0).run()
     r_flat = Simulator(sc, batch, phase_gate=False, mem_gate_bytes=0).run()
     _assert_results_equal(r_gated, r_flat)

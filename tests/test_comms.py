@@ -328,6 +328,16 @@ class TestGspmdInsertionLint:
                 spec.closed, spec.n_tiles,
                 phase_names=spec.phase_names) == []
 
+    def test_comms_fixture_cli_exits_nonzero(self, capsys):
+        """CLI-level acceptance: `--comms-fixture` must exit nonzero
+        naming the lint (and refuse a gate armed beside it)."""
+        from graphite_tpu.tools.audit import main
+
+        assert main(["--comms-fixture"]) == 1
+        assert "gspmd-insertion" in capsys.readouterr().out
+        with pytest.raises(SystemExit):
+            main(["--comms-fixture", "--lock"])
+
 
 class TestReplicationDriftLint:
     def test_partial_axis_psum_leak_fires(self):

@@ -15,6 +15,8 @@ timing, which is everything the timing model observes:
    (`[limitless] software_trap_penalty`) — visible as added latency.
 """
 
+import functools
+
 import numpy as np
 
 from graphite_tpu.config import ConfigFile, SimConfig
@@ -53,8 +55,11 @@ quantum = 1000
     return SimConfig(ConfigFile.from_string(text))
 
 
+@functools.cache
 def run_sharers_then_write(n_tiles, dir_type, k=2, trap=200, protocol=None):
-    """All tiles read one line (n sharers), then tile 0 writes it (EX)."""
+    """All tiles read one line (n sharers), then tile 0 writes it (EX).
+    The cases compare schemes against the same few runs (`full_map` at 4
+    tiles is in five of them): each is made once."""
     sc = make_config(n_tiles, dir_type, k=k, trap=trap)
     if protocol:
         sc.cfg.set("caching_protocol/type", protocol)

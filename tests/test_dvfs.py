@@ -247,8 +247,7 @@ class TestSyncDelayTransitions:
 
 class TestGoldenEquality:
     """Engine vs the hand-stepped golden interpreter with in-trace
-    retunes (fixed frequency after the set — the oracle the regress
-    rung pins at 16 tiles, here at unit-test size)."""
+    retunes (fixed frequency after the set), at unit-test size."""
 
     def test_fixed_frequency_and_retune_match_golden(self):
         from graphite_tpu.golden.interpreter import run_golden
@@ -275,6 +274,68 @@ class TestGoldenEquality:
         assert np.array_equal(np.asarray(sim.state.dvfs.errors),
                               g.dvfs_errors)
         assert g.core_freq_mhz.tolist() == [2000, 740]
+
+
+class TestRuntimeSpec:
+    """The chip-global `DvfsSpec` (dvfs/runtime.py) on two tiles."""
+
+    def _builders(self):
+        """Compute with in-trace retunes, for the governor to act on."""
+        b0 = TraceBuilder()
+        for _ in range(4):
+            b0.instr(Op.IALU)
+        b0.dvfs_set(0, 2000)            # AUTO up-retune
+        for _ in range(4):
+            b0.instr(Op.IALU)
+        b1 = TraceBuilder()
+        b1.dvfs_set(0, 500)             # AUTO down-retune
+        b1.dvfs_set(0, 5000)            # above table max: rejected
+        for _ in range(3):
+            b1.instr(Op.IALU)
+        return [b0, b1]
+
+    def test_spec_at_the_configs_frequencies_is_the_folded_engine(self):
+        """Carried frequency is mechanism, not policy: a `DvfsSpec` at
+        the config's own domain frequencies changes no statistic (of a
+        trace without retunes: the in-trace path is the per-tile one)."""
+        from graphite_tpu.dvfs import DvfsSpec
+
+        sc = make_config()
+        b0, b1 = TraceBuilder(), TraceBuilder()
+        for _ in range(6):
+            b0.instr(Op.IALU)
+        b0.send(1, 8)
+        b1.recv(0, 8)
+        for _ in range(3):
+            b1.instr(Op.IALU)
+        batch = TraceBatch.from_builders([b0, b1])
+        folded = Simulator(sc, batch).run()
+        carried = Simulator(sc, batch, dvfs=DvfsSpec()).run()
+        np.testing.assert_array_equal(carried.clock_ps, folded.clock_ps)
+        np.testing.assert_array_equal(carried.instruction_count,
+                                      folded.instruction_count)
+        assert carried.n_quanta == folded.n_quanta
+
+    def test_governor_is_deterministic(self):
+        """Two fresh engines under the reactive governor agree bit for bit
+        on the results AND on the final per-domain V/f state."""
+        from graphite_tpu.dvfs import DvfsSpec, GovernorSpec
+
+        gv = DvfsSpec(governor=GovernorSpec(interval_ps=2000, domains=(0,)))
+        runs = []
+        for _ in range(2):
+            sim = Simulator(make_config(),
+                            TraceBatch.from_builders(self._builders()),
+                            dvfs=gv)
+            res = sim.run()
+            runs.append((res, np.asarray(sim.state.dvfs_rt.domain_mhz),
+                         np.asarray(sim.state.dvfs_rt.domain_mv)))
+        (ra, fa, va), (rb, fb, vb) = runs
+        np.testing.assert_array_equal(ra.clock_ps, rb.clock_ps)
+        np.testing.assert_array_equal(ra.instruction_count,
+                                      rb.instruction_count)
+        np.testing.assert_array_equal(fa, fb)
+        np.testing.assert_array_equal(va, vb)
 
 
 class TestEnergyPricing:

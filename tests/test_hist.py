@@ -46,19 +46,19 @@ from graphite_tpu.obs.metrics import Histogram, bucket_quantile
 from graphite_tpu.tools._template import config_text
 from graphite_tpu.trace import synthetic
 
+import targets
+
 TILES = 8
 QUANTUM_PS = 1_000_000   # config_text default: 1000 ns lax_barrier
 
 
 def _config(extra: str = ""):
-    return SimConfig(ConfigFile.from_string(config_text(
-        TILES, shared_mem=True, clock_scheme="lax_barrier") + extra))
+    return targets.template_config(TILES, extra, shared_mem=True,
+                                   clock_scheme="lax_barrier")
 
 
 def _trace(seed=7, n=24):
-    return synthetic.memory_stress_trace(
-        TILES, n_accesses=n, working_set_bytes=1 << 12,
-        write_fraction=0.4, shared_fraction=0.5, seed=seed)
+    return targets.stress_trace(TILES, seed=seed, n_accesses=n)
 
 
 def _ring_batch():
@@ -579,7 +579,7 @@ class TestPerfetto:
             assert ev["args"]["p50"] == h.quantile(s, 0.5)
             assert ev["args"]["p99"] == h.quantile(s, 0.99)
 
-        # the regress invariant: per-pid monotone timestamps
+        # write_perfetto's invariant: per-pid monotone timestamps
         for pid in (1, 2):
             ts = [e["ts"] for e in evs
                   if e["pid"] == pid and e["ph"] != "M"]

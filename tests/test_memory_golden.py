@@ -24,40 +24,19 @@ Reference semantics under test: `l1_cache_cntlr.cc:90-180`,
 import numpy as np
 import pytest
 
-from graphite_tpu.config import ConfigFile, SimConfig
+import functools
+
 from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.golden import run_golden
 from graphite_tpu.trace import synthetic
 from graphite_tpu.trace.schema import TraceBatch, TraceBuilder
 
-MSI = "pr_l1_pr_l2_dram_directory_msi"
-MOSI = "pr_l1_pr_l2_dram_directory_mosi"
+import targets
+from targets import (  # noqa: F401  (test_victim_lookup_golden imports them)
+    MOSI, MSI, SHL2_MESI, SHL2_MSI, memory_config as make_config,
+)
 
-
-def make_config(n_tiles, proto=MSI, net="magic", extra=""):
-    text = f"""
-[general]
-total_cores = {n_tiles}
-mode = lite
-max_frequency = 1.0
-enable_shared_mem = true
-[network]
-user = magic
-memory = {net}
-[network/emesh_hop_counter]
-flit_width = 64
-[network/emesh_hop_counter/router]
-delay = 1
-[network/emesh_hop_counter/link]
-delay = 1
-[caching_protocol]
-type = {proto}
-[core/static_instruction_costs]
-mov = 1
-ialu = 1
-{extra}
-"""
-    return SimConfig(ConfigFile.from_string(text))
+mutex_rmw = functools.partial(targets.mutex_rmw, lines=1)
 
 
 def assert_exact(sc, batch):
@@ -72,25 +51,6 @@ def assert_exact(sc, batch):
 
 
 # ---- workload builders ----------------------------------------------------
-
-
-def mutex_rmw(n, rounds, base=0x900000, lines=1):
-    """Mutex-serialized read-modify-write of shared lines: at any moment
-    exactly one tile touches the shared data, so engine iteration order
-    and oracle clock order coincide."""
-    bs = [TraceBuilder() for _ in range(n)]
-    bs[0].mutex_init(0)
-    bs[0].barrier_init(9, n)
-    for b in bs:
-        b.barrier_wait(9)
-    for r in range(n * rounds):
-        t = r % n
-        addr = base + (r % lines) * 64
-        bs[t].mutex_lock(0)
-        bs[t].load(addr, 8)
-        bs[t].store(addr, 8)
-        bs[t].mutex_unlock(0)
-    return TraceBatch.from_builders(bs)
 
 
 def share_then_write(n, lines=4, rounds=2, base=0xA00000):
@@ -328,8 +288,6 @@ tags_access_time = 2
 
 # ---- shared-L2 protocols vs the GoldenShL2 oracle -------------------------
 
-SHL2_MSI = "pr_l1_sh_l2_msi"
-SHL2_MESI = "pr_l1_sh_l2_mesi"
 
 
 @pytest.mark.parametrize("proto", [SHL2_MSI, SHL2_MESI])

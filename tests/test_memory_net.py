@@ -22,46 +22,16 @@ Contract (BASELINE.md carve-outs):
 import numpy as np
 import pytest
 
-from graphite_tpu.config import ConfigFile, SimConfig
+import functools
+
 from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.golden import run_golden
 from graphite_tpu.trace import synthetic
 from graphite_tpu.trace.schema import TraceBatch, TraceBuilder
 
-MSI = "pr_l1_pr_l2_dram_directory_msi"
-MOSI = "pr_l1_pr_l2_dram_directory_mosi"
+from targets import MOSI, MSI, memory_config
 
-
-def make_config(n_tiles, proto=MSI, net="emesh_hop_by_hop", extra=""):
-    text = f"""
-[general]
-total_cores = {n_tiles}
-mode = lite
-max_frequency = 1.0
-enable_shared_mem = true
-[network]
-user = magic
-memory = {net}
-[network/emesh_hop_counter]
-flit_width = 64
-[network/emesh_hop_counter/router]
-delay = 1
-[network/emesh_hop_counter/link]
-delay = 1
-[network/emesh_hop_by_hop]
-flit_width = 64
-[network/emesh_hop_by_hop/router]
-delay = 1
-[network/emesh_hop_by_hop/link]
-delay = 1
-[caching_protocol]
-type = {proto}
-[core/static_instruction_costs]
-mov = 1
-ialu = 1
-{extra}
-"""
-    return SimConfig(ConfigFile.from_string(text))
+make_config = functools.partial(memory_config, net="emesh_hop_by_hop")
 
 
 def assert_exact(sc, batch):
@@ -78,7 +48,8 @@ def assert_exact(sc, batch):
 def mutex_rmw(n, rounds, base=0x900000, lines=2):
     """Mutex-serialized shared-line read-modify-writes: at any moment one
     tile touches the shared data, so engine iteration order and oracle
-    clock order coincide — the bit-exactness regime."""
+    clock order coincide — the bit-exactness regime.  (Every line under
+    each lock: not `targets.mutex_rmw`, which takes one a round.)"""
     bs = [TraceBuilder() for _ in range(n)]
     bs[0].mutex_init(0)
     bs[0].barrier_init(9, n)

@@ -22,48 +22,10 @@ from graphite_tpu.golden import run_golden
 from graphite_tpu.trace import synthetic
 from graphite_tpu.trace.schema import Op, TraceBatch, TraceBuilder
 
-MSI = "pr_l1_pr_l2_dram_directory_msi"
-MOSI = "pr_l1_pr_l2_dram_directory_mosi"
-SHL2_MSI = "pr_l1_sh_l2_msi"
-SHL2_MESI = "pr_l1_sh_l2_mesi"
-
-
-def make_config(n_tiles, proto=MSI, extra=""):
-    text = f"""
-[general]
-total_cores = {n_tiles}
-mode = lite
-max_frequency = 1.0
-enable_shared_mem = true
-[network]
-user = magic
-memory = magic
-[caching_protocol]
-type = {proto}
-[core/static_instruction_costs]
-mov = 1
-ialu = 1
-{extra}
-"""
-    return SimConfig(ConfigFile.from_string(text))
-
-
-def mutex_rmw(n, rounds, base=0x900000, lines=2):
-    """Mutex-serialized RMWs of shared lines (engine iteration order and
-    oracle clock order coincide — the bit-exact contract)."""
-    bs = [TraceBuilder() for _ in range(n)]
-    bs[0].mutex_init(0)
-    bs[0].barrier_init(9, n)
-    for b in bs:
-        b.barrier_wait(9)
-    for r in range(n * rounds):
-        t = r % n
-        addr = base + (r % lines) * 64
-        bs[t].mutex_lock(0)
-        bs[t].load(addr, 8)
-        bs[t].store(addr, 8)
-        bs[t].mutex_unlock(0)
-    return TraceBatch.from_builders(bs)
+import targets
+from targets import (
+    MOSI, MSI, SHL2_MESI, SHL2_MSI, memory_config as make_config, mutex_rmw,
+)
 
 
 # what a gated run is held to: the golden oracle, or the UNGATED program
@@ -139,9 +101,8 @@ def test_gated_matches_ungated_racy(staged):
     oracle (documented envelope) but gated and ungated programs must be
     BIT-IDENTICAL to each other: gating is mechanism, not policy.  The
     compute gaps between accesses close the home-activity gate."""
-    batch = synthetic.memory_stress_trace(
-        8, n_accesses=80, working_set_bytes=1 << 12,
-        write_fraction=0.4, shared_fraction=0.6, seed=11)
+    batch = targets.stress_trace(8, seed=11, n_accesses=80,
+                                 shared_fraction=0.6)
     kw = dict(dir_stage=True, inner_block=4) if staged else {}
     assert_exact_gated(make_config(8), batch, "ungated", **kw)
 

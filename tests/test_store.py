@@ -256,8 +256,8 @@ class TestGcAndEviction:
 
 
 class TestStoreCli:
-    """tools/store.py drives the same layer; regress rung 11 covers
-    ls/verify/corruption end-to-end, so this pins only the flag
+    """tools/store.py drives the same layer (`TestIntegrityMatrix`,
+    `TestGcAndEviction`), so this pins the exit codes and the flag
     semantics that layer cannot express."""
 
     def _filled(self, tmp_path, n=2):
@@ -281,6 +281,19 @@ class TestStoreCli:
         assert store_main(["--store", st.root, "gc",
                            "--max-bytes", "1"]) == 0
         assert len(st.entries()) == 1   # MRU survivor
+
+    def test_verify_exits_by_the_stores_soundness(self, tmp_path, capsys):
+        from graphite_tpu.tools.store import main as store_main
+
+        st = self._filled(tmp_path)
+        assert store_main(["--store", st.root, "verify"]) == 0
+        pbin = os.path.join(st.root, "entries",
+                            st.entries()[0]["entry_id"], "program.bin")
+        with open(pbin, "r+b") as f:
+            f.seek(64)
+            f.write(b"\xff")           # same length, flipped byte
+        assert store_main(["--store", st.root, "verify"]) == 1
+        assert "checksum" in capsys.readouterr().out
 
     def test_nondirectory_store_is_a_clean_exit_2(self, tmp_path,
                                                   capsys):

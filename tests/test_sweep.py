@@ -132,9 +132,9 @@ def b8_sequential_refs():
 class TestSweepEqualsSequential:
     # the forced-vmap B=8 variant is `slow` (one extra B=8-wide compile):
     # the vmap select-freeze mechanism is already tier-1-pinned at B=2 by
-    # test_vmapped_knob_grid_matches_sequential_static and at B=4 by the
-    # regress --smoke rung; tier-1 pins B=8 through the runner's actual
-    # program choice
+    # test_vmapped_knob_grid_matches_sequential_static and at B=4 by
+    # TestActivityGatesUnderTheSimAxis[solo_vmap]; tier-1 pins B=8
+    # through the runner's actual program choice
     @pytest.mark.parametrize(
         "shard",
         [None, pytest.param(False, marks=pytest.mark.slow)],

@@ -34,19 +34,19 @@ from graphite_tpu.obs.telemetry import SKIP_PREFIX, counts_the_program
 from graphite_tpu.tools._template import config_text
 from graphite_tpu.trace import synthetic
 
+import targets
+
 TILES = 8
 QUANTUM_PS = 1_000_000   # config_text default: 1000 ns lax_barrier
 
 
 def _config(extra: str = ""):
-    return SimConfig(ConfigFile.from_string(config_text(
-        TILES, shared_mem=True, clock_scheme="lax_barrier") + extra))
+    return targets.template_config(TILES, extra, shared_mem=True,
+                                   clock_scheme="lax_barrier")
 
 
 def _trace(seed=7, n=24):
-    return synthetic.memory_stress_trace(
-        TILES, n_accesses=n, working_set_bytes=1 << 12,
-        write_fraction=0.4, shared_fraction=0.5, seed=seed)
+    return targets.stress_trace(TILES, seed=seed, n_accesses=n)
 
 
 def _spec(interval=QUANTUM_PS, s=64, series=None):
