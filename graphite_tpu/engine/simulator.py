@@ -22,6 +22,7 @@ from graphite_tpu.config.simconfig import SimConfig
 from graphite_tpu.engine.state import DeviceTrace, SimState, init_state
 from graphite_tpu.engine.step import EngineParams
 from graphite_tpu.models.dvfs import module_freq_mhz
+from graphite_tpu.models.network_atac import AtacState, atac_counters
 from graphite_tpu.models.network_hop_by_hop import NocState, noc_counters
 from graphite_tpu.models.network_user import UserNetworkParams
 from graphite_tpu.obs.scopes import tagged
@@ -67,6 +68,11 @@ class SimResults:
     # network is emesh_hop_by_hop — the reference's router models keep
     # the same four (`router_model.h:15-79`)
     noc_counters: "dict | None" = None
+    # per-hub event counters of the MEMORY network's ATAC hubs ({name:
+    # int64[2 * n_clusters]}, send hubs then receive hubs:
+    # `models/network_atac.ATAC_COUNTERS`, the same four), None unless
+    # the memory network is atac
+    atac_counters: "dict | None" = None
     # iocoom detailed stall breakdown (`iocoom_core_model.cc:64-77`),
     # None for the simple core model
     detailed_stalls: "dict | None" = None
@@ -209,6 +215,21 @@ class SimResults:
                         f"{int(dc['voltage_mv'][t, d]) / 1000:g} V")
                 out.append(
                     f"    Rejected Requests: {int(dc['errors'][t])}")
+        if self.atac_counters is not None:
+            # the memory network's optical hubs (`network_model_atac.cc`
+            # outputSummary prints each on its hub's tile), a line a
+            # cluster: send hub, receive hub
+            ac = self.atac_counters
+            nc = len(ac["requests"]) // 2
+            out.append("ATAC Hub Summary (MEMORY), send hub / receive hub:")
+            labels = (("Requests", "requests"),
+                      ("Utilization (in cycles)", "utilization_cycles"),
+                      ("Total Contention Delay (in cycles)", "delay_cycles"),
+                      ("Analytical Model Used", "analytical_reads"))
+            for c in range(nc):
+                out.append(f"  Cluster {c}: " + ", ".join(
+                    f"{label} {int(ac[k][c])} / {int(ac[k][nc + c])}"
+                    for label, k in labels))
         if self.energy_pj is not None:
             from graphite_tpu.power.accounting import output_summary
 
@@ -1503,8 +1524,12 @@ class Simulator:
     def _result_parts(state: SimState):
         """Device-side pytrees for the summary counters (shared by run()
         and _results_from_state — keep in one place)."""
+        # (the memory NoC's ATAC hub queues ride whole, as the user
+        # NoC's port queues do below)
         mem_part = (
-            (state.mem.counters, state.mem.func_errors)
+            (state.mem.counters, state.mem.func_errors,
+             state.mem.noc.hub_queues.data
+             if isinstance(state.mem.noc, AtacState) else None)
             if state.mem is not None else None
         )
         ioc_part = (
@@ -1949,12 +1974,12 @@ class Simulator:
         clock = np.asarray(core.clock_ps)
         energy_pj, dvfs_counters = self._power_host(
             power_h, core, net_h, mem_h)
-        mem_counters = None
+        mem_counters = hubs_h = None
         func_errors = 0
         if mem_h is not None:
             import dataclasses as _dc
 
-            counters_h, func_errors_h = mem_h
+            counters_h, func_errors_h, hubs_h = mem_h
             mem_counters = {
                 f.name: np.asarray(getattr(counters_h, f.name))
                 for f in _dc.fields(counters_h)
@@ -1982,6 +2007,8 @@ class Simulator:
             func_errors=func_errors,
             noc_counters=(None if noc_h is None else noc_counters(
                 np.asarray(noc_h), self.params.n_tiles)),
+            atac_counters=(None if hubs_h is None else atac_counters(
+                np.asarray(hubs_h), self.params.mem.net_atac.n_clusters)),
             detailed_stalls=(
                 {k: np.asarray(v) for k, v in ioc_h.items()}
                 if ioc_h is not None else None),

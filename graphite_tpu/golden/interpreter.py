@@ -88,6 +88,10 @@ class GoldenResult:
     # engine's `SimResults.noc_counters`), None unless the user network
     # is emesh_hop_by_hop
     noc_counters: dict | None = None
+    # per-hub event counters of the MEMORY network's ATAC hubs ({name:
+    # int64[2 * n_clusters]}, send hubs then receive hubs: the engine's
+    # `SimResults.atac_counters`), None unless the memory network is atac
+    atac_counters: dict | None = None
     # the engine's `SimResults.energy_pj` ({component: int64[T] pJ}),
     # None unless [general] enable_power_modeling
     energy_pj: dict | None = None
@@ -298,6 +302,17 @@ class _AtacNet(_HbhNet):
 
     # (route() is inherited: _HbhNet already wraps route_bits with the
     # NetPacket header)
+
+    def hub_counters(self) -> dict:
+        """{name: int64[2 * n_clusters]}, send hubs then receive hubs."""
+        from graphite_tpu.models.network_atac import ATAC_COUNTERS
+
+        out = {name: np.zeros(2 * self.p.n_clusters, np.int64)
+               for name, _ in ATAC_COUNTERS}
+        for qid, s in self.q.items():
+            for name in out:
+                out[name][qid] = s[name]
+        return out
 
     def _cluster(self, t):
         p = self.p
@@ -902,4 +917,7 @@ def run_golden(sim_config, batch: TraceBatch,
         core_freq_mhz=np.asarray(core_freq, np.int64),
         noc_counters=(net.port_counters()
                       if net_kind == "emesh_hop_by_hop" else None),
+        atac_counters=(mem.net.hub_counters()
+                       if isinstance(getattr(mem, "net", None), _AtacNet)
+                       else None),
     )

@@ -321,27 +321,31 @@ def mem_net_fanout(mp: MemParams, noc, send_hs, bits: int, t0_ps, enabled):
         from graphite_tpu.time_types import ps_to_cycles
 
         p = mp.net_atac
-        zl = atac_zeroload_ps(p, src, dst, bits, enabled)       # [T, T]
-        flits = max(1, (bits + p.flit_width_bits - 1) // p.flit_width_bits)
-        onet_pair = atac_use_onet(p, src, dst)                  # [T, T]
-        k_onet = (send_hs & onet_pair).sum(axis=1, dtype=I64)
-        fan = send_hs.any(axis=1)
-        t0_cyc = ps_to_cycles(t0_ps, p.freq_mhz)
-        if p.contention_enabled:
-            go = fan & (k_onet > 0) & jnp.asarray(enabled, bool)
-            home = np.arange(T, dtype=np.int32)
-            qid = jnp.where(go, _cluster_of(p, home),
-                            2 * p.n_clusters).astype(jnp.int32)
-            queues, hub_delay = qm.scatter_queue_delay(
-                p.queue, noc.hub_queues, qid, t0_cyc, k_onet * flits, go)
-            noc = noc.replace(hub_queues=queues)
-        else:
-            hub_delay = jnp.zeros(T, I64)
-        rank = jnp.cumsum(send_hs.astype(I64), axis=1) - 1
-        extra_cyc = rank * flits + jnp.where(onet_pair, hub_delay[:, None],
-                                             0)
-        extra_cyc = jnp.where(jnp.asarray(enabled, bool), extra_cyc, 0)
-        arrival = t0_ps[:, None] + zl + cycles_to_ps(extra_cyc, p.freq_mhz)
+        with scope("gt.net.atac.fanout"):
+            zl = atac_zeroload_ps(p, src, dst, bits, enabled)   # [T, T]
+            flits = max(
+                1, (bits + p.flit_width_bits - 1) // p.flit_width_bits)
+            onet_pair = atac_use_onet(p, src, dst)              # [T, T]
+            k_onet = (send_hs & onet_pair).sum(axis=1, dtype=I64)
+            fan = send_hs.any(axis=1)
+            t0_cyc = ps_to_cycles(t0_ps, p.freq_mhz)
+            if p.contention_enabled:
+                go = fan & (k_onet > 0) & jnp.asarray(enabled, bool)
+                home = np.arange(T, dtype=np.int32)
+                qid = jnp.where(go, _cluster_of(p, home),
+                                2 * p.n_clusters).astype(jnp.int32)
+                queues, hub_delay = qm.scatter_queue_delay(
+                    p.queue, noc.hub_queues, qid, t0_cyc, k_onet * flits,
+                    go)
+                noc = noc.replace(hub_queues=queues)
+            else:
+                hub_delay = jnp.zeros(T, I64)
+            rank = jnp.cumsum(send_hs.astype(I64), axis=1) - 1
+            extra_cyc = rank * flits + jnp.where(
+                onet_pair, hub_delay[:, None], 0)
+            extra_cyc = jnp.where(jnp.asarray(enabled, bool), extra_cyc, 0)
+            arrival = t0_ps[:, None] + zl + cycles_to_ps(
+                extra_cyc, p.freq_mhz)
         return noc, arrival
     if mp.net_hbh is None:
         lat = mem_net_latency_ps(mp, src, dst, bits, enabled)

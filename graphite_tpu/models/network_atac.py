@@ -34,12 +34,19 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from graphite_tpu.models.network_hop_by_hop import NOC_COUNTERS
 from graphite_tpu.models.queue_models import (
     QueueArrays, QueueParams, make_queues, scatter_queue_delay,
 )
+from graphite_tpu.obs.scopes import scope
 from graphite_tpu.time_types import cycles_to_ps, ps_to_cycles
 
 I64 = jnp.int64
+
+# the per-hub event counters a run reports (`SimResults.atac_counters`),
+# each with its QueueArrays column: the four the mesh's ports keep, as the
+# reference's hub router models do (`router_model.h:15-79`)
+ATAC_COUNTERS = NOC_COUNTERS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,6 +155,14 @@ def init_atac_state(p: AtacParams) -> AtacState:
     return AtacState(hub_queues=make_queues(2 * p.n_clusters + 1, p.queue))
 
 
+def atac_counters(data, n_clusters: int) -> dict:
+    """{name: int64[2 * n_clusters]}, send hubs then receive hubs, from a
+    fetched `AtacState.hub_queues.data` (host-side: the scratch queue's
+    row is dropped)."""
+    hubs = data[: 2 * n_clusters]
+    return {name: hubs[:, col] for name, col in ATAC_COUNTERS}
+
+
 def _cluster_of(p: AtacParams, tile):
     """2-D sub-mesh cluster id (`getClusterID`)."""
     x = tile % p.mesh_width
@@ -214,10 +229,11 @@ def route_atac(p: AtacParams, state: AtacState, src, dst, bits, clock_ps,
     if p.contention_enabled:
         qid = jnp.where(onet_live, csrc, 2 * p.n_clusters).astype(jnp.int32)
         service = jnp.maximum(flits, 1)  # serialization cycles per packet
-        queues, delay_cyc = scatter_queue_delay(
-            p.queue, state.hub_queues, qid,
-            ps_to_cycles(sendhub_arrive, p.freq_mhz),
-            service, onet_live)
+        with scope("gt.net.atac.hub"):
+            queues, delay_cyc = scatter_queue_delay(
+                p.queue, state.hub_queues, qid,
+                ps_to_cycles(sendhub_arrive, p.freq_mhz),
+                service, onet_live)
         sendhub_done = sendhub_arrive + cyc(delay_cyc + p.send_hub_cycles)
     else:
         queues = state.hub_queues
@@ -228,10 +244,11 @@ def route_atac(p: AtacParams, state: AtacState, src, dst, bits, clock_ps,
     if p.contention_enabled:
         qid2 = jnp.where(onet_live, p.n_clusters + cdst,
                          2 * p.n_clusters).astype(jnp.int32)
-        queues, delay2 = scatter_queue_delay(
-            p.queue, queues, qid2,
-            ps_to_cycles(recvhub_arrive, p.freq_mhz),
-            jnp.maximum(flits, 1), onet_live)
+        with scope("gt.net.atac.hub"):
+            queues, delay2 = scatter_queue_delay(
+                p.queue, queues, qid2,
+                ps_to_cycles(recvhub_arrive, p.freq_mhz),
+                jnp.maximum(flits, 1), onet_live)
         recvhub_done = recvhub_arrive + cyc(delay2 + p.receive_hub_cycles)
     else:
         recvhub_done = recvhub_arrive + cyc(p.receive_hub_cycles)

@@ -18,7 +18,8 @@ to some scope; what is left unscoped is what XLA added on its own
 (copies on the loop carry, parameter moves).
 
 Applied in engine/step.py, memory/engine.py, memory/engine_shl2.py,
-models/iocoom.py, models/network_hop_by_hop.py and parallel/px.py, at the
+models/iocoom.py, models/network_hop_by_hop.py, models/network_atac.py
+and parallel/px.py, at the
 granularity of a layer a `perf_opt` PR would work on — not of a helper
 function.
 """
@@ -51,6 +52,11 @@ SCOPES = (
     "gt.net.hbh.scan",      # emesh_hop_by_hop, inside gt.net.route: path
                             #   masks, max-plus scan, per-cell delays
     "gt.net.hbh.commit",    # ... and the port occupancies' commit
+    "gt.net.atac.hub",      # atac, inside gt.net.route: a unicast's two
+                            #   hub-queue charges (send hub, receive hub)
+    "gt.net.atac.fanout",   # ... and the ATAC leg of mem_net_fanout: the
+                            #   [T, T] zero-load, ONet-pair and rank
+                            #   matrices, the one send-hub charge
     "gt.sync.barrier",
     "gt.sync.mutex_cond",   # mutex + cond block, published cond signals
     "gt.sync.join",

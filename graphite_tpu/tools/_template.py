@@ -14,18 +14,25 @@ def config_text(tiles: int, *, core: str = "simple",
                 scheme: str = "full_map", max_hw_sharers: int = 2,
                 clock_scheme: str = "lax_barrier",
                 dvfs: bool = False, dvfs_domains: str | None = None,
-                power: bool = False) -> str:
-    """`dvfs` writes a `[dvfs]` section: `dvfs_domains` (the reference's
+                power: bool = False,
+                atac_cluster_size: int | None = None) -> str:
+    """`atac_cluster_size` writes a `[network/atac]` section with that
+    `cluster_size` (`carbon_sim.cfg:315-352` ships 4; the ATAC paper's
+    chip is 64 clusters of 16); every other key of the section stays at
+    the engine's default, which mirrors `carbon_sim.cfg`.  `dvfs` writes
+    a `[dvfs]` section: `dvfs_domains` (the reference's
     `<f_ghz, MODULE, ...>` list form, `carbon_sim.cfg:147-155`) or, left
     out, the one domain `carbon_sim.cfg` ships.  `power` writes the
     reference's own `[general] enable_power_modeling = true`: the run
     then integrates per-tile energy (`SimResults.energy_pj`).  Either
     writes `technology_node` under `[general]`, where both of its readers
     look (`models/dvfs.load_levels`, `SimConfig.technology_node`).  With
-    all three at their defaults the text is what it always was."""
+    all four at their defaults the text is what it always was."""
     if dvfs_domains is not None and not dvfs:
         raise ValueError("dvfs_domains needs dvfs=True")
     extra = ""
+    if atac_cluster_size is not None:
+        extra += f"[network/atac]\ncluster_size = {atac_cluster_size}\n"
     if dvfs or power:
         extra += "\n[general]\ntechnology_node = 22\n"
         if power:

@@ -647,6 +647,49 @@ def test_canneal_dvfs_host_batch_compiles(one_chip, tiles):
         _carries_the_int64_entry_store(sim, compiled.as_text())
 
 
+def _atac(tiles):
+    """`atac-ackwise-1024-memstress` (benchmark/configs) at `tiles` tiles:
+    `memory = atac` in clusters of 16 (of 4 at 16 tiles, where 16 would be
+    one cluster and no packet would see a hub), ACKwise_4, the cell's
+    generator, host-driven as the cell."""
+    from graphite_tpu.trace.synthetic import memory_stress_trace
+
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        tiles, shared_mem=True, network="atac", scheme="ackwise",
+        max_hw_sharers=4, atac_cluster_size=16 if tiles >= 64 else 4)))
+    return Simulator(
+        sc, memory_stress_trace(
+            tiles, n_accesses=32, working_set_bytes=32768,
+            write_fraction=0.4, shared_fraction=0.5, seed=7),
+        barrier_host=True)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tiles", [16, 1024])
+def test_atac_host_batch_compiles(one_chip, tiles):
+    """The host-batch program of `memstress1024-atac` asked of the TPU
+    compiler: the hubs' `scatter_queue_delay` (the M/G/1 arm's 32-step
+    integer division, int64 scatters onto the `[2 C + 1, 10]` queue
+    table) twice a unicast and once a fan-out, and the fan-out's three
+    `[T, T]` matrices (`zl`, `onet_pair`, the int64 `cumsum` of `rank`:
+    8 MB each at 1,024 tiles) under the ACKwise broadcast arm.  Both
+    sizes are `slow`, as `canneal_dvfs`'s."""
+    sim = _atac(tiles)
+    mp = sim.params.mem
+    assert mp.net_atac is not None and mp.dir_type == "ackwise"
+    compiled = _compile_host_batch(sim, one_chip)
+    _fits(_report(f"atac-ackwise-{tiles}-host-batch", compiled))
+    text = compiled.as_text()
+    assert "gt.net.atac.hub" in text and "gt.net.atac.fanout" in text
+    if tiles == 1024:
+        # the staged directory holds under a scheme other than full_map:
+        # the landing kernels follow the store's geometry, not the scheme
+        assert mp.net_atac.n_clusters == 64 and mp.dir_stage_cap > 0
+        d = sim.state.mem.directory
+        _entry_words_land_through_the_kernel(text, d.entry.shape)
+        _overlay_fetches_a_way_a_phase(text, d)
+
+
 @pytest.mark.slow
 def test_coh_1024_single_region_compiles(one_chip):
     """1024 tiles, full directory: the single-region lax_barrier

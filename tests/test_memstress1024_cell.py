@@ -187,10 +187,12 @@ READERS = [
 def test_layer_metric_readers(name, own, want, runs):
     entry, = [m for m in MANIFEST["per_layer"] if m["name"] == name]
     # (PR 38 appended `memstress1024-shl2` to the directory counter's,
-    # PR 44 `canneal1024-dvfs` to those its program reports)
+    # PR 44 `canneal1024-dvfs` to those its program reports, PR 48
+    # `memstress1024-atac` to every list that holds this cell)
     assert entry["workloads"][0] == CELL_NAME
     assert set(entry["workloads"]) <= {CELL_NAME, "memstress1024-shl2",
-                                       "canneal1024-dvfs"}
+                                       "canneal1024-dvfs",
+                                       "memstress1024-atac"}
     assert entry["moves"] == "sim_records_per_s"
     sys.path.insert(0, BENCH)
     try:
@@ -203,9 +205,9 @@ def test_layer_metric_readers(name, own, want, runs):
 
 
 @pytest.mark.parametrize("name,scope,from_end", [
-    ("stage_flush_busy_share", "gt.mem.stage_flush", 6),
-    ("entry_land_busy_share", "gt.mem.entry_land", 2),
-    ("stage_overlay_busy_share", "gt.mem.stage_overlay", 1),
+    ("stage_flush_busy_share", "gt.mem.stage_flush", 11),
+    ("entry_land_busy_share", "gt.mem.entry_land", 7),
+    ("stage_overlay_busy_share", "gt.mem.stage_overlay", 6),
 ])
 @pytest.mark.parametrize("scoped,want", [
     (True, 5.0),        # 1.0 s under the scope of 20.0 s busy
@@ -222,11 +224,11 @@ def test_staged_scope_readers(name, scope, from_end, scoped, want):
     program has no such scope."""
     entry, = [m for m in MANIFEST["per_layer"] if m["name"] == name]
     assert entry["workloads"] == [CELL_NAME, "a2a1024-fftskel",
-                                  "canneal1024-dvfs"]
+                                  "canneal1024-dvfs", "memstress1024-atac"]
     assert (entry["moves"], entry["better"]) == ("sim_records_per_s",
                                                  "lower")
     # appended (PR 44's three metrics follow the flush's, PR 45's them,
-    # PR 46's that)
+    # PR 46's that, PR 48's five the overlay's)
     assert [m["name"] for m in MANIFEST["per_layer"]].index(
         name) == len(MANIFEST["per_layer"]) - from_end
     ctx = _ctx()

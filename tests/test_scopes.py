@@ -18,18 +18,20 @@ from graphite_tpu.engine.simulator import Simulator
 from graphite_tpu.memory import engine, engine_shl2
 from graphite_tpu.memory.engine import PHASE_NAMES
 from graphite_tpu.memory.engine_shl2 import SHL2_PHASE_NAMES
-from graphite_tpu.models import iocoom, network_hop_by_hop
+from graphite_tpu.models import iocoom, network_atac, network_hop_by_hop
 from graphite_tpu.obs import TelemetrySpec, scopes
 from graphite_tpu.parallel import px
 from graphite_tpu.tools._template import config_text
 from graphite_tpu.trace.benchmarks import (
     canneal_trace, fft_trace, radix_trace,
 )
+from graphite_tpu.trace.synthetic import memory_stress_trace
 
 TILES = 16
 MSI = "pr_l1_pr_l2_dram_directory_msi"
 SHL2 = "pr_l1_sh_l2_msi"
-SCOPED_MODULES = (step, engine, engine_shl2, iocoom, network_hop_by_hop, px)
+SCOPED_MODULES = (step, engine, engine_shl2, iocoom, network_hop_by_hop,
+                  network_atac, px)
 
 # what each program must contain: everything but the scopes whose code it
 # does not run
@@ -45,11 +47,15 @@ ONLY_POWER = {"gt.energy"}
 # below is one)
 ONLY_STAGED = {"gt.mem.stage_flush", "gt.mem.entry_land",
                "gt.mem.stage_overlay"}
+# the hubs' queue charges and the fan-out's ATAC leg (PR 48): only under
+# `memory = atac`
+ONLY_ATAC = {"gt.net.atac.hub", "gt.net.atac.fanout"}
 MSI_SCOPES = [s for s in scopes.SCOPES
-              if s not in ONLY_SHARDED | ONLY_SHL2 | ONLY_HBH | ONLY_POWER]
+              if s not in ONLY_SHARDED | ONLY_SHL2 | ONLY_HBH | ONLY_POWER
+              | ONLY_ATAC]
 SHL2_SCOPES = [s for s in scopes.SCOPES
                if s not in ONLY_SHARDED | ONLY_HBH | ONLY_POWER
-               | ONLY_STAGED | {"gt.core.iocoom", "gt.obs"}]
+               | ONLY_STAGED | ONLY_ATAC | {"gt.core.iocoom", "gt.obs"}]
 # the memoryless hop-by-hop target (`hbh256-radix`'s, at 16 tiles): the
 # core, the mailboxes, the route with its two halves, the barrier
 HBH_SCOPES = ["gt.quantum", "gt.fetch", "gt.core", "gt.net.mailbox",
@@ -58,6 +64,10 @@ HBH_SCOPES = ["gt.quantum", "gt.fetch", "gt.core", "gt.net.mailbox",
 # the simple core, two DVFS domains and power modelling on
 DVFS_SCOPES = [s for s in MSI_SCOPES if s not in ONLY_STAGED | {
     "gt.core.iocoom", "gt.obs"}] + sorted(ONLY_POWER)
+# `memstress1024-atac`'s target at 16 tiles (four clusters of 4): the
+# private-L2 program with the simple core, ACKwise_4 over `memory = atac`
+ATAC_SCOPES = [s for s in MSI_SCOPES if s not in ONLY_STAGED | {
+    "gt.core.iocoom", "gt.obs"}] + sorted(ONLY_ATAC)
 TWO_DOMAINS = ("<1.0, CORE, L1_ICACHE, L1_DCACHE, L2_CACHE> "
                "<1.0, DIRECTORY, NETWORK_USER, NETWORK_MEMORY>")
 
@@ -76,6 +86,17 @@ def build(program: str) -> Simulator:
             canneal_trace(TILES, footprint_lines=200, swaps_per_tile=2,
                           temperature_steps=2,
                           dvfs_schedule="rotate-levels"),
+            barrier_host=True)
+    if program == "atac":
+        text = config_text(TILES, shared_mem=True, protocol=MSI,
+                           network="atac", scheme="ackwise",
+                           max_hw_sharers=4, atac_cluster_size=4)
+        return Simulator(
+            SimConfig(ConfigFile.from_string(text)),
+            memory_stress_trace(TILES, n_accesses=8,
+                                working_set_bytes=1 << 12,
+                                write_fraction=0.4, shared_fraction=0.5,
+                                seed=7),
             barrier_host=True)
     batch = fft_trace(n_tiles=TILES, points_per_tile=64, use_memory=True)
     if program == "shl2":
@@ -148,6 +169,17 @@ def test_dvfs_power_program_names_its_scopes(found):
     assert set(DVFS_SCOPES) <= found("dvfs")
 
 
+def test_atac_program_names_its_scopes(found):
+    """One test, as `dvfs`'s: both hub charges of a unicast and the
+    fan-out's ATAC leg, inside `gt.net.route`."""
+    assert set(ATAC_SCOPES) <= found("atac")
+
+
+@pytest.mark.parametrize("program", ["msi", "shl2", "hbh", "dvfs"])
+def test_programs_under_another_network_have_no_atac_scope(found, program):
+    assert not ONLY_ATAC & found(program)
+
+
 @pytest.mark.parametrize("program", ["msi", "shl2", "hbh"])
 def test_programs_without_power_modelling_close_no_interval(found, program):
     assert not ONLY_POWER & found(program)
@@ -216,6 +248,12 @@ def test_cache_tag_follows_the_registry():
      "gt.energy/mul", "gt.energy"),
     ("jit(qrun)/gt.quantum/while/body/gt.core/gt.dvfs/cond/branch_1_fun/"
      "select_n", "gt.dvfs"),
+    ("jit(qrun)/gt.quantum/while/body/gt.core/gt.mem.base/"
+     "gt.mem.home_start/cond/branch_1_fun/gt.net.route/gt.net.atac.fanout/"
+     "cumsum", "gt.net.atac.fanout"),
+    ("jit(qrun)/gt.quantum/while/body/gt.core/gt.mem.base/"
+     "gt.mem.requester/cond/branch_1_fun/gt.net.route/gt.net.atac.hub/"
+     "scatter-add", "gt.net.atac.hub"),
     ("jit(run)/while/body/add", None),
     ("", None),
 ])
@@ -237,7 +275,7 @@ def scopes_off(monkeypatch):
         yield
 
 
-@pytest.mark.parametrize("program", ["msi", "shl2", "hbh", "dvfs"])
+@pytest.mark.parametrize("program", ["msi", "shl2", "hbh", "dvfs", "atac"])
 def test_scopes_change_no_equation(monkeypatch, program):
     scoped = build(program).lower()[0]
     with scopes_off(monkeypatch):
