@@ -93,9 +93,13 @@ class QueueParams:
         return self.max_list_size * max(self.min_processing_time, 1)
 
 
-# column layout of QueueArrays.data — one packed [N, 10] tensor so the
-# scatter path (NoC router hops) costs 2 gathers + 4 scatters instead of
-# ~19 per-field kernels (the engine is launch-count-bound; see PERF.md)
+# column layout of QueueArrays.data — one packed [N, 10] tensor.  The
+# lane-per-queue path (`compute_queue_delay`) is elementwise column math
+# and one stack.  `scatter_queue_delay`, where L lanes address N queues
+# freely, reads by one-hot selection over the queue axis and commits by
+# max / sum reductions over the lane axis and one stack of ten [N]
+# columns: no gather, no scatter (a conflicting-index scatter runs one
+# update after another on the TPU)
 COL_QT = 0        # queue_time: end of the busy tail
 COL_WS = 1        # window_start: oldest tracked time (history_*)
 COL_NEWEST = 2    # newest_arrival (M/G/1 moments)
@@ -307,70 +311,94 @@ def compute_queue_delay(
 def scatter_queue_delay(
     params: QueueParams,
     q: QueueArrays,
-    qid: jax.Array,           # int32[L] queue index per lane (may repeat)
+    qid: jax.Array,           # int32[L] queue index per lane
     pkt_time: jax.Array,      # int64[L]
     processing_time: jax.Array,  # int64[L]
     mask: jax.Array,          # bool[L]
 ):
     """Queue delay where lanes address arbitrary (possibly shared) queues.
 
-    Used by the NoC router ports: several packets can traverse the same
-    output port in one vectorized hop step.  Same-call conflicts read the
-    same pre-state (each gets the tail delay as of the call) while
-    occupancy accumulates exactly (scatter-max of arrival then scatter-add
-    of every processing time), so the busy tail — and therefore every
-    *later* packet's delay — stays exact; only simultaneous arrivals at
-    one port underestimate each other's mutual wait.  Bounded, documented
-    divergence vs the reference's strictly serial
-    `computeQueueDelay` (`queue_model.h:20`).
+    Used by the NoC router ports and the ATAC hubs: several packets can
+    traverse the same output port in one vectorized hop step.  Same-call
+    conflicts read the same pre-state (each gets the tail delay as of the
+    call) while occupancy accumulates exactly (the max of the arrivals,
+    then the sum of every processing time), so the busy tail — and
+    therefore every *later* packet's delay — stays exact; only
+    simultaneous arrivals at one port underestimate each other's mutual
+    wait.  Bounded, documented divergence vs the reference's strictly
+    serial `computeQueueDelay` (`queue_model.h:20`).
 
     Lanes must route masked-off traffic to a scratch queue (last index).
+    A live lane whose `qid` lies outside [0, N) addresses no queue: it
+    reads a delay of 0 and commits nothing.
+
+    Lowered dense (PR 49; `_hand/hubq49.py` has the v5e's price of a call
+    against the gather / scatter form it replaced, which
+    `tests/test_queue_models.py` keeps as the reference — the two are
+    BIT-IDENTICAL on every column of every row and on every lane's delay,
+    integer max and add being associative and commutative): a lane reads
+    its queue by one-hot selection over the queue axis, the M/G/1 wait is
+    evaluated once per QUEUE, and every column commits by a max or sum
+    reduction over the lane axis — each reduction a fusion of its own over
+    the [L, N] compare, which is never materialised.  A row no live lane
+    addresses keeps its bits: a masked lane sits on the scratch row with
+    the identities 0 for a sum and for the two time maxima and -2^62 for
+    the window start, and a row no lane addresses at all sees the int64
+    minimum.
     """
     pkt_time = jnp.asarray(pkt_time, I64)
     proc = jnp.maximum(jnp.asarray(processing_time, I64), 1)
-    N = q.data.shape[0]
+    data = q.data
+    N = data.shape[0]
     qid = jnp.where(mask, qid, N - 1).astype(jnp.int32)
+    oh = qid[:, None] == jnp.arange(N, dtype=jnp.int32)[None, :]   # [L, N]
+    lowest = jnp.iinfo(I64).min
 
-    row = q.data[qid]                               # [L, 10] — ONE gather
-    qt = row[:, COL_QT]
+    def of_queue(col):       # [N] -> [L]: the value at the lane's queue
+        return jnp.where(oh, col[None, :], 0).sum(axis=1)
+
+    def lane_sum(val):       # [L] -> [N]
+        return jnp.where(oh, val[:, None], 0).sum(axis=0)
+
+    def lane_max(val):       # [L] -> [N]
+        return jnp.where(oh, val[:, None], lowest).max(axis=0)
+
+    qt, ws, newest = data[:, COL_QT], data[:, COL_WS], data[:, COL_NEWEST]
     if params.kind in ("history_list", "history_tree"):
-        too_old = params.analytical_enabled & (
-            (pkt_time + proc) < row[:, COL_WS])
-        # M/G/1 fallback from the queue's running moments (gathered view)
-        mg1 = _mg1_wait(row[:, COL_N_ARR], row[:, COL_SUM_ST],
-                        row[:, COL_SUM_ST2], row[:, COL_NEWEST])
-        tail = jnp.maximum(qt - pkt_time, 0)
-        delay = jnp.where(too_old, mg1, tail)
+        # M/G/1 fallback from the queue's running moments, once a QUEUE
+        mg1 = _mg1_wait(data[:, COL_N_ARR], data[:, COL_SUM_ST],
+                        data[:, COL_SUM_ST2], newest)              # [N]
+        qt_lane, ws_lane, mg1_lane = of_queue(qt), of_queue(ws), of_queue(mg1)
+        tail = jnp.maximum(qt_lane - pkt_time, 0)
+        too_old = params.analytical_enabled & ((pkt_time + proc) < ws_lane)
+        delay = jnp.where(too_old, mg1_lane, tail)
         in_window = mask & ~too_old
     else:  # basic semantics (no moving average in scatter form)
-        delay = jnp.maximum(qt - pkt_time, 0)
+        delay = jnp.maximum(of_queue(qt) - pkt_time, 0)
         in_window = mask
         too_old = jnp.zeros_like(mask)
 
-    # occupancy: scatter-max the arrival then scatter-add every processing
-    data = q.data.at[qid, COL_QT].max(jnp.where(in_window, pkt_time, 0))
-    data = data.at[qid, COL_QT].add(jnp.where(in_window, proc, 0))
-    qt_new = data[qid, COL_QT]
+    # occupancy: the max of the arrivals, then the sum of every processing
+    busy = lane_sum(jnp.where(in_window, proc, 0))
+    qt_new = jnp.maximum(
+        qt, lane_max(jnp.where(in_window, pkt_time, 0))) + busy
+    # every in-window lane of a queue reads the same new tail, and books
+    # proc >= 1 there: `busy > 0` is "an in-window lane addressed it"
+    ws_new = jnp.maximum(
+        ws, jnp.where(busy > 0, qt_new - params.history_span, -(2**62)))
     end = pkt_time + delay + proc
-    # one combined max-scatter for (window_start, newest_arrival) ...
-    max_vals = jnp.stack([
-        jnp.where(in_window, qt_new - params.history_span, -(2**62)),
-        jnp.where(mask, end, 0),
+    st = lane_sum(jnp.where(mask, proc, 0))
+    count = lane_sum(mask.astype(I64))
+    data = jnp.stack([
+        qt_new,
+        ws_new,
+        jnp.maximum(newest, lane_max(jnp.where(mask, end, 0))),
+        data[:, COL_SUM_ST] + st,
+        data[:, COL_SUM_ST2] + lane_sum(jnp.where(mask, proc * proc, 0)),
+        data[:, COL_N_ARR] + count,
+        data[:, COL_REQS] + count,
+        data[:, COL_UTIL] + st,
+        data[:, COL_DELAY] + lane_sum(jnp.where(mask, delay, 0)),
+        data[:, COL_ANA] + lane_sum((mask & too_old).astype(I64)),
     ], axis=1)
-    data = data.at[qid[:, None],
-                   jnp.asarray([COL_WS, COL_NEWEST])[None, :]].max(max_vals)
-    # ... and one combined add-scatter for the moments + counters
-    add_vals = jnp.stack([
-        jnp.where(mask, proc, 0),
-        jnp.where(mask, proc * proc, 0),
-        mask.astype(I64),
-        mask.astype(I64),
-        jnp.where(mask, proc, 0),
-        jnp.where(mask, delay, 0),
-        (mask & too_old).astype(I64),
-    ], axis=1)
-    data = data.at[
-        qid[:, None],
-        jnp.asarray([COL_SUM_ST, COL_SUM_ST2, COL_N_ARR, COL_REQS,
-                     COL_UTIL, COL_DELAY, COL_ANA])[None, :]].add(add_vals)
     return q.replace(data=data), jnp.where(mask, delay, 0)

@@ -309,6 +309,44 @@ def test_engine_equals_golden_bit_for_bit_where_nothing_races(cluster):
     assert int(ac["delay_cycles"].sum()) > 0
 
 
+def test_queue_charges_hold_no_scatter_and_no_gather():
+    """The engagement counter of `scatter_queue_delay`'s dense lowering is
+    static, so it is a test: at the cell's shape - 1,024 lanes onto 2 x 64
+    + 1 hub queues - a whole `route_atac` (send hub, receive hub) holds no
+    scatter and no gather, and neither does the hop-by-hop fan-out's
+    charge of 1,024 lanes onto 1,024 x 6 + 1 ports."""
+    import jax
+    import jax.numpy as jnp
+
+    from graphite_tpu.analysis.walk import iter_eqns
+    from graphite_tpu.memory.engine import mem_net_fanout
+    from graphite_tpu.models.network_atac import init_atac_state, route_atac
+    from graphite_tpu.models.network_hop_by_hop import init_noc_state
+
+    def indexed(fn, *args):
+        names = {e.primitive.name
+                 for e in iter_eqns(jax.make_jaxpr(fn)(*args))}
+        return sorted(n for n in names
+                      if n.startswith("scatter") or n == "gather")
+
+    T = 1024
+    tiles = jnp.arange(T, dtype=jnp.int32)
+    t0 = jnp.zeros(T, jnp.int64)
+    p = MemParams.from_config(sim_config(T)).net_atac
+    hubs = init_atac_state(p)
+    assert hubs.hub_queues.data.shape == (129, 10)
+    assert indexed(
+        lambda st: route_atac(p, st, tiles, tiles[::-1], 64, t0,
+                              jnp.ones(T, bool), True), hubs) == []
+
+    mp = MemParams.from_config(sim_config(T, network="emesh_hop_by_hop"))
+    ports = init_noc_state(mp.net_hbh)
+    assert ports.queues.data.shape == (6145, 10)
+    assert indexed(
+        lambda st: mem_net_fanout(mp, st, jnp.ones((T, T), bool), 64, t0,
+                                  True), ports) == []
+
+
 # --- the cell's own traffic -------------------------------------------------
 
 def test_host_driven_equals_single_region(pair):

@@ -668,10 +668,11 @@ def _atac(tiles):
 @pytest.mark.parametrize("tiles", [16, 1024])
 def test_atac_host_batch_compiles(one_chip, tiles):
     """The host-batch program of `memstress1024-atac` asked of the TPU
-    compiler: the hubs' `scatter_queue_delay` (the M/G/1 arm's 32-step
-    integer division, int64 scatters onto the `[2 C + 1, 10]` queue
-    table) twice a unicast and once a fan-out, and the fan-out's three
-    `[T, T]` matrices (`zl`, `onet_pair`, the int64 `cumsum` of `rank`:
+    compiler: the hubs' `scatter_queue_delay` (lowered dense since PR 49:
+    the M/G/1 arm's 32-step integer division once a QUEUE, one-hot
+    selections and lane reductions over `[T, 2 C + 1]`, no scatter onto
+    the queue table) twice a unicast and once a fan-out, and the fan-out's
+    three `[T, T]` matrices (`zl`, `onet_pair`, the int64 `cumsum` of `rank`:
     8 MB each at 1,024 tiles) under the ACKwise broadcast arm.  Both
     sizes are `slow`, as `canneal_dvfs`'s."""
     sim = _atac(tiles)
@@ -681,6 +682,9 @@ def test_atac_host_batch_compiles(one_chip, tiles):
     _fits(_report(f"atac-ackwise-{tiles}-host-batch", compiled))
     text = compiled.as_text()
     assert "gt.net.atac.hub" in text and "gt.net.atac.fanout" in text
+    assert not [ln for ln in text.splitlines()
+                if "gt.net.atac." in ln and (" scatter(" in ln
+                                             or " gather(" in ln)]
     if tiles == 1024:
         # the staged directory holds under a scheme other than full_map:
         # the landing kernels follow the store's geometry, not the scheme

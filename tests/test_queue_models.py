@@ -6,14 +6,18 @@ delay, and the M/G/1 fallback reproduces the analytical waiting time
 (`queue_model_m_g_1.cc:18-47`).
 """
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from graphite_tpu.config import ConfigFile
 from graphite_tpu.models.queue_models import (
-    QueueParams, _ceil_div_bounded, _mg1_wait, compute_queue_delay,
-    make_queues,
+    COL_ANA, COL_DELAY, COL_N_ARR, COL_NEWEST, COL_QT, COL_REQS, COL_SUM_ST,
+    COL_SUM_ST2, COL_UTIL, COL_WS, QueueParams, _ceil_div_bounded, _mg1_wait,
+    compute_queue_delay, make_queues, scatter_queue_delay,
 )
 
 
@@ -179,6 +183,128 @@ class TestContentionSweep:
             exact.append(d)
             qt = max(qt, t) + s
         assert delays == exact
+
+
+def _gather_scatter_reference(params, q, qid, pkt_time, proc, mask):
+    """`scatter_queue_delay` as it was lowered up to PR 48, the reference
+    of the dense form: ONE gather of the lanes' rows, the M/G/1 wait per
+    LANE, four scatters with conflicting indices."""
+    N = q.data.shape[0]
+    proc = jnp.maximum(proc, 1)
+    qid = jnp.where(mask, qid, N - 1).astype(jnp.int32)
+    row = q.data[qid]
+    qt = row[:, COL_QT]
+    if params.kind in ("history_list", "history_tree"):
+        too_old = params.analytical_enabled & (
+            (pkt_time + proc) < row[:, COL_WS])
+        mg1 = _mg1_wait(row[:, COL_N_ARR], row[:, COL_SUM_ST],
+                        row[:, COL_SUM_ST2], row[:, COL_NEWEST])
+        delay = jnp.where(too_old, mg1, jnp.maximum(qt - pkt_time, 0))
+        in_window = mask & ~too_old
+    else:
+        delay = jnp.maximum(qt - pkt_time, 0)
+        in_window = mask
+        too_old = jnp.zeros_like(mask)
+    data = q.data.at[qid, COL_QT].max(jnp.where(in_window, pkt_time, 0))
+    data = data.at[qid, COL_QT].add(jnp.where(in_window, proc, 0))
+    qt_new = data[qid, COL_QT]
+    end = pkt_time + delay + proc
+    data = data.at[qid[:, None], jnp.asarray([COL_WS, COL_NEWEST])[None, :]
+                   ].max(jnp.stack([
+                       jnp.where(in_window, qt_new - params.history_span,
+                                 -(2**62)),
+                       jnp.where(mask, end, 0)], axis=1))
+    live = mask.astype(jnp.int64)
+    data = data.at[qid[:, None], jnp.asarray(
+        [COL_SUM_ST, COL_SUM_ST2, COL_N_ARR, COL_REQS, COL_UTIL, COL_DELAY,
+         COL_ANA])[None, :]].add(jnp.stack([
+             live * proc, live * proc * proc, live, live, live * proc,
+             live * delay, (mask & too_old).astype(jnp.int64)], axis=1))
+    return q.replace(data=data), jnp.where(mask, delay, 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _forms(kind):
+    p = QueueParams(kind=kind, max_list_size=100, min_processing_time=1)
+    return p, tuple(jax.jit(functools.partial(form, p)) for form in
+                    (scatter_queue_delay, _gather_scatter_reference))
+
+
+def _traffic(case, rng, step, N, L):
+    """(qid, pkt_time, proc, mask) of one call: lanes on random queues at
+    times around a clock that moves ~150 cycles a call, under a 3% or a
+    70% mask, masked lanes on the scratch row as the callers put them."""
+    mask = rng.random(L) < (0.03 if step % 2 else 0.7)
+    qid = rng.integers(0, N - 1, L)
+    t = np.maximum(150 * step + rng.integers(-400, 50, L), 0)
+    proc = rng.choice([1, 9], L)
+    if case == "one_queue":
+        qid[:] = N // 2
+    elif case == "all_masked" and step >= 10:
+        mask[:] = False
+    elif case == "old_packets":
+        t = np.where(rng.random(L) < 0.5, t // 8, t)
+    elif case == "sweep_1008":
+        proc = np.where(rng.random(L) < 0.1, 1008, proc)
+    qid = np.where(mask, qid, N - 1)
+    return (jnp.asarray(qid, jnp.int32), jnp.asarray(t, jnp.int64),
+            jnp.asarray(proc, jnp.int64), jnp.asarray(mask))
+
+
+class TestDenseArm:
+    """`scatter_queue_delay` is lowered dense since PR 49: reductions over
+    the lane axis against the gather / scatter form it replaced, which is
+    the reference here as the loop version is of a vectorized one."""
+
+    @pytest.mark.parametrize("case", ["mixed", "one_queue", "all_masked",
+                                      "old_packets", "sweep_1008"])
+    @pytest.mark.parametrize("shape", [(129, 1024), (5, 64)],
+                             ids=["129x1024", "5x64"])
+    @pytest.mark.parametrize("kind", ["history_tree", "basic"])
+    def test_equals_the_gather_scatter_reference(self, kind, shape, case):
+        """All ten columns of every row (the scratch row too) and every
+        lane's delay, through 60 chained calls with conflicting indices."""
+        N, L = shape
+        p, (dense, scatter) = _forms(kind)
+        rng = np.random.default_rng(49)
+        d = s = make_queues(N, p)
+        for step in range(60):
+            args = _traffic(case, rng, step, N, L)
+            before = np.asarray(d.data)
+            d, delay_d = dense(d, *args)
+            s, delay_s = scatter(s, *args)
+            np.testing.assert_array_equal(np.asarray(d.data),
+                                          np.asarray(s.data))
+            np.testing.assert_array_equal(np.asarray(delay_d),
+                                          np.asarray(delay_s))
+            if not bool(args[3].any()):
+                # the masked-no-op invariant the phase gates rest on
+                np.testing.assert_array_equal(np.asarray(d.data), before)
+        d = np.asarray(d.data)
+        assert d[:, COL_REQS].sum() > 0
+        if case == "all_masked":
+            assert not bool(args[3].any())
+        if case == "one_queue":
+            assert (d[:, COL_REQS] > 0).sum() == 1
+        if case == "old_packets":
+            # the M/G/1 arm is taken (history_*) / does not exist (basic)
+            assert (d[:, COL_ANA].sum() > 0) == (kind != "basic")
+
+    def test_a_lane_outside_the_table_addresses_no_queue(self):
+        """A live lane whose queue index is not in [0, N) reads a delay of
+        0 and commits nothing (no wrap onto a real row, no clamp)."""
+        p, (dense, _) = _forms("history_tree")
+        q = make_queues(5, p)
+        proc, live = jnp.full(4, 9, jnp.int64), jnp.ones(4, bool)
+        q, _ = dense(q, jnp.asarray([0, 1, 2, 3], jnp.int32),
+                     jnp.full(4, 100, jnp.int64), proc, live)
+        after, delay = dense(q, jnp.asarray([-1, 5, 2, -5], jnp.int32),
+                             jnp.full(4, 101, jnp.int64), proc, live)
+        assert delay.tolist() == [0, 0, 8, 0]
+        rest = [0, 1, 3, 4]
+        np.testing.assert_array_equal(np.asarray(after.data)[rest],
+                                      np.asarray(q.data)[rest])
+        assert int(after.data[2, COL_REQS]) == 2
 
 
 if __name__ == "__main__":
