@@ -225,11 +225,26 @@ def _req_consume(mail, use_pop, r_col):
                                            mail.req_type))
 
 
+def _mesh_hops(w: int, src, dst):
+    """XY hops between tiles of a mesh `w` wide (broadcasts `src` against
+    `dst`; the per-axis `nn_mod` / `nn_div` stay on the host for numpy
+    operands, `intmath`'s contract)."""
+    return (jnp.abs(nn_mod(src, w) - nn_mod(dst, w))
+            + jnp.abs(nn_div(src, w) - nn_div(dst, w)))
+
+
 @scope("gt.net.route")
 def mem_net_latency_ps(mp: MemParams, src, dst, bits: int, enabled):
     """MEMORY-network zero-load latency (`network_model_emesh_hop_counter.cc`
     + receive serialization `network_model.cc:119-149`; ATAC zero-load
-    path costs under `memory = atac`)."""
+    path costs under `memory = atac`).
+
+    `src` / `dst` are taken to the device whatever they are (traced [T]
+    from the unicast callers, numpy `arange`s from the fan-out): hops and
+    flits are cheap int32 / int64 vector arithmetic there.  What must not
+    reach the device is the conversion's int64 DIVISION, and at a static
+    `net_freq_mhz` that divides 1e6 (every shipped target's 1,000 MHz)
+    `cycles_to_ps` has none (`time_types._ps_per_cycle`)."""
     src = jnp.asarray(src)
     dst = jnp.asarray(dst)
     if mp.net_kind == "magic":
@@ -239,13 +254,9 @@ def mem_net_latency_ps(mp: MemParams, src, dst, bits: int, enabled):
         from graphite_tpu.models.network_atac import atac_zeroload_ps
 
         return atac_zeroload_ps(mp.net_atac, src, dst, bits, enabled)
-    w = mp.mesh_width
-    hops = (jnp.abs(nn_mod(src, w) - nn_mod(dst, w))
-            + jnp.abs(nn_div(src, w) - nn_div(dst, w)))
     flits = (bits + mp.flit_width_bits - 1) // mp.flit_width_bits
-    cycles = hops.astype(I64) * mp.hop_latency_cycles + jnp.where(
-        src == dst, 0, flits
-    )
+    cycles = (_mesh_hops(mp.mesh_width, src, dst).astype(I64)
+              * mp.hop_latency_cycles + jnp.where(src == dst, 0, flits))
     cycles = jnp.where(enabled, cycles, 0)
     return cycles_to_ps(cycles, mp.net_freq_mhz)
 
@@ -301,6 +312,15 @@ def mem_net_fanout(mp: MemParams, noc, send_hs, bits: int, t0_ps, enabled):
        queue contention for fan-out copies is NOT charged (documented
        approximation — under the serialized oracle contract those queues
        are empty, so serialized workloads remain exact).
+
+    The [T, T] arrival matrix is built on the device in every open home
+    phase from two `arange`s; each network kind ends in `cycles_to_ps` at
+    the network's STATIC frequency, which multiplies where that frequency
+    divides 1e6 (`time_types._ps_per_cycle`: every shipped target's 1,000
+    MHz), so the phase holds no int64 division over [T, T] - what a chip
+    that emulates int64 paid 0.5 ms a matrix for.  At a static frequency
+    that does not divide 1e6 the division is there, by the reduced
+    constant; at a traced one, by the full ratio.
     """
     T = mp.n_tiles
     src = np.arange(T, dtype=np.int32)[:, None]
@@ -357,10 +377,8 @@ def mem_net_fanout(mp: MemParams, noc, send_hs, bits: int, t0_ps, enabled):
     from graphite_tpu.time_types import ps_to_cycles
 
     p = mp.net_hbh
-    w = p.mesh_width
     flits = max(1, (bits + p.flit_width_bits - 1) // p.flit_width_bits)
-    hops = (jnp.abs(nn_mod(src, w) - nn_mod(dst, w))
-            + jnp.abs(nn_div(src, w) - nn_div(dst, w))).astype(I64)
+    hops = _mesh_hops(p.mesh_width, src, dst).astype(I64)
     step = p.router_delay + p.link_delay
     zl = p.router_delay + (hops + 1) * step + jnp.where(
         src == dst, 0, flits)

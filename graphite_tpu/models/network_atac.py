@@ -276,7 +276,12 @@ def atac_zeroload_ps(p: AtacParams, src, dst, bits, enabled):
     route_atac path costs with zero hub-queue delay — what a packet pays
     on idle hubs (`test_atac.py` pins route_atac == this on fresh state).
     Used for the MEMORY net's zero-load call sites (shl2 DRAM round trip,
-    fan-out per-target legs)."""
+    fan-out per-target legs).
+
+    `src` / `dst` go to the device whatever they are (the fan-out's numpy
+    `arange`s too); every `cyc()` converts at the static `p.freq_mhz`, so
+    where that divides 1e6 the [T, T] legs multiply and hold no int64
+    division (`time_types._ps_per_cycle`)."""
     src = jnp.asarray(src)
     dst = jnp.asarray(dst)
 

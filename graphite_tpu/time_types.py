@@ -20,8 +20,10 @@ Design differences for the TPU build:
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax.numpy as jnp
+import numpy as np
 
 from graphite_tpu.intmath import nn_ceil_div
 
@@ -49,17 +51,57 @@ def _ceil_div(a, b):
     return nn_ceil_div(a, b)
 
 
+def _ps_per_cycle(freq_mhz):
+    """ps per cycle = 1e6 / freq_mhz as a (numerator, denominator) pair.
+
+    A STATIC frequency - a Python or numpy integer, what every config-read
+    `*_freq_mhz` is - is reduced by its gcd with 1e6 here, at trace time:
+    ceil(c*p*g / (q*g)) = ceil(c*p / q) exactly, so a frequency that
+    divides 1e6 (1,000 MHz: every shipped target's network, directory and
+    DRAM clock) leaves a multiplication where the chip would emulate an
+    int64 division, and any other divides by the smaller constant.  A
+    TRACED frequency (a DVFS table riding the carry, a swept knob) cannot
+    be reduced and keeps the full ratio: the choice hangs on what the
+    argument is, not on an option."""
+    if isinstance(freq_mhz, (int, np.integer)):
+        g = math.gcd(PS_PER_CYCLE_NUMERATOR, int(freq_mhz))
+        return PS_PER_CYCLE_NUMERATOR // g, int(freq_mhz) // g
+    return PS_PER_CYCLE_NUMERATOR, freq_mhz
+
+
+def _scale(x, num, den):
+    """ceil(x * num / den) for non-negative x; a STATIC 1 (what
+    `_ps_per_cycle` reduces to) neither multiplies nor divides."""
+    def one(v):
+        return isinstance(v, int) and v == 1
+
+    if not one(num):
+        x = x * num
+    return x if one(den) else _ceil_div(x, den)
+
+
 def cycles_to_ps(cycles, freq_mhz):
     """Latency::toPicosec (`time_types.h:81-86`): ceil(1e6*cycles/freq_mhz).
 
-    Works elementwise on jnp int arrays (int64 recommended) and python ints.
+    Works elementwise on jnp int arrays (int64 recommended), numpy arrays
+    and python ints.  Operands stay where they are: Python ints and numpy
+    arrays are converted on the host (the result is an int / a numpy
+    array), `jax.Array`s on the device.  A static `freq_mhz` is reduced
+    first (`_ps_per_cycle`): bit-identical for every non-negative operand,
+    with no division where it divides 1e6.
     """
-    return _ceil_div(cycles * PS_PER_CYCLE_NUMERATOR, freq_mhz)
+    num, den = _ps_per_cycle(freq_mhz)
+    return _scale(cycles, num, den)
 
 
 def ps_to_cycles(ps, freq_mhz):
-    """Time::toCycles (`time_types.h:104-109`): ceil(ps*freq_mhz/1e6)."""
-    return _ceil_div(ps * freq_mhz, PS_PER_CYCLE_NUMERATOR)
+    """Time::toCycles (`time_types.h:104-109`): ceil(ps*freq_mhz/1e6).
+
+    The inverse ratio of `cycles_to_ps`, reduced the same way for a
+    static `freq_mhz` (at 1,000 MHz: ceil(ps / 1000)); host operands stay
+    on the host."""
+    den, num = _ps_per_cycle(freq_mhz)
+    return _scale(ps, num, den)
 
 
 def ps_to_ns(ps):

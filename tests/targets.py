@@ -72,6 +72,33 @@ ialu = 1
 """))
 
 
+# ---- the memory network alone ----------------------------------------------
+
+
+def mem_net_at(mp, freq_mhz):
+    """`mp` (a `MemParams`) with its MEMORY network clocked at `freq_mhz`,
+    in whichever model carries the clock (static: a Python int)."""
+    import dataclasses
+
+    return dataclasses.replace(
+        mp, net_freq_mhz=freq_mhz,
+        net_hbh=mp.net_hbh and dataclasses.replace(mp.net_hbh,
+                                                   freq_mhz=freq_mhz),
+        net_atac=mp.net_atac and dataclasses.replace(mp.net_atac,
+                                                     freq_mhz=freq_mhz))
+
+
+def fresh_mem_noc(mp):
+    """The MEMORY network's contention state, idle: hub queues under
+    `memory = atac`, port queues under hop_by_hop, None otherwise."""
+    from graphite_tpu.models.network_atac import init_atac_state
+    from graphite_tpu.models.network_hop_by_hop import init_noc_state
+
+    if mp.net_atac is not None:
+        return init_atac_state(mp.net_atac)
+    return None if mp.net_hbh is None else init_noc_state(mp.net_hbh)
+
+
 # ---- traces ---------------------------------------------------------------
 
 

@@ -53,6 +53,8 @@ from graphite_tpu.tools._template import config_text
 from graphite_tpu.trace.schema import FLAG_MEM0_WRITE, TraceBatch, TraceBuilder
 from graphite_tpu.trace.synthetic import memory_stress_trace
 
+from targets import fresh_mem_noc, mem_net_at
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, BENCH)
@@ -345,6 +347,40 @@ def test_queue_charges_hold_no_scatter_and_no_gather():
     assert indexed(
         lambda st: mem_net_fanout(mp, st, jnp.ones((T, T), bool), 64, t0,
                                   True), ports) == []
+
+
+@pytest.mark.parametrize("kind", ["emesh_hop_counter", "atac",
+                                  "emesh_hop_by_hop"])
+def test_fanout_divides_no_matrix_at_a_static_frequency(kind):
+    """The engagement counter of PR 50 is static, so it is a test: at T =
+    1,024 the jaxpr of `mem_net_fanout` holds no `div` / `rem` with a
+    [T, T] result at the cells' static 1,000 MHz (10^6 / f reduced to a
+    multiplication at trace time).  A traced frequency keeps the
+    division."""
+    import jax
+    import jax.numpy as jnp
+
+    from graphite_tpu.analysis.walk import iter_eqns
+    from graphite_tpu.memory.engine import mem_net_fanout
+
+    T = 1024
+    mp = MemParams.from_config(sim_config(T, network=kind))
+    net = mp.net_atac or mp.net_hbh
+    assert (net.freq_mhz if net else mp.net_freq_mhz) == 1000
+    args = (fresh_mem_noc(mp), jnp.ones((T, T), bool),
+            jnp.zeros(T, jnp.int64), jnp.asarray(True))
+
+    def fanout(freq, noc, send, t0, enabled):
+        return mem_net_fanout(mem_net_at(mp, freq), noc, send, mp.req_bits,
+                              t0, enabled)
+
+    def divisions(fn, *operands):
+        return sum(1 for e in iter_eqns(jax.make_jaxpr(fn)(*operands))
+                   if e.primitive.name in ("div", "rem")
+                   and e.outvars[0].aval.shape == (T, T))
+
+    assert divisions(functools.partial(fanout, 1000), *args) == 0
+    assert divisions(fanout, jnp.asarray(1000, jnp.int64), *args) >= 1
 
 
 # --- the cell's own traffic -------------------------------------------------

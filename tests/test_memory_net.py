@@ -29,7 +29,9 @@ from graphite_tpu.golden import run_golden
 from graphite_tpu.trace import synthetic
 from graphite_tpu.trace.schema import TraceBatch, TraceBuilder
 
-from targets import MOSI, MSI, memory_config
+from targets import (
+    MOSI, MSI, fresh_mem_noc, mem_net_at, memory_config,
+)
 
 make_config = functools.partial(memory_config, net="emesh_hop_by_hop")
 
@@ -307,6 +309,53 @@ def test_fanout_single_target_matches_unicast():
                 mp, noc, srcs, dsts, 128, t0, mask, True)
             assert int(arr_fan[src, dst]) == int(arr_uni[src]), (
                 net, src, dst)
+
+
+@pytest.mark.parametrize("freq_mhz", [1000, 870],
+                         ids=["divides-1e6", "does-not"])
+@pytest.mark.parametrize("net", ["emesh_hop_counter", "emesh_hop_by_hop",
+                                 "atac"])
+def test_fanout_matrix_matches_unicast_on_an_idle_network(net, freq_mhz):
+    """The fan-out's WHOLE [T, T] arrival matrix equals the unicast
+    path's zero-load arrival for every (home, target) pair, with the
+    models enabled and disabled, at a frequency that divides 10^6 (since
+    PR 50 the conversion multiplies) and at one that does not (it divides
+    by the reduced constant).  One target a home and a fresh network a call, so
+    no copy has a rank or a queue to wait in: call s sends home h to tile
+    (h + s) % T, and the T calls cover the matrix."""
+    import jax
+    import jax.numpy as jnp
+
+    from graphite_tpu.memory.engine import mem_net_fanout, mem_net_send
+    from graphite_tpu.memory.params import MemParams
+
+    T = 16
+    mp = mem_net_at(MemParams.from_config(make_config(
+        T, net=net, extra=ATAC_EXTRA if net == "atac" else "")), freq_mhz)
+    noc = fresh_mem_noc(mp)
+    homes = np.arange(T, dtype=np.int32)
+    # whole cycles at both frequencies (87,000 / 100,000 a step): the
+    # per-hop unicast path keeps its clock in cycles
+    t0 = jnp.asarray(100_000_000 * (1 + homes), jnp.int64)
+
+    @jax.jit
+    @functools.partial(jax.vmap, in_axes=(0, None))
+    def both(s, enabled):
+        dsts = (homes + s) % T
+        send_hs = homes[None, :] == dsts[:, None]        # [home, target]
+        _, fan = mem_net_fanout(mp, noc, send_hs, 128, t0, enabled)
+        _, uni = mem_net_send(mp, noc, homes, dsts, 128, t0,
+                              jnp.ones(T, bool), enabled)
+        return jnp.take_along_axis(fan, dsts[:, None], axis=1)[:, 0], uni
+
+    for enabled in (True, False):
+        fan, uni = both(jnp.arange(T, dtype=jnp.int32), jnp.asarray(enabled))
+        np.testing.assert_array_equal(np.asarray(fan), np.asarray(uni),
+                                      err_msg=f"{net} enabled={enabled}")
+        if enabled:
+            assert (np.asarray(fan)[1:] > np.asarray(t0)).all()  # s > 0
+        else:
+            assert (np.asarray(fan) == np.asarray(t0)).all()
 
 
 def test_shl2_atac_memory_serialized_bit_exact():

@@ -77,3 +77,64 @@ def test_int64_no_overflow():
     # 10 seconds of simulated time in ps exceeds int32
     t = jnp.asarray(10**13, dtype=jnp.int64)
     assert int(ps_to_ns(t)) == 10**10
+
+
+# --- PR 50: a static frequency's 10^6 / f is reduced at trace time --------
+
+FREQS_MHZ = [500, 1000, 2000, 870, 630, 1500, 3300]   # four divide 10^6
+CYCLES = [0, 1, 7, 999, 12345, 2**31 + 3, 2**40]
+PS = [0, 1, 499, 1001, 10**9 + 7, 2**44 + 5, 2**50]
+
+
+def ref_c2p(c: int, f: int) -> int:
+    return -(-c * 10**6 // f)
+
+
+def ref_p2c(ps: int, f: int) -> int:
+    return -(-ps * f // 10**6)
+
+
+@pytest.mark.parametrize("f", FREQS_MHZ)
+@pytest.mark.parametrize("fn,ref,xs", [(cycles_to_ps, ref_c2p, CYCLES),
+                                       (ps_to_cycles, ref_p2c, PS)],
+                         ids=["cycles_to_ps", "ps_to_cycles"])
+class TestReducedRatio:
+    """The reduced forms equal the Python-integer ceil of the full ratio
+    on every kind of operand, and each operand stays where it was."""
+
+    def test_python_ints(self, fn, ref, xs, f):
+        for x in xs:
+            got = fn(x, f)
+            assert type(got) is int and got == ref(x, f), (x, f)
+
+    def test_numpy_stays_on_the_host(self, fn, ref, xs, f):
+        import jax
+        import numpy as np
+
+        for freq in (f, np.int32(f)):
+            got = fn(np.asarray(xs, np.int64), freq)
+            assert isinstance(got, np.ndarray)
+            assert not isinstance(got, jax.Array)
+            assert got.dtype == np.int64
+            assert got.tolist() == [ref(x, f) for x in xs]
+
+    def test_device_int64(self, fn, ref, xs, f):
+        got = fn(jnp.asarray(xs, jnp.int64), f)
+        assert got.dtype == jnp.int64
+        assert got.tolist() == [ref(x, f) for x in xs]
+
+    def test_traced_frequency_takes_the_division_and_agrees(
+            self, fn, ref, xs, f):
+        """A frequency the program carries (a DVFS table, a swept knob)
+        cannot be reduced: one `div` as before, the static one's values."""
+        import jax
+
+        x = jnp.asarray(xs, jnp.int64)
+        fs = jnp.full(len(xs), f, jnp.int64)
+        names = [e.primitive.name for e in jax.make_jaxpr(fn)(x, fs).eqns]
+        assert names.count("div") == 1
+        assert jax.jit(fn)(x, fs).tolist() == fn(x, f).tolist()
+        static = [e.primitive.name
+                  for e in jax.make_jaxpr(lambda v: fn(v, f))(x).eqns]
+        one = 10**6 % f == 0 if fn is cycles_to_ps else f % 10**6 == 0
+        assert static.count("div") == (0 if one else 1)
