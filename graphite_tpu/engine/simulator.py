@@ -1662,7 +1662,8 @@ class Simulator:
                 core_h, net_h, mem_h, n_quanta, ioc_h,
                 telemetry=self._timeline_host(tel_h),
                 profile=self._profile_host(prof_h),
-                hist=self._hist_host(hist_h), power_h=power_h)
+                hist=self._hist_host(hist_h),
+                power=self._power_host(power_h, core_h, net_h, mem_h))
 
     def write_output(self, results: SimResults,
                      output_dir: str = "results") -> str:
@@ -1964,16 +1965,17 @@ class Simulator:
                 core_h, net_h, mem_h, int(n_quanta), ioc_h,
                 telemetry=self._timeline_host(tel_h),
                 profile=self._profile_host(prof_h),
-                hist=self._hist_host(hist_h), power_h=power_h)
+                hist=self._hist_host(hist_h),
+                power=self._power_host(power_h, core_h, net_h, mem_h))
 
     def _results_host(self, core, net_h, mem_h, n_quanta: int,
                       ioc_h=None, telemetry=None,
                       profile=None, hist=None,
-                      power_h=None) -> SimResults:
-        """Assemble SimResults from already-fetched host arrays."""
+                      power=None) -> SimResults:
+        """Assemble SimResults from already-fetched host arrays;
+        `power` is `_power_host`'s pair."""
         clock = np.asarray(core.clock_ps)
-        energy_pj, dvfs_counters = self._power_host(
-            power_h, core, net_h, mem_h)
+        energy_pj, dvfs_counters = power or (None, None)
         mem_counters = hubs_h = None
         func_errors = 0
         if mem_h is not None:

@@ -72,7 +72,9 @@ TERMINAL_SPANS = ("emit", "reject", "failed")
 # `run` encloses the call; `dispatch` is the call into the compiled runner
 # until it returns its futures; `wait` blocks on the control scalars (made
 # only when a tracer is attached); `fetch` is the device_get; `results`
-# assembles SimResults on the host.
+# assembles SimResults on the host.  A sweep of a power / DVFS target adds
+# `power_demux` inside `results` (`SweepRunner._outcome`: every sim's V/f
+# table rowed and its energy closed).
 RUN_SPANS = ("run", "dispatch", "wait", "fetch", "results")
 # What happens before the first run, each where the work is done:
 # `import` (the package, jax with it); `build_trace` (a generator of

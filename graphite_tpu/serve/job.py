@@ -247,6 +247,14 @@ class JobResult:
     # digest), or None where the program counts none
     phase_skips: "dict | None" = None
     base_skips: "dict | None" = None
+    # a power / DVFS target's scalars (`sweep/runner.power_row`): the
+    # integrated energy summed over tiles (not the telemetry series'),
+    # the DVFS_SET requests that took effect, and the CORE domain's
+    # frequency where the whole job ended on one (a V/f sweep's point);
+    # None where the target reports none
+    energy_pj_total: "int | None" = None
+    dvfs_transitions: "int | None" = None
+    dvfs_level_mhz: "int | None" = None
     # host latency breakdown (round 14) — populated when the service
     # runs with tracing on: {"queue_dwell_s": ..., "batch_execute_s": ...}
     timings: "dict | None" = None
@@ -275,6 +283,10 @@ class JobResult:
                 "n_iterations": self.n_iterations,
                 "func_errors": r.func_errors,
             })
+            for k in ("energy_pj_total", "dvfs_transitions",
+                      "dvfs_level_mhz"):
+                if getattr(self, k) is not None:
+                    row[k] = getattr(self, k)
             if self.telemetry is not None:
                 row["telemetry_samples"] = len(self.telemetry)
                 if "energy_pj" in getattr(self.telemetry, "series", ()):

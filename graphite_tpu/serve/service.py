@@ -848,7 +848,8 @@ class CampaignService:
                     n_quanta=int(out.n_quanta[b]),
                     n_iterations=int(out.n_iterations[b]),
                     idle_iterations=int(out.idle_iterations[b]),
-                    phase_skips=ps, base_skips=bs))
+                    phase_skips=ps, base_skips=bs,
+                    **(out.power[b] if out.power is not None else {})))
         return results
 
     # -- program cache ---------------------------------------------------

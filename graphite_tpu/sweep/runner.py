@@ -93,6 +93,8 @@ class SweepOutcome:
     # nothing (engine/step._quantum_loop) — one a quantum, plus what a
     # sim waited for the rest of its batch
     idle_iterations: "np.ndarray | None" = None
+    # per-sim scalars of a power / DVFS target (`power_row`), or None
+    power: "list[dict] | None" = None
 
     def json_rows(self) -> "list[dict]":
         """One JSON-able dict per sim (the CLI's output lines)."""
@@ -112,8 +114,31 @@ class SweepOutcome:
                 "n_quanta": int(self.n_quanta[b]),
                 "n_iterations": int(self.n_iterations[b]),
                 "func_errors": r.func_errors,
+                **(self.power[b] if self.power is not None else {}),
             })
         return rows
+
+
+def power_row(energy_pj, dvfs_counters, n_dvfs_sets: int,
+              core_domain: int) -> dict:
+    """One sim's energy and operating point as a result line's scalars,
+    from `Simulator._power_host`'s pair.  `energy_pj_total`:
+    `SimResults.energy_pj["total"]` summed over the tiles - the
+    integrated energy (power/accounting.py), NOT the telemetry series
+    `energy_pj` (ROADMAP D20).  `dvfs_transitions`: the trace's DVFS_SET
+    records less the rejected ones.  `dvfs_level_mhz`: the CORE domain's
+    frequency where every tile ended on the same one (a point of a V/f
+    sweep), left out where tiles differ."""
+    row = {}
+    if energy_pj is not None:
+        row["energy_pj_total"] = int(energy_pj["total"].sum())
+    if dvfs_counters is not None:
+        row["dvfs_transitions"] = n_dvfs_sets - int(
+            dvfs_counters["errors"].sum())
+        core = np.unique(dvfs_counters["freq_mhz"][:, core_domain])
+        if len(core) == 1:
+            row["dvfs_level_mhz"] = int(core[0])
+    return row
 
 
 def executable_text(program, inputs: tuple) -> str:
@@ -259,11 +284,14 @@ class SweepRunner:
             layout = "batch" if shard_batch else "solo"
         auto = layout is None
         self._n_dev = n_dev
+        # a power target runs on one device (refused on a mesh, below):
+        # left to itself the runner picks the layout that works
+        power = bool(config.enable_power_modeling)
         if auto:
             # legacy auto guess; a budget-driven promotion to the 2D
             # layout happens below, once the sim's state bytes exist
             layout = ("batch" if n_dev > 1 and B % n_dev == 0
-                      else "solo")
+                      and not power else "solo")
         layout = self._normalize_layout(layout, B, n_dev)
         # host span tracing (`tracer=`, attach_tracer): of construction
         # and placement (obs/trace.py: SETUP_SPANS), which without one go
@@ -277,13 +305,15 @@ class SweepRunner:
                           dict(sim_kwargs))
         self._has_mem = bool(has_mem[0])
         self.sim = self._build_sim(layout)
-        if self.sim.params.energy is not None:
-            # the demux hands no energy leaves on: a campaign would
-            # carry the accumulators and report nothing
+        if self.sim.params.energy is not None and layout != "solo":
+            # as `Simulator(mesh=...)` refuses it: `campaign_state_specs`
+            # knows no `state.energy`, and no mesh layout (the batch-axis
+            # one included) has run with the accumulators
             raise NotImplementedError(
-                "[general] enable_power_modeling: a campaign reports no "
-                "energy_pj yet (SimResults.energy_pj is a solo run's; "
-                "ROADMAP, Cells to add)")
+                "[general] enable_power_modeling on a device mesh "
+                f"(layout {self._layout_name(layout)}): the energy "
+                "accumulators (EnergyState) have no shard spec yet; a "
+                "power target is served by layout='solo' on one device")
         self.mailbox_depth = mailbox_depth
         base = Knobs.from_params(self.sim.params,
                                  self.sim.quantum_ps)
@@ -361,7 +391,7 @@ class SweepRunner:
         # big for ONE device's budget is not a refusal anymore — shard
         # the tile axis (the smallest tile_shards whose per-device
         # block fits), batch shards filling the remaining devices.
-        if auto and self.hbm_budget_bytes and n_dev > 1 \
+        if auto and self.hbm_budget_bytes and n_dev > 1 and not power \
                 and not isinstance(layout, tuple):
             per_sim = self._per_sim_bill()
             if per_sim > self.hbm_budget_bytes:
@@ -906,10 +936,12 @@ class SweepRunner:
         # counter, the rings and the gates' skip counts
         with span("fetch", parent="wait"):
             (nq, deadlock, overflow, done, core_h, net_h, mem_h, ioc_h,
-             tel_h, prof_h, hist_h, iters, idle, skips_h) = jax.device_get((
+             tel_h, prof_h, hist_h, iters, idle, skips_h,
+             power_h) = jax.device_get((
                 nq_d, deadlock_d, state.net.overflow, state.done,
                 state.core, net_part, mem_part, ioc_part, tel_part,
-                prof_part, hist_part, iters_d, idle_d, skips_d))
+                prof_part, hist_part, iters_d, idle_d, skips_d,
+                self.sim._power_part(state)))
         if overflow.any():
             raise MailboxOverflowError(
                 f"mailbox ring overflow in sim(s) "
@@ -930,10 +962,12 @@ class SweepRunner:
         self.last_run_dispatches = 1
         with span("results", parent="fetch"):
             return self._outcome(nq, iters, idle, core_h, net_h, mem_h,
-                                 ioc_h, tel_h, prof_h, hist_h, skips_h)
+                                 ioc_h, tel_h, prof_h, hist_h, skips_h,
+                                 power_h, span)
 
     def _outcome(self, nq, iters, idle, core_h, net_h, mem_h, ioc_h, tel_h,
-                 prof_h, hist_h, skips_h) -> SweepOutcome:
+                 prof_h, hist_h, skips_h, power_h=None,
+                 span=NO_SPANS) -> SweepOutcome:
         """Demux the fetched host arrays into B SimResults."""
         B = self.pack.n_sims
 
@@ -968,15 +1002,31 @@ class SweepRunner:
             # the [B, (T,) H, B'] count ring rode the same ONE batched
             # fetch; the demux serves vmap and shard_map campaigns alike
             hists = demux_hists(self.sim.hist_spec, hist_h)
+        rows = [(row(core_h, b), row(net_h, b),
+                 None if mem_h is None else row(mem_h, b))
+                for b in range(B)]
+        powers, power = [None] * B, None
+        if power_h is not None:
+            from graphite_tpu.trace.schema import Op
+
+            # each sim's V/f table, its energy closed on the host by the
+            # integers a solo run closes with (`_power_host`), and the
+            # scalars of the two a result line carries
+            with span("power_demux", parent="results", sims=B):
+                powers = [self.sim._power_host(row(power_h, b), *rows[b])
+                          for b in range(B)]
+                sets = (self.pack.op == int(Op.DVFS_SET)).sum(axis=(1, 2))
+                power = [power_row(*powers[b], int(sets[b]),
+                                   self.sim.params.dvfs.core_domain)
+                         for b in range(B)]
         results = [
             self.sim._results_host(
-                row(core_h, b), row(net_h, b),
-                None if mem_h is None else row(mem_h, b),
-                int(nq[b]),
+                *rows[b], int(nq[b]),
                 None if ioc_h is None else row(ioc_h, b),
                 telemetry=None if timelines is None else timelines[b],
                 profile=None if profiles is None else profiles[b],
-                hist=None if hists is None else hists[b])
+                hist=None if hists is None else hists[b],
+                power=powers[b])
             for b in range(B)
         ]
         phase_skips = base_skips = None
@@ -998,6 +1048,7 @@ class SweepRunner:
                             phase_skips=phase_skips,
                             base_skips=base_skips,
                             idle_iterations=np.asarray(idle),
+                            power=power,
                             seeds=self.pack.seeds,
                             quantum_valid=self.sim.quantum_ps is not None,
                             timelines=timelines,

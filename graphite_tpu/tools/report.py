@@ -24,12 +24,14 @@ Input kinds, one renderer:
                     (queue_dwell_us vs its batch's occupancy) plus
                     occupancy-bucketed dwell aggregates — the
                     measurement half of latency-aware batching.  When
-                    the file holds per-job RESULT rows that carry
-                    `energy_pj` + `completion_time_ns` (a DVFS
-                    race-to-idle campaign), the same flag renders the
-                    energy-vs-wall trade instead: one scatter row per
-                    operating point (wall, energy, EDP) plus the
-                    Pareto frontier;
+                    the file holds per-job RESULT rows that carry an
+                    energy + `completion_time_ns` (a V/f campaign),
+                    the same flag renders the energy-vs-wall trade
+                    instead: one scatter row per operating point
+                    (wall, energy, EDP) plus the Pareto frontier.  The
+                    energy is a power target's integrated total
+                    (`energy_pj_total`) where the row has it, else
+                    the telemetry series' sum (`energy_pj`);
   --metrics FILE    a Prometheus text exposition written by
                     `tools/serve.py --metrics-out` — renders counters/
                     gauges and histogram summaries (count, sum,
@@ -296,22 +298,33 @@ def trade_curve_rows(rows: "list[dict]") -> "tuple[list, list]":
     return scatter, curve
 
 
+def _row_energy_pj(r: dict):
+    """A result row's energy: the integrated total of a power target
+    (`energy_pj_total`: `SimResults.energy_pj`, closed interval by
+    interval) where the job has it, else the telemetry series' sum
+    (`energy_pj`); None where it has neither."""
+    return r.get("energy_pj_total", r.get("energy_pj"))
+
+
 def energy_trade_rows(rows: "list[dict]") -> "tuple[list, list]":
-    """Per-job result rows (tools/serve.py output lines, or any JSON
-    lines carrying `energy_pj` + `completion_time_ns`) -> (per-config
-    scatter rows, Pareto frontier rows) of the energy-vs-wall trade —
-    the race-to-idle campaign's headline curve.  Each scatter row
-    carries the operating point (the `dvfs_domain_mhz` knob when
-    present), the simulated wall, the priced energy, and their product
+    """Per-job result rows (tools/serve.py or tools/sweep.py output
+    lines, or any JSON lines carrying `energy_pj_total` or `energy_pj`,
+    + `completion_time_ns`) -> (per-config scatter rows, Pareto frontier
+    rows) of the energy-vs-wall trade — the V/f campaign's headline
+    curve.  Each scatter row carries the operating point (the job's
+    `dvfs_level_mhz`, the `dvfs_domain_mhz` knob, when present), the
+    simulated wall, the energy (`_row_energy_pj`), and their product
     (EDP, pJ·ns).  A point is on the frontier when no other point is
     at least as good on BOTH axes and better on one."""
     scatter = []
     for r in rows:
-        if "energy_pj" not in r or "completion_time_ns" not in r:
+        if _row_energy_pj(r) is None or "completion_time_ns" not in r:
             continue
-        s = {"job": r.get("job"),
+        s = {"job": r.get("job", r.get("sim")),
              "wall_ns": int(r["completion_time_ns"]),
-             "energy_pj": int(r["energy_pj"])}
+             "energy_pj": int(_row_energy_pj(r))}
+        if "dvfs_level_mhz" in r:
+            s["dvfs_level_mhz"] = int(r["dvfs_level_mhz"])
         if "dvfs_domain_mhz" in r:
             s["dvfs_domain_mhz"] = tuple(
                 int(x) for x in r["dvfs_domain_mhz"]) \
@@ -338,13 +351,14 @@ def render_trade_curve(path: str, fmt: str) -> "list[str]":
     from graphite_tpu.obs.trace import load_jsonl
 
     rows = load_jsonl(path)
-    if any("energy_pj" in r and "completion_time_ns" in r for r in rows):
+    if any(_row_energy_pj(r) is not None and "completion_time_ns" in r
+           for r in rows):
         # energy-vs-wall mode: per-job result rows from a DVFS campaign
         scatter, frontier = energy_trade_rows(rows)
         if fmt == "json":
             return [json.dumps(r) for r in scatter + frontier]
-        cols = ["job", "dvfs_domain_mhz", "wall_ns", "energy_pj",
-                "edp_pj_ns"]
+        cols = ["job", "dvfs_level_mhz", "dvfs_domain_mhz", "wall_ns",
+                "energy_pj", "edp_pj_ns"]
         frontier_keys = {(f["wall_ns"], f["energy_pj"], f["job"])
                          for f in frontier}
         body = [[str(r.get(c, "-")) for c in cols]
@@ -540,7 +554,9 @@ def main(argv=None) -> int:
                     help="render a span JSON-lines file as the "
                     "latency/occupancy trade curve (per-job queue "
                     "dwell vs batch occupancy + bucketed aggregates); "
-                    "per-job result rows with energy_pj render as the "
+                    "per-job result rows with energy_pj_total (a "
+                    "power target's integrated energy) or energy_pj "
+                    "(the telemetry series) render as the "
                     "energy-vs-wall trade + Pareto frontier instead")
     ap.add_argument("--metrics", metavar="FILE",
                     help="render a Prometheus text exposition "

@@ -54,6 +54,18 @@ breakdown); `--metrics-out metrics.prom` dumps the service's metrics
 registry in Prometheus text format (`tools/report.py --metrics`
 renders it); the trailing summary line always embeds the JSON metrics
 snapshot under "metrics" alongside the round-13 counter keys.
+
+Energy in the result lines: where a job's target models power (`[dvfs]`
++ `[general] enable_power_modeling` in its config text) its line
+carries `energy_pj_total` (the integrated `SimResults.energy_pj["total"]`
+summed over the tiles - NOT the telemetry series' `energy_pj`, which is
+priced per sample), `dvfs_transitions` and, where the whole job ended on
+one CORE frequency, `dvfs_level_mhz`; `tools/report.py --trade-curve`
+plots a job by the integrated total where it has one.  Such a config
+text is a program class of its own (the class key digests the text).  A
+job-spec line builds no such target: it is served from code, as a `Job`
+whose `config` is the power target's (benchmark/drivers/
+campaign_vf_closed.py).
 """
 
 from __future__ import annotations
@@ -175,7 +187,9 @@ def build_job(spec: dict, config_cache: dict):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="campaign service: JSON-lines jobs in, JSON-lines "
-        "results out")
+        "results out (a power target's lines carry energy_pj_total, "
+        "dvfs_transitions, dvfs_level_mhz; it is a program class of its "
+        "own)")
     ap.add_argument("--jobs", help="job-spec JSON-lines file (default: "
                     "stdin)")
     ap.add_argument("--budget-bytes", type=float, default=0,

@@ -15,6 +15,16 @@ Usage:
 Knob axes cross-multiply (grid_points); seeds replicate the grid per
 trace.  `--dryrun` pins JAX to CPU and shrinks the workload — the
 smoke-test shape `tests/test_sweep.py` also exercises.
+
+Energy in the rows: `SweepOutcome.json_rows()` gives every sim of a
+target that models power (`[dvfs]` + `[general] enable_power_modeling`)
+`energy_pj_total` (the integrated `SimResults.energy_pj["total"]` summed
+over the tiles - not the telemetry series' `energy_pj`),
+`dvfs_transitions` and, where the whole sim ended on one CORE frequency,
+`dvfs_level_mhz`.  Such a target is a config text, so a program (class)
+of its own, and runs on the `solo` layout only (a device mesh refuses
+it: the energy accumulators have no shard spec).  This CLI builds no
+such target; `SweepRunner(config, traces)` takes one from code.
 """
 
 from __future__ import annotations
@@ -44,7 +54,9 @@ def parse_knob_axes(specs: "list[str]") -> dict:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="batched simulation campaign (one compile, B sims)")
+        description="batched simulation campaign (one compile, B sims); "
+        "rows of a power target carry energy_pj_total, dvfs_transitions, "
+        "dvfs_level_mhz")
     ap.add_argument("--tiles", type=int, default=16)
     ap.add_argument("--workload", default="memstress",
                     help="memstress (seedable) or a trace/benchmarks name")

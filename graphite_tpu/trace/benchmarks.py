@@ -213,14 +213,24 @@ def blackscholes_trace(n_tiles: int, options_per_tile: int = 512,
 # temperature step's start, as a function of (tile, step).  The levels are
 # the maximal frequencies of `technology/dvfs_levels_22nm.cfg` at
 # `[general] max_frequency` 1.0 (models/dvfs.py keeps the table).
-def _rotate_levels(tile: int, step: int) -> int:
+def _level_mhz(k: int) -> int:
     from graphite_tpu.models.dvfs import _BUILTIN_LEVELS
 
-    levels = _BUILTIN_LEVELS[22]
-    return int(round(1000 * levels[(tile + step) % len(levels)][1]))
+    return int(round(1000 * _BUILTIN_LEVELS[22][k][1]))
 
 
-DVFS_SCHEDULES = {"rotate-levels": _rotate_levels}
+def _rotate_levels(tile: int, step: int) -> int:
+    return _level_mhz((tile + step) % 6)
+
+
+# "level-<k>": every tile asks for the maximal frequency of level k at
+# every step, so a whole run is at ONE operating point (a point of a V/f
+# sweep) and the record count is the same at every k
+DVFS_SCHEDULES = {
+    "rotate-levels": _rotate_levels,
+    **{f"level-{k}": (lambda tile, step, k=k: _level_mhz(k))
+       for k in range(6)},
+}
 
 
 @generator
@@ -243,8 +253,10 @@ def canneal_trace(n_tiles: int, footprint_lines: int = 4096,
     generator's.  "rotate-levels": `f` is the maximal frequency of level
     `(tile + step) mod 6` of the 22 nm table, so every level is in force
     on a sixth of the tiles in every step and every tile changes level at
-    every step.  With the defaults the records are what they always
-    were."""
+    every step.  "level-<k>" (k = 0..5): `f` is level k's maximal
+    frequency on every tile at every step - one point of a V/f sweep,
+    with the same records but `aux1` at every k.  With the defaults the
+    records are what they always were."""
     schedule = None
     if dvfs_schedule is not None:
         if dvfs_schedule not in DVFS_SCHEDULES:

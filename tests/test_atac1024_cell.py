@@ -194,14 +194,21 @@ def test_configuration_is_what_the_manifest_lists():
     cell, = [w for w in MANIFEST["workloads"] if w["name"] == CELL_NAME]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         NAME, "solo-repeat", 1)
-    # appended: the last configuration, the last cell, the last five
-    # metrics, and the last name of every list it joined
-    assert MANIFEST["configs"][-1] is entry
-    assert MANIFEST["workloads"][-1] is cell
-    assert [m["name"] for m in MANIFEST["per_layer"]][-5:] == NEW_METRICS
+    # appended: the eighth configuration, the eighth cell, five metrics
+    # behind `stage_overlay_busy_share`, and the last name of every list
+    # it joined (PR 51's served V/f cell and its three metrics follow)
+    assert MANIFEST["configs"][7] is entry
+    assert MANIFEST["workloads"][7] is cell
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    first = names.index("stage_overlay_busy_share") + 1
+    assert names[first:first + 5] == NEW_METRICS
+    assert names[first + 5:] == [
+        "served_lane_idle_share", "power_demux_ms",
+        "served_dvfs_sets_per_job"]
     joined = [m for m in MANIFEST["per_layer"] + MANIFEST["end_to_end"]
               if CELL_NAME in m.get("workloads", [])]
-    assert all(m["workloads"][-1] == CELL_NAME for m in joined)
+    assert all(m["workloads"][m["workloads"].index(CELL_NAME) + 1:]
+               in ([], ["vfsweep256-canneal"]) for m in joined)
     # ... which are the lists that hold memstress1024-coh, and its own
     assert {m["name"] for m in joined} == set(NEW_METRICS) | {
         m["name"] for m in MANIFEST["per_layer"] + MANIFEST["end_to_end"]

@@ -438,7 +438,9 @@ def test_configuration_is_what_the_manifest_lists():
     added = {"dvfs_busy_share", "energy_busy_share", "dvfs_sets_per_run"}
     for m in MANIFEST["per_layer"]:
         if m["name"] in added:
-            assert m["workloads"] == [CELL_NAME], m["name"]
+            # (the two scope shares are read in PR 51's served V/f cell
+            # too: a list only grows at its end)
+            assert m["workloads"][0] == CELL_NAME, m["name"]
             assert m["moves"] == "sim_records_per_s"
     assert added <= {m["name"] for m in MANIFEST["per_layer"]}
     reference = target.load_reference(NAME)

@@ -647,6 +647,42 @@ def test_canneal_dvfs_host_batch_compiles(one_chip, tiles):
         _carries_the_int64_entry_store(sim, compiled.as_text())
 
 
+@pytest.mark.slow
+def test_vfsweep_256_served_compiles(one_chip):
+    """The program `vfsweep256-canneal` serves (PR 51): B = 4 sims of the
+    256-tile DVFS + power target under `vmap`, its directory STAGED, one
+    job a row of the V/f table.  Asked of the TPU compiler (173 s here;
+    62.5 MB of code, 3.79 GB of temporaries beside 0.785 GB of arguments
+    and 0.778 of outputs): it fits a chip, the DVFS arm and the thirteen
+    other activity gates reach it as `conditional`s (a batched predicate
+    would leave both branches and a select), the energy close is inside,
+    and no Pallas landing is (they are solo-only: under the sim axis the
+    staged paths are XLA's fallbacks, ROADMAP M15)."""
+    from graphite_tpu.analysis.loop_copies import conditionals
+    from graphite_tpu.sweep import SweepRunner
+    from graphite_tpu.trace.benchmarks import canneal_trace
+
+    sc = SimConfig(ConfigFile.from_string(config_text(
+        256, shared_mem=True, dvfs=True, power=True,
+        dvfs_domains="<1.0, CORE, L1_ICACHE, L1_DCACHE, L2_CACHE> "
+        "<1.0, DIRECTORY, NETWORK_USER, NETWORK_MEMORY>")))
+    runner = SweepRunner(
+        sc, [canneal_trace(256, footprint_lines=15625, swaps_per_tile=9,
+                           temperature_steps=5,
+                           dvfs_schedule=f"level-{k}") for k in range(4)],
+        shard_batch=False)
+    assert runner.sim.params.mem.dir_stage_cap == 96
+    compiled = runner._get_runner(1_000_000).lower(
+        *(_shapes(t, one_chip) for t in runner.abstract_inputs())).compile()
+    _fits(_report("vfsweep-256-served-b4", compiled))
+    text = compiled.as_text()
+    conds = conditionals(text)
+    assert len(conds) == 14, [c.op_name for c in conds]
+    assert sum("gt.dvfs/cond" in c.op_name for c in conds) == 1
+    assert "gt.energy" in text and "gt.mem.stage_flush" in text
+    assert "tpu_custom_call" not in text
+
+
 def _atac(tiles):
     """`atac-ackwise-1024-memstress` (benchmark/configs) at `tiles` tiles:
     `memory = atac` in clusters of 16 (of 4 at 16 tiles, where 16 would be

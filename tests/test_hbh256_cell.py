@@ -398,7 +398,7 @@ def test_cell_reports_what_the_manifest_lists():
     # appended: the new cell was the last of every list it joined (PR 44's
     # `canneal1024-dvfs` and PR 48's `memstress1024-atac` follow it), and
     # the four new metrics came last, in this order (PR 43's, PR 44's
-    # three, PR 45's, PR 46's and PR 48's five follow them)
+    # three, PR 45's, PR 46's, PR 48's five and PR 51's three follow)
     assert [w["name"] for w in MANIFEST["workloads"]][5] == CELL_NAME
     assert [c["name"] for c in MANIFEST["configs"]][5] == NAME
     names = [m["name"] for m in MANIFEST["per_layer"]]
@@ -411,10 +411,13 @@ def test_cell_reports_what_the_manifest_lists():
         "dvfs_sets_per_run", "entry_land_busy_share",
         "stage_overlay_busy_share", "atac_hub_busy_share",
         "atac_fanout_busy_share", "hub_wait_cycles_per_packet",
-        "hub_fallback_share", "dir_broadcasts_per_record"]
+        "hub_fallback_share", "dir_broadcasts_per_record",
+        "served_lane_idle_share", "power_demux_ms",
+        "served_dvfs_sets_per_job"]
     for m in MANIFEST["per_layer"] + MANIFEST["end_to_end"]:
         if CELL_NAME in m.get("workloads", []):
             later = m["workloads"][m["workloads"].index(CELL_NAME) + 1:]
             assert later in ([], ["canneal1024-dvfs"],
-                             ["canneal1024-dvfs", "memstress1024-atac"]), \
-                m["name"]
+                             ["canneal1024-dvfs", "memstress1024-atac"],
+                             ["canneal1024-dvfs", "memstress1024-atac",
+                              "vfsweep256-canneal"]), m["name"]

@@ -72,7 +72,21 @@ TWO_DOMAINS = ("<1.0, CORE, L1_ICACHE, L1_DCACHE, L2_CACHE> "
                "<1.0, DIRECTORY, NETWORK_USER, NETWORK_MEMORY>")
 
 
-def build(program: str) -> Simulator:
+def build(program: str):
+    if program == "dvfs-served":
+        # `vfsweep256-canneal`'s program at 16 tiles: the `dvfs` target
+        # under a sim axis (two jobs of a V/f sweep), its directory staged
+        # as the 256-tile cell's is
+        from graphite_tpu.sweep.runner import SweepRunner
+
+        text = config_text(TILES, shared_mem=True, protocol=MSI, dvfs=True,
+                           dvfs_domains=TWO_DOMAINS, power=True)
+        return SweepRunner(
+            SimConfig(ConfigFile.from_string(text)),
+            [canneal_trace(TILES, footprint_lines=200, swaps_per_tile=2,
+                           temperature_steps=2,
+                           dvfs_schedule=f"level-{k}") for k in (0, 5)],
+            dir_stage=True)
     if program == "hbh":
         text = config_text(TILES, network="emesh_hop_by_hop")
         return Simulator(SimConfig(ConfigFile.from_string(text)),
@@ -119,7 +133,9 @@ def op_names(sim: Simulator) -> set:
     """The location names of the program `run()` dispatches, lowered and
     not compiled: its `op_name` paths (`jit(fn)/...`; relative to the
     body under shard_map) among file, function and argument names."""
-    if sim.mesh is not None:
+    if not isinstance(sim, Simulator):          # a SweepRunner
+        lowered = sim._get_runner(4096).lower(*sim.abstract_inputs())
+    elif sim.mesh is not None:
         from graphite_tpu.parallel.mesh import make_shard_map_runner
 
         lowered = make_shard_map_runner(
@@ -167,6 +183,14 @@ def test_dvfs_power_program_names_its_scopes(found):
     """One test, not one a name: the program is lowered once (tier-1's
     clock, ISSUE 44)."""
     assert set(DVFS_SCOPES) <= found("dvfs")
+
+
+def test_served_dvfs_power_program_keeps_its_scopes_under_vmap(found):
+    """The V/f campaign's program (PR 51): under the sim axis a scope's
+    name is wrapped (`vmap(gt.dvfs)`) and still resolves, the DVFS arm
+    and the energy close among them, with the staged directory's three."""
+    assert set(DVFS_SCOPES) | ONLY_STAGED <= found("dvfs-served")
+    assert not ONLY_ATAC & found("dvfs-served")
 
 
 def test_atac_program_names_its_scopes(found):

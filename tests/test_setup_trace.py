@@ -390,7 +390,8 @@ def test_the_entry_metrics_are_listed_as_the_issue_orders():
         if m["name"] == "batch_place_ms":
             assert (m["layer"], m["moves"], m["workloads"]) == (
                 "service - serve/service.py, sweep/runner.py",
-                "sim_records_per_s", ["campaign64-dram"])
+                "sim_records_per_s",
+                ["campaign64-dram", "vfsweep256-canneal"])
             continue
         assert (m["layer"], m["moves"], m["better"], m["workloads"]) == (
             "entry - Simulator.warmup()", "setup_s", "lower", cells)
