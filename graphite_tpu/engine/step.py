@@ -1366,8 +1366,7 @@ def _quantum_loop(params, trace, state, qend, trace_base=None, px=IDENT,
                     flush_live, 0, FLUSH_SKIPPED))
             with scope("gt.mem.stage_flush"):
                 state = state.replace(mem=mem.replace(
-                    directory=dir_stage_flush(mem.directory, flush_live,
-                                              px=px)))
+                    directory=dir_stage_flush(mem.directory, flush_live)))
         # what the quantum goes on for: the block's whole progress under
         # lax_p2p, else its last iteration's
         return (state, progress, idle, progress if whole_blocks else last_adv,

@@ -805,7 +805,7 @@ def _stage_overlay_rows(d, sets, rows):
     return out.reshape(T, K, DW * SW)
 
 
-def dir_stage_flush(d, live=None, px: ParallelCtx = IDENT):
+def dir_stage_flush(d, live=None):
     """Apply the staging rows to the big sharers store and reset them.
 
     `live` (a scalar bool, or None = forced live) gates the whole flush
@@ -819,14 +819,15 @@ def dir_stage_flush(d, live=None, px: ParallelCtx = IDENT):
     and the choice between them: a scatter-add of row deltas, a pass
     over the store whatever was staged (19 ms at 1,024 tiles), and where
     the program is lowered for a TPU a kernel that moves the staged
-    slots' tiles alone."""
+    slots' tiles alone; under a campaign's sim axis its batching rule
+    folds the sims into the lane axis and makes the same choice of the
+    folded store (`row_landing._fold_sims`), so nobody tells it."""
     if d.skey is None:
         return d
 
     def flush(stores):
         sharers, skey, sn = stores
-        return (row_landing.flush_staged(sharers, skey, d.sval, sn,
-                                         sim_axis=px.sim_axis),
+        return (row_landing.flush_staged(sharers, skey, d.sval, sn),
                 jnp.full_like(skey, -1), jnp.zeros_like(sn))
 
     sharers, skey, sn = _run_if(live, flush, (d.sharers, d.skey, d.sn))
@@ -1122,13 +1123,14 @@ def _entry_rows(entry, lanes, sets):
     return cols[..., :dw].astype(I64) | (cols[..., dw:].astype(I64) << 32)
 
 
-def _entry_land(entry, t_e, s_all, w_all, ed_all, px: ParallelCtx):
+def _entry_land(entry, t_e, s_all, w_all, ed_all):
     """The folded plan's deltas `ed_all` added to the words (t_e, s_all,
     w_all), distinct or out of bounds (`t_e` = Tl: folded away): a plan
     into the store.  int64: one scatter-add, a pass over the store on a
     TPU.  u32 words: `row_landing.apply_entry` - where the program is
-    lowered for a TPU and has no sim axis a kernel that moves the tiles
-    of the plan's nonzero words alone, a lane's phases in turn."""
+    lowered for a TPU a kernel that moves the tiles of the plan's nonzero
+    words alone, a lane's phases in turn; a campaign's sims folded into
+    its lanes by `apply_entry`'s own batching rule."""
     if entry.dtype != U32:
         return entry.at[t_e, s_all, w_all].add(
             ed_all, mode="drop", unique_indices=True)
@@ -1136,8 +1138,7 @@ def _entry_land(entry, t_e, s_all, w_all, ed_all, px: ParallelCtx):
     with scope("gt.mem.entry_land"):
         return row_landing.apply_entry(
             entry, s_all.reshape(-1, Tl), w_all.reshape(-1, Tl),
-            ed_all.reshape(-1, Tl), (t_e < Tl).reshape(-1, Tl),
-            sim_axis=px.sim_axis)
+            ed_all.reshape(-1, Tl), (t_e < Tl).reshape(-1, Tl))
 
 
 def _dir_apply_merged(d, px: ParallelCtx, packs, live=None):
@@ -1184,7 +1185,7 @@ def _dir_apply_merged(d, px: ParallelCtx, packs, live=None):
         s_all = jnp.concatenate(sets)
         w_all = jnp.concatenate(way)
         ed_all = jnp.concatenate(ed)
-        entry = _entry_land(stores[0], t_e, s_all, w_all, ed_all, px)
+        entry = _entry_land(stores[0], t_e, s_all, w_all, ed_all)
         if staged:
             return (entry,)
         t_s = jnp.concatenate([jnp.where(dr, Tl, t) for dr in drop_s])

@@ -3,7 +3,11 @@ name: the Pallas TPU kernels behind `engine_shl2._dir_apply_rows`
 (`land_rows`: row deltas, one row a slab), `engine.dir_stage_flush`
 (`flush_staged`: the private-L2 staging table) and `engine._entry_land`
 (`apply_entry`, at the end: the entry store's TWO forms, int64 | u32 words,
-chosen by `state.entry_as_words`) - each with its XLA form and the choice.
+chosen by `state.entry_as_words`) - each with its XLA form and the choice,
+made from the lowering target and the operands' shapes alone.  The two
+private-L2 entry points take a campaign's sim axis as more lanes
+(`_fold_sims`: `[B, T, ...]` is `[B * T, ...]`, and the choice is made of
+the folded shape); `land_rows` under a sim axis is still the scatter.
 
 An XLA scatter-add of 1,024 rows of 1 KB onto the `u32[1048576, 256]`
 sharers store is in place and still costs what streaming the 1.07 GB
@@ -345,16 +349,49 @@ def land_staged(sharers, skey, sval, sn, *, lanes_per_step=LANES_PER_STEP,
     return flat.reshape(sharers.shape)
 
 
-def flush_staged(sharers, skey, sval, sn, *, sim_axis=None):
+def _fold_sims(*lane_axis):
+    """A landing, whose operand i has the lanes on axis `lane_axis[i]` and
+    whose result has them on axis 0, given a batching rule for a
+    campaign's sim axis (sweep/runner.py): B sims of T lanes are a store
+    of B * T lanes.  Under `vmap` every operand goes to `[B, ...]`
+    (broadcast if it is not batched), the sim axis is merged into the lane
+    axis - of the stores a merge of leading dimensions, a bitcast under
+    the (8, 128) tiling - the landing itself is asked again, of the FOLDED
+    shapes, and the result's lanes are split back.  Without a `vmap` the
+    landing is its body."""
+    def fold(x, batched, axis, n_sims):
+        if not batched:
+            x = jnp.broadcast_to(x, (n_sims, *x.shape))
+        x = jnp.moveaxis(x, 0, axis)
+        return x.reshape(*x.shape[:axis], n_sims * x.shape[axis + 1],
+                         *x.shape[axis + 2:])
+
+    def with_rule(solo):
+        landing = jax.custom_batching.custom_vmap(solo)
+
+        @landing.def_vmap
+        def rule(n_sims, in_batched, *operands):
+            out = landing(*(fold(x, b, axis, n_sims) for x, b, axis
+                            in zip(operands, in_batched, lane_axis)))
+            return out.reshape(n_sims, -1, *out.shape[1:]), True
+
+        return landing
+
+    return with_rule
+
+
+@_fold_sims(0, 0, 0, 0)
+def flush_staged(sharers, skey, sval, sn):
     """The sharers store after a staging flush, in place: where the
-    program is lowered for a TPU, has no sim axis and the table's shape
-    allows it (`can_land_staged`: 512 tiles and up under the default
-    directory), the kernel, priced by the slots staged; everywhere else
-    the scatter-add, priced by the store."""
+    program is lowered for a TPU and the table's shape allows it
+    (`can_land_staged`: 512 tiles and up under the default directory), the
+    kernel, priced by the slots staged; everywhere else the scatter-add,
+    priced by the store.  Under a campaign's sim axis the same is asked of
+    the B * T folded lanes (`_fold_sims`): four sims of 256 tiles take the
+    1,024-lane kernel."""
     n_lanes, n_sets, width = sharers.shape
     way_width = sval.shape[2]
-    if sim_axis is not None or not can_land_staged(
-            n_lanes, n_sets, width // way_width, way_width):
+    if not can_land_staged(n_lanes, n_sets, width // way_width, way_width):
         return scatter_staged(sharers, skey, sval)
     return jax.lax.platform_dependent(
         sharers, skey, sval, sn, tpu=land_staged,
@@ -582,14 +619,16 @@ def land_entry(store, sets, way, delta, live, *,
     return flat.reshape(store.shape)
 
 
-def apply_entry(store, sets, way, delta, live, *, sim_axis=None):
+@_fold_sims(0, 1, 1, 1, 1)
+def apply_entry(store, sets, way, delta, live):
     """The u32 entry words with a plan landed, in place: where the
-    program is lowered for a TPU, has no sim axis and the store's shape
-    allows it (`can_land_entry`), the kernel, priced by the live words;
-    everywhere else `scatter_entry`, priced by the store."""
+    program is lowered for a TPU and the store's shape allows it
+    (`can_land_entry`), the kernel, priced by the live words; everywhere
+    else `scatter_entry`, priced by the store.  Under a campaign's sim
+    axis the same is asked of the B * T folded lanes, the `[B, P, T]` plan
+    as `[P, B * T]` (`_fold_sims`)."""
     n_lanes, rows, n_sets = store.shape
-    if sim_axis is not None or not can_land_entry(n_lanes, n_sets,
-                                                  rows // 2):
+    if not can_land_entry(n_lanes, n_sets, rows // 2):
         return scatter_entry(store, sets, way, delta, live)
     return jax.lax.platform_dependent(
         store, sets, way, delta, live, tpu=land_entry,

@@ -105,10 +105,10 @@ class DirectoryArrays:
      - u32[T, 2 * DW, DS], the same words as the halves already lay (ways
        on sublanes, sets on lanes; a lane's low words in rows 0..DW-1,
        its high words in rows DW..2*DW-1): where a program is lowered for
-       a TPU with no sim axis, a kernel lands the plan's live words' tiles
-       alone (`row_landing.land_entry`); elsewhere an XLA gather-and-set
-       on the same words.  The int64 word exists only in the gathered
-       rows."""
+       a TPU, a kernel lands the plan's live words' tiles alone
+       (`row_landing.land_entry`; a campaign's sims as more lanes);
+       elsewhere an XLA gather-and-set on the same words.  The int64 word
+       exists only in the gathered rows."""
 
     # packed (tag, dstate, owner, nsharers) word per entry — layout above
     entry: jax.Array     # int64[T, DS, DW] | uint32[T, 2*DW, DS]

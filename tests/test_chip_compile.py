@@ -396,7 +396,6 @@ def test_stage_flush_alone_is_in_place_at_the_cells_shape(topo, chips):
     from graphite_tpu.memory.state import DirectoryArrays
     from graphite_tpu.obs.scopes import scope
     from graphite_tpu.parallel.mesh import TILE_AXIS, _shard_map
-    from graphite_tpu.parallel.px import ParallelCtx
 
     T, DS, DW, SW, C = 1024, 1024, 16, 32, 96
     d = DirectoryArrays(
@@ -406,11 +405,10 @@ def test_stage_flush_alone_is_in_place_at_the_cells_shape(topo, chips):
         sval=jax.ShapeDtypeStruct((T, C, SW), jnp.uint32),
         sn=jax.ShapeDtypeStruct((T,), jnp.int32))
     live = jax.ShapeDtypeStruct((), jnp.bool_)
-    px = ParallelCtx(axis=TILE_AXIS, n_dev=chips)
 
     def flush(d, live):
         with scope("gt.mem.stage_flush"):
-            return dir_stage_flush(d, live, px=px)
+            return dir_stage_flush(d, live)
 
     if chips == 1:
         where = SingleDeviceSharding(topo.devices[0])
@@ -656,8 +654,9 @@ def test_vfsweep_256_served_compiles(one_chip):
     and 0.778 of outputs): it fits a chip, the DVFS arm and the thirteen
     other activity gates reach it as `conditional`s (a batched predicate
     would leave both branches and a select), the energy close is inside,
-    and no Pallas landing is (they are solo-only: under the sim axis the
-    staged paths are XLA's fallbacks, ROADMAP M15)."""
+    and since PR 52 both private-L2 landing kernels are: the sims fold
+    into 1,024 lanes (`row_landing._fold_sims`), so the entry words'
+    flat `u32[33554432]` copy-scatter-reshape is gone."""
     from graphite_tpu.analysis.loop_copies import conditionals
     from graphite_tpu.sweep import SweepRunner
     from graphite_tpu.trace.benchmarks import canneal_trace
@@ -680,7 +679,14 @@ def test_vfsweep_256_served_compiles(one_chip):
     assert len(conds) == 14, [c.op_name for c in conds]
     assert sum("gt.dvfs/cond" in c.op_name for c in conds) == 1
     assert "gt.energy" in text and "gt.mem.stage_flush" in text
-    assert "tpu_custom_call" not in text
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 2, kernels
+    assert sum("dir_entry_landing" in ln and "u32[32768,1024]" in ln
+               for ln in kernels) == 1
+    assert sum("dir_stage_landing" in ln and "u32[1048576,128]" in ln
+               for ln in kernels) == 1
+    assert "u32[33554432]" not in text
 
 
 def _atac(tiles):

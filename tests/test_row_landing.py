@@ -32,9 +32,9 @@ the XLA flush (`scatter_staged`) it replaces on the chip, the same way -
 - a staged private-L2 run (16 tiles, a 128-way directory so that a sharers
   row is lane-aligned) with every flush through the kernel ends in the
   state the XLA flush ends in;
-- at 16 tiles with the default directory, and under a sim axis, the flush's
-  jaxpr is the parent's letter for letter; at a lane-aligned shape the
-  default arm is, and the CPU lowers it and a TPU target the kernel.
+- at 16 tiles with the default directory the flush's choice is the
+  parent's scatter letter for letter; at a lane-aligned shape the default
+  arm is, and the CPU lowers it and a TPU target the kernel.
 
 The private-L2 directory's entry words (PR 45): `land_entry` on the u32
 form of the entry store against the int64 scatter-add it replaces -
@@ -46,13 +46,24 @@ form of the entry store against the int64 scatter-add it replaces -
   the gate open and closed; and `scatter_entry`, the XLA form on the same
   words, lands the same;
 - `_dir_apply_merged` on an int64 store is the parent's, letter for
-  letter; on u32 words under a sim axis it is the XLA form with no choice
-  of a platform, and with none the CPU lowers that form and a TPU target
-  the kernel, named under `gt.mem.entry_land`;
+  letter; on u32 words the CPU lowers the XLA form and a TPU target the
+  kernel, named under `gt.mem.entry_land`;
 - a staged private-L2 run (16 tiles, the default directory: the smallest
   geometry that takes the u32 form) ends in the same statistics and the
   same `line_census` whichever form its state was built in, and with every
   landing through the kernel.
+
+A campaign's sim axis (PR 52): `flush_staged` and `apply_entry` carry a
+batching rule (`row_landing._fold_sims`) that folds B sims of T lanes into
+B * T lanes and asks the solo choice of the folded shapes -
+
+- under `vmap` at a lane-aligned folded shape a TPU target lowers exactly
+  one kernel and no scatter on the store, the CPU the scatter and no
+  kernel; at a folded shape the kernel refuses, the scatter on both;
+- `vmap` of either entry point through the interpreted kernel (B = 2 and 4,
+  the gate open and closed, an operand that is not batched) lands bit for
+  bit what the per-sim scatter form lands and what the solo kernel lands on
+  the operands folded by hand.
 """
 
 import dataclasses
@@ -200,7 +211,10 @@ def _landing_args(width):
 
 @pytest.mark.parametrize("width,px", [
     (8, IDENT),                                          # 16 tiles
-    (W, dataclasses.replace(IDENT, sim_axis="sims")),    # a served batch
+    # a served batch: the SHARED-L2 landing has not adopted the fold of
+    # the two private-L2 landings (PR 52; no cell serves a shared-L2
+    # batch), so under a sim axis it still takes the scatter-add
+    (W, dataclasses.replace(IDENT, sim_axis="sims")),
 ], ids=["16-tiles", "sim-axis"])
 def test_fallback_is_the_parents_scatter_letter_for_letter(width, px):
     args = _landing_args(width)
@@ -323,8 +337,7 @@ def _flush_through(form, monkeypatch):
     through `form` (the chooser stepped over)."""
     monkeypatch.setattr(
         row_landing, "flush_staged",
-        lambda sharers, skey, sval, sn, sim_axis=None: form(
-            sharers, skey, sval, sn))
+        lambda sharers, skey, sval, sn: form(sharers, skey, sval, sn))
     return jax.jit(dir_stage_flush)
 
 
@@ -407,15 +420,15 @@ def test_staged_engine_through_the_kernel_ends_where_the_xla_flush_ends(
     res_plain = plain.run()
     calls = []
 
-    def through_kernel(sharers, skey, sval, sn, sim_axis=None):
-        calls.append((sharers.shape, sim_axis))
+    def through_kernel(sharers, skey, sval, sn):
+        calls.append(sharers.shape)
         return row_landing.land_staged(sharers, skey, sval, sn,
                                        interpret=True)
 
     monkeypatch.setattr(row_landing, "flush_staged", through_kernel)
     kernel = _staged_sim()
     res_kernel = kernel.run()
-    assert calls and set(calls) == {((16, 8, 128), None)}
+    assert calls and set(calls) == {(16, 8, 128)}
     assert plain.last_base_skips["flush"] < plain.last_n_iterations // 4
     assert int(np.asarray(plain.state.mem.directory.sharers).any())
     assert res_plain.mem_counters["dir_accesses"].sum() > 0
@@ -499,18 +512,66 @@ def _flush_args(ways, way_width):
         sn=jnp.zeros(FT, jnp.int32)), jnp.asarray(True)
 
 
-@pytest.mark.parametrize("ways,way_width,px", [
-    (16, 1, IDENT),                                       # 16 tiles
-    (FDW, FSW, dataclasses.replace(IDENT, sim_axis="sims")),
+def _letters(jaxpr):
+    # (a branch or a call takes its constants as inputs, a traced function
+    # closes over them: the `;` between the two lists moves)
+    return " ".join(str(jaxpr).replace(";", " ").split())
+
+
+def _forms(fn, *args):
+    """[(scatters, kernels)] of `fn` lowered for the CPU and for a TPU."""
+    scatter, kernel = '"stablehlo.scatter"(', "@tpu_custom_call("
+    traced = jax.jit(fn).trace(*args)
+    return [(text.count(scatter), text.count(kernel)) for text in (
+        traced.lower().as_text(),
+        traced.lower(lowering_platforms=("tpu",)).as_text())]
+
+
+def _batch(tree, sims):
+    return jax.tree.map(lambda x: jnp.stack([x] * sims), tree)
+
+
+@pytest.mark.parametrize("ways,way_width,sims", [
+    (16, 1, None),                                        # 16 tiles
+    (FDW, FSW, 2),                                        # a served batch
 ], ids=["16-tiles", "sim-axis"])
 def test_flush_fallback_is_the_parents_flush_letter_for_letter(
-        ways, way_width, px):
-    args = _flush_args(ways, way_width)
-    got = jax.make_jaxpr(lambda d, live: dir_stage_flush(d, live, px=px))(
-        *args)
-    want = jax.make_jaxpr(_parents_flush)(*args)
-    assert str(got) == str(want)
-    assert "pallas_call" not in str(got) and "platform_index" not in str(got)
+        ways, way_width, sims):
+    from graphite_tpu.analysis import iter_eqns
+
+    d, live = _flush_args(ways, way_width)
+    if sims is None:
+        # the choice is a `custom_vmap_call` whose body, letter for
+        # letter, is the parent's scatter-add of row deltas: no kernel, no
+        # choice of a platform, on the CPU and for a TPU one scatter
+        got = jax.make_jaxpr(dir_stage_flush)(d, live)
+        call, = [e for e in iter_eqns(got)
+                 if e.primitive.name == "custom_vmap_call"]
+        parent = jax.make_jaxpr(
+            lambda sharers, skey, sval, sn: _parents_scatter(
+                sharers, skey, sval, ways))(d.sharers, d.skey, d.sval, d.sn)
+        assert _letters(call.params["call"].jaxpr) == _letters(parent.jaxpr)
+        assert ("pallas_call" not in str(got)
+                and "platform_index" not in str(got))
+        assert _forms(dir_stage_flush, d, live) == [(1, 0), (1, 0)]
+        return
+
+    # under `vmap` the rule folds the sims into the lanes and asks again:
+    # at a lane-aligned folded shape a TPU target lowers ONE kernel and no
+    # scatter on the store, the CPU the scatter and no kernel ...
+    def served(d, live):
+        return jax.vmap(dir_stage_flush, in_axes=(0, None),
+                        axis_name="sims")(d, live)
+
+    assert row_landing.can_land_staged(sims * FT, FDS, ways, way_width)
+    assert _forms(served, _batch(d, sims), live) == [(1, 0), (0, 1)]
+    folded = str(jax.make_jaxpr(served)(_batch(d, sims), live))
+    assert f"u32[{sims * FT},{FDS},{ways * way_width}]" in folded
+    # ... and at a folded shape the kernel refuses (16 tiles' rows are not
+    # lane-aligned), the scatter on the folded store on both
+    narrow, _ = _flush_args(16, 1)
+    assert not row_landing.can_land_staged(sims * FT, FDS, 16, 1)
+    assert _forms(served, _batch(narrow, sims), live) == [(1, 0), (1, 0)]
 
 
 def test_lane_aligned_flush_follows_the_lowering_target():
@@ -535,12 +596,7 @@ def test_lane_aligned_flush_follows_the_lowering_target():
         lambda sharers, skey, sval, sn: _parents_scatter(
             sharers, skey, sval, FDW))(d.sharers, d.skey, d.sval, d.sn)
 
-    def letters(jaxpr):
-        # (a branch takes its constants as inputs, a traced function
-        # closes over them: the `;` between the two lists moves)
-        return " ".join(str(jaxpr).replace(";", " ").split())
-
-    assert letters(choice.params["branches"][-1].jaxpr) == letters(
+    assert _letters(choice.params["branches"][-1].jaxpr) == _letters(
         parent.jaxpr)
     scatter, kernel = '"stablehlo.scatter"(', "@tpu_custom_call("
     cpu = traced.lower().as_text()
@@ -733,11 +789,21 @@ def test_int64_store_keeps_the_parents_landing_letter_for_letter():
 def test_entry_landing_follows_the_lowering_target():
     d, packs, live = _apply_args(
         row_landing.entry_words(_entry_plan("random")[0]))
+    # a served batch: the rule folds the two sims' lanes and plans, and
+    # the folded shape takes the kernel where a TPU is the target
     sims = dataclasses.replace(IDENT, sim_axis="sims")
-    served = str(jax.make_jaxpr(
-        lambda d, packs, live: _dir_apply_merged(d, sims, packs, live))(
-        d, packs, live))
-    assert "pallas_call" not in served and "platform_index" not in served
+
+    def served(d, packs, live):
+        return jax.vmap(
+            lambda d, packs: _dir_apply_merged(d, sims, packs, live),
+            axis_name="sims")(d, packs)
+
+    assert _forms(served, _batch(d, 2), _batch(packs, 2), live) == [
+        (1, 0), (0, 1)]
+    folded = str(jax.make_jaxpr(served)(_batch(d, 2), _batch(packs, 2),
+                                        live))
+    assert (f"u32[{2 * ET},{2 * EDW},{EDS}]" in folded
+            and f"i32[{EP},{2 * ET}]" in folded)
     traced = jax.jit(
         lambda d, packs, live: _dir_apply_merged(d, IDENT, packs, live)
     ).trace(d, packs, live)
@@ -772,8 +838,8 @@ def test_entry_engine_ends_the_same_in_either_form_and_through_the_kernel(
 
     calls = []
 
-    def through_kernel(store, sets, way, delta, live, sim_axis=None):
-        calls.append((store.shape, sets.shape, sim_axis))
+    def through_kernel(store, sets, way, delta, live):
+        calls.append((store.shape, sets.shape))
         return row_landing.land_entry(store, sets, way, delta, live,
                                       interpret=True)
 
@@ -781,7 +847,7 @@ def test_entry_engine_ends_the_same_in_either_form_and_through_the_kernel(
         m.setattr(row_landing, "apply_entry", through_kernel)
         kernel = _entry_sim()
         res_kernel = kernel.run()
-    assert calls and set(calls) == {((16, 32, 1024), (3, 16), None)}
+    assert calls and set(calls) == {((16, 32, 1024), (3, 16))}
 
     # the same geometry with its state built in the int64 form: the
     # parent's program
@@ -812,3 +878,114 @@ def test_entry_engine_ends_the_same_in_either_form_and_through_the_kernel(
     for a, b in zip(jax.tree.leaves(words.state),
                     jax.tree.leaves(kernel.state)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# a campaign's sim axis: B sims of T lanes are B * T lanes (PR 52)
+# ---------------------------------------------------------------------------
+
+# (sims, the gate, the operand that is NOT batched)
+FOLD_CASES = [(2, None, None), (4, None, None), (2, True, None),
+              (2, False, None), (4, None, "shared")]
+FOLD_IDS = ["B2", "B4", "B2-open", "B2-closed", "B4-unbatched-operand"]
+SCATTER_STAGED, SCATTER_ENTRY = (row_landing.scatter_staged,
+                                 row_landing.scatter_entry)
+
+
+def _fold(x, axis=0):
+    """`[B, ...]` with the sim axis merged into a sim's lane axis `axis`,
+    by hand."""
+    x = jnp.moveaxis(x, 0, axis)
+    return x.reshape(*x.shape[:axis], -1, *x.shape[axis + 2:])
+
+
+@pytest.mark.parametrize("sims,gate,shared", FOLD_CASES, ids=FOLD_IDS)
+def test_vmapped_flush_through_the_kernel_lands_what_each_sim_lands(
+        sims, gate, shared, monkeypatch):
+    """`vmap(flush_staged)`: on the CPU the choice lowers its default arm,
+    `scatter_staged`; with that arm swapped for the interpreted kernel the
+    batch lands through `land_staged` on the FOLDED operands."""
+    dirs = [_staged_dir(case) for case in (
+        "random", "repeated_key", "two_sets_one_group", "full_lane")[:sims]]
+    sharers = jnp.stack([d.sharers + U32(b) for b, d in enumerate(dirs)])
+    skey = jnp.stack([d.skey for d in dirs])
+    sn = jnp.stack([d.sn for d in dirs])
+    # (the table's values: one `[T, C, SW]` array for every sim where the
+    # case asks for an operand that is not batched)
+    sval = dirs[0].sval if shared else jnp.stack(
+        [d.sval + U32(b) for b, d in enumerate(dirs)])
+    want = jnp.stack([SCATTER_STAGED(
+        sharers[b], skey[b], sval if shared else sval[b])
+        for b in range(sims)])
+    assert bool((want != sharers).any())
+    lanes = []
+
+    def through_kernel(sharers, skey, sval):
+        lanes.append(sharers.shape[0])
+        return row_landing.land_staged(
+            sharers, skey, sval, jnp.sum(skey >= 0, axis=1, dtype=jnp.int32),
+            interpret=True)
+
+    monkeypatch.setattr(row_landing, "scatter_staged", through_kernel)
+    live = None if gate is None else jnp.asarray(gate)
+
+    @jax.jit
+    def served(sharers, skey, sval, sn, live):
+        return jax.vmap(
+            lambda sharers, skey, sval, sn: _run_if(
+                live, lambda s: row_landing.flush_staged(s, skey, sval, sn),
+                sharers),
+            in_axes=(0, 0, None if shared else 0, 0), axis_name="sims")(
+            sharers, skey, sval, sn)
+
+    got = served(sharers, skey, sval, sn, live)
+    assert lanes[-1] == sims * FT       # (the rule's trace; FT: the body's)
+    np.testing.assert_array_equal(got, sharers if gate is False else want)
+    by_hand = row_landing.land_staged(
+        _fold(sharers), _fold(skey),
+        _fold(jnp.stack([sval] * sims) if shared else sval), _fold(sn),
+        interpret=True).reshape(sharers.shape)
+    np.testing.assert_array_equal(by_hand, want)
+
+
+@pytest.mark.parametrize("sims,gate,shared", FOLD_CASES, ids=FOLD_IDS)
+def test_vmapped_entry_landing_through_the_kernel_lands_what_each_sim_lands(
+        sims, gate, shared, monkeypatch):
+    """`vmap(apply_entry)` the same way: the `[B, P, T]` plans reach
+    `land_entry` as `[P, B * T]`, the stores as B * T lanes - and where the
+    STORE is the operand that is not batched, every sim lands its plan on
+    a copy of it."""
+    plans = [_entry_plan(case) for case in (
+        "random", "three_phases_one_tile", "carry", "all_live")[:sims]]
+    words = [row_landing.entry_words(p[0]) for p in plans]
+    store = words[0] if shared else jnp.stack(words)
+    plan = [jnp.stack([p[i] for p in plans]) for i in range(1, 5)]
+    want = jnp.stack([SCATTER_ENTRY(
+        store if shared else store[b], *(x[b] for x in plan))
+        for b in range(sims)])
+    lanes = []
+
+    def through_kernel(store, sets, way, delta, live):
+        lanes.append((store.shape[0], sets.shape))
+        return row_landing.land_entry(store, sets, way, delta, live,
+                                      interpret=True)
+
+    monkeypatch.setattr(row_landing, "scatter_entry", through_kernel)
+    live = None if gate is None else jnp.asarray(gate)
+
+    @jax.jit
+    def served(store, plan, live):
+        return jax.vmap(
+            lambda store, *plan: _run_if(
+                live, lambda s: row_landing.apply_entry(s, *plan), store),
+            in_axes=(None if shared else 0, 0, 0, 0, 0), axis_name="sims")(
+            store, *plan)
+
+    got = served(store, plan, live)
+    assert lanes[-1] == (sims * ET, (EP, sims * ET))
+    whole = jnp.stack([store] * sims) if shared else store
+    np.testing.assert_array_equal(got, whole if gate is False else want)
+    by_hand = row_landing.land_entry(
+        _fold(whole), *(_fold(x, 1) for x in plan),
+        interpret=True).reshape(whole.shape)
+    np.testing.assert_array_equal(by_hand, want)

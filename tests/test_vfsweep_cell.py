@@ -17,7 +17,8 @@ row of the 22 nm V/f table, batches of four that mix rows.  So:
   itself, at 256 tiles and for all 12 jobs (the reference's stored golden
   numbers: the arithmetic is checked here, the 64-tile re-run is `slow`);
 - a STAGED geometry under the sim axis (the cell's: `dir_stage` forced at
-  16 tiles here) == solo, energy included;
+  16 tiles here) == solo, energy included, and a served staged batch
+  through both landing kernels (interpreted) lands B * T lanes;
 - a power target is its own program class, a device mesh is refused with
   the missing shard spec named, and the rows / envelopes / trade curve
   carry the integrated energy;
@@ -281,8 +282,14 @@ def test_stored_envelope_numbers(k):
 
 def test_staged_geometry_under_the_sim_axis_equals_solo():
     """The cell's directory is STAGED (sharers store 128 MB at 256
-    tiles): `flush_staged`, `apply_entry` and the staging overlay's fetch
-    run as XLA's fallbacks under `vmap`.  Forced at 16 tiles here."""
+    tiles): under `vmap` the batching rule of `flush_staged` and
+    `apply_entry` folds the sims into the lane axis and makes the solo
+    choice of the folded store (`row_landing._fold_sims`: the kernels the
+    1,024-tile solo cells run, at the cell's 4 x 256 lanes on a TPU); the
+    staging overlay's fetch is a batched gather.  Forced at 16 tiles
+    here, where the folded 32 lanes' sharers rows are not lane-aligned
+    and the CPU lowers the scatter forms anyway: the arithmetic is the
+    solo run's, bit for bit."""
     sc = sim_config(16)
     traces = [level(16, 244, k) for k in (0, 5)]
     runner = SweepRunner(sc, traces, dir_stage=True)
@@ -302,6 +309,64 @@ def test_staged_geometry_under_the_sim_axis_equals_solo():
     assert [r["energy_pj_total"] for r in rows] == [
         int(r.energy_pj["total"].sum()) for r in out.results]
     assert [r["dvfs_transitions"] for r in rows] == [80, 80]
+
+
+# 128 sets x 128 ways a slice: at 16 tiles (one sharer word a way) a
+# sharers row of 128 words and entry words in whole (8, 128) tiles - the
+# smallest directory BOTH landing kernels take
+BOTH_KERNELS = "[dram_directory]\ntotal_entries = 16384\nassociativity = 128\n"
+
+
+def test_served_staged_batch_through_the_kernels_lands_b_times_t_lanes(
+        monkeypatch):
+    """A served staged batch with both landings through the interpreted
+    kernels (on the CPU each choice lowers its default arm, the scatter
+    form: swapped for the kernel, as `tests/test_row_landing.py` does for
+    the solo engine) ends where the scatter forms end, every statistic,
+    and both kernels were handed B * T lanes."""
+    import jax.numpy as jnp
+
+    from graphite_tpu.config import ConfigFile, SimConfig
+    from graphite_tpu.memory import row_landing
+    from graphite_tpu.tools._template import config_text
+
+    text = dict(CELL["config_text"])
+    text.pop("tiles")
+    sc = SimConfig(ConfigFile.from_string(
+        config_text(16, **text) + BOTH_KERNELS))
+    traces = [level(16, 244, k) for k in (0, 5)]
+    plain = SweepRunner(sc, traces, dir_stage=True, inner_block=4)
+    d = plain.sim.state.mem.directory
+    assert d.entry.shape == (16, 256, 128) and d.entry.dtype == np.uint32
+    assert d.sharers.shape == (16, 128, 128)
+    want = plain.run()
+    calls = set()
+
+    def staged(sharers, skey, sval):
+        calls.add(("staged", sharers.shape))
+        return row_landing.land_staged(
+            sharers, skey, sval, jnp.sum(skey >= 0, axis=1, dtype=jnp.int32),
+            interpret=True)
+
+    def entry(store, sets, way, delta, live):
+        calls.add(("entry", store.shape, sets.shape))
+        return row_landing.land_entry(store, sets, way, delta, live,
+                                      interpret=True)
+
+    monkeypatch.setattr(row_landing, "scatter_staged", staged)
+    monkeypatch.setattr(row_landing, "scatter_entry", entry)
+    got = SweepRunner(sc, traces, dir_stage=True, inner_block=4).run()
+    # (the 16-lane shapes are `custom_vmap` tracing the solo body before
+    # its rule runs; what is lowered is the folded call)
+    assert calls == {("staged", (16, 128, 128)), ("staged", (32, 128, 128)),
+                     ("entry", (16, 256, 128), (3, 16)),
+                     ("entry", (32, 256, 128), (3, 32))}
+    for a, b in zip(want.results, got.results):
+        assert int(a.mem_counters["dir_accesses"].sum()) > 0
+        a, b = digest.statistics(a), digest.statistics(b)
+        assert sorted(a) == sorted(b)
+        for name in a:
+            np.testing.assert_array_equal(a[name], b[name], err_msg=name)
 
 
 # --- the program class, the mesh, the rows ------------------------------
